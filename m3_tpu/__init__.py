@@ -52,11 +52,3 @@ if _os.environ.get("M3_TPU_RACEWATCH", "") not in ("", "0"):
     from .utils import racewatch as _racewatch
 
     _racewatch.install()
-
-if _os.environ.get("M3_TPU_JAX_PLATFORM"):
-    # Hard platform override (e.g. "cpu" for hermetic service runs/CI).
-    # The env var JAX_PLATFORMS alone does not stop out-of-tree plugin
-    # backends from initializing; the config update does.
-    import jax as _jax
-
-    _jax.config.update("jax_platforms", _os.environ["M3_TPU_JAX_PLATFORM"])

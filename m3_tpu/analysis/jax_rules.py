@@ -75,8 +75,7 @@ def _static_argnames(call: ast.Call) -> Set[str]:
 
 _JIT_NAMES = ("jax.jit", "jit", "jax.pjit", "pjit")
 _TRANSFORM_NAMES = _JIT_NAMES + (
-    "jax.shard_map", "shard_map", "jax.vmap", "vmap", "jax.pmap", "pmap",
-    "jax.experimental.shard_map.shard_map")
+    "jax.shard_map", "shard_map", "jax.vmap", "vmap", "jax.pmap", "pmap")
 
 
 def _is_jax_jit(node: ast.AST) -> bool:
@@ -589,9 +588,7 @@ class MeshSpecRule(Rule):
     dirs = ("parallel", "ops")
     requires_import = "jax"
 
-    _SHARD_MAP_NAMES = ("shard_map", "shard_map_compat", "jax.shard_map",
-                        "exp_shard_map",
-                        "jax.experimental.shard_map.shard_map")
+    _SHARD_MAP_NAMES = ("shard_map", "jax.shard_map")
     _COLLECTIVES = ("psum", "pmin", "pmax", "pmean", "all_gather",
                     "axis_index", "ppermute")
     _MESH_NAMES = ("Mesh", "jax.sharding.Mesh", "jax.make_mesh")
@@ -764,8 +761,8 @@ class UnguardedPallasDispatchRule(Rule):
 
     1. The enclosing builder must take an `interpret` parameter and
        forward it into the call (`interpret=interpret`). A hard-coded
-       `interpret=False` breaks every non-TPU environment (CI, the CPU
-       fallback protocol); a hard-coded `True` means real hardware never
+       `interpret=False` breaks every non-TPU environment (the CPU test
+       platform); a hard-coded `True` means real hardware never
        gets a compiled kernel; a missing kwarg silently defaults to
        compiled-only. The parameter seam is what lets the dispatch gate
        (`M3_TPU_PALLAS`) pick per-backend behavior from OUTSIDE the
@@ -843,7 +840,7 @@ class UnguardedPallasDispatchRule(Rule):
                 yield self.finding(
                     mod, call,
                     "pallas_call without interpret= forwards: the kernel "
-                    "can never run on CPU (tests, fallback protocol); "
+                    "can never run on CPU (the test platform); "
                     "thread an `interpret` parameter through the builder")
                 continue
             if isinstance(kw.value, ast.Constant):

@@ -178,7 +178,7 @@ class Connection:
 
 
 class RemoteError(Exception):
-    """Server-side failure relayed to the caller (not a transport error)."""
+    """Server-side failure passed back to the caller (not a transport error)."""
 
 
 class HostClient:
@@ -297,7 +297,7 @@ class HostClient:
             raise
         except DeadlineExceeded as e:
             # conn.call already dropped a desynced stream (reply never
-            # read); a server-relayed deadline frame leaves the stream
+            # read); a server-sent deadline frame leaves the stream
             # synced and poolable. The breaker records a failure when
             # the HOST burned the budget; a pre-I/O expiry (tagged by
             # Deadline.check — budget died before any bytes went out)
@@ -790,7 +790,7 @@ class Session:
         replica of a shard -> {host_id: {series_id: {tags, blocks}}}.
 
         A peer that fails in one of the typed transport ways (connection
-        death, expired budget, deliberate shed) or relays a server-side
+        death, expired budget, deliberate shed) or returns a server-side
         error is SKIPPED — counted in the `session.peers` scope and
         reported into `errors` (host_id -> message) when the caller passes
         a dict — so bootstrap/repair see partial coverage instead of a

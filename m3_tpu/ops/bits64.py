@@ -113,12 +113,12 @@ def neg64(a):
 def _shl32(x, s):
     """x << s with s possibly 0..32; s>=32 yields 0 (XLA shift is UB at 32)."""
     s = jnp.asarray(s, U32)
-    return jnp.where(s >= 32, jnp.zeros_like(x), x << jnp.minimum(s, U32(31)))
+    return jnp.where(s >= 32, jnp.zeros_like(x), x << (s & U32(31)))
 
 
 def _shr32(x, s):
     s = jnp.asarray(s, U32)
-    return jnp.where(s >= 32, jnp.zeros_like(x), x >> jnp.minimum(s, U32(31)))
+    return jnp.where(s >= 32, jnp.zeros_like(x), x >> (s & U32(31)))
 
 
 def shl64(a, s):

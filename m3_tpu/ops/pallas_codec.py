@@ -18,9 +18,10 @@ Three kernels, one dispatch gate:
   decode_core   the decode point scan with the stream words VMEM-resident
                 per lane tile. Reuses tsz._decode_header/_decode_step
                 verbatim — the wire format has ONE definition — swapping
-                only the bit readers for VMEM sublane gathers. Emits the
-                same dt/tick/value planes as tsz._decode_core so the
-                fused decode consumers are route-agnostic.
+                only the bit readers for a dense masked select over the
+                word column (Mosaic's sublane gather serves one vreg).
+                Emits the same dt/tick/value planes as tsz._decode_core
+                so the fused decode consumers are route-agnostic.
 
   hash_words    batched murmur3-32 over the hash_batch buffer layout
                 (zero-padded little-endian u32 rows), lane-parallel with
@@ -30,16 +31,22 @@ Template lineage (ops/pallas_window.py): these kernels inherit its VMEM
 tiling half — lru_cached `_build(..., interpret)` seams, BlockSpec lane
 tiles, interpret-mode parity on CPU — but NOT its strided-window
 scheduling half: the codec loops walk a data-dependent bit cursor, so
-there is no static window stride to unroll and no
-MAX_UNROLL_STEPS-style lane-alignment workaround here; dynamic sublane
-gathers/stores do the addressing instead.
+there is no static window stride to unroll. Rows are read from the Refs
+at a dynamic sublane (`ref[pl.ds(j, 1), :]`) and per-lane addressing is
+a masked select, never `lax.dynamic_slice`/`take_along_axis` on loaded
+values: the installed Pallas TPU lowering has no rule for the former and
+Mosaic refuses the latter past one vreg.
 
 Dispatch: `enabled()` gates every call site (M3_TPU_PALLAS=1 opt-in
-off-TPU where kernels run in interpret mode; on-by-default on a real TPU
-backend; =0 is the kill switch — Mosaic support for the sublane gathers
-is unverified without hardware, and the XLA paths remain complete).
-Interpret-mode parity against the XLA route and ops/ref_codec.py is
-asserted by the oracle suite named below and by scripts/codec_smoke.py.
+off-TPU where kernels run in interpret mode; on-by-default on a TPU
+backend; =0 is the kill switch — the XLA paths remain complete). What
+proves the kernels, at which depth:
+  tests/test_codec_pallas.py   interpret-mode bit-identity vs the XLA
+                               route and ops/ref_codec.py (the algebra)
+  tests/test_pallas_lowering.py  they build for a v5e from the CPU
+                               (lowering rules + Mosaic legalization)
+  chip_smoke.py                compiled on the chip: bit-identity vs the
+                               XLA twins and ref_codec at served shapes
 """
 
 from __future__ import annotations
@@ -58,7 +65,8 @@ from .bits64 import U32
 
 I32 = jnp.int32
 
-# Interpret-mode parity against the XLA path and ref_codec lives in:
+# Interpret-mode parity against the XLA path and ref_codec lives in
+# (tests/test_pallas_lowering.py builds the same kernels for TPU):
 _PALLAS_ORACLE = "tests/test_codec_pallas.py"
 
 _LANES = 128  # series per grid tile, riding the vector lanes
@@ -122,18 +130,15 @@ def _pack_kernel(c0_ref, c1_ref, c2_ref, nb_ref, out_ref, *, n_slots, mwp):
     implicit fourth chunk word zero. The scatter becomes a dense masked OR
     over the word window (rel == j), which vectorizes on the VPU instead
     of serializing; words past the padded bound simply never match."""
-    c0 = c0_ref[...]
-    c1 = c1_ref[...]
-    c2 = c2_ref[...]
-    nbs = nb_ref[...]
     wiota = jax.lax.broadcasted_iota(I32, (mwp, _LANES), 0)
 
     def body(j, state):
         cur, acc = state
-        a0 = jax.lax.dynamic_slice(c0, (j, 0), (1, _LANES))
-        a1 = jax.lax.dynamic_slice(c1, (j, 0), (1, _LANES))
-        a2 = jax.lax.dynamic_slice(c2, (j, 0), (1, _LANES))
-        nb = jax.lax.dynamic_slice(nbs, (j, 0), (1, _LANES))
+        row = pl.ds(j, 1)
+        a0 = c0_ref[row, :]
+        a1 = c1_ref[row, :]
+        a2 = c2_ref[row, :]
+        nb = nb_ref[row, :]
         cb = (cur & 31).astype(U32)
         inv = U32(32) - cb
         s0 = b64._shr32(a0, cb)
@@ -194,11 +199,19 @@ def _decode_kernel(words_ref, npts_ref, dt_ref, tshi_ref, tslo_ref,
     runs at trace time and avoids a module-level cycle."""
     from . import tsz as _tsz
 
-    words = words_ref[...]
+    # i32 view: the Pallas TPU lowering has no unsigned reductions.
+    words = jax.lax.bitcast_convert_type(words_ref[...], I32)
     npts = npts_ref[...]
+    wiota = jax.lax.broadcasted_iota(I32, words.shape, 0)
 
     def take(wi):
-        return jnp.take_along_axis(words, jnp.clip(wi, 0, mw - 1), axis=0)
+        # Per-lane word pick as a dense masked select + sublane reduce:
+        # Mosaic's dynamic_gather serves one source vreg along the gather
+        # axis, and the word column spans mwp/8 of them. Exactly one row
+        # matches the clamped index, so the (wrapping) sum is the gather.
+        hit = wiota == jnp.clip(wi, 0, mw - 1)
+        picked = jnp.sum(jnp.where(hit, words, 0), axis=0, keepdims=True)
+        return jax.lax.bitcast_convert_type(picked, jnp.uint32)
 
     def read32(pos):
         wi = pos >> 5
@@ -301,19 +314,23 @@ def _hash_kernel(w_ref, len_ref, out_ref, *, cols, seed):
     sublanes and IDs on lanes. Tail bytes come from the word at index
     nblocks: the buffer is zero past each row's length by construction,
     and every tail byte is additionally gated on tail_len."""
-    words = w_ref[...]
     lens = len_ref[...]
     nblocks = lens >> 2
+    # Tail word = the word at index nblocks (clamped like the numpy
+    # twin's index bound); picked up inside the block loop by a per-lane
+    # select instead of a sublane gather Mosaic cannot lower.
+    tail_at = jnp.minimum(nblocks, cols - 1)
     h0 = jnp.full((1, _LANES), np.uint32(seed), jnp.uint32)
 
-    def body(j, h):
-        kw = jax.lax.dynamic_slice(words, (j, 0), (1, _LANES))
-        kw = _rotl(kw * U32(_C1), 15) * U32(_C2)
+    def body(j, state):
+        h, tw = state
+        kw0 = w_ref[pl.ds(j, 1), :]
+        tw = jnp.where(tail_at == j, kw0, tw)
+        kw = _rotl(kw0 * U32(_C1), 15) * U32(_C2)
         h2 = _rotl(h ^ kw, 13) * U32(5) + U32(0xE6546B64)
-        return jnp.where(nblocks > j, h2, h)
+        return jnp.where(nblocks > j, h2, h), tw
 
-    h = jax.lax.fori_loop(0, cols, body, h0)
-    tw = jnp.take_along_axis(words, jnp.clip(nblocks, 0, cols - 1), axis=0)
+    h, tw = jax.lax.fori_loop(0, cols, body, (h0, jnp.zeros_like(h0)))
     tl = lens & 3
     z = jnp.zeros_like(h)
     k = jnp.where(tl >= 3, ((tw >> U32(16)) & U32(0xFF)) << U32(16), z)

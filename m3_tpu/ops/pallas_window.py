@@ -14,33 +14,28 @@ launch (the XLA path builds a separate masked volume per moment).
 
 Semantics match temporal._window_stat (masked by finiteness, m2 in the
 two-pass mean-then-deviation form that survives f32): the parity tests
-run both over the same grids, NaN holes included. On real hardware the
-accumulated stats (sum/m2) differ from the XLA path by reduction-order
-ULPs only (measured max abs 8e-6 on N(0,1) windows of 30).
+run both over the same grids, NaN holes included; accumulated stats
+(sum/m2) may differ from the XLA path by reduction-order ULPs.
 
-ON-CHIP STATUS (v5e, 2026-07-31, 10k x 438 grid, W=30, stride=3 — the
-bench's promql shape): compiles and matches, but LOSES to the XLA path —
-count 8.9ms vs 5.7, sum 13.3 vs 6.5, m2 33.4 vs 8.3. The theoretical
-O(W/stride) work saving never materializes: each output step reduces an
-[8, W] tile that fills 30 of 128 VPU lanes and pays a relayout for its
-unaligned static offset, while XLA's fused reduce_window streams full
-[8, 128] tiles. The kernel stays opt-in (M3_TPU_PALLAS=1) as an
-honestly-measured negative result — the pallas playbook's "don't
-hand-schedule what the compiler already schedules well" conclusion.
+STATUS: opt-in only (M3_TPU_PALLAS=1). The one timing ever taken of it
+on hardware (July 2026, 10k x 438 grid, W=30, stride=3, a toolchain and
+chip access that no longer exist) had it slower than the XLA path on
+every stat; on the current installation it is known to BUILD for a v5e
+(tests/test_pallas_lowering.py) and its speed on the chip is not
+measured. The plausible reason it would lose again: each output step
+reduces an [8, W] tile that fills 30 of 128 VPU lanes and pays a layout
+change for its unaligned static offset, while XLA's fused reduce_window
+streams full [8, 128] tiles. ROADMAP C4 decides whether it stays.
 Its structure became the template for the codec kernels
-(ops/pallas_codec.py), and the lesson splits cleanly down the middle:
-the codec kernels inherit the VMEM-tiling half (lane-tiled BlockSpecs,
-lru_cached `_build(..., interpret)` seams, interpret-mode parity as the
-CPU oracle) but NOT the strided-window-scheduling half — their inner
-loop walks a data-dependent bit cursor that XLA cannot fuse or
-pre-schedule, so there is no MAX_UNROLL_STEPS analog and no compiler
-schedule to lose to. Hand-written windows over data XLA already tiles:
-loses (this file). Hand-written cursors over data XLA serializes into
-gather chains: wins (pallas_codec).
+(ops/pallas_codec.py): they inherit the VMEM-tiling half (lane-tiled
+BlockSpecs, lru_cached `_build(..., interpret)` seams, interpret-mode
+parity as the CPU oracle) but NOT the strided-window-scheduling half —
+their inner loop walks a data-dependent bit cursor, so there is no
+MAX_UNROLL_STEPS analog.
 
 Opt-in wiring: temporal._window_stat_strided dispatches here when
 M3_TPU_PALLAS=1 (interpret mode backs the kernel on CPU so the tests
-and any CPU fallback stay correct).
+stay correct).
 """
 
 from __future__ import annotations
@@ -55,7 +50,8 @@ from jax.experimental import pallas as pl
 _F32 = jnp.float32
 
 # Where this module's interpret-vs-XLA parity is asserted (the m3lint
-# unguarded-pallas-dispatch rule checks the declared oracle exists).
+# unguarded-pallas-dispatch rule checks the declared oracle exists;
+# tests/test_pallas_lowering.py builds the kernel for TPU from the CPU).
 _PALLAS_ORACLE = "tests/test_temporal.py"
 
 # Row tile: f32 VMEM tiling is (8, 128); eight series rows per program
@@ -78,11 +74,10 @@ def _kernel(x_ref, o_ref, c_ref, *, W: int, stride: int, T_out: int,
     # slices to start at provable multiples of 128, and a window start of
     # i*stride from a fori_loop counter is not — the dynamic-slice form
     # fails TPU compilation outright ("cannot statically prove that index
-    # in dimension 1 is a multiple of 128", found by the on-chip proof
-    # run; interpret mode on CPU never sees the constraint). Constant
-    # offsets lower fine (Mosaic inserts the relayouts), and T_out is a
-    # query's output step count (~100s), so the unrolled loop stays a
-    # modest program.
+    # in dimension 1 is a multiple of 128"; interpret mode on CPU never
+    # sees the constraint). Constant offsets lower fine (Mosaic inserts
+    # the layout changes), and T_out is a query's output step count
+    # (~100s), so the unrolled loop stays a modest program.
     x = x_ref[:, :]
     iota_w = jax.lax.broadcasted_iota(jnp.int32, (_BS, W), 1)
     for i in range(T_out):

@@ -1046,45 +1046,19 @@ def boundary_metadata(inp: dict) -> dict:
             "valid": np.ones(npts.shape[0], bool)}
 
 
-@functools.lru_cache(maxsize=1)
-def _seal_mesh():
-    """1-D "s" mesh over every attached device for the seal-path encode,
-    or None single-chip. The sealed-block encode is row-parallel, so
-    sharding the prepared columns lets XLA SPMD split one block across
-    the mesh — the storage tier's own use of multi-chip, mirroring how
-    the reference splits flush work across its worker pool."""
-    devs = jax.devices()
-    if len(devs) <= 1:
-        return None
-    from jax.sharding import Mesh
-
-    return Mesh(np.asarray(devs), ("s",))
-
-
 def encode_prepared(inp: dict, max_words: int):
-    """encode_batch from prepared inputs (seal path). On a multi-device
-    platform, blocks whose (padded) series count divides the mesh run as
-    ONE SPMD program sharded over the "s" axis."""
-    dt, t0, vhi, vlo = inp["dt"], inp["t0"], inp["vhi"], inp["vlo"]
-    int_mode, k, npts = inp["int_mode"], inp["k"], inp["npoints"]
-    ts_regular, delta0 = inp["ts_regular"], inp["delta0"]
-    mesh = _seal_mesh()
-    if mesh is not None and np.asarray(dt).shape[0] % mesh.shape["s"] == 0:
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
-        row = NamedSharding(mesh, P("s"))
-        rowc = NamedSharding(mesh, P("s", None))
-        # DELIBERATE raw puts (mesh-flush staging): the sharded tiles are
-        # consumed by the encode program below and freed when this frame
-        # returns — charging the lifetime-tracked HBM budget would cost a
-        # finalizer per seal for buffers that never outlive the call.
-        put = jax.device_put
-        dt, vhi, vlo = (put(a, rowc) for a in (dt, vhi, vlo))  # m3lint: disable=unbudgeted-device-put
-        t0 = tuple(put(a, row) for a in t0)  # m3lint: disable=unbudgeted-device-put
-        int_mode, k, npts, ts_regular, delta0 = (
-            put(a, row) for a in (int_mode, k, npts, ts_regular, delta0))  # m3lint: disable=unbudgeted-device-put
+    """encode_batch from prepared inputs (seal path), on the default
+    device. Multi-device seals go through the shard_map flush encoder
+    (parallel.ingest.flush_encode_prepared) BEFORE reaching here; what
+    arrives is below its dispatch floor or does not divide the mesh.
+    Sharding such a tile with GSPMD would not split the Pallas pack
+    kernel anyway: the partitioner cannot see inside a pallas_call, so it
+    all-gathers the chunk planes and runs the whole kernel on every
+    device (cross-compiled for a 2x2 v5e: 20 all-gathers, replicated
+    output)."""
     return encode_batch(
-        dt, t0, vhi, vlo, int_mode, k, npts, ts_regular, delta0,
+        inp["dt"], inp["t0"], inp["vhi"], inp["vlo"], inp["int_mode"],
+        inp["k"], inp["npoints"], inp["ts_regular"], inp["delta0"],
         max_words=max_words)
 
 
@@ -1114,15 +1088,34 @@ def _decode_route():
             and guard.available("codec.decode") else "xla")
 
 
+def _row_mesh(words):
+    """(mesh, row axes) when `words` is a device array whose ROWS are
+    partitioned over more than one device (the block cache's retained
+    mesh-flush output), else None."""
+    sh = getattr(words, "sharding", None)
+    if not isinstance(sh, jax.sharding.NamedSharding) or sh.mesh.size <= 1:
+        return None
+    spec = tuple(sh.spec)
+    if not spec or spec[0] is None or any(a is not None for a in spec[1:]):
+        return None
+    return sh.mesh, spec[0]
+
+
 @functools.lru_cache(maxsize=None)
 def _decode_fused_jit(window: int, unit_nanos: int, with_f32: bool,
-                      route: str):
+                      route: str, rows=None):
     """Jitted fused decode program for one static (window, unit, route):
     stream scan + tick cumsum + unit-nanos multiply (mul64_const — minute
     units exceed u32 range) + exact on-device int->f64 bit conversion for
     k=0 int rows, emitting PAIR_HI-ordered [N, W, 2] u32 planes the host
     views zero-copy as int64/f64. k>0 rows (fixed-decimal gauges) keep
-    raw mantissa pairs; `fix` marks them for the host's exact /10^k."""
+    raw mantissa pairs; `fix` marks them for the host's exact /10^k.
+
+    `rows` = _row_mesh(words): row-partitioned input decodes as an
+    explicit shard_map over those rows (decode is row-independent), each
+    device scanning its own slice. Left to GSPMD, the Pallas route's
+    pallas_call is opaque to the partitioner, which all-gathers the
+    streams and runs the full kernel on every device."""
     hi = b64.PAIR_HI
 
     def stack(pair):
@@ -1131,7 +1124,6 @@ def _decode_fused_jit(window: int, unit_nanos: int, with_f32: bool,
         parts[1 - hi] = pair[1]
         return jnp.stack(parts, axis=-1)
 
-    @jax.jit
     def run(words, npoints):
         if route == "pallas":
             from . import pallas_codec
@@ -1150,7 +1142,17 @@ def _decode_fused_jit(window: int, unit_nanos: int, with_f32: bool,
             res["f32"] = b64.f64_bits_to_f32(vhi, vlo)
         return res
 
-    return run
+    if rows is not None:
+        from jax.sharding import PartitionSpec as P
+
+        mesh, axes = rows
+        out_specs = {"ts": P(axes, None, None), "vals": P(axes, None, None),
+                     "fix": P(axes), "k": P(axes)}
+        if with_f32:
+            out_specs["f32"] = P(axes, None)
+        run = jax.shard_map(run, mesh=mesh, in_specs=(P(axes, None), P(axes)),
+                            out_specs=out_specs, check_vma=False)
+    return jax.jit(run)
 
 
 def decode_plane(words, npoints, *, window: int, unit_nanos: int = 1,
@@ -1176,8 +1178,9 @@ def decode_plane(words, npoints, *, window: int, unit_nanos: int = 1,
 
     route = _decode_route()
     telemetry.codec_route("decode", route == "pallas")
+    row_mesh = _row_mesh(words)
     run = _decode_fused_jit(int(window), int(unit_nanos), bool(with_f32),
-                            route)
+                            route, row_mesh)
     jwords = jnp.asarray(words)
     jnp_ = jnp.asarray(npoints, I32)
     if route == "pallas":
@@ -1200,20 +1203,24 @@ def decode_plane(words, npoints, *, window: int, unit_nanos: int = 1,
             # corpus — rebuilt under its own lru key ("xla" rides in the
             # cache key, so no cache surgery is needed to reroute).
             fb = _decode_fused_jit(int(window), int(unit_nanos),
-                                   bool(with_f32), "xla")
+                                   bool(with_f32), "xla", row_mesh)
             return fb(jwords, jnp_)
 
         out = guard.dispatch("codec.decode", _pallas_decode, _xla_decode)
     else:
         out = run(jwords, jnp_)
-    ts = np.asarray(out["ts"]).view(np.int64)[..., 0]
-    vals = np.asarray(out["vals"]).view(np.float64)[..., 0]
+    # ascontiguousarray: a TPU array of minor dimension 2 comes back with
+    # the device layout's strides, and a dtype view needs the pair axis
+    # contiguous (a no-op where the fetch is already C-ordered).
+    pairs_ts = np.ascontiguousarray(out["ts"])
+    pairs_v = np.ascontiguousarray(out["vals"])
+    ts = pairs_ts.view(np.int64)[..., 0]
+    vals = pairs_v.view(np.float64)[..., 0]
     f32 = np.asarray(out["f32"]) if with_f32 else None
     rows = np.flatnonzero(np.asarray(out["fix"]))
     if rows.size:
         k = np.asarray(out["k"])[rows].astype(np.float64)
-        raw = np.ascontiguousarray(
-            np.asarray(out["vals"])[rows]).view(np.int64)[..., 0]
+        raw = np.ascontiguousarray(pairs_v[rows]).view(np.int64)[..., 0]
         fixed = raw.astype(np.float64) / np.power(10.0, k)[:, None]
         if not vals.flags.writeable:
             vals = vals.copy()

@@ -35,7 +35,7 @@ from jax.sharding import PartitionSpec as P
 
 from . import guard as pguard
 from . import telemetry
-from .ingest import flush_mesh, shard_map_compat
+from .ingest import flush_mesh
 from ..ops import aggregation as agg
 from ..utils import numwatch
 
@@ -63,8 +63,8 @@ def make_mesh_rank_selector(mesh, width: int, qs: tuple):
     def local_select(values, counts):
         return agg.quantile_rank_select(values, counts, qs)
 
-    fn = shard_map_compat(local_select, mesh=mesh,
-                          in_specs=(rowc, rows), out_specs=rowc)
+    fn = jax.shard_map(local_select, mesh=mesh, in_specs=(rowc, rows),
+                       out_specs=rowc, check_vma=False)
     return jax.jit(fn)
 
 

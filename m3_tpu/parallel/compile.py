@@ -7,7 +7,7 @@ The interpreter (query/executor.py, retained as the oracle
 per block with host round trips between operators and a fully host-side
 aggregation fan-in. Here the plan IR (query/plan.py) lowers into ONE
 traced function: operator chains fuse, cross-shard aggregation fan-in
-becomes XLA collectives (psum/pmin/pmax over ICI via shard_map_compat)
+becomes XLA collectives (psum/pmin/pmax over ICI via jax.shard_map)
 instead of host gather, and the only device->host transfer is the final
 result. In/out shardings match the layout the selector staging places
 (rows partitioned over the mesh "shard" axis, NamedSharding
@@ -817,8 +817,6 @@ def _plan_executable(stripped: PlanNode, geom: Geometry,
     if not sharded:
         return jax.jit(body)
 
-    from .ingest import shard_map_compat
-
     fetch_specs = []
     for f in fetches:
         for kind in kinds_by_fetch[f]:
@@ -851,10 +849,10 @@ def _plan_executable(stripped: PlanNode, geom: Geometry,
                      if root_edge.kind == SERIES
                      and root_edge.sharding == qplan.SHARDED else P())
     extras_spec = (P(), P()) if root_is_sum else ()
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(tuple(fetch_specs), aux_specs, P()),
-        out_specs=(out_root_spec, extras_spec))
+        out_specs=(out_root_spec, extras_spec), check_vma=False)
     return jax.jit(fn)
 
 

@@ -106,15 +106,6 @@ class DispatchTimeout(ComputeError):
     kind = "timeout"
 
 
-# Exception type names that mark a device/runtime-side failure. Matched
-# by NAME (not import) so classification works against every jaxlib
-# vintage and against the injector's stand-in when jaxlib's class cannot
-# be constructed.
-_DEVICE_EXC_NAMES = frozenset({
-    "XlaRuntimeError", "JaxRuntimeError", "InternalError",
-    "FailedPreconditionError", "ResourceExhaustedError",
-})
-
 _OOM_MARKERS = ("RESOURCE_EXHAUSTED", "Out of memory", "out of memory")
 _TIMEOUT_MARKERS = ("DEADLINE_EXCEEDED", "deadline exceeded", "timed out")
 _COMPILE_MARKERS = ("compilation", "Compilation", "Mosaic",
@@ -122,8 +113,14 @@ _COMPILE_MARKERS = ("compilation", "Compilation", "Mosaic",
 
 
 def _is_device_exc(exc: BaseException) -> bool:
-    return any(t.__name__ in _DEVICE_EXC_NAMES
-               for t in type(exc).__mro__)
+    """The runtime's own error type marks a device/runtime-side failure
+    (compile, execution, allocation). Anything Python raises while
+    tracing or lowering — NotImplementedError from a missing Pallas
+    lowering rule, an AssertionError in one — is a bug in the program
+    and stays unclassified, so dispatch() re-raises it."""
+    from jax.errors import JaxRuntimeError
+
+    return isinstance(exc, JaxRuntimeError)
 
 
 def classify(exc: BaseException, route: str) -> Optional[ComputeError]:

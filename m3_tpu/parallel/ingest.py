@@ -43,22 +43,6 @@ from ..ops import bits64 as b64
 from ..ops import tsz
 
 
-def shard_map_compat(fn, *, mesh, in_specs, out_specs):
-    """jax.shard_map across JAX versions: the top-level API (newer
-    releases, `check_vma` kwarg) or jax.experimental.shard_map (0.4.x,
-    `check_rep` kwarg). The serving flush path routes through this, so
-    mesh encode must not depend on which spelling the installed JAX
-    ships."""
-    top = getattr(jax, "shard_map", None)
-    if top is not None:
-        return top(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_vma=False)
-    from jax.experimental.shard_map import shard_map as exp_shard_map
-
-    return exp_shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
-
-
 class IngestBatch(NamedTuple):
     """Device inputs for one shard x block-window ingest step.
 
@@ -217,11 +201,11 @@ def make_flush_encoder(mesh: Mesh, max_words: int):
             dt, (t0_hi, t0_lo), vhi, vlo, int_mode, k, npoints,
             ts_regular, delta0, max_words=max_words)
 
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         local_encode, mesh=mesh,
         in_specs=(rowc, rows, rows, rowc, rowc, rows, rows, rows, rows,
                   rows),
-        out_specs=(rowc, rows))
+        out_specs=(rowc, rows), check_vma=False)
     return jax.jit(fn)
 
 
@@ -328,12 +312,13 @@ def make_sharded_ingest(mesh: Mesh, *, rollup_factor: int, max_words: int, quant
             total_bits,
         )
 
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         local_step,
         mesh=mesh,
         in_specs=(chunk, per_series, per_series, chunk, chunk, per_series,
                   per_series, per_series, per_series, per_series, chunk),
         out_specs=(chunk, per_series, chunk, chunk, merged, P()),
+        check_vma=False,
     )
     return jax.jit(fn)
 

@@ -81,11 +81,8 @@ def make_sharded_agg_rate(mesh: Mesh, *, op: str, func: str, W: int,
         return total, n
 
     spec = P("shard", None)
-    from .ingest import shard_map_compat
-
-    fn = shard_map_compat(local, mesh=mesh,
-                          in_specs=(spec, spec, spec),
-                          out_specs=(P(), P()))
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
+                       out_specs=(P(), P()), check_vma=False)
     return jax.jit(fn)
 
 

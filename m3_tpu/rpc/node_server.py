@@ -585,7 +585,7 @@ class NodeServer:
                                 "id": msg_id, "ok": False,
                                 "kind": "resource_exhausted", "err": str(e)})
                         # DELIBERATE broad except: the dispatch contract is
-                        # to relay ANY server-side application error to the
+                        # to return ANY server-side application error to the
                         # caller as a typed error frame — the wire write in
                         # the try is the success path, and its own failures
                         # hit the outer typed handler when the error frame

@@ -9,6 +9,27 @@ import sys
 import threading
 
 
+def _print_backend():
+    """Say once, at start, which backend this process computes on. A
+    chip belongs to one process: on a one-chip host exactly one service
+    (the dbnode with its embedded coordinator) may see the TPU here; the
+    others are started with JAX_PLATFORMS=cpu."""
+    import jax
+
+    # Imported HERE, before the listeners open: the write path's first
+    # shard-routing hash_batch otherwise pays this module's ~1 s import
+    # (jax.experimental.pallas) inside the first acknowledged write.
+    from ..ops import pallas_codec
+    from ..utils import compile_cache
+
+    cache_dir = compile_cache.configure()
+    devs = jax.devices()
+    print(f"m3_tpu backend: platform={devs[0].platform} "
+          f"device_kind={devs[0].device_kind} count={len(devs)} "
+          f"codec={'pallas' if pallas_codec.enabled() else 'xla'} "
+          f"compile_cache={cache_dir}", flush=True)
+
+
 def main(argv=None):
     parser = argparse.ArgumentParser(prog="m3_tpu.services")
     parser.add_argument("service",
@@ -25,6 +46,9 @@ def main(argv=None):
         cfg = cfgmod.load_file(args.config, args.service)
     else:
         cfg = cfgmod.load_dict({}, args.service)
+
+    if args.service != "kv":  # the KV service runs no device code
+        _print_backend()
 
     if args.service == "dbnode":
         handle = runmod.run_dbnode(cfg)

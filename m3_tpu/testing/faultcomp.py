@@ -52,11 +52,8 @@ __all__ = ["ComputeFaultPlan", "FaultComp", "NO_FAULT", "install",
 
 NO_FAULT = "ok"
 
-try:  # real jaxlib class when constructible, so classify() sees the
-    from jaxlib.xla_extension import XlaRuntimeError  # genuine article
-except Exception:  # pragma: no cover - jaxlib always present in-tree
-    class XlaRuntimeError(RuntimeError):
-        """Stand-in matching guard's by-name classification."""
+# The runtime's own error class, so classify() sees the genuine article.
+from jax.errors import JaxRuntimeError as XlaRuntimeError  # noqa: E402
 
 
 @dataclasses.dataclass(frozen=True)

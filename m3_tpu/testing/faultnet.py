@@ -193,7 +193,7 @@ class FaultProxy:
                 (n,) = _U32.unpack(header)
                 body = _read_exact(src, n, dead)
                 if body is None:
-                    break  # upstream died mid-frame: relay the break below
+                    break  # upstream died mid-frame: pass the break on below
                 fault = self.plan.decide(rng, direction)
                 log.append(fault)
                 if fault != NO_FAULT:
@@ -235,7 +235,7 @@ class FaultProxy:
 def _read_exact(sock: socket.socket, n: int,
                 dead: threading.Event) -> Optional[bytes]:
     """n bytes or None on EOF/teardown (clean close OR mid-read — the pump
-    relays the close either way; fault semantics come from the injector
+    passes the close on either way; fault semantics come from the injector
     side). Periodic timeouts poll the dead flag so a fault on the other
     direction unblocks this one."""
     parts = []

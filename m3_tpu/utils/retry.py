@@ -51,7 +51,7 @@ class NonRetryableError(Exception):
 
 
 class DeadlineExceeded(Exception):
-    """The operation's time budget ran out (client-observed or relayed
+    """The operation's time budget ran out (client-observed or passed on
     from a server's typed deadline error frame). Never retried: the
     budget that expired is the caller's whole budget."""
 
@@ -73,7 +73,7 @@ def default_is_retryable(e: BaseException) -> bool:
     (the breaker's cooldown far exceeds any sane backoff, so re-asking
     the SAME breaker is guaranteed-futile sleeping — retrying a different
     host belongs to the quorum/fanout layer above), and everything else
-    (server-side application errors relayed over the wire, protocol
+    (server-side application errors sent back over the wire, protocol
     desyncs surfaced as ValueError — retrying a desynced exchange
     re-sends into garbage)."""
     if isinstance(e, (NonRetryableError, DeadlineExceeded, BreakerOpen)):
@@ -128,7 +128,7 @@ class Deadline:
         `pre_io=True`: a check() fires BEFORE work starts (lock waits,
         queueing, backoff), so breakers must not blame the endpoint for
         it — deadline expiry DURING I/O surfaces as a socket timeout or
-        a server-relayed deadline frame instead."""
+        a server-sent deadline frame instead."""
         rem = self.remaining()
         if rem <= 0:
             e = DeadlineExceeded(f"{what}: deadline exceeded "
@@ -363,7 +363,7 @@ class Breaker:
     def call(self, fn: Callable, *args, **kwargs):
         """Guarded call: BreakerOpen without I/O when open, outcome
         recorded otherwise. DeadlineExceeded counts as a failure (the
-        endpoint burned the whole budget); server-relayed application
+        endpoint burned the whole budget); server-sent application
         errors should be recorded as success by callers that can tell —
         this convenience wrapper treats any exception as failure."""
         if not self.allow():

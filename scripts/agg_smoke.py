@@ -43,15 +43,11 @@ if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", "")
 # floor heuristic.
 os.environ["M3_TPU_MESH_AGG_MIN_CELLS"] = "1"
 
-# Persistent compile cache (same dir as bench.py): the quantile-selector
-# shapes compile once per machine, keeping warm runs inside the budget.
-import jax  # noqa: E402
+# Persistent compile cache: the quantile-selector shapes compile once per
+# checkout, keeping warm runs inside the budget.
+from m3_tpu.utils import compile_cache  # noqa: E402
 
-jax.config.update("jax_compilation_cache_dir", os.environ.get(
-    "JAX_COMPILATION_CACHE_DIR",
-    os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                 ".jax_cache")))
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+compile_cache.configure()
 
 from m3_tpu.aggregator import elem as elem_mod  # noqa: E402
 from m3_tpu.aggregator import list as list_mod  # noqa: E402

@@ -42,16 +42,10 @@ def main(argv=None) -> int:
     t_start = time.monotonic()
 
     # Persist kernel compiles across runs: the scenario's SLOs measure
-    # serving, not XLA compilation (bench.py uses the same cache).
-    import jax
+    # serving, not XLA compilation.
+    from m3_tpu.utils import compile_cache
 
-    cache_dir = os.environ.get(
-        "JAX_COMPILATION_CACHE_DIR",
-        os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), ".jax_cache"))
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+    compile_cache.configure()
 
     from m3_tpu.testing.scenario import ChurnScenario, ChurnScenarioOptions
 

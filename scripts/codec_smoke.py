@@ -32,10 +32,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 # Force the Pallas route BEFORE any m3_tpu import resolves the gate.
 os.environ["M3_TPU_PALLAS"] = "1"
-if os.environ.get("JAX_PLATFORMS") == "cpu":
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
 
 from m3_tpu.ops import pallas_codec, ref_codec, tsz  # noqa: E402
 from m3_tpu.parallel import telemetry  # noqa: E402

@@ -37,9 +37,6 @@ import numpy as np
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-import jax  # noqa: E402
-
-jax.config.update("jax_platforms", "cpu")
 
 from m3_tpu.client.session import Session, SessionOptions  # noqa: E402
 from m3_tpu.cluster.placement import Instance, ShardState  # noqa: E402

@@ -12,7 +12,8 @@ WORKDIR=$(mktemp -d)
 PIDS=()
 trap 'kill "${PIDS[@]}" 2>/dev/null || true; rm -rf "$WORKDIR"' EXIT
 
-export M3_TPU_JAX_PLATFORM=${M3_TPU_JAX_PLATFORM:-cpu}
+# Several cooperating processes: a chip belongs to one process at a time,
+# so all of them compute on the CPU backend here.
 export JAX_PLATFORMS=${JAX_PLATFORMS:-cpu}
 
 await_log() { # file pattern

@@ -70,16 +70,10 @@ def main(argv=None) -> int:
     budget_s = float(os.environ.get("OBS_SMOKE_BUDGET_S", "10.0"))
     t_start = time.monotonic()
 
-    # Persist kernel compiles across runs (churn_smoke convention).
-    import jax
+    # Persist kernel compiles across runs.
+    from m3_tpu.utils import compile_cache
 
-    cache_dir = os.environ.get(
-        "JAX_COMPILATION_CACHE_DIR",
-        os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), ".jax_cache"))
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+    compile_cache.configure()
 
     from m3_tpu.client.session import Session, SessionOptions
     from m3_tpu.coordinator import SelfScraper, run_clustered

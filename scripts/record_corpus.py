@@ -44,9 +44,7 @@ def main(argv) -> int:
         print(f"refusing to append to existing corpus {out_path}")
         return 2
 
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
     sys.path.insert(0, os.path.join(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))),

@@ -31,13 +31,9 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
-import jax  # noqa: E402
+from m3_tpu.utils import compile_cache  # noqa: E402
 
-jax.config.update("jax_compilation_cache_dir", os.environ.get(
-    "JAX_COMPILATION_CACHE_DIR",
-    os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                 ".jax_cache")))
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+compile_cache.configure()
 
 from m3_tpu.cluster import kv as cluster_kv  # noqa: E402
 from m3_tpu.coordinator.downsample import Downsampler  # noqa: E402

@@ -27,9 +27,6 @@ import time
 import urllib.parse
 import urllib.request
 
-import jax
-jax.config.update("jax_platforms", "cpu")
-
 from m3_tpu.services import load_dict, run_dbnode
 
 SECONDS = float(os.environ.get("SOAK_SECONDS", "30"))

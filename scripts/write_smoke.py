@@ -34,15 +34,11 @@ if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", "")
     os.environ["XLA_FLAGS"] = os.environ.get("XLA_FLAGS", "") + \
         " --xla_force_host_platform_device_count=8"
 
-# Persistent compile cache (same dir as bench.py): the seal/mesh encode
-# shapes compile once per machine, keeping warm runs inside the budget.
-import jax  # noqa: E402
+# Persistent compile cache: the seal/mesh encode shapes compile once per
+# checkout, keeping warm runs inside the budget.
+from m3_tpu.utils import compile_cache  # noqa: E402
 
-jax.config.update("jax_compilation_cache_dir", os.environ.get(
-    "JAX_COMPILATION_CACHE_DIR",
-    os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                 ".jax_cache")))
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+compile_cache.configure()
 
 from m3_tpu.index import query as iq  # noqa: E402
 from m3_tpu.index.namespace_index import NamespaceIndex  # noqa: E402

@@ -7,8 +7,9 @@ BIT-identical to its XLA twin and to ops/ref_codec.py over a property
 corpus covering the codec's hostile regions — NaN holes, rewrite-window
 churn past REWRITE_THRESHOLD, int/float mode mixes, wild f64 bit
 patterns, and npoints 0/1 edges. On CPU the kernels run in interpret
-mode (the CPU-fallback protocol DIVERGENCES.md documents); on a real
-TPU the same tests exercise compiled Mosaic kernels unchanged."""
+mode: this proves the algebra. That they BUILD for a TPU is
+tests/test_pallas_lowering.py; that the compiled kernels produce these
+same bits on the chip is chip_smoke.py's phase_codec_twins."""
 
 import os
 

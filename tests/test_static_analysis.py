@@ -1026,10 +1026,10 @@ class TestRetryRules:
         src = """
             from ..rpc import wire
 
-            def relay(sock, work):
+            def forward(sock, work):
                 try:
                     wire.write_frame(sock, work())
-                # DELIBERATE: error-relay contract
+                # DELIBERATE: error-forwarding contract
                 except Exception:  # m3lint: disable=broad-except-wire-io
                     pass
         """
@@ -3257,16 +3257,13 @@ class TestMeshSpecRule:
             import jax
             from jax.sharding import PartitionSpec as P
 
-            def shard_map_compat(fn, *, mesh, in_specs, out_specs):
-                return fn
-
             def build(mesh):
                 def local(values, counts):
                     return values
 
-                return shard_map_compat(local, mesh=mesh,
-                                        in_specs=(P("shard"),),
-                                        out_specs=P("shard"))
+                return jax.shard_map(local, mesh=mesh,
+                                     in_specs=(P("shard"),),
+                                     out_specs=P("shard"))
         """
         found = lint(src, MeshSpecRule(), "m3_tpu/parallel/a.py")
         assert rule_ids(found) == ["shard-spec-arity"]
@@ -3276,16 +3273,13 @@ class TestMeshSpecRule:
             import jax
             from jax.sharding import PartitionSpec as P
 
-            def shard_map_compat(fn, *, mesh, in_specs, out_specs):
-                return fn
-
             def build(mesh):
                 def local(values, counts):
                     return values
 
                 specs = (P("shard"), P("shard"))
-                return shard_map_compat(local, mesh=mesh, in_specs=specs,
-                                        out_specs=P("shard"))
+                return jax.shard_map(local, mesh=mesh, in_specs=specs,
+                                     out_specs=P("shard"))
         """
         assert lint(src, MeshSpecRule(), "m3_tpu/parallel/a.py") == []
 
@@ -3294,13 +3288,10 @@ class TestMeshSpecRule:
             import jax
             from jax.sharding import PartitionSpec as P
 
-            def shard_map_compat(fn, *, mesh, in_specs, out_specs):
-                return fn
-
             def plan_executable(body, mesh):
-                return shard_map_compat(body, mesh=mesh,
-                                        in_specs=(P("shard", None),),
-                                        out_specs=(P("shard", None),))
+                return jax.shard_map(body, mesh=mesh,
+                                     in_specs=(P("shard", None),),
+                                     out_specs=(P("shard", None),))
         """
         found = lint(src, MeshSpecRule(), "m3_tpu/parallel/compile.py")
         assert "unannotated-out-sharding" in rule_ids(found)
@@ -3314,15 +3305,13 @@ class TestMeshSpecRule:
 
             SHARDED = "shard"
 
-            def shard_map_compat(fn, *, mesh, in_specs, out_specs):
-                return fn
 
             def plan_executable(body, mesh, root_edge):
                 out_root_spec = (P("shard", None)
                                  if root_edge.sharding == SHARDED else P())
-                return shard_map_compat(body, mesh=mesh,
-                                        in_specs=(P("shard", None),),
-                                        out_specs=(out_root_spec, P()))
+                return jax.shard_map(body, mesh=mesh,
+                                     in_specs=(P("shard", None),),
+                                     out_specs=(out_root_spec, P()))
         """
         found = [f for f in lint(src, MeshSpecRule(),
                                  "m3_tpu/parallel/compile.py")
@@ -3334,15 +3323,12 @@ class TestMeshSpecRule:
             import jax
             from jax.sharding import PartitionSpec as P
 
-            def shard_map_compat(fn, *, mesh, in_specs, out_specs):
-                return fn
-
             def build(mesh):
                 def local(rows):
                     return rows
 
-                return shard_map_compat(local, mesh=mesh,
-                                        in_specs=(P("shard"),),
+                return jax.shard_map(local, mesh=mesh,
+                                     in_specs=(P("shard"),),
                                         out_specs=(P("shard"),))
         """
         assert lint(src, MeshSpecRule(), "m3_tpu/parallel/ingest.py") == []
@@ -3495,16 +3481,14 @@ class TestMeshSpecReviewRegressions:
 
             SHARDED = "shard"
 
-            def shard_map_compat(fn, *, mesh, in_specs, out_specs):
-                return fn
 
             def plan_executable(body, mesh, root_edge):
                 out_root_spec = (P("shard", None)
                                  if root_edge.sharding == SHARDED else P())
                 specs = (out_root_spec, P())
-                return shard_map_compat(body, mesh=mesh,
-                                        in_specs=(P("shard", None),),
-                                        out_specs=specs)
+                return jax.shard_map(body, mesh=mesh,
+                                     in_specs=(P("shard", None),),
+                                     out_specs=specs)
         """
         found = [f for f in lint(src, MeshSpecRule(),
                                  "m3_tpu/parallel/compile.py")
@@ -3516,15 +3500,12 @@ class TestMeshSpecReviewRegressions:
             import jax
             from jax.sharding import PartitionSpec as P
 
-            def shard_map_compat(fn, *, mesh, in_specs, out_specs):
-                return fn
-
             def build(mesh):
                 def local(*planes):
                     return planes[0]
 
-                return shard_map_compat(local, mesh=mesh,
-                                        in_specs=(P("shard"), P("shard")),
+                return jax.shard_map(local, mesh=mesh,
+                                     in_specs=(P("shard"), P("shard")),
                                         out_specs=P("shard"))
         """
         assert lint(src, MeshSpecRule(), "m3_tpu/parallel/a.py") == []
@@ -3534,20 +3515,17 @@ class TestMeshSpecReviewRegressions:
             import jax
             from jax.sharding import PartitionSpec as P
 
-            def shard_map_compat(fn, *, mesh, in_specs, out_specs):
-                return fn
-
             def build(mesh):
                 def local(values, counts=None):
                     return values
 
-                ok = shard_map_compat(local, mesh=mesh,
-                                      in_specs=(P("shard"),),
-                                      out_specs=P("shard"))
-                bad = shard_map_compat(local, mesh=mesh,
-                                       in_specs=(P("shard"), P("shard"),
-                                                 P("shard")),
-                                       out_specs=P("shard"))
+                ok = jax.shard_map(local, mesh=mesh,
+                                   in_specs=(P("shard"),),
+                                   out_specs=P("shard"))
+                bad = jax.shard_map(local, mesh=mesh,
+                                    in_specs=(P("shard"), P("shard"),
+                                              P("shard")),
+                                    out_specs=P("shard"))
                 return ok, bad
         """
         found = lint(src, MeshSpecRule(), "m3_tpu/parallel/a.py")

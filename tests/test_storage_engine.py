@@ -24,6 +24,15 @@ def make_db(num_shards=8):
     return db, now
 
 
+def test_murmur3_cached_accepts_any_bytes_like():
+    # the lru memo keys on bytes; bytearray/memoryview ids (which the
+    # uncached function hashes fine) are normalized first, not a TypeError
+    from m3_tpu.utils.hashing import murmur3_32_cached
+
+    for buf in (bytearray(b"hello"), memoryview(b"hello")):
+        assert murmur3_32_cached(buf) == murmur3_32(b"hello") == 0x248BFA47
+
+
 def test_murmur3_reference_vectors():
     # Standard MurmurHash3 x86-32 test vectors.
     assert murmur3_32(b"") == 0
