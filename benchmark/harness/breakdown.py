@@ -1,0 +1,68 @@
+"""The traced run's breakdown: the device operations that took most
+time, and the device's idle time by what the host was doing."""
+
+from __future__ import annotations
+
+from typing import List, Tuple
+
+from . import spans, trace_reduce
+
+# Several requests are in flight at once; an idle instant goes to the
+# first of these that is active. Deepest program span first, the
+# benchmark's own intervals after.
+PRIORITY = ["gc", "index.query", "storage.read", "query.fetch", "query.parse",
+            "query.execute_range", "mediator.tick", "render", "http"]
+IDLE = "loadgen-wait"
+
+
+def host_intervals(m) -> List[Tuple[float, float, str]]:
+    """Everything the host is known to have been doing, on the trace's clock."""
+    tr = m.trace
+    out = []
+
+    def add(lo, hi, name):
+        out.append((tr.to_trace_ns(lo), tr.to_trace_ns(hi), name))
+
+    for a, b, _gen in m.gc_events:
+        add(a, b, "gc")
+    for a, b in m.ticks:
+        add(a, b, "mediator.tick")
+    trees = spans.by_trace_id(m.span_trees)
+    for tree in m.span_trees:
+        for node in spans.walk(tree):
+            if not node["name"].startswith("http."):
+                add(node["start"], node["end"], node["name"])
+    # a request in flight outside its handler's spans: `http` before the
+    # engine starts and after the handler returns, `render` between the
+    # engine's end and the handler's
+    if "i" in m.rec:
+        for i, sent, done in zip(m.rec["i"], m.rec["sent"], m.rec["done"]):
+            root = trees.get(int(i) + 1)
+            ex = None
+            if root is not None:
+                ex = next((n for n in spans.walk(root)
+                           if n["name"] == "query.execute_range"), None)
+            if ex is None:
+                add(sent, done, "http")
+                continue
+            add(sent, ex["start"], "http")
+            add(ex["end"], root["end"], "render")
+            add(root["end"], done, "http")
+    elif "sent" in m.rec:
+        for sent, done in zip(m.rec["sent"], m.rec["done"]):
+            add(sent, done, "http")
+    return out
+
+
+def idle_by_host(m, lo: float, hi: float) -> dict:
+    busy = trace_reduce.union(iv for per in m.trace.busy(lo, hi).values()
+                              for iv in per)
+    return trace_reduce.attribute_gaps(busy, lo, hi, host_intervals(m),
+                                       PRIORITY, IDLE)
+
+
+def breakdown(m, lo: float, hi: float) -> dict:
+    ops = sorted(m.trace.op_seconds(lo, hi).items(), key=lambda kv: -kv[1])
+    gaps = sorted(idle_by_host(m, lo, hi).items(), key=lambda kv: -kv[1])
+    return {"device_ops": [[k, v] for k, v in ops[:10]],
+            "idle_gaps": [[k, v] for k, v in gaps[:10]]}

@@ -1,0 +1,6 @@
+"""memory_stats()['peak_bytes_in_use'], the fullest device."""
+
+
+
+def read(m):
+    return max(m.peak_bytes) if m.peak_bytes else None

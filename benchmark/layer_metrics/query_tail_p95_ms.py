@@ -1,0 +1,11 @@
+"""The dashboard tail: the 95th percentile of the window's latencies, from
+when each request was due. Not judged end to end yet: over 15 same-code
+runs it spread by 12% (a few full collections and host stalls a window
+decide which of the heaviest class's requests rank 49th of 976), more
+than a bound of 25% admits (PERF.md, Open questions)."""
+
+from harness import reduce
+
+
+def read(m):
+    return reduce.latency_percentile(m, 95)
