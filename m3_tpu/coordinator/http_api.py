@@ -9,6 +9,7 @@ the high-volume path — the wire format carries numpy columns end-to-end."""
 
 from __future__ import annotations
 
+import functools
 import json
 import math
 import re
@@ -27,6 +28,7 @@ from ..query.block import Block
 from ..query.model import Matcher, MatchType
 from ..query import promql
 from ..query.promql import parse_duration_ns
+from ..utils import tracing
 from ..utils.limits import ResourceExhausted
 from .ingest import DownsamplerAndWriter
 
@@ -135,8 +137,6 @@ class HTTPApi:
     def debug_traces(self, req) -> dict:
         """Recent finished span trees (opentracing-analog) + the
         slow-query ring (?trace_id=N filters the trees to one trace)."""
-        from ..utils import tracing
-
         tid = req.param("trace_id", None)
         return tracing.debug_traces_payload(int(tid) if tid else None)
 
@@ -145,15 +145,11 @@ class HTTPApi:
         Sampling runs on ONE shared background thread with a hard cap
         (M3_TPU_PROFILE_MAX_S): a profile request cannot stall a serving
         thread past the cap, and concurrent requests share the window."""
-        from ..utils import tracing
-
         return tracing.debug_profile_payload(float(req.param("seconds", "1")))
 
     def debug_stacks(self, req):
         """All-threads stack dump (goroutine-dump analog, debug=2 form;
         also served as /debug/pprof/threads)."""
-        from ..utils import tracing
-
         return RawResponse("text/plain; charset=utf-8",
                            tracing.thread_stacks().encode())
 
@@ -406,15 +402,23 @@ class HTTPApi:
         if self.writer is None:
             raise HTTPError(501, "no write backend configured")
         try:
-            raw = promremote.snappy_decompress(req.body)
-            series = promremote.decode_write_request(raw)
+            with tracing.child_span("remote_write.decompress"):
+                raw = promremote.snappy_decompress(req.body)
+            with tracing.child_span("remote_write.decode"):
+                series = promremote.decode_write_request(raw)
         except (promremote.SnappyError, promremote.ProtoError) as e:
             raise HTTPError(400, f"bad remote write body: {e}")
         wrote = 0
-        for tags, samples in series:
-            for t_ms, value in samples:
-                self.writer.write(tags, t_ms * 1_000_000, value)
-                wrote += 1
+        with tracing.child_span("remote_write.append") as sp:
+            # sample by sample; under a traced request each write also
+            # accounts its phases on this span (one flag read a request)
+            write = self.writer.write if not sp.detailed else \
+                functools.partial(self.writer.write, acc=sp)
+            for tags, samples in series:
+                for t_ms, value in samples:
+                    write(tags, t_ms * 1_000_000, value)
+                    wrote += 1
+            sp.add_cost("samples_n", wrote)
         return {"status": "success", "wrote": wrote}
 
     def prom_remote_read(self, req):
@@ -529,47 +533,52 @@ class HTTPApi:
                         # to the caller's trace (the HTTP twin of the
                         # wire frames' "tr" field). No header, no span —
                         # plain requests pay one dict get.
-                        from ..utils import tracing as _tracing
-
-                        tspan = _tracing.TRACER.span_from(
-                            _trace_header_ctx(self.headers.get("X-M3-Trace")),
-                            f"http.{self.command} {parsed.path}")
-                        try:
-                            with tspan:
-                                out = fn(req)
-                            code = 200
-                        except HTTPError as e:
-                            out, code = {"status": "error", "error": e.msg}, e.code
-                        except ResourceExhausted as e:
-                            # Shed by a query limit or the ingest admission
-                            # gate: 429 with Retry-After so well-behaved
-                            # producers back off instead of retrying hot.
-                            out, code = {"status": "error",
-                                         "errorType": "resource_exhausted",
-                                         "error": str(e)}, 429
-                        except Exception as e:  # noqa: BLE001
-                            out, code = {"status": "error", "error": str(e)}, 400
-                        if isinstance(out, RawResponse):
-                            ctype, data = out.content_type, out.data
-                            extra = out.headers
+                        ctx = _trace_header_ctx(self.headers.get("X-M3-Trace"))
+                        if ctx is None:
+                            out, code = _run(fn, req)
+                            self._send(code, *_serialize(out, code))
                         else:
-                            ctype, data = "application/json", json.dumps(out).encode()
-                            # shed responses tell producers WHEN to retry
-                            extra = {"Retry-After": "1"} if code == 429 else {}
-                        self.send_response(code)
-                        self.send_header("Content-Type", ctype)
-                        self.send_header("Content-Length", str(len(data)))
-                        for k, v in extra.items():
-                            self.send_header(k, v)
-                        self.end_headers()
-                        self.wfile.write(data)
+                            self._traced(ctx, fn, req)
                         return
                 self.send_response(404)
                 self.end_headers()
 
+            def _traced(self, ctx, fn, req):
+                """The request under its root span, accept to last byte.
+                The connection's thread was born at accept (a thread and
+                a connection per request), so the stamp backdates the
+                root and `http.read`, and their CPU clock starts at 0."""
+                accepted = self.server.accepted_ns.pop(self.connection, None)
+                born = {} if accepted is None else \
+                    {"start_ns": accepted, "cpu_start_ns": 0}
+                root = tracing.TRACER.span_from(
+                    ctx, f"http.{req.method} {req.path}", **born)
+                with root:
+                    with tracing.child_span("http.read", **born):
+                        pass    # request line, headers and body: just read
+                    out, code = _run(fn, req,
+                                     tracing.child_span("http.handler"))
+                    if isinstance(out, dict) and "wrote" in out:
+                        root.set_tag("samples", out["wrote"])
+                    with tracing.child_span("http.serialize"):
+                        ctype, data, extra = _serialize(out, code)
+                    root.set_tag("status", code)
+                    root.set_tag("bytes_out", len(data))
+                    with tracing.child_span("http.write"):
+                        self._send(code, ctype, data, extra)
+
+            def _send(self, code, ctype, data, extra):
+                self.send_response(code)
+                self.send_header("Content-Type", ctype)
+                self.send_header("Content-Length", str(len(data)))
+                for k, v in extra.items():
+                    self.send_header(k, v)
+                self.end_headers()
+                self.wfile.write(data)
+
             do_GET = do_POST = do_DELETE = do_PUT = _dispatch
 
-        self._server = ThreadingHTTPServer((host, port), Handler)
+        self._server = _StampingServer((host, port), Handler)
         threading.Thread(target=self._server.serve_forever, daemon=True).start()
         return self
 
@@ -582,6 +591,53 @@ class HTTPApi:
         if self._server is not None:
             self._server.shutdown()
             self._server.server_close()
+
+
+class _StampingServer(ThreadingHTTPServer):
+    """Stamps every connection at accept(), so a traced request's root
+    span starts there and not where its handler is called: the thread's
+    start, the header parse and the body read are the front's cost.
+    One perf_counter_ns a connection, whether traced or not."""
+
+    def __init__(self, *args, **kw):
+        self.accepted_ns: Dict[object, int] = {}    # by connection socket
+        super().__init__(*args, **kw)
+
+    def get_request(self):
+        request, addr = super().get_request()
+        self.accepted_ns[request] = time.perf_counter_ns()
+        return request, addr
+
+    def shutdown_request(self, request):
+        self.accepted_ns.pop(request, None)  # untraced: nobody took it
+        super().shutdown_request(request)
+
+
+def _run(fn, req, span=tracing.NOOP_SPAN):
+    """(result, status) of one handler call, errors mapped to their
+    responses; `span` wraps the call alone."""
+    try:
+        with span:
+            return fn(req), 200
+    except HTTPError as e:
+        return {"status": "error", "error": e.msg}, e.code
+    except ResourceExhausted as e:
+        # Shed by a query limit or the ingest admission gate: 429 with
+        # Retry-After so well-behaved producers back off instead of
+        # retrying hot.
+        return {"status": "error", "errorType": "resource_exhausted",
+                "error": str(e)}, 429
+    except Exception as e:  # noqa: BLE001
+        return {"status": "error", "error": str(e)}, 400
+
+
+def _serialize(out, code: int):
+    """(content type, body bytes, extra headers) of a handler result."""
+    if isinstance(out, RawResponse):
+        return out.content_type, out.data, out.headers
+    # shed responses tell producers WHEN to retry
+    return ("application/json", json.dumps(out).encode(),
+            {"Retry-After": "1"} if code == 429 else {})
 
 
 class RawResponse:
@@ -638,13 +694,11 @@ def _trace_header_ctx(header: Optional[str]):
     wire.trace_from_frame)."""
     if not header:
         return None
-    from ..utils.tracing import SpanContext
-
     parts = header.split(":")
     if len(parts) != 2:
         return None
     try:
-        return SpanContext(int(parts[0]), int(parts[1]))
+        return tracing.SpanContext(int(parts[0]), int(parts[1]))
     except ValueError:
         return None
 

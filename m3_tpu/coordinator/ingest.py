@@ -13,6 +13,7 @@ from ..aggregator.handler import decode_aggregated_batch
 from ..metrics.metric import MetricType
 from ..utils.health import AdmissionGate, Priority
 from ..utils.instrument import ROOT
+from ..utils.tracing import clock_ns
 from .downsample import Downsampler
 
 _scope = ROOT.sub_scope("coordinator.ingest")
@@ -41,22 +42,30 @@ class DownsamplerAndWriter:
     def write(self, tags: Dict[bytes, bytes], t_nanos: int, value: float,
               metric_type: MetricType = MetricType.GAUGE,
               downsample: bool = True, write_unaggregated: bool = True,
-              priority: Priority = Priority.NORMAL):
+              priority: Priority = Priority.NORMAL, acc=None):
         """write.go WriteBatch dual path. Raises Backpressure when the
-        admission gate sheds this priority class."""
+        admission gate sheds this priority class. `acc` (a detailed
+        span, utils.tracing.detail, read once by the caller's loop)
+        receives `id_ns` and, from the storage below, the write's other
+        phases."""
         with self.gate.held(priority=priority):
             self._write_admitted(tags, t_nanos, value, metric_type,
-                                 downsample, write_unaggregated)
+                                 downsample, write_unaggregated, acc)
 
     def _write_admitted(self, tags, t_nanos, value, metric_type,
-                        downsample, write_unaggregated):
+                        downsample, write_unaggregated, acc=None):
         if downsample and self._downsampler is not None:
             if self._downsampler.write(tags, t_nanos, value, metric_type):
                 self.downsampled += 1
                 _scope.counter("downsampled").inc()
         if write_unaggregated:
-            sid = _series_id(tags)
-            self._storage.write(sid, tags, t_nanos, value)
+            if acc is None:
+                self._storage.write(_series_id(tags), tags, t_nanos, value)
+            else:
+                t0 = clock_ns()
+                sid = _series_id(tags)
+                acc.add_cost("id_ns", clock_ns() - t0)
+                self._storage.write(sid, tags, t_nanos, value, acc=acc)
             self.written += 1
             _scope.counter("written").inc()
 

@@ -57,7 +57,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from . import guard as pguard
 from . import telemetry
 from ..ops import series_agg, temporal
-from ..utils import numwatch
+from ..utils import numwatch, tracing
 from ..query import explain as qexplain
 from ..query import plan as qplan
 from ..query import promql
@@ -1003,7 +1003,8 @@ def execute(bound: "qplan.Bound", mesh: Optional[Mesh]):
         _fault_fallback,
         key=bucket, evict=_plan_executable.cache_clear)
     if sync:
-        (root_val, extras) = jax.block_until_ready((root_val, extras))
+        with tracing.phase("device_wait"):
+            (root_val, extras) = jax.block_until_ready((root_val, extras))
         dt = time.perf_counter() - t0
         if missed:
             telemetry.plan_compile_recorded(dt)
@@ -1045,14 +1046,13 @@ def execute(bound: "qplan.Bound", mesh: Optional[Mesh]):
         # (rows in the k best at any step), so the tags can only be
         # fixed after materialization — the interpreter's all-NaN row
         # drop, applied to the masked plane.
-        t0f = time.perf_counter() if actx is not None else 0.0
-        vals = np.asarray(root_val)[:n_rows, :steps]
-        telemetry.count_d2h(result_bytes)
-        keep = ~np.all(np.isnan(vals), axis=1)
-        tags = [t for t, k in zip(bound.out_tags, keep) if k]
-        vals = np.ascontiguousarray(vals[keep])
+        with tracing.phase("device_wait", stage="result_materialize"):
+            vals = np.asarray(root_val)[:n_rows, :steps]
+            telemetry.count_d2h(result_bytes)
+            keep = ~np.all(np.isnan(vals), axis=1)
+            tags = [t for t, k in zip(bound.out_tags, keep) if k]
+            vals = np.ascontiguousarray(vals[keep])
         if actx is not None:
-            actx.add("result_materialize", time.perf_counter() - t0f)
             actx.event("d2h_bytes", result_bytes)
         return None, tags, (lambda: vals)
 
@@ -1064,16 +1064,15 @@ def execute(bound: "qplan.Bound", mesh: Optional[Mesh]):
         temporal._copy_async(s_dev, cnt_dev)
 
         def fetch():
-            t0 = time.perf_counter() if actx is not None else 0.0
-            s = np.asarray(s_dev, dtype=np.float64)[:n_rows, :steps]
-            cnt = np.asarray(cnt_dev, dtype=np.float64)[:n_rows, :steps]
-            telemetry.count_d2h(result_bytes)
-            if root.exact:
-                s = s + _exact_base_contrib(bound, root, n_rows, steps)
-            out = s / np.maximum(cnt, 1) if root.op == "avg" else s
-            result = np.where(cnt > 0, out, np.nan)
+            with tracing.phase("device_wait", stage="result_materialize"):
+                s = np.asarray(s_dev, dtype=np.float64)[:n_rows, :steps]
+                cnt = np.asarray(cnt_dev, dtype=np.float64)[:n_rows, :steps]
+                telemetry.count_d2h(result_bytes)
+                if root.exact:
+                    s = s + _exact_base_contrib(bound, root, n_rows, steps)
+                out = s / np.maximum(cnt, 1) if root.op == "avg" else s
+                result = np.where(cnt > 0, out, np.nan)
             if actx is not None:
-                actx.add("result_materialize", time.perf_counter() - t0)
                 actx.event("d2h_bytes", result_bytes)
             return result
 
@@ -1082,13 +1081,12 @@ def execute(bound: "qplan.Bound", mesh: Optional[Mesh]):
     temporal._copy_async(root_val)
 
     def fetch():
-        t0 = time.perf_counter() if actx is not None else 0.0
-        telemetry.count_d2h(result_bytes)
-        # f32, like the per-op interpreter path's result planes: the
-        # padded [rows_pad, t_pad] plane is sliced, not up-converted.
-        result = np.asarray(root_val)[:n_rows, :steps]
+        with tracing.phase("device_wait", stage="result_materialize"):
+            telemetry.count_d2h(result_bytes)
+            # f32, like the per-op interpreter path's result planes: the
+            # padded [rows_pad, t_pad] plane is sliced, not up-converted.
+            result = np.asarray(root_val)[:n_rows, :steps]
         if actx is not None:
-            actx.add("result_materialize", time.perf_counter() - t0)
             actx.event("d2h_bytes", result_bytes)
         return result
 

@@ -56,6 +56,7 @@ from typing import Any, Callable, Dict, Hashable, Optional
 
 from . import telemetry
 from ..utils import retry as uretry
+from ..utils import tracing
 
 __all__ = [
     "ComputeError", "CompileError", "DeviceOOM", "KernelFault",
@@ -451,7 +452,11 @@ def dispatch(route: str,
         out: Any = None
         t0 = r.clock()
         try:
-            out = _seam.call(route, primary)
+            # The enqueue: JAX returns before the device finishes, so on
+            # a detailed span this is host time (`dispatch_ns`), and the
+            # wait shows where the result is read (`device_wait_ns`).
+            with tracing.phase("dispatch"):
+                out = _seam.call(route, primary)
         except ComputeError as exc:
             err = exc
         except Exception as exc:  # noqa: BLE001 — classified or re-raised

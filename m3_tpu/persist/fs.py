@@ -31,7 +31,7 @@ from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 import numpy as np
 
 from ..storage.block import SealedBlock
-from ..utils import xtime
+from ..utils import tracing, xtime
 from ..utils.bloom import BloomFilter
 from ..utils.checksum import adler32_rows
 from ..utils.instrument import ROOT
@@ -87,8 +87,19 @@ class FilesetWriter:
         d = fileset_dir(self.root, namespace, shard, blk.block_start, snapshot_version)
         tmp = d + ".tmp"
         try:
-            return self._write(d, tmp, blk, registry, snapshot_version,
-                               wal_position)
+            # one span per fileset when somebody is tracing (the
+            # mediator's tick): what the files cost, and their bytes
+            with tracing.child_span(
+                    "persist.write",
+                    volume="flush" if snapshot_version is None
+                    else "snapshot") as sp:
+                out = self._write(d, tmp, blk, registry, snapshot_version,
+                                  wal_position)
+                if sp.sampled:
+                    sp.set_tag("bytes", sum(
+                        os.path.getsize(os.path.join(out, name))
+                        for name in os.listdir(out)))
+                return out
         except OSError as e:
             # Typed classification (EIO -> DiskWriteError, ENOSPC ->
             # DiskFullError): the flush path retries/degrades on these

@@ -25,6 +25,7 @@ from . import corpus as qcorpus
 from . import explain as qexplain
 from . import promql
 from ..utils import limits as xlimits
+from ..utils import tracing
 from ..utils.retry import DeadlineExceeded
 from ..utils.tracing import SLOW_QUERIES, span
 from .block import Block, BlockMeta, consolidate_series
@@ -253,8 +254,6 @@ class Engine:
                                route=self.last_route(),
                                trace_id=sp.trace_id or None)
             raise
-        from ..utils import tracing
-
         duration_ns = time.perf_counter_ns() - t0
         SLOW_QUERIES.maybe("query", query, duration_ns,
                            # Lazy SUBTREE rollup: cache events accrue on
@@ -352,12 +351,10 @@ class Engine:
         return self._eval_interp(node, params)
 
     def _eval_interp(self, node: Node, params: QueryParams) -> Value:
-        """Interpreter evaluation, staged under ANALYZE when a context
-        is active (one thread-local read otherwise)."""
-        actx = qexplain.current()
-        if actx is None:
-            return self._eval(node, params)
-        with actx.stage("interpreter_eval"):
+        """Interpreter evaluation: a phase of a detailed span and, from
+        the same hook, an ANALYZE stage when a context is active (one
+        thread-local read otherwise)."""
+        with tracing.phase("interpreter_eval", stage="interpreter_eval"):
             return self._eval(node, params)
 
     def _try_plan(self, node: Node, params: QueryParams) -> Optional[Value]:
@@ -375,14 +372,11 @@ class Engine:
         # bind() fetches + grids every selector through the SAME cached
         # selector paths the interpreter uses and runs the host tag
         # algebra; QueryError (matching violations) carries the
-        # interpreter's exact semantics and propagates. Under ANALYZE
-        # the bind (fetch + host tag algebra) is its own stage.
-        actx = qexplain.current()
-        if actx is None:
+        # interpreter's exact semantics and propagates. The bind (fetch
+        # + host tag algebra) is a phase of a detailed span and, from
+        # the same hook, its own stage under ANALYZE.
+        with tracing.phase("bind", stage="bind"):
             bound = qplan.bind(plan, self, params, slot_values)
-        else:
-            with actx.stage("bind"):
-                bound = qplan.bind(plan, self, params, slot_values)
         if bound.total_cells < qplan.PLAN_MIN_CELLS:
             # Tiny queries keep the interpreter's exact-f64 finishes; the
             # grids just fetched stay warm in the grid cache, so the
@@ -419,8 +413,6 @@ class Engine:
         compiled path, the historical tag vocabulary) + the thread-local
         route record `last_route()` reads (the slow ring, the corpus
         sampler and the ?explain=true HTTP surface)."""
-        from ..utils import tracing
-
         self._local.route_info = {
             "route": route,
             "fallback_reason": reason or None,
@@ -575,8 +567,6 @@ class Engine:
         re-arms every id-keyed device cache downstream (temporal's derived
         cache skips its content hash when the same grid object returns)."""
         from ..utils.instrument import ROOT
-
-        from ..utils import tracing
 
         key = (promql.selector_matchers(sel),
                meta.start_ns, meta.step_ns, meta.steps, lookback_ns)

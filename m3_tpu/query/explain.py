@@ -35,10 +35,9 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-import threading
-import time
 from typing import Dict, Iterator, Optional
 
+from ..utils import tracing
 from . import plan as qplan
 from . import promql
 from .plan import (
@@ -212,14 +211,6 @@ class Analyze:
     def add(self, stage: str, seconds: float):
         self.stages[stage] = self.stages.get(stage, 0.0) + seconds
 
-    @contextlib.contextmanager
-    def stage(self, name: str):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.add(name, time.perf_counter() - t0)
-
     def event(self, name: str, n: float = 1):
         self.events[name] = self.events.get(name, 0) + n
 
@@ -231,23 +222,18 @@ class Analyze:
         }
 
 
-_TLS = threading.local()
-
-
 def current() -> Optional[Analyze]:
     """The thread's active ANALYZE context, or None (the hot-path check:
-    one thread-local read, same shape as tracing's NOOP test)."""
-    return getattr(_TLS, "analyze", None)
+    one thread-local read, same shape as tracing's NOOP test). The
+    context is the thread's stage sink in utils/tracing: the timed sites
+    (`tracing.phase(name, stage=...)`: bind, interpreter_eval,
+    result_materialize) feed spans and ANALYZE from one hook."""
+    return tracing.current_stage_sink()
 
 
 @contextlib.contextmanager
 def analyzing():
     """Install a fresh ANALYZE context for this thread; restores the
     previous one on exit (nesting yields the inner context)."""
-    prev = getattr(_TLS, "analyze", None)
-    ctx = Analyze()
-    _TLS.analyze = ctx
-    try:
+    with tracing.stage_sink(Analyze()) as ctx:
         yield ctx
-    finally:
-        _TLS.analyze = prev

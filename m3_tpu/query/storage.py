@@ -12,7 +12,10 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
+from ..utils import tracing
+from ..utils.tracing import clock_ns as _clock
 from .model import Matcher, matchers_to_index_query
+
 
 
 class LocalStorage:
@@ -29,20 +32,35 @@ class LocalStorage:
         ids = self._db.query_ids(self._namespace, q, start_ns, end_ns)
         out: Dict[bytes, dict] = {}
         ns = self._db.namespace(self._namespace)
+        # Under a detailed span (the caller's query.fetch) the loop's
+        # phases become costs of that span, never child spans: its self
+        # time stays the loop's whole time. One flag read per fetch.
+        acc = tracing.detail()
+        timed = acc is not None
+        t_loop = _clock() if timed else 0
+        tags_ns = 0
         for sid in ids:
             shard_id = self._db.shard_set.lookup(sid)
             shard = ns.shards.get(shard_id)
             if shard is None:
                 continue
-            t, v = shard.read(sid, start_ns, end_ns)
+            t, v = shard.read(sid, start_ns, end_ns, acc)
+            t0 = _clock() if timed else 0
             idx = shard.registry.get(sid)
             tags = shard.registry.tags_of(idx) if idx is not None else {}
+            if timed:
+                tags_ns += _clock() - t0
             out[sid] = {"tags": tags or {}, "t": t, "v": v}
+        if timed:
+            acc.add_cost("series_n", len(ids))
+            acc.add_cost("tags_ns", tags_ns)
+            acc.add_cost("read_ns", _clock() - t_loop)
         return out
 
     def write(self, series_id: bytes, tags: Dict[bytes, bytes], t_ns: int,
-              value: float):
-        self._db.write(self._namespace, series_id, t_ns, value, tags=tags)
+              value: float, acc=None):
+        self._db.write(self._namespace, series_id, t_ns, value, tags=tags,
+                       acc=acc)
 
     def write_batch(self, series_ids: Sequence[bytes], tags: Sequence[dict],
                     ts, vals):
@@ -76,7 +94,9 @@ class SessionStorage:
         return self._session.fetch_tagged(self._namespace, q, start_ns, end_ns)
 
     def write(self, series_id: bytes, tags: Dict[bytes, bytes], t_ns: int,
-              value: float):
+              value: float, acc=None):
+        # `acc`: the write's phases happen on the dbnodes, whose spans
+        # graft into the caller's trace; nothing to account here
         self._session.write_tagged(self._namespace, series_id, tags, t_ns, value)
 
     def complete_tags(self, matchers: Sequence[Matcher], start_ns: int,
@@ -116,7 +136,10 @@ class FanoutStorage:
                         cur["tags"] = entry["tags"]
         return merged
 
-    def write(self, series_id: bytes, tags, t_ns: int, value: float):
+    def write(self, series_id: bytes, tags, t_ns: int, value: float,
+              acc=None):
+        # `acc` stops here: one sample fans out to several stores, and
+        # summing their phases would count it once per store
         for store in self._stores:
             store.write(series_id, tags, t_ns, value)
 

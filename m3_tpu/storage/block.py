@@ -19,7 +19,7 @@ import numpy as np
 
 from ..ops import tsz
 from ..parallel import ingest as par_ingest
-from ..utils import xtime
+from ..utils import tracing, xtime
 from ..utils.checksum import adler32_rows
 from ..utils.instrument import ROOT
 from . import block_cache
@@ -299,29 +299,45 @@ def encode_block(block_start: int, series_indices, tdense, vdense, npoints,
     mesh (Shard._tick_locked seals, mediator snapshots), closing the gap
     where make_sharded_ingest was exercised only by dryrun/bench."""
     s, w = tdense.shape
+    # One span per encoded block when somebody is tracing (the
+    # mediator's tick, a traced request); its phases are costs.
+    with tracing.child_span("encode.block", series=s, window=w):
+        return _encode_block(block_start, series_indices, tdense, vdense,
+                             npoints, max_words)
+
+
+def _encode_block(block_start: int, series_indices, tdense, vdense, npoints,
+                  max_words: Optional[int]) -> SealedBlock:
+    s, w = tdense.shape
     wp = _next_pow2(w)
     sp = _next_pow2(s, floor=1)
-    if wp != w:
-        padc_t = np.repeat(tdense[:, -1:], wp - w, axis=1)
-        padc_v = np.repeat(vdense[:, -1:], wp - w, axis=1)
-        tdense = np.concatenate([tdense, padc_t], axis=1)
-        vdense = np.concatenate([vdense, padc_v], axis=1)
-    npoints = np.asarray(npoints, np.int32)
-    if sp != s:
-        tdense = np.concatenate([tdense, np.repeat(tdense[:1], sp - s, axis=0)])
-        vdense = np.concatenate([vdense, np.repeat(vdense[:1], sp - s, axis=0)])
-        npoints = np.concatenate([npoints, np.ones(sp - s, np.int32)])
+    with tracing.phase("pad"):
+        if wp != w:
+            padc_t = np.repeat(tdense[:, -1:], wp - w, axis=1)
+            padc_v = np.repeat(vdense[:, -1:], wp - w, axis=1)
+            tdense = np.concatenate([tdense, padc_t], axis=1)
+            vdense = np.concatenate([vdense, padc_v], axis=1)
+        npoints = np.asarray(npoints, np.int32)
+        if sp != s:
+            tdense = np.concatenate(
+                [tdense, np.repeat(tdense[:1], sp - s, axis=0)])
+            vdense = np.concatenate(
+                [vdense, np.repeat(vdense[:1], sp - s, axis=0)])
+            npoints = np.concatenate([npoints, np.ones(sp - s, np.int32)])
     window = wp
-    unit = choose_time_unit(tdense)
-    mw = max_words if max_words is not None else tsz.max_words_for(window)
-    inp = tsz.prepare_encode_inputs(tdense // unit.nanos, vdense, npoints)
+    with tracing.phase("prepare"):
+        unit = choose_time_unit(tdense)
+        mw = max_words if max_words is not None else tsz.max_words_for(window)
+        inp = tsz.prepare_encode_inputs(tdense // unit.nanos, vdense, npoints)
+    # the encode's enqueue is `dispatch_ns` (parallel/guard.py)
     got = par_ingest.flush_encode_prepared(inp, max_words=mw)
     if got is not None:
         words, nbits = got
         _FLUSH_METRICS.counter("mesh_encode").inc()
     else:
         words, nbits = tsz.encode_prepared(inp, max_words=mw)
-    boundary = tsz.boundary_metadata(inp)
+    with tracing.phase("prepare"):
+        boundary = tsz.boundary_metadata(inp)
     # Keep the just-encoded DEVICE buffers (padded [sp, mw] words + padded
     # npoints — exactly what a later whole-block decode consumes) for the
     # device block cache: the seal hook (Shard._tick_locked) adopts them
@@ -332,8 +348,10 @@ def encode_block(block_start: int, series_indices, tdense, vdense, npoints,
     encoded_dev = None
     if block_cache.wants_encoded():
         encoded_dev = (words, np.asarray(npoints, np.int32))
-    words = np.asarray(words)[:s]
-    nbits = np.asarray(nbits)[:s]
+    with tracing.phase("device_wait"):     # the device finishes, then D2H
+        words = np.asarray(words)[:s]
+        nbits = np.asarray(nbits)[:s]
+    tracing.count_cost("d2h_bytes", words.nbytes + nbits.nbytes)
     # Every pack backend silently drops bits past max_words; an undersized
     # caller-supplied bound would seal truncated, undecodable streams.
     tsz.check_cursor(nbits, mw)

@@ -20,6 +20,7 @@ from ..utils.health import DiskHealth, Priority
 from ..utils.instrument import ROOT
 from ..utils.limits import Backpressure
 from ..utils.retry import RetryOptions, Retrier
+from ..utils.tracing import clock_ns as _clock
 from .namespace import Namespace, NamespaceOptions
 from .series import charge_read
 
@@ -123,17 +124,24 @@ class Database:
     # ------------------------------------------------------------------ write
 
     def write(self, namespace: bytes, series_id: bytes, t_ns: int, value: float,
-              tags: Optional[dict] = None, priority=None):
-        """database.go:536 Write + :561 commit log append."""
+              tags: Optional[dict] = None, priority=None, acc=None):
+        """database.go:536 Write + :561 commit log append. `acc` (a
+        detailed span, utils.tracing.detail, read once by the caller's
+        loop) receives `id_ns` (the shard hash), `buffer_ns` (the shard
+        append, its `lock_wait_ns` inside it) and `commitlog_ns`."""
         ns = self.namespace(namespace)
         self._check_writable(priority)
+        timed = acc is not None
+        t0 = _clock() if timed else 0
         shard_id = self.shard_set.lookup(series_id)
         now = self.clock()
+        t1 = _clock() if timed else 0
         if priority is None:
-            ns.write(shard_id, series_id, t_ns, value, now, tags)
+            ns.write(shard_id, series_id, t_ns, value, now, tags, acc=acc)
         else:
             ns.shard_for(shard_id).write(series_id, t_ns, value, now, tags,
-                                         priority=priority)
+                                         priority=priority, acc=acc)
+        t2 = _clock() if timed else 0
         if self.commitlog is not None and ns.opts.writes_to_commitlog:
             try:
                 self.commitlog.write(namespace, series_id, t_ns, value, tags)
@@ -143,6 +151,10 @@ class Database:
                 self.disk_health.failure()
                 raise
             self.disk_health.success()
+        if timed:
+            acc.add_cost("id_ns", t1 - t0)
+            acc.add_cost("buffer_ns", t2 - t1)
+            acc.add_cost("commitlog_ns", _clock() - t2)
 
     def write_batch(self, namespace: bytes, ids: Sequence[bytes], ts, vals,
                     tags: Optional[Sequence[Optional[dict]]] = None,
@@ -240,8 +252,9 @@ class Database:
         per-second windows."""
         ns = self.namespace(namespace)
         with tracing.child_span("storage.read") as sp:
+            # the read's phases (Shard.read) land on this span as costs
             t, v = ns.read(self.shard_set.lookup(series_id), series_id,
-                           start_ns, end_ns)
+                           start_ns, end_ns, sp if sp.detailed else None)
             sp.set_tag("points", len(t))
         charge_read(n_series=1, n_points=len(t), n_bytes=t.nbytes + v.nbytes)
         return t, v

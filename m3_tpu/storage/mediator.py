@@ -20,7 +20,7 @@ from typing import Dict, Optional
 from ..persist.diskio import DiskWriteError
 from ..persist.fs import PersistManager
 from ..storage.block import encode_block
-from ..utils import xtime
+from ..utils import tracing, xtime
 
 
 @dataclasses.dataclass
@@ -44,13 +44,26 @@ class Mediator:
     # ------------------------------------------------------------------ steps
 
     def run_once(self, now_ns: Optional[int] = None) -> Dict[str, int]:
-        now = now_ns if now_ns is not None else self.db.clock()
-        stats = dict(self.db.tick(now))
-        if self.persist is not None:
-            stats["flushed"] = self.db.flush(self.persist, now)
-            if self.opts.snapshot_enabled:
-                stats["snapshotted"] = self.snapshot(now)
-            stats["cleaned"] = self.cleanup(now)
+        """One tick, under its own root span (`mediator.tick`, a
+        background root: whoever drives the tick — `start`'s loop, a
+        test, a benchmark's ticker — gets the same tree). One child per
+        step; below them `encode.block` (storage/block.py) and
+        `persist.write` (persist/fs.py) per block; the tick's stats are
+        the root's tags."""
+        with tracing.background_span("mediator.tick") as root:
+            now = now_ns if now_ns is not None else self.db.clock()
+            with tracing.child_span("mediator.seal"):
+                stats = dict(self.db.tick(now))
+            if self.persist is not None:
+                with tracing.child_span("mediator.flush"):
+                    stats["flushed"] = self.db.flush(self.persist, now)
+                if self.opts.snapshot_enabled:
+                    with tracing.child_span("mediator.snapshot"):
+                        stats["snapshotted"] = self.snapshot(now)
+                with tracing.child_span("mediator.cleanup"):
+                    stats["cleaned"] = self.cleanup(now)
+            for k, v in stats.items():
+                root.set_tag(k, v)
         self.last_stats = stats
         return stats
 
@@ -90,9 +103,10 @@ class Mediator:
             except ValueError:
                 wal_position = None  # closed log: snapshot without one
         if wal_position is not None:
-            for ns in list(self.db.namespaces.values()):
-                for shard in ns.shards.values():
-                    shard.insert_queue.drain()
+            with tracing.phase("drain"):
+                for ns in list(self.db.namespaces.values()):
+                    for shard in ns.shards.values():
+                        shard.insert_queue.drain()
         count = 0
         for ns in list(self.db.namespaces.values()):
             if not ns.opts.snapshot_enabled:
@@ -112,9 +126,11 @@ class Mediator:
                         # (the pre-existing snapshot, if any, remains
                         # the newest for this block start).
                         continue
-                    dense = shard.buffer.snapshot(bs)
+                    with tracing.phase("buffer_snapshot"):
+                        dense = shard.buffer.snapshot(bs)
                     if dense is None:
                         continue
+                    tracing.count_cost("buckets_n")
                     series, tdense, vdense, npoints = dense
                     blk = encode_block(bs, series, tdense, vdense, npoints)
                     try:

@@ -104,11 +104,13 @@ class Namespace:
         return sh
 
     def write(self, shard_id: int, series_id: bytes, t_ns: int, value: float,
-              now_ns: int, tags: Optional[dict] = None):
-        self.shard_for(shard_id).write(series_id, t_ns, value, now_ns, tags)
+              now_ns: int, tags: Optional[dict] = None, acc=None):
+        self.shard_for(shard_id).write(series_id, t_ns, value, now_ns, tags,
+                                       acc=acc)
 
-    def read(self, shard_id: int, series_id: bytes, start_ns: int, end_ns: int):
-        return self.shard_for(shard_id).read(series_id, start_ns, end_ns)
+    def read(self, shard_id: int, series_id: bytes, start_ns: int, end_ns: int,
+             acc=None):
+        return self.shard_for(shard_id).read(series_id, start_ns, end_ns, acc)
 
     def tick(self, now_ns: int) -> dict:
         totals = {"sealed": 0, "expired": 0}

@@ -23,6 +23,7 @@ from ..persist.diskio import CorruptionError
 from ..utils import xtime
 from ..utils.health import Priority
 from ..utils.instrument import ROOT
+from ..utils.tracing import clock_ns as _clock
 from . import block_cache
 from .block import SealedBlock, encode_block, merge_same_start
 from .buffer import ShardBuffer
@@ -115,7 +116,9 @@ class Shard:
 
     def write(self, series_id: bytes, t_ns: int, value: float, now_ns: int,
               tags: Optional[dict] = None,
-              priority: Priority = Priority.NORMAL) -> bool:
+              priority: Priority = Priority.NORMAL, acc=None) -> bool:
+        """`acc` (a detailed span, utils.tracing.detail) receives
+        `lock_wait_ns`: the time this write waited for the shard lock."""
         if not self.buffer.accepts(now_ns, t_ns):
             raise ValueError(
                 f"datapoint at {t_ns} outside acceptance window at {now_ns} "
@@ -124,7 +127,10 @@ class Shard:
         idx = self.registry.get(series_id)  # lock-free snapshot resolve
         if idx is not None:
             self.registry.ensure_tags(idx, tags)
+            t0 = _clock() if acc is not None else 0
             with self.write_lock:
+                if acc is not None:
+                    acc.add_cost("lock_wait_ns", _clock() - t0)
                 self.buffer.write(idx, t_ns, value)
             return False
         self.insert_queue.insert(
@@ -317,14 +323,22 @@ class Shard:
         self._retriever = retriever
         self._retriever_ns = namespace_name
 
-    def read(self, series_id: bytes, start_ns: int, end_ns: int) -> Tuple[np.ndarray, np.ndarray]:
+    def read(self, series_id: bytes, start_ns: int, end_ns: int,
+             acc=None) -> Tuple[np.ndarray, np.ndarray]:
         """Merged datapoints from sealed blocks + mutable buffer + disk in
         [start, end).
 
         Block starts resident in memory are served from `self.blocks`; block
         starts only on disk fall through to the retriever (seek + WiredList),
         mirroring series.go:292 ReadEncoded -> buffer, cached blocks, then
-        the retriever for everything else."""
+        the retriever for everything else.
+
+        `acc` (a detailed span, utils.tracing.detail) receives where this
+        read's time went: `lock_wait_ns` (waiting for the shard lock),
+        `buffer_ns` (the buffer read under it), `block_ns` / `block_n`
+        (the overlapping blocks' reads: cache hit, decode or disk) and
+        `merge_ns` (clip, concatenate, sort, dedup)."""
+        timed = acc is not None
         idx = self.registry.get(series_id)
         parts_t: List[np.ndarray] = []
         parts_v: List[np.ndarray] = []
@@ -344,17 +358,23 @@ class Shard:
         # blocks and creates buffer buckets concurrently); SealedBlocks are
         # immutable once referenced, and the buffer read happens inside the
         # lock, so the decode/clip work below runs lock-free.
+        t0 = _clock() if timed else 0
         with self.write_lock:
+            t1 = _clock() if timed else 0
             blocks = dict(self.blocks)
             if idx is not None:
                 bt, bv = self.buffer.read(idx, start_ns, end_ns)
             else:
                 bt = bv = None
+        # Read every overlapping block, then clip: two passes, so that a
+        # timed read takes two clock reads a series and not two a block.
+        got: List[Optional[tuple]] = []
+        t2 = _clock() if timed else 0
         if idx is not None:
             for bs in sorted(blocks):
                 if overlaps(bs):
                     try:
-                        clip_append(blocks[bs].read(idx))
+                        got.append(blocks[bs].read(idx))
                     except CorruptionError:
                         # A block paged in from a fileset flunked its lazy
                         # row verification mid-serve: drop it and keep
@@ -370,28 +390,38 @@ class Shard:
                 if (self._retention_cutoff is not None
                         and bs + self.opts.block_size_ns <= self._retention_cutoff):
                     continue  # past retention; cleanup just hasn't run yet
-                clip_append(self._retriever.retrieve(
+                got.append(self._retriever.retrieve(
                     self._retriever_ns, self.shard_id, bs, series_id))
+        t3 = _clock() if timed else 0
+        for g in got:
+            clip_append(g)
         if bt is not None and len(bt):
             parts_t.append(bt)
             parts_v.append(bv)
         if not parts_t:
-            return np.zeros(0, np.int64), np.zeros(0, np.float64)
-        t = np.concatenate(parts_t)
-        v = np.concatenate(parts_v)
-        order = np.argsort(t, kind="stable")
-        t, v = t[order], v[order]
-        if len(t) > 1 and (t[:-1] == t[1:]).any():
-            # A sealed block and the mutable buffer can briefly cover the
-            # same (series, timestamp): a snapshot-recovered block with
-            # the WAL tail replayed on top (the conservative chunk-window
-            # overlap), or a write racing a seal before the same-start
-            # merge folds it in. Last-arrival wins, matching the buffer's
-            # own drain dedup — parts append blocks-then-buffer and the
-            # sort is stable, so keeping the final duplicate keeps the
-            # buffer's (newer) value.
-            keep = np.concatenate([t[:-1] != t[1:], [True]])
-            t, v = t[keep], v[keep]
+            t, v = np.zeros(0, np.int64), np.zeros(0, np.float64)
+        else:
+            t = np.concatenate(parts_t)
+            v = np.concatenate(parts_v)
+            order = np.argsort(t, kind="stable")
+            t, v = t[order], v[order]
+            if len(t) > 1 and (t[:-1] == t[1:]).any():
+                # A sealed block and the mutable buffer can briefly cover
+                # the same (series, timestamp): a snapshot-recovered block
+                # with the WAL tail replayed on top (the conservative
+                # chunk-window overlap), or a write racing a seal before
+                # the same-start merge folds it in. Last-arrival wins,
+                # matching the buffer's own drain dedup — parts append
+                # blocks-then-buffer and the sort is stable, so keeping
+                # the final duplicate keeps the buffer's (newer) value.
+                keep = np.concatenate([t[:-1] != t[1:], [True]])
+                t, v = t[keep], v[keep]
+        if timed:
+            acc.add_cost("lock_wait_ns", t1 - t0)
+            acc.add_cost("buffer_ns", t2 - t1)
+            acc.add_cost("block_ns", t3 - t2)
+            acc.add_cost("block_n", len(got))
+            acc.add_cost("merge_ns", _clock() - t3)
         return t, v
 
     def _drop_corrupt_block(self, bs: int, blk: SealedBlock) -> None:

@@ -16,8 +16,25 @@ Sampling: root spans are sampled at `M3_TPU_TRACE_SAMPLE` (default 1.0);
 an unsampled root is the shared no-op span, children of no span are
 no-ops too (`child_span`), and unsampled requests never attach a wire
 context — so the hot path's cost when tracing is off is one thread-local
-read (proven <3% on the write/index benches by
-scripts/obs_overhead_guard.py even with tracing ON).
+read per call site (scripts/obs_overhead_guard.py holds the write/index
+benches to <3% on a CPU container; PERF.md section 6 has what a traced
+and an untraced run cost on the chip's host).
+
+Detail: a root that was ASKED for — `span_from` with a context (the
+`X-M3-Trace` header, a wire `"tr"` field) — or a background root
+(`background_span`: the mediator's tick) is `detailed`, and so is every
+span below it. Only detailed spans read their thread's CPU time
+(`tags["cpu_ns"]`: wall minus CPU is time spent waiting — for the GIL, a
+lock, a socket, the device) and only under them do `phase` and the
+per-series / per-sample accumulators (`detail()`, then `add_cost`) run.
+A head-sampled root (`span`) is not detailed: an untraced request opens
+the spans it always opened and pays nothing more.
+
+Phases: `phase(name)` times a stretch of the CURRENT span into its
+`costs` (`<name>_ns`, `<name>_n`) instead of opening a child span, so a
+span's self time keeps its meaning; given a `stage` it also feeds the
+thread's stage sink (query/explain.py's ANALYZE context) from the same
+site. Spans never synchronise a device dispatch; ANALYZE does.
 
 Slow queries: a bounded ring of {name, duration, typed reason, costs}
 entries (`SLOW_QUERIES`) — reasons are `limit-shed` (ResourceExhausted),
@@ -47,6 +64,10 @@ import traceback
 from typing import Dict, List, NamedTuple, Optional
 
 # ---------------------------------------------------------------- spans
+
+# The spans' clock, for the sites that time phases by hand: CLOCK_MONOTONIC,
+# which the benchmark's load generator and profiler annotation share.
+clock_ns = time.perf_counter_ns
 
 
 class SpanContext(NamedTuple):
@@ -85,16 +106,24 @@ def _new_id() -> int:
 
 class Span:
     __slots__ = ("name", "tags", "start_ns", "end_ns", "children", "costs",
-                 "trace_id", "span_id", "remote_parent", "_tracer", "_parent")
+                 "trace_id", "span_id", "remote_parent", "detailed", "_cpu0",
+                 "_tracer", "_parent")
 
     sampled = True  # real spans exist only when sampled
 
     def __init__(self, name: str, tracer: "Tracer", parent: Optional["Span"],
                  tags: Optional[dict] = None,
-                 remote: Optional[SpanContext] = None):
+                 remote: Optional[SpanContext] = None,
+                 start_ns: Optional[int] = None,
+                 cpu_start_ns: Optional[int] = None):
+        """`start_ns` (perf_counter_ns) backdates the span to a stamp
+        taken before it could be opened — the accept time of a
+        connection; `cpu_start_ns` is the thread's CPU clock at that
+        stamp (0 for a thread born with the connection)."""
         self.name = name
         self.tags = dict(tags or {})
-        self.start_ns = time.perf_counter_ns()
+        self.start_ns = time.perf_counter_ns() if start_ns is None \
+            else start_ns
         self.end_ns: Optional[int] = None
         self.children: List = []  # Span or grafted remote dicts
         self.costs: Dict[str, float] = {}
@@ -106,6 +135,9 @@ class Span:
             self.trace_id = _new_id()
         self.span_id = _new_id()
         self.remote_parent = remote.span_id if remote is not None else None
+        self.detailed = parent.detailed if parent is not None \
+            else remote is not None
+        self._cpu0 = cpu_start_ns
         self._tracer = tracer
         self._parent = parent
 
@@ -134,10 +166,16 @@ class Span:
 
     def __enter__(self) -> "Span":
         self._tracer._push(self)
+        if self.detailed and self._cpu0 is None:
+            self._cpu0 = time.thread_time_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         self.end_ns = time.perf_counter_ns()
+        if self.detailed:
+            # in tags, not costs: collect_costs sums costs over a
+            # subtree, and nested CPU times would count twice
+            self.tags["cpu_ns"] = time.thread_time_ns() - self._cpu0
         if exc_type is not None:
             self.tags["error"] = repr(exc)
         self._tracer._pop(self)
@@ -146,7 +184,10 @@ class Span:
     def to_dict(self) -> dict:
         return {
             "name": self.name,
+            "start_ns": self.start_ns,
             "duration_us": round(self.duration_ns / 1000, 1),
+            **({"cpu_us": round(self.tags["cpu_ns"] / 1000, 1)}
+               if "cpu_ns" in self.tags else {}),
             "trace_id": self.trace_id,
             "span_id": self.span_id,
             **({"remote_parent": self.remote_parent}
@@ -165,6 +206,7 @@ class _NoopSpan:
 
     __slots__ = ()
     sampled = False
+    detailed = False
     name = ""
     tags: dict = {}
     costs: dict = {}
@@ -235,22 +277,39 @@ class Tracer:
             return Span(name, self, None, tags)
         return Span(name, self, parent, tags)
 
-    def child_span(self, name: str, **tags):
+    def child_span(self, name: str, start_ns: Optional[int] = None,
+                   cpu_start_ns: Optional[int] = None, **tags):
         """A span ONLY when sampled work is already in flight — the
         hot-path-safe form for storage/index internals: with no active
         span (benchmarks, bare calls) the cost is one thread-local read."""
         parent = getattr(self._local, "current", None)
         if parent is None:
             return NOOP_SPAN
-        return Span(name, self, parent, tags)
+        return Span(name, self, parent, tags, start_ns=start_ns,
+                    cpu_start_ns=cpu_start_ns)
 
-    def span_from(self, ctx: Optional[SpanContext], name: str, **tags):
+    def span_from(self, ctx: Optional[SpanContext], name: str,
+                  start_ns: Optional[int] = None,
+                  cpu_start_ns: Optional[int] = None, **tags):
         """Remote-parented root for a propagated wire context (rpc
-        dispatch, msg consume, kv ops); NOOP when the request carried no
-        context (the caller was unsampled or untraced)."""
+        dispatch, msg consume, kv ops, the HTTP `X-M3-Trace` header);
+        NOOP when the request carried no context (the caller was
+        unsampled or untraced). The caller asked for this trace, so the
+        root is detailed."""
         if ctx is None:
             return NOOP_SPAN
-        return Span(name, self, None, tags, remote=ctx)
+        return Span(name, self, None, tags, remote=ctx, start_ns=start_ns,
+                    cpu_start_ns=cpu_start_ns)
+
+    def background_span(self, name: str, **tags):
+        """Root of background work nobody's request waits on (the
+        mediator's tick): sampling-gated like `span`, and detailed — a
+        few such roots a minute can afford what a request root cannot.
+        A child, and its parent's kind, when some span is active."""
+        sp = self.span(name, **tags)
+        if sp.sampled and sp._parent is None:
+            sp.detailed = True
+        return sp
 
     def current(self) -> Optional[Span]:
         return getattr(self._local, "current", None)
@@ -299,6 +358,18 @@ def child_span(name: str, **tags):
     return TRACER.child_span(name, **tags)
 
 
+def background_span(name: str, **tags):
+    return TRACER.background_span(name, **tags)
+
+
+def detail() -> Optional[Span]:
+    """The active span when it is detailed, else None: the ONE flag read
+    a per-series or per-sample loop makes, before the loop. The loop
+    then hands the span down (`acc`) and its sites `add_cost` on it."""
+    cur = getattr(TRACER._local, "current", None)
+    return cur if cur is not None and cur.detailed else None
+
+
 def count_cost(kind: str, n: float = 1):
     """Tally a cost/cache event onto the active span, if any — the
     charge-site hook block/grid caches and QueryScope exits use. One
@@ -329,6 +400,88 @@ def collect_costs(span) -> Dict[str, float]:
 
     walk(span)
     return out
+
+
+# ---------------------------------------------------------------- phases
+
+# Threads with a stage sink installed, process-wide: a phase site reads
+# the thread-local sink only while some ANALYZE runs somewhere, so an
+# ordinary request pays one thread-local read per site (`current`).
+_SINKS_ACTIVE = 0
+_SINK_LOCK = threading.Lock()
+_SINK = threading.local()
+
+
+def current_stage_sink():
+    """This thread's stage sink (an object with `add(stage, seconds)`:
+    query/explain.py's Analyze), or None."""
+    return getattr(_SINK, "sink", None)
+
+
+@contextlib.contextmanager
+def stage_sink(sink):
+    """Install `sink` for this thread; the previous one returns on exit."""
+    global _SINKS_ACTIVE
+    prev = getattr(_SINK, "sink", None)
+    _SINK.sink = sink
+    with _SINK_LOCK:
+        _SINKS_ACTIVE += 1
+    try:
+        yield sink
+    finally:
+        with _SINK_LOCK:
+            _SINKS_ACTIVE -= 1
+        _SINK.sink = prev
+
+
+class _Phase:
+    __slots__ = ("key", "span", "stage", "sink", "t0")
+
+    def __init__(self, key: str, span, stage: str, sink):
+        self.key, self.span, self.stage, self.sink = key, span, stage, sink
+
+    def __enter__(self):
+        span = self.span
+        if span is not None:
+            # A phase inside the same phase (guard routes nest:
+            # block.decode over codec.decode) is the outer one's time.
+            is_open = TRACER._local.__dict__.setdefault("phases", set())
+            if self.key in is_open:
+                self.span = None
+            else:
+                is_open.add(self.key)
+        self.t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc):
+        dt = time.perf_counter_ns() - self.t0
+        span = self.span
+        if span is not None:
+            TRACER._local.phases.discard(self.key)
+            costs = span.costs
+            costs[self.key + "_ns"] = costs.get(self.key + "_ns", 0) + dt
+            costs[self.key + "_n"] = costs.get(self.key + "_n", 0) + 1
+        if self.sink is not None:
+            self.sink.add(self.stage, dt / 1e9)
+        return False
+
+
+_NOOP_PHASE = contextlib.nullcontext()
+
+
+def phase(name: str, stage: Optional[str] = None):
+    """Time a stretch of the current span WITHOUT a child span: under a
+    detailed root, `<name>_ns` and `<name>_n` accumulate in the span's
+    costs; with `stage` given and an ANALYZE context active on this
+    thread, the same stretch lands there under that stage name. Neither
+    active: a shared no-op."""
+    cur = getattr(TRACER._local, "current", None)
+    span = cur if cur is not None and cur.detailed else None
+    sink = getattr(_SINK, "sink", None) \
+        if stage is not None and _SINKS_ACTIVE else None
+    if span is None and sink is None:
+        return _NOOP_PHASE
+    return _Phase(name, span, stage, sink)
 
 
 # ---------------------------------------------------------- slow queries
