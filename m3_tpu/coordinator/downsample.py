@@ -91,10 +91,12 @@ class Downsampler:
         metric_type) rows: single match pass, grouped aggregator adds.
         Returns (matched, dropped) — the same per-sample accounting the
         per-metric path keeps in samples_matched/samples_dropped."""
+        if not self._matcher.has_rules():
+            return 0, 0  # asked before a row is read or an id encoded
         samples = list(samples)
         mids = [_encode_tags(tags) for tags, _t, _v, _mt in samples]
         results = self._matcher.match_batch(mids)
-        if results is None:
+        if results is None:  # the rule set went between the two reads
             return 0, 0
         n = len(samples)
         accepted = [False] * n

@@ -9,7 +9,6 @@ the high-volume path — the wire format carries numpy columns end-to-end."""
 
 from __future__ import annotations
 
-import functools
 import json
 import math
 import re
@@ -408,18 +407,16 @@ class HTTPApi:
                 series = promremote.decode_write_request(raw)
         except (promremote.SnappyError, promremote.ProtoError) as e:
             raise HTTPError(400, f"bad remote write body: {e}")
-        wrote = 0
         with tracing.child_span("remote_write.append") as sp:
-            # sample by sample; under a traced request each write also
-            # accounts its phases on this span (one flag read a request)
-            write = self.writer.write if not sp.detailed else \
-                functools.partial(self.writer.write, acc=sp)
-            for tags, samples in series:
-                for t_ms, value in samples:
-                    write(tags, t_ms * 1_000_000, value)
-                    wrote += 1
-            sp.add_cost("samples_n", wrote)
-        return {"status": "success", "wrote": wrote}
+            # the request as ONE batch: one admission, one append per
+            # shard touched, one commit-log append. A traced request's
+            # phases (`id_ns`, `buffer_ns`, `commitlog_ns`) land on this
+            # span once, from the layers below.
+            rows = [(tags, t_ms * 1_000_000, value)
+                    for tags, samples in series for t_ms, value in samples]
+            self.writer.write_batch(rows)
+            sp.add_cost("samples_n", len(rows))
+        return {"status": "success", "wrote": len(rows)}
 
     def prom_remote_read(self, req):
         """remote/read.go — snappy+proto prompb.ReadRequest in,
@@ -598,6 +595,12 @@ class _StampingServer(ThreadingHTTPServer):
     span starts there and not where its handler is called: the thread's
     start, the header parse and the body read are the front's cost.
     One perf_counter_ns a connection, whether traced or not."""
+
+    # socketserver's 5 is fewer than the senders of one Prometheus: the
+    # accepting thread shares the GIL with every handler thread, and a
+    # connection that finds the listen queue full is dropped and tried
+    # again by its client a second later.
+    request_queue_size = 128
 
     def __init__(self, *args, **kw):
         self.accepted_ns: Dict[object, int] = {}    # by connection socket

@@ -10,13 +10,16 @@ from __future__ import annotations
 from typing import Callable, Dict, Optional, Sequence
 
 from ..aggregator.handler import decode_aggregated_batch
+from ..metrics import id as metric_id
 from ..metrics.metric import MetricType
+from ..utils import tracing
 from ..utils.health import AdmissionGate, Priority
 from ..utils.instrument import ROOT
-from ..utils.tracing import clock_ns
 from .downsample import Downsampler
 
 _scope = ROOT.sub_scope("coordinator.ingest")
+_BATCHES = _scope.counter("batches")
+_BATCH_SAMPLES = _scope.counter("batch_samples")
 
 
 class DownsamplerAndWriter:
@@ -42,30 +45,21 @@ class DownsamplerAndWriter:
     def write(self, tags: Dict[bytes, bytes], t_nanos: int, value: float,
               metric_type: MetricType = MetricType.GAUGE,
               downsample: bool = True, write_unaggregated: bool = True,
-              priority: Priority = Priority.NORMAL, acc=None):
+              priority: Priority = Priority.NORMAL):
         """write.go WriteBatch dual path. Raises Backpressure when the
-        admission gate sheds this priority class. `acc` (a detailed
-        span, utils.tracing.detail, read once by the caller's loop)
-        receives `id_ns` and, from the storage below, the write's other
-        phases."""
+        admission gate sheds this priority class."""
         with self.gate.held(priority=priority):
             self._write_admitted(tags, t_nanos, value, metric_type,
-                                 downsample, write_unaggregated, acc)
+                                 downsample, write_unaggregated)
 
     def _write_admitted(self, tags, t_nanos, value, metric_type,
-                        downsample, write_unaggregated, acc=None):
+                        downsample, write_unaggregated):
         if downsample and self._downsampler is not None:
             if self._downsampler.write(tags, t_nanos, value, metric_type):
                 self.downsampled += 1
                 _scope.counter("downsampled").inc()
         if write_unaggregated:
-            if acc is None:
-                self._storage.write(_series_id(tags), tags, t_nanos, value)
-            else:
-                t0 = clock_ns()
-                sid = _series_id(tags)
-                acc.add_cost("id_ns", clock_ns() - t0)
-                self._storage.write(sid, tags, t_nanos, value, acc=acc)
+            self._storage.write(_series_id(tags), tags, t_nanos, value)
             self.written += 1
             _scope.counter("written").inc()
 
@@ -90,8 +84,10 @@ class DownsamplerAndWriter:
         write_unaggregated = kw.get("write_unaggregated", True)
         with self.gate.held(len(samples), priority=priority):
             if downsample and self._downsampler is not None:
+                # a generator: with no rule set installed the
+                # downsampler answers before a row of it is built
                 matched, dropped = self._downsampler.write_batch(
-                    [(tags, t, v, metric_type) for tags, t, v in samples])
+                    (tags, t, v, metric_type) for tags, t, v in samples)
                 # write() counts a sample as downsampled when the
                 # downsampler accepted it — DROP_MUST drops included.
                 accepted = matched + dropped
@@ -100,9 +96,12 @@ class DownsamplerAndWriter:
                     _scope.counter("downsampled").inc(accepted)
             if write_unaggregated:
                 self._storage_write_batch(samples)
+        _BATCHES.inc()
+        _BATCH_SAMPLES.inc(len(samples))
 
     def _storage_write_batch(self, samples: Sequence[tuple]):
-        sids = [_series_id(tags) for tags, _t, _v in samples]
+        with tracing.phase("id"):  # `id_ns` of a detailed span
+            sids = [_series_id(tags) for tags, _t, _v in samples]
         batch_write = getattr(self._storage, "write_batch", None)
         if batch_write is not None:
             batch_write(sids, [s[0] for s in samples],
@@ -129,8 +128,6 @@ class M3MsgIngester:
         self.ingested = 0
 
     def __call__(self, shard: int, payload: bytes):
-        from ..metrics import id as metric_id
-
         # CRITICAL priority: this is the aggregation pipeline's own
         # output, already accepted and acked upstream — shedding it here
         # would silently lose aggregated data the platform promised to
@@ -156,8 +153,6 @@ class M3MsgIngester:
 
 
 def _series_id(tags: Dict[bytes, bytes]) -> bytes:
-    from ..metrics import id as metric_id
-
     name = tags.get(b"__name__", b"")
     return metric_id.encode(name, {k: v for k, v in tags.items()
                                    if k != b"__name__"})

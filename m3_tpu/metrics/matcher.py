@@ -193,6 +193,13 @@ class Matcher:
             self._compiled = None
             self._generation += 1
 
+    def has_rules(self) -> bool:
+        """Whether a rule set is installed: what a batch caller asks
+        before it encodes a batch of ids that match_batch would answer
+        None for. One reference read; match_batch reads again under the
+        lock."""
+        return self._active is not None
+
     def match(self, metric_id: bytes,
               from_nanos: Optional[int] = None,
               to_nanos: Optional[int] = None) -> Optional[MatchResult]:

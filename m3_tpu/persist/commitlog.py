@@ -53,6 +53,13 @@ _CHUNK_HEADER = struct.Struct("<II")      # payload_len, adler32
 # query after kill -9.
 _META_ENTRY = struct.Struct("<BHHH")
 _DATA_ENTRY = struct.Struct("<BIqd")      # tag=1, series_ref, time_ns, value
+# One data entry (tag=1) viewed columnar: numpy's packed layout of this
+# dtype is byte-identical to _DATA_ENTRY's struct layout, so a run of
+# consecutive data entries decodes as ONE frombuffer view (replay) and
+# a batch of known series packs as one `tobytes` (write_batch).
+_DATA_DTYPE = np.dtype([("tag", "u1"), ("ref", "<u4"),
+                        ("t", "<i8"), ("v", "<f8")])
+assert _DATA_DTYPE.itemsize == _DATA_ENTRY.size
 
 
 class Strategy(enum.Enum):
@@ -210,13 +217,31 @@ class CommitLog:
             self._maybe_flush()
 
     def write_batch(self, namespace: bytes, ids, ts, vals, tags=None):
+        """One lock acquisition a batch, held as briefly as the batch
+        allows: a batch of series this file already knows (every scrape
+        but a file's first) is packed as ONE column-built run of data
+        entries, the same bytes the entry-by-entry loop appends. Every
+        writer queues on this lock, and a holder that loses the GIL
+        mid-loop keeps them all waiting."""
+        keys = [(namespace, sid) for sid in ids]
+        rows = np.empty(len(keys), _DATA_DTYPE)
+        rows["tag"], rows["t"], rows["v"] = 1, ts, vals
         with self._lock:
             if self._f is None:
                 raise ValueError("commit log is closed")
-            for i, (sid, t, v) in enumerate(zip(ids, ts, vals)):
-                ref = self._ref(namespace, sid,
-                                tags[i] if tags is not None else None)
-                self._buf += _DATA_ENTRY.pack(1, ref, int(t), float(v))
+            refs = list(map(self._series_refs.get, keys))
+            if None in refs or (tags is not None and not
+                                self._untagged_keys.isdisjoint(keys)):
+                # a meta entry is due (first sighting this file, or a
+                # tagged write of a series logged untagged): it has to
+                # precede its data entry, so entry by entry
+                for i, (sid, t, v) in enumerate(zip(ids, ts, vals)):
+                    ref = self._ref(namespace, sid,
+                                    tags[i] if tags is not None else None)
+                    self._buf += _DATA_ENTRY.pack(1, ref, int(t), float(v))
+            else:
+                rows["ref"] = refs
+                self._buf += rows.tobytes()
             self._maybe_flush()
 
     def _maybe_flush(self):
@@ -347,14 +372,6 @@ def _iter_chunks(path: str) -> Iterator[Tuple[bytes, int]]:
                 return  # torn/corrupt tail chunk: stop replaying this file
             offset += _CHUNK_HEADER.size + plen
             yield body, offset
-
-
-# One decoded data entry (tag=1) viewed columnar: numpy's packed layout
-# of this dtype is byte-identical to _DATA_ENTRY's struct layout, so a
-# run of consecutive data entries decodes as ONE frombuffer view.
-_DATA_DTYPE = np.dtype([("tag", "u1"), ("ref", "<u4"),
-                        ("t", "<i8"), ("v", "<f8")])
-assert _DATA_DTYPE.itemsize == _DATA_ENTRY.size
 
 
 class ReplayBatch(NamedTuple):

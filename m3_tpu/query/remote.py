@@ -209,12 +209,10 @@ class RemoteStorage:
         }
 
     def write(self, series_id: bytes, tags, t_ns: int, value: float,
-              deadline: Optional[Deadline] = None, acc=None):
+              deadline: Optional[Deadline] = None):
         """Datapoint writes are idempotent (replica merge dedups on
         timestamp), so the retrier may safely re-send one that failed
-        mid-exchange — unlike the KV store's mutations. `acc` (the
-        traced caller's phase accumulator) has nothing to receive here:
-        the write's phases happen on the remote."""
+        mid-exchange — unlike the KV store's mutations."""
         self._call({"method": "write", "id": series_id, "tags": dict(tags),
                     "time": t_ns, "value": value}, deadline)
 

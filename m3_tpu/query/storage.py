@@ -58,16 +58,21 @@ class LocalStorage:
         return out
 
     def write(self, series_id: bytes, tags: Dict[bytes, bytes], t_ns: int,
-              value: float, acc=None):
-        self._db.write(self._namespace, series_id, t_ns, value, tags=tags,
-                       acc=acc)
+              value: float):
+        self._db.write(self._namespace, series_id, t_ns, value, tags=tags)
 
     def write_batch(self, series_ids: Sequence[bytes], tags: Sequence[dict],
                     ts, vals):
         """Columnar write: one shard-routed db.write_batch append instead
-        of a per-sample write loop (the coordinator ingest batch path)."""
-        self._db.write_batch(self._namespace, list(series_ids), ts, vals,
-                             tags=list(tags))
+        of a per-sample write loop (the coordinator ingest batch path).
+        What a coordinator writes it writes again every scrape, so the
+        rows are routed through the shard memo (`id_ns` of a detailed
+        span) and the node hashes nothing."""
+        series_ids = list(series_ids)
+        with tracing.phase("id"):
+            shard_ids = self._db.shard_set.lookup_memo(series_ids)
+        self._db.write_batch(self._namespace, series_ids, ts, vals,
+                             tags=list(tags), shard_ids=shard_ids)
 
     def complete_tags(self, matchers: Sequence[Matcher], start_ns: int,
                       end_ns: int, name_only: bool = False,
@@ -94,9 +99,7 @@ class SessionStorage:
         return self._session.fetch_tagged(self._namespace, q, start_ns, end_ns)
 
     def write(self, series_id: bytes, tags: Dict[bytes, bytes], t_ns: int,
-              value: float, acc=None):
-        # `acc`: the write's phases happen on the dbnodes, whose spans
-        # graft into the caller's trace; nothing to account here
+              value: float):
         self._session.write_tagged(self._namespace, series_id, tags, t_ns, value)
 
     def complete_tags(self, matchers: Sequence[Matcher], start_ns: int,
@@ -136,10 +139,7 @@ class FanoutStorage:
                         cur["tags"] = entry["tags"]
         return merged
 
-    def write(self, series_id: bytes, tags, t_ns: int, value: float,
-              acc=None):
-        # `acc` stops here: one sample fans out to several stores, and
-        # summing their phases would count it once per store
+    def write(self, series_id: bytes, tags, t_ns: int, value: float):
         for store in self._stores:
             store.write(series_id, tags, t_ns, value)
 

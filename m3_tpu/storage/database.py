@@ -124,24 +124,17 @@ class Database:
     # ------------------------------------------------------------------ write
 
     def write(self, namespace: bytes, series_id: bytes, t_ns: int, value: float,
-              tags: Optional[dict] = None, priority=None, acc=None):
-        """database.go:536 Write + :561 commit log append. `acc` (a
-        detailed span, utils.tracing.detail, read once by the caller's
-        loop) receives `id_ns` (the shard hash), `buffer_ns` (the shard
-        append, its `lock_wait_ns` inside it) and `commitlog_ns`."""
+              tags: Optional[dict] = None, priority=None):
+        """database.go:536 Write + :561 commit log append."""
         ns = self.namespace(namespace)
         self._check_writable(priority)
-        timed = acc is not None
-        t0 = _clock() if timed else 0
         shard_id = self.shard_set.lookup(series_id)
         now = self.clock()
-        t1 = _clock() if timed else 0
         if priority is None:
-            ns.write(shard_id, series_id, t_ns, value, now, tags, acc=acc)
+            ns.write(shard_id, series_id, t_ns, value, now, tags)
         else:
             ns.shard_for(shard_id).write(series_id, t_ns, value, now, tags,
-                                         priority=priority, acc=acc)
-        t2 = _clock() if timed else 0
+                                         priority=priority)
         if self.commitlog is not None and ns.opts.writes_to_commitlog:
             try:
                 self.commitlog.write(namespace, series_id, t_ns, value, tags)
@@ -151,34 +144,39 @@ class Database:
                 self.disk_health.failure()
                 raise
             self.disk_health.success()
-        if timed:
-            acc.add_cost("id_ns", t1 - t0)
-            acc.add_cost("buffer_ns", t2 - t1)
-            acc.add_cost("commitlog_ns", _clock() - t2)
 
     def write_batch(self, namespace: bytes, ids: Sequence[bytes], ts, vals,
                     tags: Optional[Sequence[Optional[dict]]] = None,
-                    priority=None):
+                    priority=None, shard_ids: Optional[np.ndarray] = None):
         """database.go:624 WriteBatch: single shard-route + columnar
         append. `priority` (utils.health.Priority) rides down to the
         shard insert queues' admission gates — BULK backfill sheds first
-        when a queue's bounded depth fills."""
+        when a queue's bounded depth fills. `shard_ids`: the rows'
+        shards where the caller has routed them already (a serving path
+        through ShardSet.lookup_memo); without it the batch is hashed
+        here in one lookup_batch, the bulk route. Under a detailed span
+        the caller's span receives `buffer_ns` (the shard appends, their
+        `lock_wait_ns` inside it) and `commitlog_ns`, once a batch."""
         ns = self.namespace(namespace)
         self._check_writable(priority)
         ts = np.asarray(ts, np.int64)
         vals = np.asarray(vals, np.float64)
         now = self.clock()
         pri = Priority.NORMAL if priority is None else priority
+        acc = tracing.detail()  # the caller's span, before ours opens
         # child_span: a real span ONLY under an already-sampled request
         # (the rpc dispatch / executor span) — the bench-bare write path
         # pays one thread-local read (scripts/obs_overhead_guard.py).
         with tracing.child_span("storage.write_batch", points=len(ids)):
+            if shard_ids is None:
+                shard_ids = self.shard_set.lookup_batch(ids)
             self._write_batch_routed(namespace, ns, ids, ts, vals, tags, now,
-                                     pri)
+                                     pri, shard_ids, acc)
 
     def _write_batch_routed(self, namespace, ns, ids, ts, vals, tags, now,
-                            pri):
-        shard_ids = self.shard_set.lookup_batch(ids)
+                            pri, shard_ids, acc):
+        timed = acc is not None
+        t0 = _clock() if timed else 0
         # Route columns per shard through object arrays: one fancy-index
         # per shard instead of a Python listcomp over selected rows
         # (~4x on the per-batch routing cost).
@@ -196,7 +194,7 @@ class Database:
                 ns.shard_for(int(sid)).write_batch(
                     ids_arr[m].tolist(), ts[m], vals[m], now,
                     tags=tags_arr[m].tolist() if tags_arr is not None else None,
-                    priority=pri,
+                    priority=pri, acc=acc,
                 )
                 if applied is not None:
                     applied |= m
@@ -220,6 +218,7 @@ class Database:
                     self.disk_health.failure()
                     raise
             raise
+        t1 = _clock() if timed else 0
         if log:
             try:
                 self.commitlog.write_batch(namespace, ids, ts, vals, tags)
@@ -227,6 +226,9 @@ class Database:
                 self.disk_health.failure()
                 raise
             self.disk_health.success()
+        if timed:
+            acc.add_cost("buffer_ns", t1 - t0)
+            acc.add_cost("commitlog_ns", _clock() - t1)
 
     def _check_writable(self, priority) -> None:
         """Read-only posture under persistent disk faults: shed NORMAL

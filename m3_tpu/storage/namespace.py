@@ -104,9 +104,8 @@ class Namespace:
         return sh
 
     def write(self, shard_id: int, series_id: bytes, t_ns: int, value: float,
-              now_ns: int, tags: Optional[dict] = None, acc=None):
-        self.shard_for(shard_id).write(series_id, t_ns, value, now_ns, tags,
-                                       acc=acc)
+              now_ns: int, tags: Optional[dict] = None):
+        self.shard_for(shard_id).write(series_id, t_ns, value, now_ns, tags)
 
     def read(self, shard_id: int, series_id: bytes, start_ns: int, end_ns: int,
              acc=None):

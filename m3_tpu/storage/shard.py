@@ -116,9 +116,7 @@ class Shard:
 
     def write(self, series_id: bytes, t_ns: int, value: float, now_ns: int,
               tags: Optional[dict] = None,
-              priority: Priority = Priority.NORMAL, acc=None) -> bool:
-        """`acc` (a detailed span, utils.tracing.detail) receives
-        `lock_wait_ns`: the time this write waited for the shard lock."""
+              priority: Priority = Priority.NORMAL) -> bool:
         if not self.buffer.accepts(now_ns, t_ns):
             raise ValueError(
                 f"datapoint at {t_ns} outside acceptance window at {now_ns} "
@@ -127,10 +125,7 @@ class Shard:
         idx = self.registry.get(series_id)  # lock-free snapshot resolve
         if idx is not None:
             self.registry.ensure_tags(idx, tags)
-            t0 = _clock() if acc is not None else 0
             with self.write_lock:
-                if acc is not None:
-                    acc.add_cost("lock_wait_ns", _clock() - t0)
                 self.buffer.write(idx, t_ns, value)
             return False
         self.insert_queue.insert(
@@ -142,7 +137,10 @@ class Shard:
 
     def write_batch(self, ids: Sequence[bytes], ts: np.ndarray, vals: np.ndarray,
                     now_ns: int, tags: Optional[Sequence[Optional[dict]]] = None,
-                    priority: Priority = Priority.NORMAL):
+                    priority: Priority = Priority.NORMAL, acc=None):
+        """`acc` (a detailed span, utils.tracing.detail, read once a
+        batch by Database.write_batch) receives `lock_wait_ns`: the time
+        this shard's append waited for the shard lock."""
         ts = np.asarray(ts, np.int64)
         vals = np.asarray(vals, np.float64)
         ok = (ts >= now_ns - self.opts.buffer_past_ns) & (ts <= now_ns + self.opts.buffer_future_ns)
@@ -163,7 +161,10 @@ class Shard:
                 if t is not None:
                     ensure(int(sidx[i]), t)
         if not unknown.any():
+            t0 = _clock() if acc is not None else 0
             with self.write_lock:
+                if acc is not None:
+                    acc.add_cost("lock_wait_ns", _clock() - t0)
                 self.buffer.write_batch(sidx, ts, vals)
             return
         # Slow path: coalesce the first-seen remainder into the insert

@@ -79,15 +79,10 @@ def murmur3_32_cached(data: bytes, seed: int = 0) -> int:
     return _murmur3_32_lru(data, seed)
 
 
-def hash_batch(ids: Sequence[bytes], seed: int = 0) -> np.ndarray:
-    """Vectorized murmur3-32 over variable-length IDs.
-
-    IDs are padded into a [N, maxlen] byte matrix; the 4-byte block mixing
-    runs columnwise in numpy with per-row active masks, so throughput scales
-    with the longest ID rather than per-ID Python loops."""
+def _padded_words(ids: Sequence[bytes]):
+    """(buf, words, lens): the ids as a zero-padded [N, maxlen] byte
+    matrix, its little-endian u32 view, and their lengths."""
     n = len(ids)
-    if n == 0:
-        return np.zeros(0, np.uint32)
     lens = np.fromiter(map(len, ids), np.int64, n)
     maxlen = int(lens.max(initial=1))
     padded = maxlen + (-maxlen) % 4
@@ -100,7 +95,18 @@ def hash_batch(ids: Sequence[bytes], seed: int = 0) -> np.ndarray:
     if joined:
         mask = np.arange(padded)[None, :] < lens[:, None]
         buf[mask] = np.frombuffer(joined, np.uint8)
-    words = buf.view("<u4")  # [n, padded // 4]
+    return buf, buf.view("<u4"), lens  # words: [n, padded // 4]
+
+
+def hash_batch(ids: Sequence[bytes], seed: int = 0) -> np.ndarray:
+    """Vectorized murmur3-32 over variable-length IDs.
+
+    IDs are padded into a [N, maxlen] byte matrix; the 4-byte block mixing
+    runs columnwise in numpy with per-row active masks, so throughput scales
+    with the longest ID rather than per-ID Python loops."""
+    if not len(ids):
+        return np.zeros(0, np.uint32)
+    buf, words, lens = _padded_words(ids)
 
     # Pallas route (ops.pallas_codec.hash_words, lane-parallel murmur3):
     # same padded-buffer layout, bit-identical output; gated on the codec
@@ -127,7 +133,22 @@ def hash_batch(ids: Sequence[bytes], seed: int = 0) -> np.ndarray:
                 return out
             # Guarded fallback: fall through to the numpy loop below —
             # the declared oracle for this kernel.
+    return _murmur3_words_host(buf, words, lens, seed)
 
+
+def hash_batch_host(ids: Sequence[bytes], seed: int = 0) -> np.ndarray:
+    """`hash_batch` on the host alone: no codec dispatch, no route
+    counter, no jit keyed by the row count. For a serving path whose
+    batches come in any size (the shard memo's misses, ShardSet.
+    lookup_memo): a device call there would compile a new shape
+    mid-traffic."""
+    if not len(ids):
+        return np.zeros(0, np.uint32)
+    return _murmur3_words_host(*_padded_words(ids), seed)
+
+
+def _murmur3_words_host(buf, words, lens, seed: int) -> np.ndarray:
+    n, padded = buf.shape
     h = np.full(n, seed, np.uint32)
     nblocks = lens // 4
     with np.errstate(over="ignore"):

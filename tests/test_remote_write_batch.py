@@ -1,0 +1,388 @@
+"""A remote-write request travels from the decoder to the WAL as ONE
+batch (one admission, one append per shard touched, one commit-log
+append), and stores exactly what the same samples store one `write` at
+a time: points, registry tags, WAL bytes. Shard routing on that path is
+the shard memo (ShardSet.lookup_memo): no hash_batch, no codec dispatch."""
+
+import threading
+import urllib.error
+import urllib.request
+
+import numpy as np
+import pytest
+
+from m3_tpu.coordinator import promremote as pr
+from m3_tpu.coordinator import run_embedded
+from m3_tpu.index.namespace_index import NamespaceIndex
+from m3_tpu.parallel import sharding
+from m3_tpu.parallel.sharding import ShardSet
+from m3_tpu.persist import commitlog
+from m3_tpu.storage.database import Database
+from m3_tpu.storage.namespace import NamespaceOptions
+from m3_tpu.utils import hashing
+from m3_tpu.utils.instrument import ROOT
+
+S = 1_000_000_000
+T0 = 1_700_000_000 * S
+NS = b"default"
+
+
+class Node:
+    """A dbnode with its commit log on, behind an embedded coordinator,
+    under a clock the test moves."""
+
+    def __init__(self, tmp_path, name="node", shards=8,
+                 strategy=commitlog.Strategy.WRITE_BEHIND, **kw):
+        self.now = {"t": T0}
+        self.wal_dir = str(tmp_path / name / "commitlog")
+        self.log = commitlog.CommitLog(self.wal_dir, strategy=strategy,
+                                       clock=lambda: self.now["t"])
+        self.db = Database(ShardSet(shards), commitlog=self.log,
+                           clock=lambda: self.now["t"])
+        self.db.create_namespace(
+            NS, NamespaceOptions(),
+            index=NamespaceIndex(clock=lambda: self.now["t"]))
+        self.coord = run_embedded(self.db, clock=lambda: self.now["t"], **kw)
+
+    def post(self, series):
+        req = urllib.request.Request(
+            self.coord.endpoint + "/api/v1/prom/remote/write",
+            data=pr.snappy_compress(pr.encode_write_request(series)),
+            method="POST")
+        req.add_header("Content-Type", "application/x-protobuf")
+        with urllib.request.urlopen(req) as resp:
+            return resp.status, resp.read()
+
+    def read(self, sid):
+        return self.db.read(NS, sid, T0 - 3600 * S, T0 + 3600 * S)
+
+    def registry_tags(self, sid):
+        shard = self.db.namespace(NS).shards[self.db.shard_set.lookup(sid)]
+        idx = shard.registry.get(sid)
+        return None if idx is None else shard.registry.tags_of(idx)
+
+    def wal(self):
+        """(entries, tags by series, each file's entry bytes: its chunk
+        bodies joined, since where a chunk ends is when a flush fell
+        due, not the format) of the closed log."""
+        self.log.close()
+        entries, tags = [], {}
+        for b in commitlog.replay_batches(self.wal_dir):
+            for ns, sid, t, v, tg in zip(b.namespaces, b.ids, b.t_ns,
+                                         b.values, b.tags):
+                entries.append((ns, sid, int(t), float(v)))
+                tags[sid] = tg
+        raw = [b"".join(body for body, _ in commitlog._iter_chunks(f))
+               for f in self.log.files()]
+        return entries, tags, raw
+
+    def close(self):
+        self.coord.close()
+
+
+@pytest.fixture
+def node(tmp_path):
+    n = Node(tmp_path)
+    yield n
+    n.close()
+
+
+def _scrape(hosts, steps=1, t0_ms=T0 // 1_000_000, name=b"cpu", first=0):
+    return [({b"__name__": name, b"host": b"h%03d" % i, b"dc": b"d%d" % (i % 3)},
+             [(t0_ms + 10_000 * k, float(i * 100 + k)) for k in range(steps)])
+            for i in range(first, first + hosts)]
+
+
+def _sid(tags):
+    from m3_tpu.coordinator.ingest import _series_id
+
+    return _series_id(tags)
+
+
+def _counters(prefix):
+    return {k: v for k, v in ROOT.snapshot().items()
+            if k.startswith(prefix) and isinstance(v, (int, float))}
+
+
+def _moved(before, prefix):
+    after = _counters(prefix)
+    return {k: v - before.get(k, 0) for k, v in after.items()
+            if v != before.get(k, 0)}
+
+
+# ------------------------------------------------- (a) batch == per sample
+
+
+@pytest.mark.parametrize("hosts,steps", [(1, 1), (40, 3), (250, 2)])
+def test_one_request_stores_what_per_sample_writes_store(tmp_path, hosts,
+                                                         steps):
+    series = _scrape(hosts, steps)
+    batch, ref = Node(tmp_path, "batch"), Node(tmp_path, "ref")
+    try:
+        status, body = batch.post(series)
+        assert status == 200
+        assert body == b'{"status": "success", "wrote": %d}' % (hosts * steps)
+        for tags, samples in series:
+            for t_ms, v in samples:
+                ref.coord.writer.write(tags, t_ms * 1_000_000, v)
+        for tags, samples in series:
+            sid = _sid(tags)
+            (t, v), (rt, rv) = batch.read(sid), ref.read(sid)
+            assert t.tolist() == rt.tolist() == [
+                t_ms * 1_000_000 for t_ms, _ in samples]
+            assert v.tolist() == rv.tolist() == [x for _, x in samples]
+            assert batch.registry_tags(sid) == ref.registry_tags(sid) == tags
+        (entries, tags_b, raw_b), (rentries, tags_r, raw_r) = \
+            batch.wal(), ref.wal()
+        assert entries == rentries and len(entries) == hosts * steps
+        assert tags_b == tags_r
+        assert all(tags_b[_sid(tags)] == tags for tags, _ in series)
+        assert raw_b == raw_r       # the on-disk format, byte for byte
+    finally:
+        batch.close()
+        ref.close()
+
+
+@pytest.mark.parametrize("first", ["tagged", "untagged"])
+def test_a_batch_of_known_series_packs_the_same_wal_bytes(tmp_path, first):
+    """The commit log packs a batch of series its file already knows as
+    one column-built run; a series first logged untagged gets its
+    tagged meta entry before its next data entry, as one write at a
+    time would log it."""
+    scrapes = [_scrape(40, 1, T0 // 1_000_000 + 10_000 * k) for k in range(3)]
+    ids = [_sid(tags) for tags, _ in scrapes[0]]
+    batch, ref = Node(tmp_path, "batch"), Node(tmp_path, "ref")
+    try:
+        for n in (batch, ref):
+            n.now["t"] = T0 + 30 * S
+            if first == "untagged":
+                n.db.write_batch(NS, ids, np.full(40, T0 - 10 * S),
+                                 np.zeros(40))
+        for series in scrapes:
+            assert batch.post(series)[0] == 200
+            for tags, samples in series:
+                for t_ms, v in samples:
+                    ref.coord.writer.write(tags, t_ms * 1_000_000, v)
+        (entries, tags_b, raw_b), (rentries, tags_r, raw_r) = \
+            batch.wal(), ref.wal()
+        assert len(entries) == 120 + 40 * (first == "untagged")
+        assert entries == rentries and tags_b == tags_r
+        assert all(tags_b[_sid(tags)] == tags for tags, _ in scrapes[0])
+        assert raw_b == raw_r
+    finally:
+        batch.close()
+        ref.close()
+
+
+# -------------------------------------------------- (b) concurrent senders
+
+
+def test_eight_concurrent_senders_every_acknowledged_sample_reads_back(node):
+    senders, requests, hosts = 8, 6, 25
+    acked, errors = [], []
+
+    def send(s):
+        try:
+            for r in range(requests):
+                series = _scrape(hosts, 1, T0 // 1_000_000 + 10_000 * r,
+                                 first=s * hosts)
+                status, _ = node.post(series)
+                if status == 200:
+                    acked.extend((_sid(tags), t_ms * 1_000_000, v)
+                                 for tags, smp in series for t_ms, v in smp)
+        except Exception as e:  # noqa: BLE001
+            errors.append(e)
+
+    node.now["t"] = T0 + 60 * S
+    threads = [threading.Thread(target=send, args=(s,))
+               for s in range(senders)]
+    for th in threads:
+        th.start()
+    for th in threads:
+        th.join()
+    assert not errors
+    assert len(acked) == senders * requests * hosts
+    want = {}
+    for sid, t, v in acked:
+        want.setdefault(sid, {})[t] = v
+    for sid, points in want.items():
+        t, v = node.read(sid)
+        assert dict(zip(t.tolist(), v.tolist())) == points
+    entries, _tags, _raw = node.wal()
+    assert len(entries) == len(acked)
+    assert sorted(entries) == sorted((NS, sid, t, v) for sid, t, v in acked)
+
+
+# ----------------------------------------- (c) shed, and the window's edge
+
+
+def test_a_shed_request_answers_429_and_writes_nothing(node):
+    gate = node.coord.writer.gate
+    gate.admit(gate.capacity)            # the gate is at capacity
+    try:
+        with pytest.raises(urllib.error.HTTPError) as e:
+            node.post(_scrape(20))
+        assert e.value.code == 429
+        assert e.value.headers["Retry-After"] == "1"
+    finally:
+        gate.release(gate.capacity)
+    for tags, _ in _scrape(20):
+        assert node.registry_tags(_sid(tags)) is None
+    assert node.wal()[0] == []
+
+
+def test_a_sample_outside_the_window_answers_400_and_logs_what_it_applied(
+        node):
+    series = _scrape(30)
+    past = node.db.namespace(NS).opts.buffer_past_ns
+    tags, _ = series[17]
+    series[17] = (tags, [((T0 - past - 60 * S) // 1_000_000, 1.0)])
+    with pytest.raises(urllib.error.HTTPError) as e:
+        node.post(series)
+    assert e.value.code == 400
+    assert b"outside acceptance window" in e.value.read()
+    # shards are applied in ascending order up to the one holding the
+    # sample; whatever was applied is in the WAL, and nothing else is
+    bad_shard = node.db.shard_set.lookup(_sid(tags))
+    applied = []
+    for tg, samples in series:
+        sid = _sid(tg)
+        t, _v = node.read(sid)
+        assert (len(t) == 1) == (node.db.shard_set.lookup(sid) < bad_shard)
+        applied += [(NS, sid, int(x), samples[0][1]) for x in t]
+    assert sorted(node.wal()[0]) == sorted(applied)
+
+
+def test_a_failed_commit_log_append_fails_the_request(tmp_path):
+    from m3_tpu.testing import faultfs
+
+    faultfs.install(faultfs.DiskFaultPlan(seed=9, write_eio=1.0))
+    n = Node(tmp_path, strategy=commitlog.Strategy.WRITE_WAIT)
+    try:
+        with pytest.raises(urllib.error.HTTPError) as e:
+            n.post(_scrape(20))
+        assert e.value.code == 400       # as the per-sample path answered
+        assert n.db.disk_health.failures >= 1
+    finally:
+        faultfs.uninstall()
+        n.close()
+
+
+# ------------------------------------------------ (d) routing by the memo
+
+
+def test_the_request_path_never_hashes_a_batch_on_the_device_route(
+        node, monkeypatch):
+    calls = []
+    real = hashing.hash_batch
+    for mod in (hashing, sharding):
+        monkeypatch.setattr(mod, "hash_batch",
+                            lambda ids, seed=0: calls.append(len(ids))
+                            or real(ids, seed))
+    codec0, memo0, ing0 = (_counters("telemetry.codec."),
+                           _counters("sharding.memo."),
+                           _counters("coordinator.ingest.batch"))
+    series = _scrape(60)
+    node.post(series)                    # first sightings
+    assert _moved(memo0, "sharding.memo.") == {"sharding.memo.misses": 60}
+    memo1 = _counters("sharding.memo.")
+    node.post(_scrape(60, t0_ms=T0 // 1_000_000 + 10_000))   # known series
+    assert _moved(memo1, "sharding.memo.") == {"sharding.memo.hits": 60}
+    assert calls == []
+    assert not {k: v for k, v in _moved(codec0, "telemetry.codec.").items()
+                if k.endswith("_hash")}
+    assert _moved(ing0, "coordinator.ingest.batch") == {
+        "coordinator.ingest.batches": 2,
+        "coordinator.ingest.batch_samples": 120}
+    # the bulk route is as it was: one hash_batch for the whole load
+    ids = [_sid(tags) for tags, _ in series]
+    node.db.write_batch(NS, ids, np.full(60, T0 + 30 * S), np.ones(60))
+    assert calls == [60]
+
+
+# ------------------------------------------------------- the memo itself
+
+
+def _ids(n, seed, width=(1, 40)):
+    rng = np.random.default_rng(seed)
+    return [rng.bytes(int(rng.integers(*width))) for _ in range(n)]
+
+
+@pytest.mark.parametrize("shards", [1, 64, 4096])
+def test_lookup_memo_routes_like_lookup(shards):
+    ss = ShardSet(shards)
+    ids = _ids(300, shards) + [b"", b"x" * 256, b"y" * 257, b"z" * 1000]
+    want = [ss.lookup(i) for i in ids]
+    assert ss.lookup_memo(ids).tolist() == want          # all misses
+    assert ss.lookup_memo(ids[::-1]).tolist() == want[::-1]   # hits
+    assert ss.lookup_memo(ids[:5] + _ids(5, 99)).tolist()[:5] == want[:5]
+    assert ss.lookup_memo([]).tolist() == []
+    assert ss.lookup_memo(ids).dtype == ss.lookup_batch(ids).dtype
+
+
+def test_lookup_memo_is_bounded_in_entries_and_key_size(monkeypatch):
+    monkeypatch.setattr(sharding, "MEMO_MAX_ENTRIES", 100)
+    ss = ShardSet(64)
+    big = [b"k" * 300 + b"%d" % i for i in range(10)]
+    ss.lookup_memo(big)
+    assert len(ss._memo) == 0            # oversize ids are never kept
+    a, b, bulk = _ids(80, 1), _ids(80, 2), _ids(101, 3, width=(8, 40))
+    ss.lookup_memo(a)
+    assert len(ss._memo) == 80
+    ss.lookup_memo(b)                    # would pass the bound: flushed whole
+    assert set(ss._memo) == set(b)
+    ss.lookup_memo(bulk)                 # larger than the memo: passes by
+    assert set(ss._memo) == set(b)
+    assert ss.lookup_memo(a + big).tolist() == [ss.lookup(i) for i in a + big]
+
+
+def test_lookup_memo_counts_hits_and_misses():
+    ss = ShardSet(16)
+    ids = _ids(50, 7, width=(4, 30))
+    c0 = _counters("sharding.memo.")
+    ss.lookup_memo(ids)
+    ss.lookup_memo(ids + _ids(10, 8, width=(31, 40)))
+    assert _moved(c0, "sharding.memo.") == {"sharding.memo.misses": 60,
+                                           "sharding.memo.hits": 50}
+
+
+@pytest.mark.parametrize("seed", [0, 7, 0xDEADBEEF])
+def test_hash_batch_host_is_murmur3(seed):
+    ids = _ids(200, seed or 1, width=(0, 70))
+    assert hashing.hash_batch_host(ids, seed).tolist() == [
+        hashing.murmur3_32(i, seed) for i in ids]
+    assert hashing.hash_batch_host([], seed).tolist() == []
+
+
+# ----------------------------------------- the downsample leg asks first
+
+
+def test_the_downsampler_reads_no_row_while_no_rule_set_is_installed():
+    from m3_tpu.cluster import kv as cluster_kv
+    from m3_tpu.coordinator.downsample import Downsampler
+    from m3_tpu.metrics.matcher import Matcher, RuleSetStore
+
+    matcher = Matcher(RuleSetStore(cluster_kv.MemStore()), b"default")
+    assert not matcher.has_rules()
+    ds = Downsampler(matcher, lambda *a: None)
+
+    def rows():
+        raise AssertionError("a row was read")
+        yield
+
+    assert ds.write_batch(rows()) == (0, 0)
+
+
+def test_a_write_signature_carries_no_accumulator():
+    import inspect
+
+    from m3_tpu.coordinator.ingest import DownsamplerAndWriter
+    from m3_tpu.query import remote, storage
+    from m3_tpu.storage.namespace import Namespace
+    from m3_tpu.storage.shard import Shard
+
+    for fn in (DownsamplerAndWriter.write, storage.LocalStorage.write,
+               storage.SessionStorage.write, storage.FanoutStorage.write,
+               remote.RemoteStorage.write, Database.write, Namespace.write,
+               Shard.write):
+        assert "acc" not in inspect.signature(fn).parameters, fn
