@@ -5,13 +5,16 @@ from __future__ import annotations
 
 from typing import List, Tuple
 
-from . import spans, trace_reduce
+from . import phases, spans, trace_reduce
 
-# Several requests are in flight at once; an idle instant goes to the
-# first of these that is active. Deepest program span first, the
-# benchmark's own intervals after.
+# Several requests are in flight at once, beside the mediator's tick; an
+# idle instant goes to the first of these that is active. Deepest program
+# span first (the parts of a tick and of a write before `mediator.tick`
+# and `http`, which enclose them), the benchmark's own intervals after.
 PRIORITY = ["gc", "index.query", "storage.read", "query.fetch", "query.parse",
-            "query.execute_range", "mediator.tick", "render", "http"]
+            "query.execute_range", "persist.write", "encode.block",
+            "mediator.snapshot", "storage.write_batch", "remote_write.append",
+            "remote_write.decode", "mediator.tick", "render", "http"]
 IDLE = "loadgen-wait"
 
 
@@ -25,7 +28,7 @@ def host_intervals(m) -> List[Tuple[float, float, str]]:
 
     for a, b, _gen in m.gc_events:
         add(a, b, "gc")
-    for a, b in m.ticks:
+    for _asked, a, b in m.ticks:
         add(a, b, "mediator.tick")
     trees = spans.by_trace_id(m.span_trees)
     for tree in m.span_trees:
@@ -35,22 +38,15 @@ def host_intervals(m) -> List[Tuple[float, float, str]]:
     # a request in flight outside its handler's spans: `http` before the
     # engine starts and after the handler returns, `render` between the
     # engine's end and the handler's
-    if "i" in m.rec:
-        for i, sent, done in zip(m.rec["i"], m.rec["sent"], m.rec["done"]):
-            root = trees.get(int(i) + 1)
-            ex = None
-            if root is not None:
-                ex = next((n for n in spans.walk(root)
-                           if n["name"] == "query.execute_range"), None)
-            if ex is None:
-                add(sent, done, "http")
-                continue
-            add(sent, ex["start"], "http")
-            add(ex["end"], root["end"], "render")
-            add(root["end"], done, "http")
-    elif "sent" in m.rec:
-        for sent, done in zip(m.rec["sent"], m.rec["done"]):
+    for i, sent, done in zip(m.rec["i"], m.rec["sent"], m.rec["done"]):
+        root = trees.get(int(i) + 1)
+        ex = root and phases.descendant(root, "query.execute_range")
+        if not ex:
             add(sent, done, "http")
+            continue
+        add(sent, ex["start"], "http")
+        add(ex["end"], root["end"], "render")
+        add(root["end"], done, "http")
     return out
 
 

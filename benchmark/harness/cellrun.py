@@ -19,9 +19,8 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from . import datagen, spec
+from . import spec
 
-S = datagen.S
 LOADGEN = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                        "loadgen.py")
 
@@ -130,113 +129,6 @@ def require_chips(chips: int):
     return devs
 
 
-# ------------------------------------------------------------------- checking
-
-
-def check_queries(m: Measurement, server, child: Child, seed: int,
-                  keep: List[int], control: Optional[str] = None) -> dict:
-    """Compare the sampled (open loop) or every replayed (closed loop)
-    answer of the window with the plain reference."""
-    from reference import promql_ref
-
-    from . import schedule
-
-    cell = m.cell
-    t0_s = datagen.T0 // S
-    reqs = schedule.requests_for(
-        cell.to_wire(), seed, schedule.n_requests(cell.traffic, m.seconds))
-    held = server.vals[:, :int(cell.traffic["setup"]["load_steps"])]
-    gap_limit = float(cell.traffic["limits"]["worst_rel_gap"])
-    agg = {"answers": 0, "values": 0, "label_sets_differ": 0,
-           "points_missing_or_extra": 0, "worst_rel_gap": 0.0,
-           "unanswered": 0}
-    open_steps = int(cell.traffic["setup"].get("open_steps", 0))
-    for lo in range(0, len(keep), 50):
-        bodies = child.call(op="bodies", indices=keep[lo:lo + 50])["bodies"]
-        for i in keep[lo:lo + 50]:
-            got = bodies.get(str(i))
-            if got is None and cell.traffic["loop"] != "open":
-                continue        # a replay entry the window never reached
-            if got is None or got[0] != 200:
-                agg["unanswered"] += 1
-                continue
-            req = reqs[i]
-            cls = cell.classes[req["cls"]]
-            want = promql_ref.evaluate(cls, cell.config, server.labels, held,
-                                       req, t0_s)
-            if control is None:
-                have = promql_ref.parse_response(got[1], req)
-            else:   # the control, put in the program's place
-                have = promql_ref.evaluate(
-                    cls, cell.config, server.labels, held, req, t0_s,
-                    control=control, open_steps=open_steps)
-            c = promql_ref.compare(have, want)
-            if (c["worst_rel_gap"] > gap_limit or c["label_sets_differ"]
-                    or c["points_missing_or_extra"]):
-                say(f"answer {i} differs from the reference: {c}; "
-                    f"{req['path'][:300]}")
-            agg["answers"] += 1
-            agg["values"] += c["values"]
-            agg["label_sets_differ"] += c["label_sets_differ"]
-            agg["points_missing_or_extra"] += c["points_missing_or_extra"]
-            agg["worst_rel_gap"] = max(agg["worst_rel_gap"],
-                                       c["worst_rel_gap"])
-    return agg
-
-
-def check_readback(m: Measurement, server, seed: int,
-                   drop: bool = False) -> dict:
-    """Read a seeded sample of acknowledged (series, timestamp) pairs
-    back over HTTP, half from the set-up's load (sealed block and
-    buffer), half from the window, each exactly."""
-    import urllib.parse
-    import urllib.request
-
-    cell, cfg = m.cell, m.cell.config
-    t = cell.traffic
-    nf = len(cfg["schema"]["fields"])
-    per = int(t["samples_per_send"])
-    rng = np.random.default_rng([seed & 0xFFFFFFFF, seed >> 32, 29])
-    ok = (m.rec["status"] == 200) & (m.rec["samples"] > 0)
-    acked = np.stack([m.rec["step"][ok], m.rec["group"][ok]], 1)
-    pairs = int(t["readback_reads"])
-    picks = []
-    load_steps = int(t["setup"]["load_steps"])
-    for _ in range(pairs // 2):
-        picks.append((int(rng.integers(0, load_steps)),
-                      int(rng.integers(0, cfg["scale"]))))
-    if len(acked):
-        for j in rng.choice(len(acked), min(pairs - len(picks), len(acked)),
-                            replace=False):
-            step, group = (int(x) for x in acked[j])
-            lo = group * per // nf
-            hi = min((group + 1) * per // nf, cfg["scale"])
-            picks.append((step, int(rng.integers(lo, max(hi, lo + 1)))))
-    out = {"pairs": 0, "readback_mismatched": 0, "reads_failed": 0}
-    cadence = int(cfg["cadence_s"])
-    name = cfg["schema"]["measurement"]
-    for step, host in picks:
-        ts = int(datagen.step_ts(cfg, step) // S)
-        q = 'max_over_time(%s{hostname="host_%d"}[%ds])' % (name, host, cadence)
-        url = (server.base + "/api/v1/query?"
-               + urllib.parse.urlencode({"query": q, "time": ts}))
-        try:
-            with urllib.request.urlopen(url, timeout=60) as r:
-                res = json.loads(r.read())["data"]["result"]
-        except (OSError, ValueError, KeyError):
-            out["reads_failed"] += 1
-            continue
-        got = {s["metric"].get("field"): float(s["value"][1]) for s in res}
-        for f, fname in enumerate(cfg["schema"]["fields"]):
-            want = float(server.vals[host * nf + f, step])
-            if drop and f == 0:          # the control: one sample not stored
-                got.pop(fname, None)
-            out["pairs"] += 1
-            if got.get(fname) != want:
-                out["readback_mismatched"] += 1
-    return out
-
-
 # ----------------------------------------------------------------------- run
 
 
@@ -298,22 +190,6 @@ class CellRun:
         if wait:
             self.child.recv()
 
-    def keep_indices(self, cell: spec.Cell, seconds: float) -> List[int]:
-        """Which answers the child keeps for the comparison: a sample
-        drawn from the seed (open loop) or every replay entry's newest."""
-        t = cell.traffic
-        if t["kind"] == "remote_write":
-            return []
-        if t["loop"] != "open":
-            return list(range(int(t["replay_len"])))
-        from . import schedule
-
-        n = schedule.n_requests(t, seconds)
-        rng = np.random.default_rng(
-            [self.seed & 0xFFFFFFFF, self.seed >> 32, 23])
-        return sorted(int(i) for i in rng.choice(
-            n, min(n, int(t["check_sample"])), replace=False))
-
     def window(self, seconds: float,
                traffic_overrides: Optional[dict] = None,
                draw_seed: Optional[int] = None) -> Measurement:
@@ -335,11 +211,8 @@ class CellRun:
                             cell.traffic["request_timeout_s"]),
                         setup=self.setup_facts,
                         stored_bytes=self.setup_facts["stored_bytes"])
-        m.keep = self.keep_indices(cell, seconds)
-        stop_ticker = None
-        if cell.traffic.get("mediator_tick_s"):
-            stop_ticker = server.start_ticker(
-                float(cell.traffic["mediator_tick_s"]))
+        m.keep = spec.load_part("traffic_kinds", cell.traffic["kind"]
+                                ).keep_indices(cell, self.seed, seconds)
         trace_dir = os.path.join(self.workdir, "trace")
         m.counters0 = server_mod.counters()
         if self.trace:
@@ -351,8 +224,17 @@ class CellRun:
             with jax.profiler.TraceAnnotation(
                     trace_reduce.SYNC, t=str(time.perf_counter_ns())):
                 time.sleep(0.001)
+        # the window's first instant, on the clock both processes share:
+        # the generator starts there, and the mediator's cadence counts
+        # from there
+        t0 = time.perf_counter_ns() + 200_000_000
+        stop_ticker = None
+        if cell.traffic.get("mediator_tick_s"):
+            stop_ticker = server.start_ticker(
+                float(cell.traffic["mediator_tick_s"]), t0)
         try:
-            rec = self.child.call(op="run", keep=m.keep, trace=int(self.trace))
+            rec = self.child.call(op="run", keep=m.keep, t0=t0,
+                                  trace=int(self.trace))
         finally:
             if self.trace:
                 jax.profiler.stop_trace()
@@ -375,12 +257,15 @@ class CellRun:
             m.trace = trace_reduce.Trace(trace_reduce.newest_xplane(trace_dir))
             say(f"trace: {m.trace.summary()}")
         say(f"window closed: {len(next(iter(m.rec.values()), []))} records")
-        say("  cache: " + ", ".join(
-            f"{k.split('.', 1)[1]} {m.counters1.get(k, 0):.0f}"
-            f" (+{m.moved(k):.0f})" for k in (
-                "storage.block_cache.hits", "storage.block_cache.misses",
-                "storage.block_cache.evictions", "storage.block_cache.bytes",
-                "hbm.bytes")))
+        # what JAX traced, lowered or fetched inside the window, though
+        # nothing compiled: a shape the warm-up did not meet
+        by_event: Dict[str, list] = {}
+        for t, event, secs in server.compile_log.events:
+            if m.window[0] <= t <= m.t_end:
+                by_event.setdefault(event, []).append(secs)
+        for event, secs in sorted(by_event.items()):
+            say(f"  jax in the window: {event} x{len(secs)}, "
+                f"{sum(secs):.3f}s, longest {max(secs):.3f}s")
         if "cls" in m.rec:
             lat = (m.rec["done"] - m.rec["due"]) / 1e6
             for c, cls in enumerate(cell.classes):
@@ -388,51 +273,24 @@ class CellRun:
                 if len(mine):
                     say(f"  {cls['name']}: {len(mine)} requests, median "
                         f"{np.median(mine):.1f} ms, max {mine.max():.1f} ms")
+        # a stall of the host shows as one long request, with a queue behind it
+        took = (m.rec["done"] - m.rec["sent"]) / 1e6
+        for j in np.argsort(took)[::-1][:3]:
+            say(f"  slowest: request {int(m.rec['i'][j])} sent at "
+                f"t0+{(m.rec['sent'][j] - m.window[0]) / 1e9:.2f}s took "
+                f"{took[j]:.1f} ms")
         return m
 
     def check(self, m: Measurement, control: Optional[str] = None):
-        """(checks, attempted, failed): each check a number beside its limit."""
-        from . import server as server_mod
-
-        cell, server = m.cell, self.server
-        checks = []
-        if cell.traffic["kind"] == "remote_write":
-            bad = int(((m.rec["status"] != 200)
-                       | (m.rec["samples"] != m.rec["want"])).sum())
-            rb = check_readback(m, server, self.seed, drop=(control == "drop"))
-            checks += [("writes_not_acknowledged_in_full", bad, 0),
-                       ("readback_mismatched", rb["readback_mismatched"], 0),
-                       ("readback_reads_failed", rb["reads_failed"], 0),
-                       ("readback_pairs_compared_at_least", -rb["pairs"],
-                        -int(cell.traffic["readback_reads"]) * len(
-                            cell.config["schema"]["fields"]) // 2)]
-            failed = bad + rb["readback_mismatched"] + rb["reads_failed"]
-        else:
-            bad = int((m.rec["status"] != 200).sum())
-            agg = check_queries(m, server, self.child, self.seed, m.keep,
-                                control)
-            limits = cell.traffic["limits"]
-            gap_limit = float(limits["worst_rel_gap"])
-            checks += [
-                ("requests_failed", bad, 0),
-                ("answers_unanswered", agg["unanswered"], 0),
-                ("label_sets_differ", agg["label_sets_differ"], 0),
-                ("points_missing_or_extra", agg["points_missing_or_extra"], 0),
-                ("worst_rel_gap", agg["worst_rel_gap"], gap_limit),
-                ("answers_compared_at_least", -agg["answers"],
-                 -min(len(m.keep), len(m.rec["status"]),
-                      int(limits["answers_compared_at_least"]))),
-            ]
-            failed = (bad + agg["unanswered"] + agg["label_sets_differ"]
-                      + agg["points_missing_or_extra"]
-                      + int(agg["worst_rel_gap"] > gap_limit))
-        allow = tuple(cell.traffic.get("allowed_runtime_fallbacks",
-                                       ["below-floor"]))
-        for name, value, limit, detail in server.verdict(
-                m.counters0, server_mod.counters(), allow):
-            checks.append((name, value, limit))
-            if value > limit:
-                say(f"verdict {name}: {detail}")
+        """(checks, attempted, failed): each check a number beside its
+        limit, from the files the traffic file lists (`checks`; absent,
+        its kind's own list) under benchmark/checks/. `control` names the
+        control that the check it belongs to puts in the program's place."""
+        checks, failed = [], 0
+        for name in m.cell.checks:
+            rows, bad = spec.load_part("checks", name).check(self, m, control)
+            checks += rows
+            failed += bad
         checks.append(("compiles_in_window", m.compiles_in_window, 0))
         for e in self.child.call(op="errors")["errors"][:3]:
             say(f"request error: {e}")
@@ -440,12 +298,7 @@ class CellRun:
 
     def result(self, m: Measurement, checks, attempted: int,
                failed: int) -> dict:
-        correct = True
-        for name, value, limit in checks:
-            ok = value <= limit
-            correct &= ok
-            print(f"check {name}: {value!r} (limit {limit!r}) "
-                  f"{'ok' if ok else 'FAILED'}", flush=True)
+        correct = all(value <= limit for _name, value, limit in checks)
         metrics = {}
         wanted = m.cell.per_layer if self.trace else m.cell.end_to_end
         kind = "layer_metrics" if self.trace else "end_to_end"
@@ -467,6 +320,9 @@ class CellRun:
             device["busy_s"] = m.trace.busy_s(lo, hi)
             device["window_s"] = (hi - lo) / 1e9
             result["breakdown"] = breakdown.breakdown(m, lo, hi)
+        # each number compared beside its limit, last in the line
+        result["checks"] = {name: [float(value), float(limit)]
+                            for name, value, limit in checks}
         return result
 
     def close(self):

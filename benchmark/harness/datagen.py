@@ -49,6 +49,12 @@ def series_labels(cfg: dict, seed: int) -> List[Dict[str, str]]:
     return out
 
 
+def wire_tags(labels: List[Dict[str, str]]) -> List[Dict[bytes, bytes]]:
+    """The label sets as the program's write paths take them."""
+    return [{k.encode(): v.encode() for k, v in lab.items()}
+            for lab in labels]
+
+
 def walk(cfg: dict, seed: int, steps: int) -> np.ndarray:
     """Values [series, steps] as uint8 (whole numbers in [0, 100])."""
     n = cfg["scale"] * len(cfg["schema"]["fields"])

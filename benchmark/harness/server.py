@@ -1,9 +1,9 @@
 """The system under test, booted as its users boot it, inside the
-benchmark's own process (a chip belongs to one process): the normal
-entry point `services.run_dbnode` with the embedded coordinator, under
-an injected clock. Boot, counters, the compile log and the served-path
-verdict are copied from chip_smoke.py, not imported: later PRs may
-change the program, and not the yardstick.
+benchmark's own process (a chip belongs to one process), under an
+injected clock. What is booted and how it is filled are files found by
+name (`deployments/`, `setups/`); the counters, the compile log and the
+served-path verdict around it are copied from chip_smoke.py, not
+imported: later PRs may change the program, and not the yardstick.
 
 From the program this takes the system itself, its spans
 (utils/tracing), its counters (utils/instrument.ROOT) and its guard
@@ -19,7 +19,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from . import datagen
+from . import datagen, spec
 
 S = datagen.S
 BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
@@ -29,18 +29,22 @@ FAULT_COUNTERS = ("faults", "trips", "trip_open", "quarantined",
 
 class CompileLog:
     """Counts XLA backend compiles through jax.monitoring, each with the
-    monotonic time it ended at, so the window's can be told apart."""
+    monotonic time it ended at, so the window's can be told apart. Every
+    other timed event of JAX's (a trace, a lowering, a fetch from the
+    persistent cache) is kept too: (ended_ns, event, seconds)."""
 
     def __init__(self):
         import jax
 
         self.ended_ns: List[int] = []
         self.seconds = 0.0
+        self.events: List[tuple] = []
         jax.monitoring.register_event_duration_secs_listener(self._dur)
 
     def _dur(self, event: str, secs: float, **_kw):
+        self.events.append((time.perf_counter_ns(), event, secs))
         if event == BACKEND_COMPILE_EVENT:
-            self.ended_ns.append(time.perf_counter_ns())
+            self.ended_ns.append(self.events[-1][0])
             self.seconds += secs
 
     def between(self, t0: int, t1: int) -> int:
@@ -75,14 +79,11 @@ def counters() -> Dict[str, float]:
             if isinstance(v, (int, float))}
 
 
-def moved(c0: dict, c1: dict) -> Dict[str, float]:
-    return {k: v - c0.get(k, 0) for k, v in c1.items()}
-
-
 class Server:
-    def __init__(self, cell, seed: int, workdir: str):
-        from m3_tpu.services import load_dict, run_dbnode
+    """Whatever `benchmark/deployments/<deployment_kind>.py` booted, with
+    the injected clock, the logs and the counters around it."""
 
+    def __init__(self, cell, seed: int, workdir: str):
         self.cell, self.seed, self.workdir = cell, seed, workdir
         self.cfg = cell.config
         self.clock_file = os.path.join(workdir, "clock.i64")
@@ -92,69 +93,60 @@ class Server:
         self.compile_log = CompileLog()
         self.gc_log = GcLog()
         self.counters0 = counters()
-        node = dict(self.cfg["dbnode"])
-        node["data_dir"] = os.path.join(workdir, "data")
-        node["coordinator"] = {}
         clock = self.clock
-        self.handle = run_dbnode(load_dict(node, "dbnode"),
-                                 clock=lambda: int(clock[0]))
-        self.base = self.handle.coordinator.endpoint
-        self.ticks: List[tuple] = []     # (start_ns, end_ns) of mediator ticks
-        self._mediator = None
+        self.handle = spec.load_part("deployments", cell.deployment).boot(
+            cell, workdir, lambda: int(clock[0]))
+        self.base = self.handle.base
+        # mediator ticks: (asked for by the cadence, began, ended), ns
+        self.ticks: List[tuple] = []
+        from m3_tpu.storage.mediator import Mediator
+
+        self.mediator = Mediator(self.handle.db, self.handle.persist)
         self.vals: Optional[np.ndarray] = None
         self.labels: List[dict] = []
 
     # ------------------------------------------------------------------- load
 
-    def mediator(self):
-        from m3_tpu.storage.mediator import Mediator
-
-        if self._mediator is None:
-            self._mediator = Mediator(self.handle.db, self.handle.persist)
-        return self._mediator
-
-    def tick(self) -> dict:
+    def tick(self, asked_ns: Optional[int] = None) -> dict:
         t0 = time.perf_counter_ns()
-        stats = self.mediator().run_once()
-        self.ticks.append((t0, time.perf_counter_ns()))
+        stats = self.mediator.run_once()
+        self.ticks.append((asked_ns or t0, t0, time.perf_counter_ns()))
         return stats
 
-    def load(self, say) -> dict:
-        """The set-up's fixed load: `load_steps` scrapes of every series
-        through the node's batched write, the clock following the data,
+    def replay_scrapes(self, write_scrape, say):
+        """The set-up's `load_steps` scrapes, each handed to
+        `write_scrape(k, ts_ns, values)`: the clock following the data,
         the mediator ticking where the traffic file says a live node's
         would have, then the clock moved on so every full block that is
         due seals and flushes."""
-        from m3_tpu.metrics import id as metric_id
-
         setup = self.cell.traffic["setup"]
-        steps = int(setup["load_steps"])
-        extra = int(self.cell.traffic.get("max_window_steps", 0))
         cadence = int(self.cfg["cadence_s"]) * S
-        self.labels = datagen.series_labels(self.cfg, self.seed)
-        self.vals = datagen.walk(self.cfg, self.seed, steps + extra)
-        tags = [{k.encode(): v.encode() for k, v in lab.items()}
-                for lab in self.labels]
-        name = self.cfg["schema"]["measurement"].encode()
-        ids = [metric_id.encode(name, {k: v for k, v in t.items()
-                                       if k != b"__name__"}) for t in tags]
-        n = len(ids)
-        db = self.handle.db
         tick_at = set(setup.get("tick_at_steps", []))
-        t_load = time.perf_counter()
-        for k in range(steps):
+        for k in range(int(setup["load_steps"])):
             ts = int(datagen.step_ts(self.cfg, k))
             self.clock[0] = ts + cadence
-            db.write_batch(b"default", ids, np.full(n, ts, np.int64),
-                           self.vals[:, k].astype(np.float64),
-                           tags if k == 0 else None)
+            write_scrape(k, ts, self.vals[:, k].astype(np.float64))
             if k in tick_at:
                 say(f"mediator at step {k}: {self.tick()}")
         self.clock[0] += int(setup["final_clock_advance_s"]) * S
         say(f"mediator at end of load: {self.tick()}")
-        ns = db.namespace(b"default")
+
+    def load(self, say) -> dict:
+        """The set-up's fixed load from the seed, by
+        `benchmark/setups/<setup.via>.py`; then what every set-up must
+        have left behind: the blocks the traffic file expects, sealed and
+        flushed."""
+        setup = self.cell.traffic["setup"]
+        steps = int(setup["load_steps"])
+        extra = int(self.cell.traffic.get("max_window_steps", 0))
+        self.labels = datagen.series_labels(self.cfg, self.seed)
+        self.vals = datagen.walk(self.cfg, self.seed, steps + extra)
+        t_load = time.perf_counter()
+        facts = spec.load_part("setups", self.cell.setup_via).load(self, say)
+        name = self.handle.namespace
+        ns = self.handle.db.namespace(name)
         sealed = sorted({bs for sh in ns.shards.values() for bs in sh.blocks})
-        filesets = sum(len(self.handle.persist.list_filesets(b"default", sid))
+        filesets = sum(len(self.handle.persist.list_filesets(name, sid))
                        for sid in ns.shards)
         want = int(setup["sealed_blocks"])
         if len(sealed) != want or filesets < want * len(ns.shards):
@@ -162,9 +154,8 @@ class Server:
                 f"set-up sealed {len(sealed)} block starts and flushed "
                 f"{filesets} filesets; the traffic file expects {want} blocks "
                 f"of {len(ns.shards)} shards")
-        return {"series": n, "samples": n * steps, "sealed_blocks": len(sealed),
-                "filesets": filesets,
-                "load_s": time.perf_counter() - t_load}
+        return dict(facts, sealed_blocks=len(sealed), filesets=filesets,
+                    load_s=time.perf_counter() - t_load)
 
     def data_dir_bytes(self) -> int:
         total = 0
@@ -178,13 +169,19 @@ class Server:
 
     # ------------------------------------------------------------- the window
 
-    def start_ticker(self, interval_s: float):
-        """The mediator on its cadence in real time, for the window."""
+    def start_ticker(self, interval_s: float, t0_ns: int):
+        """The mediator on its cadence in real time, for the window: the
+        first tick `interval_s` after the window's first instant, each
+        later one `interval_s` after the last has ended (the program's
+        own `Mediator.start` loop)."""
         stop = threading.Event()
 
         def loop():
-            while not stop.wait(interval_s):
-                self.tick()
+            asked = t0_ns + int(interval_s * 1e9)
+            while not stop.wait(max(0.0, (asked - time.perf_counter_ns())
+                                    / 1e9)):
+                self.tick(asked)
+                asked = time.perf_counter_ns() + int(interval_s * 1e9)
 
         th = threading.Thread(target=loop, daemon=True)
         th.start()
@@ -205,7 +202,7 @@ class Server:
         from m3_tpu.parallel import guard
         from m3_tpu.utils import retry as uretry
 
-        c = moved(c0, c1)
+        c = {k: v - c0.get(k, 0) for k, v in c1.items()}
         faults = {k: v for k, v in c.items()
                   if v and k.startswith("telemetry.compute.")
                   and any(s in k for s in FAULT_COUNTERS)}

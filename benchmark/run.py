@@ -41,6 +41,12 @@ def main(argv=None) -> int:
     except cellrun.NoChip as e:
         print(f"benchmark: {e}", file=sys.stderr)
         return 3
+    # every number compared beside its limit: the last lines on standard
+    # error, and the last key of the result's line
+    for name, (value, limit) in result["checks"].items():
+        print(f"check {name}: {value!r} (limit {limit!r}) "
+              f"{'ok' if value <= limit else 'FAILED'}", file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(result), flush=True)
     return 0
 

@@ -23,9 +23,18 @@ def bench() -> dict:
     for m in real["end_to_end"] + real["per_layer"]:
         if thin["name"] in m.get("workloads", []):
             m["workloads"] = m["workloads"] + ["cpu4k-query-fat"]
-    return dict(real, workloads=cells, configs=[{
-        "name": "tsbs-cpu-tiny",
-        "file": "benchmark/tests/tsbs-cpu-tiny.json"}])
+    # a stand-in deployment: the coordinator configured by the
+    # configuration's own block, the store filled through its writer
+    ingest = next(w for w in cells if w["traffic"] == "tsbs-remote-write")
+    cells.append(dict(ingest, name="tiny-via-coordinator",
+                      config="tsbs-cpu-tiny-coordinator",
+                      traffic="tiny-via-coordinator"))
+    for m in real["end_to_end"] + real["per_layer"]:
+        if ingest["name"] in m.get("workloads", []):
+            m["workloads"] = m["workloads"] + ["tiny-via-coordinator"]
+    return dict(real, workloads=cells, configs=[
+        {"name": name, "file": "benchmark/tests/%s.json" % name}
+        for name in ("tsbs-cpu-tiny", "tsbs-cpu-tiny-coordinator")])
 
 
 def cell(workload: str, **traffic_overrides):
