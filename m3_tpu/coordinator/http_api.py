@@ -404,17 +404,23 @@ class HTTPApi:
             with tracing.child_span("remote_write.decompress"):
                 raw = promremote.snappy_decompress(req.body)
             with tracing.child_span("remote_write.decode"):
-                series = promremote.decode_write_request(raw)
+                series, series_ids = promremote.decode_write_request(
+                    raw, self.writer.label_memo)
         except (promremote.SnappyError, promremote.ProtoError) as e:
             raise HTTPError(400, f"bad remote write body: {e}")
         with tracing.child_span("remote_write.append") as sp:
             # the request as ONE batch: one admission, one append per
             # shard touched, one commit-log append. A traced request's
             # phases (`id_ns`, `buffer_ns`, `commitlog_ns`) land on this
-            # span once, from the layers below.
-            rows = [(tags, t_ms * 1_000_000, value)
-                    for tags, samples in series for t_ms, value in samples]
-            self.writer.write_batch(rows)
+            # span once, from the layers below. A row's tags and id are
+            # the label memo's: shared with every other request that
+            # carries the series, so nothing below may write to them.
+            rows, row_ids = [], []
+            for (tags, samples), sid in zip(series, series_ids):
+                for t_ms, value in samples:
+                    rows.append((tags, t_ms * 1_000_000, value))
+                    row_ids.append(sid)
+            self.writer.write_batch(rows, series_ids=row_ids)
             sp.add_cost("samples_n", len(rows))
         return {"status": "success", "wrote": len(rows)}
 

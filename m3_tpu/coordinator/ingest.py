@@ -16,6 +16,7 @@ from ..utils import tracing
 from ..utils.health import AdmissionGate, Priority
 from ..utils.instrument import ROOT
 from .downsample import Downsampler
+from .promremote import LabelMemo
 
 _scope = ROOT.sub_scope("coordinator.ingest")
 _BATCHES = _scope.counter("batches")
@@ -39,6 +40,9 @@ class DownsamplerAndWriter:
         # default; services size it from config where it matters.
         self.gate = gate if gate is not None else AdmissionGate(
             capacity=4096, name="coordinator.ingest")
+        # the remote-write decode's memo, kept where ids are made: the
+        # ids it yields are the ones write_batch takes as `series_ids`
+        self.label_memo = LabelMemo(_series_id)
         self.written = 0
         self.downsampled = 0
 
@@ -64,7 +68,8 @@ class DownsamplerAndWriter:
             _scope.counter("written").inc()
 
     def write_batch(self, samples: Sequence[tuple],
-                    priority: Priority = Priority.NORMAL, **kw):
+                    priority: Priority = Priority.NORMAL,
+                    series_ids: Optional[Sequence[bytes]] = None, **kw):
         """All-or-nothing admission: the whole batch is admitted ONCE up
         front. Per-sample admission would let a mid-batch shed leave a
         partially-written prefix that the 429-retrying producer then
@@ -75,7 +80,10 @@ class DownsamplerAndWriter:
         Downsampler.write_batch call matches the whole batch against the
         rule set (batch matcher + grouped columnar aggregator adds)
         instead of a per-sample match+append loop; the unaggregated leg
-        rides the storage's columnar write_batch when it has one."""
+        rides the storage's columnar write_batch when it has one.
+
+        `series_ids`: each row's id where the caller has it already (the
+        remote-write handler, from `label_memo`); else made here."""
         samples = list(samples)
         if not samples:
             return
@@ -95,13 +103,14 @@ class DownsamplerAndWriter:
                 if accepted:
                     _scope.counter("downsampled").inc(accepted)
             if write_unaggregated:
-                self._storage_write_batch(samples)
+                self._storage_write_batch(samples, series_ids)
         _BATCHES.inc()
         _BATCH_SAMPLES.inc(len(samples))
 
-    def _storage_write_batch(self, samples: Sequence[tuple]):
-        with tracing.phase("id"):  # `id_ns` of a detailed span
-            sids = [_series_id(tags) for tags, _t, _v in samples]
+    def _storage_write_batch(self, samples: Sequence[tuple], sids):
+        if sids is None:
+            with tracing.phase("id"):  # `id_ns` of a detailed span
+                sids = [_series_id(tags) for tags, _t, _v in samples]
         batch_write = getattr(self._storage, "write_batch", None)
         if batch_write is not None:
             batch_write(sids, [s[0] for s in samples],

@@ -27,9 +27,13 @@ Prometheus senders with exemplars/metadata fields still parse.
 from __future__ import annotations
 
 import struct
-from typing import List, Tuple
+import threading
+from itertools import islice
+from typing import Callable, Dict, List, Optional, Tuple
 
 from ..query.model import Matcher, MatchType
+from ..utils import tracing
+from ..utils.instrument import ROOT
 
 # ---------------------------------------------------------------------------
 # snappy block format (github.com/google/snappy/blob/main/format_description.txt)
@@ -206,13 +210,199 @@ def _f64(bits: int) -> float:
     return struct.unpack("<d", bits.to_bytes(8, "little"))[0]
 
 
-def decode_write_request(data: bytes) -> List[Tuple[dict, List[Tuple[int, float]]]]:
-    """prompb.WriteRequest -> [(tags {bytes: bytes}, [(t_ms, value), ...])]."""
-    out = []
-    for field, wt, v in _fields(memoryview(data)):
-        if field == 1 and wt == 2:
-            out.append(_decode_timeseries(v))
-    return out
+# 65,536 label blocks (the shard memo's bound, parallel/sharding.py) of at
+# most LABEL_MEMO_MAX_KEY bytes each; a longer block is decoded every time.
+LABEL_MEMO_MAX_ENTRIES = 65536
+LABEL_MEMO_MAX_KEY = 2048
+
+_memo_scope = ROOT.sub_scope("coordinator.remote_write.label_memo")
+_MEMO_HITS = _memo_scope.counter("hits")
+_MEMO_MISSES = _memo_scope.counter("misses")
+
+_F64 = struct.Struct("<d")
+
+
+class LabelMemo:
+    """A TimeSeries message's label block (the raw bytes of its leading
+    `labels` fields) -> (tags, series id). A sender repeats a series'
+    label bytes in every request, so a known block costs one dict probe
+    instead of a decode per label and an id per series. The key is the
+    bytes themselves and the value what the full decoder and `series_id`
+    made of exactly those bytes, so a hit IS the slow path's answer.
+
+    The tags dict of an entry is shared by every request that carries
+    the block (and by whatever keeps a row's tags: a shard's registry):
+    nothing downstream may mutate it.
+
+    Probes are lock-free dict reads; the lock orders the miss path's
+    bound check with its insert. A full memo drops its oldest eighth
+    (insertion order): room is made once in 8,192 first sightings, and
+    a series still being sent comes back on its next miss."""
+
+    def __init__(self, series_id: Callable[[dict], bytes],
+                 max_entries: int = LABEL_MEMO_MAX_ENTRIES):
+        self._series_id = series_id
+        self._max = max_entries
+        self._entries: Dict[bytes, Tuple[dict, bytes]] = {}
+        self._lock = threading.Lock()
+        self.lookup = self._entries.get  # block -> entry | None, no lock
+
+    def __len__(self) -> int:
+        return len(self._entries)
+
+    def resolve(self, tags: dict) -> Tuple[dict, bytes]:
+        """The entry the full decoder's tags make; not remembered."""
+        return tags, self._series_id(tags)
+
+    def remember(self, block: bytes, tags: dict) -> Tuple[dict, bytes]:
+        entry = self.resolve(tags)
+        if len(block) <= LABEL_MEMO_MAX_KEY:
+            entries = self._entries
+            with self._lock:
+                if len(entries) >= self._max:
+                    for old in list(islice(entries, max(1, self._max // 8))):
+                        del entries[old]
+                entries[block] = entry
+        return entry
+
+
+def decode_write_request(data: bytes, memo: LabelMemo) -> Tuple[
+        List[Tuple[dict, List[Tuple[int, float]]]], List[bytes]]:
+    """prompb.WriteRequest -> ([(tags {bytes: bytes}, [(t_ms, value), ...])],
+    [series id]), one of each per TimeSeries in the request's order.
+
+    The request is walked at the level of field headers. A TimeSeries
+    that is a run of `labels` fields followed by a run of `samples`
+    fields and nothing else has its label block looked up in `memo` by
+    its bytes (only the labels' LENGTHS are read) and its samples
+    decoded in place; a first sighting decodes the block with the full
+    decoder and is remembered. Any other layout takes the full decoder
+    for that series, so this gives the rows the full decoder gives for
+    the same bytes, or raises ProtoError where it does. Counts series in
+    `coordinator.remote_write.label_memo.hits` / `.misses` (a series
+    decoded in full is a miss) and, under a detailed span, in its
+    `memo_hit_n` / `memo_miss_n`."""
+    data = bytes(data)
+    series: List[Tuple[dict, List[Tuple[int, float]]]] = []
+    ids: List[bytes] = []
+    probe = memo.lookup
+    unpack = _F64.unpack_from
+    hits = misses = 0
+    pos, n = 0, len(data)
+    while pos < n:
+        if data[pos] == 0x0A:
+            pos += 1
+        else:
+            key, pos = _read_uvarint_mv(data, pos)
+            if key != 0x0A:  # not `timeseries`: skipped, as ever
+                pos = _skip_value(data, pos, key & 7)
+                continue
+        if pos + 1 < n and data[pos + 1] < 0x80:  # one or two length bytes
+            ln = data[pos]
+            if ln < 0x80:
+                start = pos + 1
+            else:
+                ln = (ln & 0x7F) | (data[pos + 1] << 7)
+                start = pos + 2
+        else:
+            ln, start = _read_uvarint_mv(data, pos)
+        pos = end = start + ln
+        if end > n:
+            raise ProtoError("truncated bytes field")
+        entry = None
+        try:
+            p = start
+            while p < end and data[p] == 0x0A:  # a label: its length only
+                ln = data[p + 1]
+                if ln < 0x80:
+                    p += 2 + ln
+                else:
+                    ln, p = _read_uvarint_mv(data, p + 1)
+                    p += ln
+            labels_end = p
+            samples = []
+            while p < end and data[p] == 0x12 and data[p + 1] < 0x80:
+                q = p + 2 + data[p + 1]
+                # value then timestamp is what every sender writes
+                t_ms = _stamp_ms(data, p + 12, q) if (
+                    q - p > 12 and data[p + 2] == 0x09
+                    and data[p + 11] == 0x10) else None
+                if t_ms is not None:
+                    samples.append((t_ms, unpack(data, p + 3)[0]))
+                else:  # a default left out, another order, more fields
+                    samples.append(_decode_sample(memoryview(data)[p + 2:q]))
+                p = q
+            if p == end:
+                block = data[start:labels_end]
+                entry = probe(block)
+                if entry is None:
+                    entry = memo.remember(
+                        block, _decode_timeseries(memoryview(block))[0])
+                    misses += 1
+                else:
+                    hits += 1
+        except (IndexError, ProtoError):
+            entry = None  # the full decoder says what is wrong with it
+        if entry is None:
+            tags, samples = _decode_timeseries(memoryview(data)[start:end])
+            entry = memo.resolve(tags)
+            misses += 1
+        series.append((entry[0], samples))
+        ids.append(entry[1])
+    if hits:
+        _MEMO_HITS.inc(hits)
+    if misses:
+        _MEMO_MISSES.inc(misses)
+    acc = tracing.detail()
+    if acc is not None:
+        acc.add_cost("memo_hit_n", hits)
+        acc.add_cost("memo_miss_n", misses)
+    return series, ids
+
+
+def _skip_value(buf: bytes, pos: int, wt: int) -> int:
+    """Past one field's value, with `_fields`' own complaints."""
+    n = len(buf)
+    if wt == 0:
+        return _read_uvarint_mv(buf, pos)[1]
+    if wt == 2:
+        ln, pos = _read_uvarint_mv(buf, pos)
+        if pos + ln > n:
+            raise ProtoError("truncated bytes field")
+        return pos + ln
+    if wt == 1 or wt == 5:
+        pos += 8 if wt == 1 else 4
+        if pos > n:
+            raise ProtoError("truncated fixed64" if wt == 1
+                             else "truncated fixed32")
+        return pos
+    raise ProtoError(f"unsupported wire type {wt}")
+
+
+def _stamp_ms(buf: bytes, pos: int, end: int) -> Optional[int]:
+    """buf[pos:end] as exactly one varint of at most ten bytes (an int64
+    in two's complement), else None."""
+    if not pos < end <= pos + 10:
+        return None
+    out = shift = 0
+    for b in buf[pos:end - 1]:
+        if b < 0x80:
+            return None
+        out |= (b & 0x7F) << shift
+        shift += 7
+    b = buf[end - 1]
+    return None if b & 0x80 else _zigzag_i64(out | (b << shift))
+
+
+def _decode_sample(buf: memoryview) -> Tuple[int, float]:
+    val = 0.0
+    t_ms = 0
+    for field, wt, v in _fields(buf):
+        if field == 1 and wt == 1:
+            val = _f64(v)
+        elif field == 2 and wt == 0:
+            t_ms = _zigzag_i64(v)
+    return t_ms, val
 
 
 def _decode_timeseries(buf: memoryview):
@@ -228,14 +418,7 @@ def _decode_timeseries(buf: memoryview):
                     value = bytes(v2)
             tags[name] = value
         elif field == 2 and wt == 2:
-            val = 0.0
-            t_ms = 0
-            for f2, w2, v2 in _fields(v):
-                if f2 == 1 and w2 == 1:
-                    val = _f64(v2)
-                elif f2 == 2 and w2 == 0:
-                    t_ms = _zigzag_i64(v2)
-            samples.append((t_ms, val))
+            samples.append(_decode_sample(v))
     return tags, samples
 
 

@@ -354,6 +354,184 @@ def test_hash_batch_host_is_murmur3(seed):
     assert hashing.hash_batch_host([], seed).tolist() == []
 
 
+# --------------------------------------------------------- the label memo
+#
+# The remote-write decode's memo (promremote.LabelMemo, the writer's
+# `label_memo`): a series' label bytes -> (tags, series id), so a request
+# of known series decodes no label and encodes no id.
+
+
+MEMO = "coordinator.remote_write.label_memo."
+
+
+def _request(hosts, first=0, t_ms=T0 // 1_000_000):
+    return pr.encode_write_request(_scrape(hosts, 1, t_ms, first=first))
+
+
+def _full(data):
+    return [pr._decode_timeseries(v)
+            for f, wt, v in pr._fields(memoryview(data)) if f == 1 and wt == 2]
+
+
+def test_the_label_memo_is_bounded_and_stays_right_past_its_bound(monkeypatch):
+    memo = pr.LabelMemo(_sid, max_entries=64)
+    c0 = _counters(MEMO)
+    sizes = []
+    for first in range(0, 200, 40):          # 200 series through 64 places
+        data = _request(40, first)
+        series, ids = pr.decode_write_request(data, memo)
+        assert series == _full(data) and ids == [_sid(t) for t, _ in series]
+        sizes.append(len(memo))
+    # full at 64, a first sighting drops the oldest eighth and goes in
+    assert sizes == [40, 64, 64, 64, 64] and max(sizes) <= 64
+    assert _moved(c0, MEMO) == {MEMO + "misses": 200}
+    c0 = _counters(MEMO)
+    newest, oldest = _request(40, 160), _request(40, 0)
+    assert pr.decode_write_request(newest, memo)[0] == _full(newest)
+    assert _moved(c0, MEMO) == {MEMO + "hits": 40}
+    c0 = _counters(MEMO)
+    assert pr.decode_write_request(oldest, memo)[0] == _full(oldest)
+    assert _moved(c0, MEMO) == {MEMO + "misses": 40}     # they had gone
+    assert len(memo) <= 64
+    # a label block past the key bound is decoded every time, never kept
+    monkeypatch.setattr(pr, "LABEL_MEMO_MAX_KEY", 30)
+    memo, c0 = pr.LabelMemo(_sid), _counters(MEMO)
+    for _ in range(2):
+        assert pr.decode_write_request(newest, memo)[0] == _full(newest)
+    assert len(memo) == 0
+    assert _moved(c0, MEMO) == {MEMO + "misses": 80}
+
+
+def test_label_blocks_one_byte_apart_are_two_series():
+    a = {b"__name__": b"cpu", b"host": b"h001"}
+    b = {b"__name__": b"cpu", b"host": b"h002"}
+    c = {b"__name__": b"cpv", b"host": b"h001"}
+    memo = pr.LabelMemo(_sid)
+    data = pr.encode_write_request([(t, [(1, 1.0)]) for t in (a, b, c, a)])
+    for _ in range(2):                        # first sightings, then hits
+        series, ids = pr.decode_write_request(data, memo)
+        assert [t for t, _ in series] == [a, b, c, a]
+        assert ids == [_sid(a), _sid(b), _sid(c), _sid(a)]
+        assert len(set(ids)) == 3 and len(memo) == 3
+
+
+def test_a_request_with_ids_stores_what_one_without_stores(tmp_path):
+    """The handler hands the memo's ids to the writer; a writer left to
+    make them itself stores the same rows, log entries and answer."""
+    given, made = Node(tmp_path, "given"), Node(tmp_path, "made")
+    seen = []
+    inner = made.coord.writer.write_batch
+
+    def without_ids(rows, series_ids=None, **kw):
+        seen.append(series_ids)
+        return inner(rows, **kw)
+
+    made.coord.writer.write_batch = without_ids
+    try:
+        for r in range(3):                   # first sightings, then hits
+            series = _scrape(60, 2, T0 // 1_000_000 + 20_000 * r)
+            assert given.post(series) == made.post(series) == (
+                200, b'{"status": "success", "wrote": 120}')
+        assert len(seen) == 3 and all(
+            ids == [_sid(t) for t, smp in _scrape(60, 2) for _ in smp]
+            for ids in seen)
+        for tags, _ in series:
+            sid = _sid(tags)
+            (t, v), (mt, mv) = given.read(sid), made.read(sid)
+            assert t.tolist() == mt.tolist() and len(t) == 6
+            assert v.tolist() == mv.tolist()
+            assert given.registry_tags(sid) == made.registry_tags(sid) == tags
+        assert given.wal() == made.wal()      # entries, tags, bytes
+    finally:
+        given.close()
+        made.close()
+
+
+def test_sixteen_threads_on_a_memo_too_small_for_them_all_decode_right():
+    """Probes are lock-free while another thread makes room: with more
+    threads than cores, a short switch interval and a memo a quarter of
+    the series' number, every decode is still the full decoder's and
+    the bound holds at every look."""
+    import sys
+    import time
+
+    memo = pr.LabelMemo(_sid, max_entries=32)
+    requests = [_request(24, first) for first in range(0, 120, 8)]
+    want = [(_full(d), [_sid(t) for t, _ in _full(d)]) for d in requests]
+    errors, sizes = [], []
+    deadline = time.monotonic() + 20
+
+    def work(k):
+        try:
+            for i in range(60):
+                j = (k + i) % len(requests)
+                assert pr.decode_write_request(requests[j], memo) == want[j]
+                sizes.append(len(memo))
+                if time.monotonic() > deadline:
+                    raise TimeoutError
+        except BaseException as e:  # noqa: BLE001
+            errors.append(e)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=work, args=(k,)) for k in range(16)]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(timeout=30)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not errors and not any(th.is_alive() for th in threads)
+    assert len(sizes) == 16 * 60 and 0 < max(sizes) <= 32
+
+
+def test_eight_senders_of_overlapping_groups_leave_each_series_its_tags(node):
+    """Eight handler threads read and fill one memo; groups overlap, so
+    the same label block is a first sighting on several threads at
+    once. Every series reads back whole, under its own tags, and every
+    entry of the memo is still what the full decoder makes of its key:
+    nothing downstream wrote to a dict it shares."""
+    senders, rounds, hosts, stride = 8, 5, 40, 15
+    errors = []
+    c0 = _counters(MEMO)
+
+    def send(s):
+        try:
+            for r in range(rounds):
+                status, _ = node.post(_scrape(
+                    hosts, 1, T0 // 1_000_000 + 10_000 * r, first=s * stride))
+                assert status == 200
+        except Exception as e:  # noqa: BLE001
+            errors.append(e)
+
+    node.now["t"] = T0 + 60 * S
+    threads = [threading.Thread(target=send, args=(s,))
+               for s in range(senders)]
+    for th in threads:
+        th.start()
+    for th in threads:
+        th.join()
+    assert not errors
+    everyone = _scrape(stride * (senders - 1) + hosts, rounds)
+    for tags, samples in everyone:
+        sid = _sid(tags)
+        t, v = node.read(sid)
+        assert dict(zip(t.tolist(), v.tolist())) == {
+            t_ms * 1_000_000: samples[0][1] for t_ms, _ in samples}
+        assert node.registry_tags(sid) == tags
+    memo = node.coord.writer.label_memo
+    assert len(memo) == len(everyone)
+    for block, (tags, sid) in memo._entries.items():
+        assert pr._decode_timeseries(memoryview(block)) == (tags, [])
+        assert sid == _sid(tags)
+    moved = _moved(c0, MEMO)
+    assert moved[MEMO + "hits"] + moved[MEMO + "misses"] == \
+        senders * rounds * hosts
+    assert moved[MEMO + "misses"] >= len(everyone)
+    assert len(node.wal()[0]) == senders * rounds * hosts
+
+
 # ----------------------------------------- the downsample leg asks first
 
 
