@@ -10,9 +10,9 @@ Rules:
                           storage / query / ops / parallel modules — the
                           layers that move block-sized arrays (sealed
                           blocks, consolidated grids, flush tiles) onto
-                          devices. Route one-shot uploads through
-                          `utils.hbm.budgeted_put` (charged for the
-                          array's lifetime) or a budget-registered cache,
+                          devices. Route uploads through a cache
+                          registered with `utils.hbm.HBMBudget` (the
+                          upload/derived grid caches, the block cache),
                           or carry a justified suppression (the
                           mesh-flush staging path deliberately stages
                           transient tiles that the encode program
@@ -77,8 +77,8 @@ class UnbudgetedDevicePutRule(Rule):
             yield self.finding(
                 mod, node,
                 "raw jax.device_put pins device memory no budget sees; "
-                "route through utils.hbm.budgeted_put (or a budget-"
-                "registered cache), or suppress with a justification "
+                "route through a cache registered with utils.hbm."
+                "HBMBudget, or suppress with a justification "
                 "for transient staging the program frees itself")
 
 

@@ -770,9 +770,8 @@ class UnguardedPallasDispatchRule(Rule):
     2. The module must declare `_PALLAS_ORACLE = "<path>"` naming the
        test file that asserts interpret-vs-XLA parity, and the path must
        exist. Pallas kernels ship only with a standing bit-identity
-       oracle — pallas_window.py and pallas_codec.py both ride this
-       contract, and the constant keeps the pointer from rotting
-       silently when tests move.
+       oracle — pallas_codec.py rides this contract, and the constant
+       keeps the pointer from rotting silently when tests move.
     """
 
     id = "unguarded-pallas-dispatch"
@@ -861,7 +860,7 @@ class UnguardedPallasDispatchRule(Rule):
                     "pallas_call's interpret= does not come from an "
                     "enclosing builder parameter — the lru_cached "
                     "`_build(..., interpret)` seam is the contract "
-                    "(pallas_window.py / pallas_codec.py)")
+                    "(pallas_codec.py)")
 
 
 class UnclassifiedDeviceDispatchRule(Rule):

@@ -42,8 +42,7 @@ Two further rules reconstruct the exact bug shapes fixed in PRs 4/6:
   finalizer-under-lock       a `weakref.finalize` callback that
       acquires a lock (directly or one call level deep). Finalizers
       run at ANY bytecode boundary — including while the same thread
-      holds that lock — so they must stay lock-free (the PR 6
-      HBMBudget transient-release fix).
+      holds that lock — so they must stay lock-free.
 
 The modules that DEFINE the paired primitives (utils/retry.py,
 utils/health.py, utils/cost.py, utils/limits.py, utils/hbm.py,
@@ -816,7 +815,7 @@ class FinalizerUnderLockRule(Rule):
                     "bytecode boundary, including while this thread "
                     "already holds that lock — keep finalizers lock-free "
                     "(append to a GIL-atomic list and drain it under the "
-                    "lock elsewhere, the HBMBudget transient pattern)",
+                    "lock elsewhere)",
                     self.severity)
 
     def _locks_in(self, fn, model: _LockModel, defs, depth: int

@@ -16,8 +16,8 @@ Two ingest paths with identical semantics:
     (Aggregator.add_untimed_batch) instead of per-metric add_untimed.
   * write_ref — the retained per-metric oracle (metrics_appender.go
     SamplesAppender, verbatim pre-batch shape): re-match, then one
-    add_untimed per matched pipeline. The downsample_rules bench and the
-    property suite hold the two paths' counters and flushed rows equal.
+    add_untimed per matched pipeline. The property suite holds the two
+    paths' counters and flushed rows equal.
 
 Flush rides the PR 10 columnar plane: the aggregator's emit_batch hands
 the WHOLE round's (ids, times, values, policy) groups to handle_columnar

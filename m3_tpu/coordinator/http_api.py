@@ -124,13 +124,12 @@ class HTTPApi:
 
     def debug_vars(self, req) -> dict:
         """Process metrics snapshot (the reference exposes pprof + tally;
-        dbnode/server/server.go:575 debug listener), plus the query
-        engine's live device-vs-host placement cost model."""
+        dbnode/server/server.go:575 debug listener), plus the compute
+        guard's breaker and quarantine state."""
         from ..parallel import guard
         from ..utils.instrument import ROOT
 
         return {"metrics": ROOT.snapshot(),
-                "query_placement": self.engine.placement_snapshot(),
                 "compute": guard.debug_snapshot()}
 
     def debug_traces(self, req) -> dict:

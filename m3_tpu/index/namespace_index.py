@@ -237,8 +237,7 @@ class NamespaceIndex:
         at postings evaluation): a regexp matching the whole namespace is
         rejected by ResourceExhausted before it gathers a single id."""
         # child_span: real only under an already-sampled request (rpc
-        # dispatch / executor) — a bare index query pays one TLS read
-        # (the obs_overhead_guard's index bench contract).
+        # dispatch / executor) — a bare index query pays one TLS read.
         with tracing.child_span("index.query") as sp:
             parts = []
             segs = 0

@@ -25,8 +25,7 @@ structurally (rule-order pipeline merging, dict.fromkeys dedup, rollup
 new-id generation, last-wins duplicate-rollup-id merge, cutover = max of
 matched snapshot cutovers including tombstoned ones), so results are
 EQUAL (dataclass equality) to the per-metric oracle — the property suite
-(tests/test_batch_matcher.py) and the downsample_rules bench hold the
-two paths identical."""
+(tests/test_batch_matcher.py) holds the two paths identical."""
 
 from __future__ import annotations
 

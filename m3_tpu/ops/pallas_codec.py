@@ -27,11 +27,10 @@ Three kernels, one dispatch gate:
                 (zero-padded little-endian u32 rows), lane-parallel with
                 per-lane active masks; bit-identical to hashing.murmur3_32.
 
-Template lineage (ops/pallas_window.py): these kernels inherit its VMEM
-tiling half — lru_cached `_build(..., interpret)` seams, BlockSpec lane
-tiles, interpret-mode parity on CPU — but NOT its strided-window
-scheduling half: the codec loops walk a data-dependent bit cursor, so
-there is no static window stride to unroll. Rows are read from the Refs
+Shape of every kernel here: an lru_cached `_build(..., interpret)` seam,
+BlockSpec lane tiles, interpret-mode parity on CPU. The codec loops walk
+a data-dependent bit cursor, so nothing is unrolled over a static
+stride. Rows are read from the Refs
 at a dynamic sublane (`ref[pl.ds(j, 1), :]`) and per-lane addressing is
 a masked select, never `lax.dynamic_slice`/`take_along_axis` on loaded
 values: the installed Pallas TPU lowering has no rule for the former and

@@ -31,7 +31,6 @@ next query's host prep (double-buffering across a dashboard burst)."""
 from __future__ import annotations
 
 import collections
-import contextlib
 import functools
 import hashlib
 import os
@@ -54,47 +53,13 @@ _F32 = jnp.float32
 _UPLOAD_METRICS = ROOT.sub_scope("ops.upload_cache")
 _DERIVED_METRICS = ROOT.sub_scope("ops.derived_cache")
 
-# ------------------------------------------------------- query placement
-#
-# The engine may route a whole range-function evaluation to a specific
-# device — in practice the HOST cpu backend when placement's measured
-# cost model says so (m3_tpu/query/placement.py). The
-# same jitted kernels run either way (XLA compiles per backend); inputs
-# committed to the placed device keep execution there. Thread-local
-# because one engine serves concurrent queries.
 
-_PLACEMENT = threading.local()
-
-
-@contextlib.contextmanager
-def placed_on(device):
-    """Run the enclosed kernel calls with inputs committed to `device`
-    (None = default backend). Cache entries are tagged per placement so a
-    host-placed and device-placed eval of the same grid never collide."""
-    prev = getattr(_PLACEMENT, "device", None)
-    _PLACEMENT.device = device
-    try:
-        yield
-    finally:
-        _PLACEMENT.device = prev
-
-
-def _place_device():
-    return getattr(_PLACEMENT, "device", None)
-
-
-def _place_tag():
-    dev = _place_device()
-    return None if dev is None else (dev.platform, dev.id)
-
-
-def _placed_put(arr):
-    # DELIBERATE raw puts: this is the implementation under the content-
+def _put(arr):
+    # DELIBERATE raw put: this is the implementation under the content-
     # addressed upload/derived caches, whose entries are charged to the
     # shared HBM budget by the callers below.
-    # m3lint: disable=unbudgeted-device-put
-    dev = _place_device()
-    return jax.device_put(arr, dev) if dev is not None else jax.device_put(arr)  # m3lint: disable=unbudgeted-device-put
+    return jax.device_put(arr)  # m3lint: disable=unbudgeted-device-put
+
 
 # ------------------------------------------------------------ upload cache
 #
@@ -210,7 +175,7 @@ def _derived(grid: np.ndarray, kind: str, build):
     tier below it runs only with a real accelerator attached (on host CPU
     the 49ms blake2b costs more than the work it would save)."""
     global _derived_cache_bytes, _derived_id_fast_bytes
-    fast_key = (id(grid), kind, _place_tag())
+    fast_key = (id(grid), kind)
     with _PUT_CACHE_LOCK:
         fast = _DERIVED_ID_FAST.get(fast_key)
         if fast is not None and fast[0] is grid:
@@ -223,8 +188,7 @@ def _derived(grid: np.ndarray, kind: str, build):
             _id_fast_store(fast_key, grid, val)
         return val
     g = np.ascontiguousarray(grid)
-    key = (hashlib.blake2b(g, digest_size=16).digest(), g.shape, kind,
-           _place_tag())
+    key = (hashlib.blake2b(g, digest_size=16).digest(), g.shape, kind)
     with _PUT_CACHE_LOCK:
         hit = _DERIVED_CACHE.get(key)
         if hit is not None:
@@ -273,7 +237,7 @@ def _cached_put(arr: np.ndarray):
         return arr
     arr = np.ascontiguousarray(arr)
     key = (hashlib.blake2b(arr, digest_size=16).digest(),
-           arr.shape, arr.dtype.str, _place_tag())
+           arr.shape, arr.dtype.str)
     with _PUT_CACHE_LOCK:
         hit = _PUT_CACHE.get(key)
         if hit is not None:
@@ -281,7 +245,7 @@ def _cached_put(arr: np.ndarray):
             _UPLOAD_METRICS.counter("hits").inc()
             return hit[0]
     _UPLOAD_METRICS.counter("misses").inc()
-    dev = _placed_put(arr)
+    dev = _put(arr)
     # A miss IS a host->device transfer: count the bytes at the choke
     # point so /debug/vars shows real upload volume per process.
     telemetry.count_h2d(int(getattr(dev, "nbytes", arr.nbytes)))
@@ -510,9 +474,9 @@ def _rate_args(grid: np.ndarray, is_counter: bool):
     def build(g):
         adj, finite, grid32 = rate_inputs(g, is_counter)
         arrs = (adj, finite) + ((grid32,) if is_counter else ())
-        if not _cache_enabled() and _place_device() is None:
+        if not _cache_enabled():
             return arrs, 0
-        devs = tuple(_placed_put(a) for a in arrs)
+        devs = tuple(_put(a) for a in arrs)
         # Charge the canonicalized device sizes (what the entry pins).
         return devs, sum(int(getattr(a, "nbytes", 0)) for a in devs)
 
@@ -675,40 +639,6 @@ def _window_stat(resid, W: int, stat: str, stride: int = 1):
     return out, cnt
 
 
-# Opt-in Pallas kernel for the strided window moments (M3_TPU_PALLAS=1):
-# computes ONLY every stride-th window in VMEM instead of reducing all of
-# them and striding after — O(W/stride) less work per grid cell. Off by
-# default until proven on-chip; parity-tested against the XLA path
-# (tests/test_temporal.py::TestPallasWindow).
-_PALLAS_ENABLED = os.environ.get("M3_TPU_PALLAS") == "1"
-
-
-def _use_pallas() -> bool:
-    """Pallas dispatch requires a REAL tpu backend: on anything else the
-    kernel would run in interpret mode (a per-op Python evaluator,
-    orders of magnitude slower than the XLA path) — a fleetwide
-    M3_TPU_PALLAS=1 must not become a silent cliff on CPU nodes.
-    (Tests monkeypatch this to exercise the dispatch off-TPU.)"""
-    return _PALLAS_ENABLED and jax.default_backend() == "tpu"
-
-
-def _window_stat_strided(resid, W: int, stat: str, stride: int):
-    """(stat, count) planes already consolidated to the output stride."""
-    if _use_pallas() and resid.shape[-1] >= W:
-        # K < W falls through: the pallas grid would have zero (or
-        # negative) output columns where the XLA path returns the valid
-        # empty plane. Oversized unrolls fall through too — the kernel
-        # statically unrolls T_out window reductions (Mosaic alignment),
-        # so an unstrided wide grid would trace/compile pathologically.
-        from . import pallas_window
-
-        t_out = (resid.shape[-1] - W) // stride + 1
-        if (stat in pallas_window.STATS
-                and t_out <= pallas_window.MAX_UNROLL_STEPS):
-            return pallas_window.window_stat(resid, W, stride, stat)
-    return _window_stat(resid, W, stat, stride)
-
-
 @guard.guarded_builder("temporal.over_time")
 @telemetry.jit_builder("over_time")
 @functools.lru_cache(maxsize=256)
@@ -720,7 +650,7 @@ def _over_time_fn(W: int, stat: str, stride: int = 1):
     and striding before the transfer are what keep this D2H-lean."""
 
     def fn(resid):
-        out, cnt = _window_stat_strided(resid, W, stat, stride)
+        out, cnt = _window_stat(resid, W, stat, stride)
         cnt_dtype = jnp.uint16 if W <= 0xFFFF else jnp.int32
         return out.astype(_F32), cnt.astype(cnt_dtype)
 
@@ -754,7 +684,7 @@ def over_time_math(resid, base32, *, W: int, kind: str, stride: int = 1):
     (parallel/compile.py) fuses into one program, and the body of the
     standalone fully-fused kernel below."""
     stat_name = _OVER_TIME_STATS[kind]
-    stat, cnt = _window_stat_strided(resid, W, stat_name, stride)
+    stat, cnt = _window_stat(resid, W, stat_name, stride)
     out = _finish_over_time(jnp, kind, stat, cnt, base32[:, None])
     return jnp.where(cnt > 0, out, jnp.nan).astype(_F32)
 
@@ -789,9 +719,9 @@ def _resid_args(grid: np.ndarray):
     def build(g):
         resid, base = center(g)
         base32 = base.astype(np.float32)
-        if not _cache_enabled() and _place_device() is None:
+        if not _cache_enabled():
             return (resid, base, base32), 0
-        resid_dev, base32_dev = _placed_put(resid), _placed_put(base32)
+        resid_dev, base32_dev = _put(resid), _put(base32)
         return ((resid_dev, base, base32_dev),
                 int(getattr(resid_dev, "nbytes", resid.nbytes))
                 + int(getattr(base32_dev, "nbytes", base32.nbytes)))

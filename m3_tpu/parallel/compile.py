@@ -335,7 +335,7 @@ def _stage_fetch(bf: "qplan.BoundFetch", kinds: Tuple[str, ...],
             # cache's HBM-budget tenant bounds the resident total.
             return placed, sum(int(getattr(a, "nbytes", 0)) for a in placed)
         if temporal._cache_enabled():
-            placed = tuple(temporal._placed_put(a) for a in arrs)
+            placed = tuple(temporal._put(a) for a in arrs)
             return placed, sum(int(getattr(a, "nbytes", 0)) for a in placed)
         return tuple(arrs), 0
 
@@ -982,7 +982,7 @@ def execute(bound: "qplan.Bound", mesh: Optional[Mesh]):
     # ANALYZE: with a context active the dispatch synchronizes so the
     # stage records the true program wall (keyed by shape bucket); off,
     # the cost is this one thread-local read and the async pipeline is
-    # untouched (obs_overhead_guard's ANALYZE section enforces it).
+    # untouched.
     actx = qexplain.current()
     sync = missed or actx is not None
     t0 = time.perf_counter() if sync else 0.0

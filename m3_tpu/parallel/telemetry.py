@@ -28,8 +28,7 @@ compile shows `jit_compile` in its cost tags.
 
   count_h2d / count_d2h
                       host<->device transfer bytes at the choke points
-                      (hbm.budgeted_put uploads, the upload cache's
-                      inserts, LazyBlock result materialization).
+                      (the upload cache's inserts, LazyBlock result materialization).
 
   mesh_dispatch(kernel)
                       per-kernel mesh-program dispatch counter (flush
@@ -279,8 +278,8 @@ def _compute_route_counters(route: str):
     # The guard dispatches on hot interpreter paths (one per temporal
     # op invocation): resolve the tagged counter objects once per route
     # so the per-dispatch cost is two Counter.inc()s, not a sub_scope
-    # build + registry lookup (the obs_overhead_guard guard-seam section
-    # holds this under 3%).
+    # build + registry lookup. The seam's cost has no reading on the
+    # chip's host yet (ROADMAP C13).
     scope = _SCOPE.sub_scope("compute", route=route)
     return (scope.counter("primary"), scope.counter("fallback"),
             _COMPUTE.counter("primary"), _COMPUTE.counter("fallback"))

@@ -205,14 +205,6 @@ class Engine:
         # shared slot would charge one query's datapoints to another.
         self._local = threading.local()
         self._grid_cache = _GridCache()
-        from .placement import QueryPlacement
-        self._placement = QueryPlacement()
-
-    def placement_snapshot(self) -> dict:
-        """Live device-vs-host cost model state (mode, measured D2H
-        bandwidth/RTT, per-path rate EWMAs) for /debug/vars and the bench
-        extra."""
-        return self._placement.snapshot()
 
     def execute_range(self, query: str, start_ns: int, end_ns: int,
                       step_ns: int, ast: Optional[Node] = None,
@@ -676,6 +668,9 @@ class Engine:
         return self._eval_instant_func(node, params)
 
     def _eval_range_func(self, node: Call, params: QueryParams) -> Block:
+        from ..parallel import telemetry
+        from .block import LazyBlock
+
         range_args = [a for a in node.args
                       if isinstance(a, (VectorSelector, Subquery))]
         if not range_args or not (isinstance(range_args[-1], Subquery)
@@ -690,34 +685,11 @@ class Engine:
             ext, W, stride = self._eval_range_selector(sel, params)
         grid = ext.values
         step_ns = ext.meta.step_ns
-        f = node.func
         # Every kernel consolidates to the query's output step grid ON
         # DEVICE (stride), so nothing wider than [series, steps] comes
         # back to the host. The hot dashboard shapes (rate-family and
         # *_over_time moments) additionally return fetch closures whose
         # async copy overlaps the next query's host prep (LazyBlock).
-        # WHERE the kernels run is placement.py's decision: the default
-        # accelerator unless its cost model, fully measured, says the
-        # host is cheaper.
-        from ..utils.instrument import ROOT
-
-        cells = int(np.asarray(grid).size)
-        result_bytes = ext.n_series * params.meta().steps * 4
-        placed = self._placement.choose(cells, result_bytes)
-        ROOT.counter("query.placement.host" if placed is not None
-                     else "query.placement.device").inc()
-        t_dispatch = time.perf_counter()
-        with temporal.placed_on(placed):
-            return self._dispatch_range_func(
-                node, sel, params, ext, grid, W, stride, step_ns,
-                placed=placed, cells=cells, result_bytes=result_bytes,
-                t_dispatch=t_dispatch)
-
-    def _dispatch_range_func(self, node, sel, params, ext, grid, W, stride,
-                             step_ns, *, placed, cells, result_bytes,
-                             t_dispatch):
-        from .block import LazyBlock
-
         f = node.func
         fetch = None
         if f == "rate":
@@ -765,31 +737,17 @@ class Engine:
                                              finish="auto")
         drop_name = f not in ("last_over_time",)
         tags = [_strip_name(t) if drop_name else t for t in ext.series_tags]
+        # Result materialization is THE device->host transfer on the
+        # query path (kernels consolidate on device first): counted once
+        # per materialised result, lazy or eager.
+        result_bytes = ext.n_series * params.meta().steps * 4
         if fetch is not None:
-            placement, inner = self._placement, fetch
-            # Observed cost = dispatch segment + materialization segment.
-            # The wall interval between them is EXCLUDED: LazyBlock exists
-            # so unrelated work (the next query's prep) interleaves there,
-            # and charging it to this eval would deflate the rate model.
-            dispatch_s = time.perf_counter() - t_dispatch
-
-            def observed_fetch():
-                from ..parallel import telemetry
-
-                t0 = time.perf_counter()
-                result = inner()
-                placement.observe(placed, cells, result_bytes,
-                                  dispatch_s + time.perf_counter() - t0)
-                # Result materialization is THE device->host transfer on
-                # the query path (kernels consolidate on device first).
+            def counted_fetch():
+                result = fetch()
                 telemetry.count_d2h(result_bytes)
                 return result
 
-            return LazyBlock(params.meta(), tags, observed_fetch)
-        self._placement.observe(placed, cells, result_bytes,
-                                time.perf_counter() - t_dispatch)
-        from ..parallel import telemetry
-
+            return LazyBlock(params.meta(), tags, counted_fetch)
         telemetry.count_d2h(result_bytes)
         return Block(params.meta(), tags, out)
 
@@ -945,36 +903,12 @@ class Engine:
                   "group"):
             # f64 host reduce keeps counter-sum exactness; the jitted f32
             # segment kernel (series_agg.grouped_reduce) is the fast path
-            # for large fan-in where 24-bit mantissas suffice. The large
-            # path goes through placement too: its input is a full
-            # [S, T] H2D upload (the same economics as the range-func
-            # result transfer).
+            # for large fan-in where 24-bit mantissas suffice.
             kind = "count" if op == "group" else op
             if vals.shape[0] < 4096:
                 out = series_agg.grouped_reduce_f64(vals, group_ids, G, kind)
             else:
-                cells = int(np.asarray(vals).size)
-                # Transfer term = H2D upload of the f32 input + D2H of the
-                # grouped result; the SAME value feeds observe() so the
-                # model nets out what choose() charged (an inconsistent
-                # pair would fold the upload into "compute" and bias
-                # future choices).
-                xfer_bytes = cells * 4 + G * vals.shape[1] * 8
-                placed = self._placement.choose(cells, xfer_bytes)
-                arr = vals
-                if placed is not None:
-                    from ..utils import hbm
-
-                    # Budget-charged upload (utils.hbm): the transient
-                    # [S, T] f32 plane is real HBM pressure for its
-                    # lifetime and must count against the same budget the
-                    # resident caches share.
-                    arr = hbm.budgeted_put(
-                        np.asarray(vals, dtype=np.float32), placed)
-                t0 = time.perf_counter()
-                out = series_agg.grouped_reduce(arr, group_ids, G, kind)
-                self._placement.observe(placed, cells, xfer_bytes,
-                                        time.perf_counter() - t0)
+                out = series_agg.grouped_reduce(vals, group_ids, G, kind)
             if op == "group":
                 # promql group(): 1 per group with any present series.
                 out = np.where(out > 0, 1.0, np.nan)

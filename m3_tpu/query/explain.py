@@ -25,9 +25,9 @@ ANALYZE is an instrumented execution mode: `with analyzing() as a:`
 installs a thread-local context the query path feeds stage wall times
 (host tag-algebra bind, device program dispatch per shape bucket, d2h
 result materialization) and cache events (grid-cache hit/miss per
-fetch, d2h bytes) into. Zero cost when disabled: every hook is one
-`current()` call returning None — enforced by
-scripts/obs_overhead_guard.py's ANALYZE section. Exposed over HTTP via
+fetch, d2h bytes) into. When disabled every hook is one `current()`
+call returning None (no reading of that on the chip's host yet:
+ROADMAP C13). Exposed over HTTP via
 `/debug/explain?query=...&analyze=true` and `?explain=true` on the
 PromQL read API (coordinator/http_api.py)."""
 

@@ -165,8 +165,9 @@ class Database:
         pri = Priority.NORMAL if priority is None else priority
         acc = tracing.detail()  # the caller's span, before ours opens
         # child_span: a real span ONLY under an already-sampled request
-        # (the rpc dispatch / executor span) — the bench-bare write path
-        # pays one thread-local read (scripts/obs_overhead_guard.py).
+        # (the rpc dispatch / executor span) — an untraced write pays one
+        # thread-local read (its cost on the chip's host: PERF.md
+        # section 6, PR 24).
         with tracing.child_span("storage.write_batch", points=len(ids)):
             if shard_ids is None:
                 shard_ids = self.shard_set.lookup_batch(ids)

@@ -24,12 +24,10 @@ its own lock inside them (tenant lock -> budget lock is the one permitted
 order; callers must invoke `reclaim()` only outside their own locks when
 their evictor takes that lock).
 
-`budgeted_put` is the raw-`jax.device_put` replacement for one-shot
-uploads on the storage/query serving path (m3lint's `unbudgeted-device-put`
-rule flags the raw calls): it charges the ACTUAL device-buffer size to a
-transient tenant and releases it when the array is garbage-collected, so
-memory pressure from in-flight uploads is visible to the same budget that
-governs the resident caches.
+Uploads on the storage/query serving path go through a registered tenant
+(m3lint's `unbudgeted-device-put` rule flags raw `jax.device_put` there);
+staging a device program consumes and frees itself carries a justified
+suppression instead.
 
 Saturation exports through instrument gauges (`hbm.bytes`,
 `hbm.saturation`) and `pressure()` registers as a HealthTracker probe:
@@ -43,12 +41,11 @@ from __future__ import annotations
 
 import os
 import threading
-import weakref
 from typing import Callable, Dict, Optional
 
 from .instrument import ROOT
 
-__all__ = ["HBMBudget", "shared_budget", "budgeted_put"]
+__all__ = ["HBMBudget", "shared_budget"]
 
 DEFAULT_BUDGET_BYTES = 2 * 1024 * 1024 * 1024
 
@@ -68,14 +65,6 @@ class HBMBudget:
         # along, approximating global LRU without a cross-tenant clock.
         self._rotation = 0
         self._metrics = ROOT.sub_scope(name)
-        self._transient = 0
-        # Releases arrive from weakref finalizers, which the cyclic GC may
-        # run at ANY bytecode boundary — including while this thread holds
-        # self._lock. A finalizer must therefore never acquire a lock:
-        # it appends to this list (list.append is GIL-atomic) and the
-        # usage probe drains it under the lock.
-        self._transient_released: list = []
-        self.register("transient", self._transient_usage)
 
     # ---------------------------------------------------------------- tenants
 
@@ -189,47 +178,6 @@ class HBMBudget:
         self._metrics.gauge("saturation").update(self.saturation())
         return freed
 
-    # ------------------------------------------------------- transient puts
-
-    def _release_transient(self, n: int):
-        # Finalizer context: lock-free by contract (see __init__).
-        self._transient_released.append(n)
-
-    def _transient_usage(self) -> int:
-        with self._lock:
-            while self._transient_released:
-                self._transient -= self._transient_released.pop()
-            if self._transient < 0:
-                self._transient = 0
-            return self._transient
-
-    def device_put(self, arr, dst=None):
-        """jax.device_put charged to the budget for the LIFETIME of the
-        device array: the actual (canonicalized) device-buffer size is
-        charged on upload and released when the array is collected, so
-        transient query uploads show up as real memory pressure."""
-        import jax
-
-        dev = jax.device_put(arr, dst) if dst is not None \
-            else jax.device_put(arr)  # m3lint: disable=unbudgeted-device-put
-        # DELIBERATE raw put above: this IS the budget API's charge point.
-        n = int(getattr(dev, "nbytes", getattr(arr, "nbytes", 0)))
-        # Transfer telemetry at the same choke point the budget charges
-        # (lazy import: utils must stay importable without parallel).
-        from ..parallel import telemetry
-
-        telemetry.count_h2d(n)
-        with self._lock:
-            self._transient += n
-        try:
-            weakref.finalize(dev, self._release_transient, n)
-        except TypeError:
-            # Backend arrays that refuse weakrefs: release immediately
-            # (accounting degrades to charge-at-upload only).
-            self._release_transient(n)
-        self.reclaim()
-        return dev
-
 
 _SHARED: Optional[HBMBudget] = None
 _SHARED_LOCK = threading.Lock()
@@ -248,8 +196,3 @@ def shared_budget() -> HBMBudget:
 
             TRACKER.register("hbm_pressure", _SHARED.pressure)
         return _SHARED
-
-
-def budgeted_put(arr, dst=None):
-    """Module-level convenience over shared_budget().device_put."""
-    return shared_budget().device_put(arr, dst)

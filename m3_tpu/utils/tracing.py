@@ -16,9 +16,9 @@ Sampling: root spans are sampled at `M3_TPU_TRACE_SAMPLE` (default 1.0);
 an unsampled root is the shared no-op span, children of no span are
 no-ops too (`child_span`), and unsampled requests never attach a wire
 context — so the hot path's cost when tracing is off is one thread-local
-read per call site (scripts/obs_overhead_guard.py holds the write/index
-benches to <3% on a CPU container; PERF.md section 6 has what a traced
-and an untraced run cost on the chip's host).
+read per call site (PERF.md section 6, PR 24, has what a traced and an
+untraced run cost on the chip's host; `benchmark/run.py --trace 0|1`
+reads both again).
 
 Detail: a root that was ASKED for — `span_from` with a context (the
 `X-M3-Trace` header, a wire `"tr"` field) — or a background root

@@ -29,14 +29,14 @@ python -m m3_tpu.analysis --jobs 0 m3_tpu/
 echo "== index microbench smoke (<5s; bitmap-vs-ref + cache hit-rate asserted) =="
 # Array-native inverted index: bitmap kernels must agree with the
 # set-algebra reference and the postings cache must serve the warm pass
-# (full matrix: tests/test_index_property.py; bench: index_fetch_tagged).
+# (full matrix: tests/test_index_property.py).
 python scripts/index_smoke.py
 
 echo "== block-cache smoke (<5s; warm hit-rate, eviction under tiny budget, zero residency after close) =="
 # HBM-resident block cache: warm reads must hit, results must be
 # bit-identical to the uncached decode, a tiny budget must evict, and
 # namespace close must drop every cached byte. Full matrix:
-# tests/test_block_cache.py; bench: hot_set_read. Wall budget via
+# tests/test_block_cache.py. Wall budget via
 # CACHE_SMOKE_BUDGET_S.
 JAX_PLATFORMS=cpu python scripts/cache_smoke.py
 
@@ -77,7 +77,7 @@ echo "== churn smoke (SLO-under-churn: chaos + placement churn + concurrent repa
 # WHILE add/remove/replace-node churn and a repair sweep run — zero lost
 # acked writes, zero shed CRITICAL, bounded p99/queues, replica-
 # consistent convergence. Full matrix: tests/test_dtest_scenarios.py +
-# tests/test_bootstrap_repair.py; bench: peer_migration. Wall budget via
+# tests/test_bootstrap_repair.py. Wall budget via
 # CHURN_SMOKE_BUDGET_S (first cold run pays one-time kernel compiles,
 # persisted to .jax_cache for later runs).
 JAX_PLATFORMS=cpu python scripts/churn_smoke.py --seed 7
@@ -111,7 +111,7 @@ echo "== restart smoke (<10s; kill -9 a real dbnode mid-flush, restart, zero ack
 # torn WAL tail + checkpoint-less fileset injected, restarted — every
 # acked write must be served, nothing fabricated, restart bounded. Full
 # matrix: tests/test_durability.py (+ migration/backfill variants);
-# campaign: scripts/fuzz_durability.py; bench: bootstrap_replay. Wall
+# campaign: scripts/fuzz_durability.py. Wall
 # budget via RESTART_SMOKE_BUDGET_S.
 JAX_PLATFORMS=cpu python scripts/restart_smoke.py --seed 7
 
@@ -123,7 +123,7 @@ echo "== rules smoke (<5s; batch matcher ≡ per-metric oracle, 100% warm match-
 # one alert rule evaluated incrementally on a live embedded coordinator
 # with the firing transition asserted and recorded output queried back
 # over the PromQL HTTP API. Full matrix: tests/test_batch_matcher.py +
-# tests/test_rules_engine.py; bench: downsample_rules. Wall budget via
+# tests/test_rules_engine.py. Wall budget via
 # RULES_SMOKE_BUDGET_S.
 JAX_PLATFORMS=cpu python scripts/rules_smoke.py
 
@@ -172,7 +172,7 @@ echo "== plan-compiler smoke (<5s; compiled-vs-oracle, 100% warm plan-cache hit,
 # matching, irate/timestamp/quantile_over_time), the warm pass must be
 # served 100% from the plan cache, and a set op must fall back cleanly.
 # The 8-virtual-device mesh exercises the shard_map collective fan-in.
-# Full matrix: tests/test_plan_compile.py; bench: promql_plan_agg.
+# Full matrix: tests/test_plan_compile.py.
 # Wall budget via PLAN_SMOKE_BUDGET_S.
 JAX_PLATFORMS=cpu XLA_FLAGS="--xla_force_host_platform_device_count=8" \
   python scripts/plan_smoke.py
@@ -182,8 +182,8 @@ echo "== serve smoke (<5s; columnar HTTP result frames byte-identical to render_
 # /api/v1/query renders straight from the value matrix (query/render.py,
 # zero per-series dicts) and must be byte-identical to the retained
 # per-series oracle; one query per new lowering family must take the
-# compiled route over real HTTP. Full matrix: tests/test_result_frame.py;
-# bench: query_serve_e2e. Wall budget via SERVE_SMOKE_BUDGET_S.
+# compiled route over real HTTP. Full matrix: tests/test_result_frame.py.
+# Wall budget via SERVE_SMOKE_BUDGET_S.
 JAX_PLATFORMS=cpu XLA_FLAGS="--xla_force_host_platform_device_count=8" \
   python scripts/serve_smoke.py
 
@@ -208,8 +208,7 @@ echo "== aggregator smoke (<5s; mesh-vs-ref bit-equality, one-publish-per-destin
 # publish per topic shard and ONE fbatch frame per (destination, meta
 # group), and the DAGOR-style tenant gate must shed the noisy tenant at
 # its share while quiet and CRITICAL traffic pass. Full matrix:
-# tests/test_agg_mesh.py + tests/test_overload.py; benches:
-# counter_gauge_rollup + agg_rollup_10x. Wall budget via
+# tests/test_agg_mesh.py + tests/test_overload.py. Wall budget via
 # AGG_SMOKE_BUDGET_S.
 JAX_PLATFORMS=cpu XLA_FLAGS="--xla_force_host_platform_device_count=8" \
   python scripts/agg_smoke.py
@@ -269,14 +268,6 @@ python -m pytest tests/ -x -q
 echo "== multichip dryrun (virtual 8-device mesh) =="
 JAX_PLATFORMS=cpu XLA_FLAGS="--xla_force_host_platform_device_count=8" \
   python -c "import __graft_entry__ as g; g.dryrun_multichip(8); print('dryrun OK')"
-
-echo "== instrumentation-overhead guard (tracing <3% on write/index benches) =="
-# Tracing at default sampling (every child span REAL — harsher than
-# production) must stay within 3% of the untraced run on
-# write_path_ingest and index_fetch_tagged, and above the recorded
-# bench_baseline.json floors. ~3-4 minutes (full bench configs,
-# interleaved A/B reps). Numbers recorded in PERF.md round 10.
-python scripts/obs_overhead_guard.py
 
 echo "== fuzz campaigns =="
 JAX_PLATFORMS=cpu python scripts/fuzz_codec.py --rounds "$ROUNDS" --seed 7

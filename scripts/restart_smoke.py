@@ -19,8 +19,7 @@ fileset), restarts over the same data dir, and asserts:
 The full matrix (4+ seeds, namespace-migration and out-of-order
 backfill variants riding the same-start merge, batched-vs-_ref replay
 bit-identity, corruption fuzz subsets) lives in tests/test_durability.py;
-the open-ended campaign is scripts/fuzz_durability.py; bench:
-bootstrap_replay (series/sec to serving-ready).
+the open-ended campaign is scripts/fuzz_durability.py.
 
 Usage: python scripts/restart_smoke.py [--seed N]
 Wall budget: RESTART_SMOKE_BUDGET_S (default 10 seconds).

@@ -5,7 +5,6 @@ invalidation matrix (mirroring tests/test_index_property.py's postings-
 cache matrix), the racing-seal re-pin refusal, budget-driven eviction
 across tenants, and the upload-cache counter export."""
 
-import gc
 
 import numpy as np
 import pytest
@@ -402,24 +401,16 @@ class TestBudget:
         budget.register("t", lambda: 500)
         assert budget.pressure() == 1.0
 
-    def test_budgeted_put_charges_for_lifetime(self):
-        budget = HBMBudget(1 << 30)
-        arr = np.arange(1024, dtype=np.float32)
-        dev = budget.device_put(arr)
-        assert budget.usage()["transient"] >= arr.nbytes
-        del dev
-        gc.collect()
-        assert budget.usage()["transient"] == 0
-
-    def test_finalizer_release_is_lock_free(self):
-        """A GC-run finalizer may fire while the budget lock is held: the
-        release path must not acquire it (it appends to a pending list
-        the usage probe drains)."""
+    def test_a_budget_holds_only_what_registered(self):
+        """No tenant of the budget's own: what it reads is what the
+        registered caches report, and a tenant that leaves takes its
+        bytes with it."""
         budget = HBMBudget(1 << 20)
-        with budget._lock:
-            budget._release_transient(123)  # must not deadlock
-        budget._transient = 123
-        assert budget._transient_usage() == 0
+        assert budget.usage() == {} and budget.total() == 0
+        budget.register("t", lambda: 64)
+        assert budget.usage() == {"t": 64} and budget.total() == 64
+        budget.unregister("t")
+        assert budget.usage() == {} and budget.pressure() == 0.0
 
     def test_dead_usage_probe_reads_zero(self):
         budget = HBMBudget(100)

@@ -28,7 +28,6 @@ def test_phases_run_tiny_on_cpu(tmp_path, monkeypatch):
     assert not ctx.cuts
     assert ctx.facts["seal"]["sealed_block_starts"] >= 2
     assert ctx.facts["queries"]["second_pass_compiles"] == 0
-    assert ctx.facts["routes"]["placement_host"] == 0
     assert ctx.facts["fileset"]["retriever"]["seeks"] > 0
     assert set(ctx.results) >= {"sum_by_host_rate", "bare_rate",
                                 "instant_sum_by_host", "instant_http_leg",

@@ -191,7 +191,7 @@ class TestLockdepCheck:
 
     def test_green_on_statically_known_edge(self, tmp_path):
         d = dict(self.BASE)
-        d["edges"] = [{"from": "hbm._SHARED_LOCK", "to": "HBMBudget._lock",
+        d["edges"] = [{"from": "hbm._SHARED_LOCK", "to": "HealthTracker._lock",
                        "count": 1, "blocked": 0, "site": "hbm.py:1"}]
         out = _run_check(tmp_path, d)
         assert out.returncode == 0, out.stdout + out.stderr

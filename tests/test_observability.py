@@ -961,14 +961,17 @@ class TestBenchmarkContract:
             assert snap[counter] == before + 1
 
     @pytest.mark.parametrize("needle,path", [
-        ('"query.placement.host"', "m3_tpu/query/executor.py"),
+        ('("pallas_" if pallas else "xla_") + kernel',
+         "m3_tpu/parallel/telemetry.py"),
         ('sub_scope("telemetry")', "m3_tpu/parallel/telemetry.py"),
         ("def _encode_batch(", "m3_tpu/ops/tsz.py")])
     def test_names_only_a_chip_run_exercises(self, needle, path):
-        """The host-placement counter, the telemetry scope and the pack
-        kernel's jit name (`_encode_batch(.N)` in the device trace, read
-        by encode_roofline) cannot be driven from a CPU test; their
-        spelling in the source is pinned instead."""
+        """The codec route counters (`telemetry.codec.pallas_<kernel>` is
+        the default route only on a TPU; `codec_dispatches_off_gate` reads
+        both spellings), the telemetry scope and the pack kernel's jit
+        name (`_encode_batch(.N)` in the device trace, read by
+        encode_roofline) cannot be driven from a CPU test; their spelling
+        in the source is pinned instead."""
         import os
 
         root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -1110,7 +1113,8 @@ class TestMediatorTickTree:
 
         coord, now, tracer, _ = served
         db = coord.engine.storage._db
-        stats = Mediator(db, PersistManager(str(tmp_path))).run_once()
+        mediator = Mediator(db, PersistManager(str(tmp_path)))
+        stats = mediator.run_once()
         (tick,) = _roots(tracer)
         assert tick.name == "mediator.tick" and tick.detailed
         assert [c.name for c in tick.children] == [
@@ -1135,9 +1139,31 @@ class TestMediatorTickTree:
                 if s.name == "persist.write"] == ["snapshot"] * len(encodes)
         inside = sum(c.duration_ns for c in tick.children)
         assert inside <= tick.duration_ns
-        assert tick.duration_ns - inside <= max(0.02 * tick.duration_ns,
-                                                500_000)
+        # the four children are the tick's work: what its thread computed
+        # outside them, in CPU time ...
+        cpu, cpu_inside = tick.tags["cpu_ns"], sum(
+            c.tags["cpu_ns"] for c in tick.children)
+        assert cpu - cpu_inside <= max(0.02 * cpu, 500_000)
         assert tick.tags["cpu_ns"] <= tick.duration_ns * 1.01
+        # ... and what it waited outside them, on the wall clock. The
+        # runner's load also opens a gap between two spans (the thread
+        # descheduled: 4.5 ms of a 38 ms tick, 2 runs of 24 under six
+        # workers), but in one tick; a wait in run_once outside its spans (a
+        # lock, an fsync, a sleep) is in every tick. So the bound holds on
+        # the tick with the smallest gap of at most three.
+        def over(t):
+            gap = t.duration_ns - sum(c.duration_ns for c in t.children)
+            return gap - max(0.02 * t.duration_ns, 500_000)
+
+        worst = over(tick)
+        for n in (2, 3):
+            if worst <= 0:
+                break
+            mediator.run_once()
+            again = _roots(tracer, n)[-1]
+            assert again.name == "mediator.tick" and again is not tick
+            worst = min(worst, over(again))
+        assert worst <= 0
 
     def test_a_seal_outside_any_trace_opens_nothing(self, served):
         coord, now, tracer, _ = served

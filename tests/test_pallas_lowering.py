@@ -27,7 +27,6 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from m3_tpu.ops import pallas_codec as pc
-from m3_tpu.ops import pallas_window as pw
 from m3_tpu.ops import tsz
 
 
@@ -86,14 +85,6 @@ def test_hash_words_builds(n_ids, id_cols):
     cols = tiles * pc._LANES
     build_for_tpu(pc._build_hash(cp, tiles, 0, False),
                   ((cp, cols), U32), ((1, cols), I32))
-
-
-@pytest.mark.parametrize("stat", pw.STATS)
-def test_window_stat_builds(stat):
-    # the dashboard shape PERF.md measured it at, fewer rows (the grid
-    # over row tiles does not change the kernel): [*, 447] W=30 stride=3
-    build_for_tpu(pw._build(64, 447, 30, 3, stat, False),
-                  ((64, 447), jnp.float32))
 
 
 def test_whole_codec_programs_build(monkeypatch):

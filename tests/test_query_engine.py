@@ -734,3 +734,99 @@ class TestRemainingFunctionConformance:
         want4 = 10.0 * 10.0 * (4 * 4 - 1) / 12.0
         vals = fine.values[0][np.isfinite(fine.values[0])]
         np.testing.assert_allclose(vals[3:], want4, rtol=1e-6)
+
+
+class TestInterpreterAccountsForWhatItMoves:
+    """An interpreter evaluation runs on the default backend and counts
+    its result transfer once per materialised result: a `LazyBlock`
+    family when `.values` is first read, an eager family when the block
+    is built. The ≥4,096-row grouped reduce takes the f32 segment kernel."""
+
+    N_BIG = 4100
+
+    @staticmethod
+    def _storage(n):
+        st = MemStorage()
+        t = np.arange(0, 40) * 15 * S
+        for i in range(n):
+            st.add({"__name__": "reqs", "job": f"j{i % 4}",
+                    "instance": f"i{i}"}, t, np.arange(40) * float(i % 7 + 1))
+        return st
+
+    @pytest.mark.parametrize("query,n,lazy", [
+        ("rate(reqs[2m])", 6, True),
+        ("irate(reqs[2m])", 6, False),
+        ("sum by (job) (reqs)", N_BIG, None),
+    ])
+    def test_answer_equals_reference_and_d2h_counted_once(
+            self, query, n, lazy, monkeypatch):
+        from m3_tpu.ops import series_agg
+        from m3_tpu.query.block import LazyBlock
+        from m3_tpu.utils.instrument import ROOT
+
+        reduced = []
+        kernel = series_agg.grouped_reduce
+
+        def spy(vals, *args):
+            reduced.append(np.shape(vals))
+            return kernel(vals, *args)
+
+        monkeypatch.setattr(series_agg, "grouped_reduce", spy)
+
+        def d2h():
+            snap = ROOT.snapshot()
+            return (snap.get("telemetry.transfer.d2h_bytes", 0),
+                    snap.get("telemetry.transfer.d2h_transfers", 0))
+
+        eng = Engine(self._storage(n))
+        start, end = 5 * MIN, 9 * MIN
+        before = d2h()
+        ref = eng.execute_range_ref(query, start, end, STEP)
+        if lazy is not None:
+            assert isinstance(ref, LazyBlock) == lazy
+            if lazy:
+                assert d2h() == before  # nothing materialised yet
+            vals = ref.values
+            assert ref.values is vals
+            want = n * ref.meta.steps * 4
+            assert d2h() == (before[0] + want, before[1] + 1)
+        else:
+            assert reduced == [(n, ref.meta.steps)]
+        got = eng.execute_range(query, start, end, STEP)
+        by_tags = {t.id(): row for t, row in zip(ref.series_tags, ref.values)}
+        assert len(by_tags) == got.n_series == (4 if lazy is None else n)
+        for t, row in zip(got.series_tags, got.values):
+            np.testing.assert_allclose(row, by_tags[t.id()], rtol=1e-5)
+        # and the plain arithmetic of steady counters: slope i%7+1 per 15 s
+        slopes = np.array([i % 7 + 1 for i in range(n)], float)
+        if lazy is None:
+            steps = ref.meta.times() // (15 * S)
+            for t, row in zip(ref.series_tags, ref.values):
+                j = int(t.get(b"job")[1:])
+                np.testing.assert_allclose(
+                    row, slopes[j::4].sum() * steps, rtol=1e-5)
+        else:
+            inst = [int(t.get(b"instance")[1:]) for t in ref.series_tags]
+            np.testing.assert_allclose(
+                ref.values, np.repeat((slopes[inst] / 15.0)[:, None],
+                                      ref.meta.steps, axis=1), rtol=1e-5)
+
+
+def test_readme_lists_every_environment_option():
+    """The README's "Environment options" table is the whole `M3_TPU_*`
+    surface: the names read anywhere under m3_tpu/ and the table's rows
+    are the same set."""
+    import pathlib
+    import re
+
+    root = pathlib.Path(__file__).resolve().parent.parent
+    in_code = set()
+    for path in (root / "m3_tpu").rglob("*"):
+        if path.suffix in (".py", ".md", ".txt"):
+            in_code |= set(re.findall(r"M3_TPU_[A-Z0-9_]+", path.read_text()))
+    readme = (root / "README.md").read_text()
+    section = readme.split("## Environment options", 1)[1].split("\n## ", 1)[0]
+    rows = re.findall(r"^\| `(M3_TPU_[A-Z0-9_]+)` \| [^|]+ \| [^|]+ \|$",
+                      section, re.M)
+    assert len(rows) == len(set(rows))
+    assert set(rows) == in_code

@@ -258,100 +258,54 @@ def test_rate_no_cancellation_on_huge_counter():
     assert (out[0, -5:] > 0).all()  # counter increase can never go negative
 
 
-class TestPallasWindow:
-    """Parity of the opt-in Pallas strided-window kernel (M3_TPU_PALLAS=1)
-    against the XLA reduce_window path — same masked-by-finiteness
-    semantics, m2 in the same two-pass form, empty windows included."""
+class TestWindowStatStrided:
+    """The window-moment core consolidates to the output stride at the
+    primitives: every strided plane equals the stride-1 plane sliced
+    `[:, ::stride]` — masked-by-finiteness semantics, m2 in its two-pass
+    form, empty windows included."""
 
-    def test_kernel_parity_all_stats_strides(self):
-        import jax.numpy as jnp
-
-        from m3_tpu.ops import pallas_window as pw
-        from m3_tpu.ops import temporal
-
+    @pytest.mark.parametrize("stride", [2, 3])
+    @pytest.mark.parametrize("stat",
+                             ["count", "sum", "min", "max", "last", "m2"])
+    def test_strided_equals_sliced(self, stat, stride):
         rng = np.random.default_rng(3)
         S, K, W = 13, 67, 6
         resid = rng.standard_normal((S, K)).astype(np.float32)
         resid[rng.random((S, K)) < 0.2] = np.nan
         resid[0] = np.nan  # one fully-empty series
-        for stride in (1, 2, 3):
-            for stat in pw.STATS:
-                got_s, got_c = pw.window_stat(jnp.asarray(resid), W, stride, stat)
-                ref_s, ref_c = temporal._window_stat(jnp.asarray(resid), W, stat)
-                got_s, got_c = np.asarray(got_s), np.asarray(got_c)
-                ref_s = np.asarray(ref_s)[:, ::stride]
-                ref_c = np.asarray(ref_c)[:, ::stride].astype(np.float32)
-                np.testing.assert_array_equal(got_c, ref_c)
-                # The contract covers populated windows only: both callers
-                # mask count==0 to NaN, and the raw empty-window planes
-                # legitimately differ ('last': 0.0 vs the XLA gather's
-                # clipped-index artifact).
-                pop = ref_c > 0
-                np.testing.assert_allclose(
-                    got_s[pop], ref_s[pop],
-                    rtol=1e-6, atol=1e-6, err_msg=f"{stat} stride={stride}")
-
-    def test_empty_window_counts_zero(self):
-        import jax.numpy as jnp
-
-        from m3_tpu.ops import pallas_window as pw
-
-        # crafted fully-NaN window inside a row whose column 0 is finite
-        # (the case the XLA raw plane renders differently)
-        resid = np.array([[5.0, 1.0, np.nan, np.nan, np.nan, 2.0, 3.0, 4.0]],
-                         np.float32)
-        got_s, got_c = pw.window_stat(jnp.asarray(resid), 3, 1, "last")
+        got_s, got_c = temporal._window_stat(resid, W, stat, stride)
+        ref_s, ref_c = temporal._window_stat(resid, W, stat)
         got_s, got_c = np.asarray(got_s), np.asarray(got_c)
-        assert got_c[0, 2] == 0.0
-        assert got_s[0, 2] == 0.0  # documented empty-window value
+        ref_s = np.asarray(ref_s)[:, ::stride]
+        ref_c = np.asarray(ref_c)[:, ::stride]
+        assert got_s.shape == ref_s.shape == (S, (K - W) // stride + 1)
+        np.testing.assert_array_equal(got_c, ref_c)
+        assert (got_c[0] == 0).all()
+        # The contract covers populated windows only: every caller masks
+        # count==0 to NaN, and the raw empty-window planes are not part
+        # of it ('last' gathers through a clipped index there).
+        pop = ref_c > 0
+        np.testing.assert_allclose(got_s[pop], ref_s[pop],
+                                   rtol=1e-6, atol=1e-6)
 
-    def test_over_time_dispatch(self, monkeypatch):
-        from m3_tpu.ops import temporal
-
-        rng = np.random.default_rng(5)
-        grid = np.cumsum(rng.poisson(3.0, (9, 50)), axis=1).astype(np.float64)
-        grid[rng.random((9, 50)) < 0.1] = np.nan
-        refs = {k: temporal.over_time(grid, 5, k, stride=2)
-                for k in ("sum", "avg", "min", "max", "count", "last",
-                          "stddev", "stdvar")}
-        monkeypatch.setattr(temporal, "_use_pallas", lambda: True)
-        temporal._over_time_fn.cache_clear()
-        temporal._over_time_finish_fn.cache_clear()
-        try:
-            for k, ref in refs.items():
-                got = temporal.over_time(grid, 5, k, stride=2)
-                np.testing.assert_allclose(got, ref, rtol=1e-6, atol=1e-9,
-                                           equal_nan=True, err_msg=k)
-                got_dev = temporal.over_time(grid, 5, k, stride=2,
-                                             finish="device")
-                np.testing.assert_allclose(got_dev, ref, rtol=1e-5, atol=1e-5,
-                                           equal_nan=True, err_msg=k + " device")
-        finally:
-            temporal._over_time_fn.cache_clear()
-            temporal._over_time_finish_fn.cache_clear()
-
-    def test_narrow_grid_falls_back(self, monkeypatch):
-        # K < W: the dispatch must use the XLA empty plane, not a
-        # zero/negative-width pallas grid.
-        from m3_tpu.ops import temporal
-
-        monkeypatch.setattr(temporal, "_use_pallas", lambda: True)
-        resid = np.full((4, 3), 1.0, np.float32)
-        out, cnt = temporal._window_stat_strided(resid, 6, "sum", 1)
-        assert out.shape == (4, 0) and cnt.shape == (4, 0)
-
-    def test_oversized_unroll_falls_back(self, monkeypatch):
-        # The kernel statically unrolls T_out window reductions (Mosaic
-        # alignment constraint); past MAX_UNROLL_STEPS the dispatch must
-        # take the constant-program-size XLA path instead of tracing a
-        # pathological kernel — and window_stat itself must refuse.
-        from m3_tpu.ops import pallas_window as pw
-
-        monkeypatch.setattr(temporal, "_use_pallas", lambda: True)
-        K = pw.MAX_UNROLL_STEPS + 40  # stride 1, W 6 -> T_out > cap
+    @pytest.mark.parametrize("K,shape,first", [
+        (3, (4, 0), None),          # narrower than the window: empty planes
+        (1064, (4, 1059), 6.0)])    # a wide stride-1 grid, one program
+    def test_grid_widths_at_the_edges(self, K, shape, first):
+        """A grid narrower than its window has no output step; a wide
+        stride-1 grid is served whole, whatever its number of steps."""
         resid = np.ones((4, K), np.float32)
-        out, cnt = temporal._window_stat_strided(resid, 6, "sum", 1)
-        assert out.shape == (4, K - 5)  # XLA path served it
-        assert float(np.asarray(out)[0, 0]) == 6.0
-        with pytest.raises(ValueError, match="MAX_UNROLL_STEPS"):
-            pw.window_stat(resid, 6, 1, "sum")
+        out, cnt = temporal._window_stat(resid, 6, "sum", 1)
+        assert out.shape == cnt.shape == shape
+        if first is not None:
+            assert float(np.asarray(out)[0, 0]) == first
+            assert (np.asarray(cnt) == 6).all()
+
+    def test_empty_window_counts_zero_and_renders_nan(self):
+        # a fully-NaN window inside a row whose column 0 is finite
+        grid = np.array([[5.0, 1.0, np.nan, np.nan, np.nan, 2.0, 3.0, 4.0]])
+        _, cnt = temporal._window_stat(grid.astype(np.float32), 3, "last")
+        assert np.asarray(cnt)[0].tolist() == [2, 1, 0, 1, 2, 3]
+        for kind in ("last", "sum", "count"):
+            out = temporal.over_time(grid, 3, kind)
+            assert np.isnan(out[0, 2]) and np.isfinite(out[0, [0, 1, 3]]).all()
