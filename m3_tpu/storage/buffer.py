@@ -119,6 +119,14 @@ def to_dense(sidx, ts, vals):
     return series, tdense, vdense, counts.astype(np.int32)
 
 
+def one_block_start(t_min: int, t_max: int, block_size_ns: int) -> Optional[int]:
+    """The block start that rows between t_min and t_max share, or None
+    where they straddle a boundary."""
+    if t_min // block_size_ns != t_max // block_size_ns:
+        return None
+    return xtime.truncate(t_min, block_size_ns)
+
+
 class ShardBuffer:
     """All mutable buckets for one shard, keyed by block start."""
 
@@ -141,11 +149,27 @@ class ShardBuffer:
     def write(self, series_idx: int, t_ns: int, value: float):
         self._bucket(xtime.truncate(t_ns, self.block_size_ns)).cols.append(series_idx, t_ns, value)
 
-    def write_batch(self, sidx: np.ndarray, ts: np.ndarray, vals: np.ndarray):
+    def write_batch(self, sidx: np.ndarray, ts: np.ndarray, vals: np.ndarray,
+                    block_start: Optional[int] = None) -> bool:
+        """Append rows in arrival order. Rows of one block (the common
+        batch: a scrape) are one bucket lookup and one extend; rows that
+        straddle a boundary are split per block. `block_start`: the
+        rows' one block where the caller has worked it out already (for
+        a larger batch these rows are part of). True where the rows took
+        the one-block route."""
+        if block_start is None:
+            if not len(ts):
+                return True
+            block_start = one_block_start(int(ts.min()), int(ts.max()),
+                                          self.block_size_ns)
+        if block_start is not None:
+            self._bucket(block_start).cols.extend(sidx, ts, vals)
+            return True
         starts = ts - ts % self.block_size_ns
         for bs in np.unique(starts):
             m = starts == bs
             self._bucket(int(bs)).cols.extend(sidx[m], ts[m], vals[m])
+        return False
 
     def read(self, series_idx: int, start_ns: int, end_ns: int) -> Tuple[np.ndarray, np.ndarray]:
         """Merged in-order datapoints for one series in [start, end)."""

@@ -21,10 +21,16 @@ from ..utils.instrument import ROOT
 from ..utils.limits import Backpressure
 from ..utils.retry import RetryOptions, Retrier
 from ..utils.tracing import clock_ns as _clock
+from .buffer import one_block_start
 from .namespace import Namespace, NamespaceOptions
 from .series import charge_read
 
 _FLUSH_METRICS = ROOT.sub_scope("storage.flush")
+# Shard appends of routed batches, and those of them that were *fast*
+# (Shard.write_batch: one block, every id known, no tags backfilled);
+# each moves once a batch, by the batch's total.
+_SHARD_APPENDS = ROOT.counter("storage.write_batch.shard_appends")
+_FAST_APPENDS = ROOT.counter("storage.write_batch.fast_appends")
 
 
 def fold_tags(out: Dict[bytes, set], tags, filter_set, name_only: bool):
@@ -176,42 +182,63 @@ class Database:
 
     def _write_batch_routed(self, namespace, ns, ids, ts, vals, tags, now,
                             pri, shard_ids, acc):
+        """One routing pass for the batch; what has one answer for the
+        whole batch is worked out once, before any shard is touched: the
+        acceptance window (on the batch's min and max timestamp, so a
+        refused batch leaves nothing applied) and the block start (where
+        min and max share a block). Each shard then gets contiguous
+        slices of the columns in shard order. The sort is stable, so a
+        shard's rows keep their arrival order (last arrival wins inside
+        a bucket) and the rows applied so far are a prefix of `order`."""
         timed = acc is not None
         t0 = _clock() if timed else 0
-        # Route columns per shard through object arrays: one fancy-index
-        # per shard instead of a Python listcomp over selected rows
-        # (~4x on the per-batch routing cost).
-        ids_arr = np.empty(len(ids), object)
-        ids_arr[:] = ids
-        tags_arr = None
-        if tags:
-            tags_arr = np.empty(len(ids), object)
-            tags_arr[:] = tags
+        n = len(ids)
+        block_start = None
+        if n:
+            t_min, t_max = int(ts.min()), int(ts.max())
+            past, future = now - ns.opts.buffer_past_ns, now + ns.opts.buffer_future_ns
+            if t_min < past or t_max > future:
+                bad = int(((ts < past) | (ts > future)).sum())
+                raise ValueError(f"{bad} datapoints outside acceptance window")
+            block_start = one_block_start(t_min, t_max,
+                                          ns.opts.block_size_ns)
+        order = np.argsort(shard_ids, kind="stable")
+        rows = order.tolist()
+        ids_s = list(map(ids.__getitem__, rows))
+        ts_s, vals_s = ts[order], vals[order]
+        starts, ends, shards = [], [], []
+        if n:
+            by_shard = shard_ids[order]
+            cuts = (np.flatnonzero(by_shard[1:] != by_shard[:-1]) + 1).tolist()
+            starts, ends = [0] + cuts, cuts + [n]
+            shards = by_shard[starts].tolist()
+        row_tags = tags or None
         log = (self.commitlog is not None and ns.opts.writes_to_commitlog)
-        applied = np.zeros(len(ids), bool) if log else None
+        appends = fast = 0
+        applied = 0  # rows order[:applied] are in their shards' buffers
         try:
-            for sid in np.unique(shard_ids):
-                m = shard_ids == sid
-                ns.shard_for(int(sid)).write_batch(
-                    ids_arr[m].tolist(), ts[m], vals[m], now,
-                    tags=tags_arr[m].tolist() if tags_arr is not None else None,
-                    priority=pri, acc=acc,
-                )
-                if applied is not None:
-                    applied |= m
+            for a, b, sid in zip(starts, ends, shards):
+                fast += ns.shard_for(sid).write_batch(
+                    ids_s[a:b], ts_s[a:b], vals_s[a:b], now, tags=row_tags,
+                    priority=pri, acc=acc, rows=rows[a:b], checked=True,
+                    block_start=block_start)
+                appends += 1
+                applied = b
         except BaseException:
-            # A later shard's queue shed (Backpressure) or window check
-            # aborted the batch mid-loop: earlier shards' writes are
-            # already query-visible, so they MUST reach the commit log
-            # before the error propagates — otherwise a restart replay
-            # silently drops accepted datapoints.
-            if applied is not None and applied.any():
+            # A later shard's queue shed (Backpressure): earlier shards'
+            # writes are already query-visible, so they MUST reach the
+            # commit log before the error propagates — otherwise a
+            # restart replay silently drops accepted datapoints. They
+            # are rows order[:applied], logged in the request's own order.
+            if log and applied:
+                done = np.sort(order[:applied])
+                picked = done.tolist()
                 try:
                     self.commitlog.write_batch(
-                        namespace, ids_arr[applied].tolist(), ts[applied],
-                        vals[applied],
-                        tags_arr[applied].tolist() if tags_arr is not None
-                        else None)
+                        namespace, [ids[i] for i in picked], ts[done],
+                        vals[done],
+                        [row_tags[i] for i in picked]
+                        if row_tags is not None else None)
                 except DiskWriteError:
                     # The rescue append itself hit the disk fault: the
                     # typed WAL error supersedes the shed — callers must
@@ -219,6 +246,10 @@ class Database:
                     self.disk_health.failure()
                     raise
             raise
+        finally:
+            if appends:
+                _SHARD_APPENDS.inc(appends)
+                _FAST_APPENDS.inc(fast)
         t1 = _clock() if timed else 0
         if log:
             try:

@@ -8,6 +8,7 @@ is the only id-keyed structure on the hot path."""
 
 from __future__ import annotations
 
+import threading
 from itertools import repeat
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -19,6 +20,13 @@ class SeriesRegistry:
         self._index: Dict[bytes, int] = {}
         self._ids: List[bytes] = []
         self._tags: List[Optional[dict]] = []
+        # How many entries of _tags are None, so "does this batch hold a
+        # series to backfill" is one integer test on the write path. It
+        # moves under _untagged_lock (a leaf), up BEFORE the series' id
+        # is published and down AFTER its tags are stored: a lock-free
+        # reader that sees 0 can resolve no untagged series.
+        self.untagged = 0
+        self._untagged_lock = threading.Lock()
 
     def __len__(self) -> int:
         return len(self._ids)
@@ -35,8 +43,7 @@ class SeriesRegistry:
     def get_or_create(self, series_id: bytes, tags: Optional[dict] = None) -> Tuple[int, bool]:
         idx = self._index.get(series_id)
         if idx is not None:
-            if tags is not None and self._tags[idx] is None:
-                self._tags[idx] = tags
+            self.ensure_tags(idx, tags)
             return idx, False
         idx = len(self._ids)
         # Lists BEFORE the id map: lock-free readers (lookup_batch, the
@@ -45,6 +52,8 @@ class SeriesRegistry:
         # would briefly point past the lists.
         self._ids.append(series_id)
         self._tags.append(tags)
+        if tags is None:
+            self._count_untagged(1)
         self._index[series_id] = idx
         return idx, True
 
@@ -79,6 +88,8 @@ class SeriesRegistry:
             # readers must never resolve an index past the lists' ends.
             id_list.extend(ids)
             tag_list.extend(tags if tags is not None else (None,) * n)
+            self._count_untagged(
+                n if tags is None else sum(t is None for t in tags))
             index.update(zip(ids, range(base, base + n)))
             return out, list(range(n))
         out = np.empty(n, np.int32)
@@ -91,10 +102,12 @@ class SeriesRegistry:
                 idx = len(id_list)
                 id_list.append(sid)
                 tag_list.append(t)
+                if t is None:
+                    self._count_untagged(1)
                 index[sid] = idx
                 created.append(i)
-            elif t is not None and tag_list[idx] is None:
-                tag_list[idx] = t
+            else:
+                self.ensure_tags(idx, t)
             out[i] = idx
         return out, created
 
@@ -112,11 +125,45 @@ class SeriesRegistry:
         return np.fromiter(map(self._index.get, ids, repeat(-1)), np.int32,
                            count=len(ids))
 
-    def ensure_tags(self, idx: int, tags: Optional[dict]):
-        """Backfill tags for an existing series (benign when racing: both
-        writers carry equivalent tags for the same id)."""
-        if tags is not None and self._tags[idx] is None:
+    def lookup_known(self, ids: Sequence[bytes]) -> Optional[np.ndarray]:
+        """`lookup_batch` where every id is known, else None: the write
+        path's common answer and its test for unknowns in one pass (a
+        miss raises out of the C-level iteration). Lock-free on the
+        same terms as lookup_batch."""
+        try:
+            return np.fromiter(map(self._index.__getitem__, ids), np.int32,
+                               count=len(ids))
+        except KeyError:
+            return None
+
+    def ensure_tags(self, idx: int, tags: Optional[dict]) -> bool:
+        """Backfill tags for an existing series; True where this call
+        stored them (racing writers carry equivalent tags for the same
+        id: one of them stores, and the untagged count moves once)."""
+        if tags is None or self._tags[idx] is not None:
+            return False
+        with self._untagged_lock:
+            if self._tags[idx] is not None:
+                return False
             self._tags[idx] = tags
+            self.untagged -= 1
+        return True
+
+    def ensure_tags_batch(self, sidx: Sequence[int],
+                          tags: Sequence[Optional[dict]]) -> int:
+        """`ensure_tags` for a batch's rows (`sidx[i] < 0`: not a known
+        series, skipped); returns how many it backfilled. Call it where
+        `untagged` is non-zero: rows of series that hold their tags
+        cost one list probe each and no call."""
+        held = self._tags
+        return sum(self.ensure_tags(x, tags[i])
+                   for i, x in enumerate(sidx)
+                   if x >= 0 and held[x] is None)
+
+    def _count_untagged(self, n: int):
+        if n:
+            with self._untagged_lock:
+                self.untagged += n
 
     def all_ids(self) -> List[bytes]:
         return list(self._ids)

@@ -137,36 +137,58 @@ class Shard:
 
     def write_batch(self, ids: Sequence[bytes], ts: np.ndarray, vals: np.ndarray,
                     now_ns: int, tags: Optional[Sequence[Optional[dict]]] = None,
-                    priority: Priority = Priority.NORMAL, acc=None):
+                    priority: Priority = Priority.NORMAL, acc=None, *,
+                    rows: Optional[Sequence[int]] = None,
+                    checked: bool = False,
+                    block_start: Optional[int] = None) -> bool:
         """`acc` (a detailed span, utils.tracing.detail, read once a
         batch by Database.write_batch) receives `lock_wait_ns`: the time
-        this shard's append waited for the shard lock."""
-        ts = np.asarray(ts, np.int64)
-        vals = np.asarray(vals, np.float64)
-        ok = (ts >= now_ns - self.opts.buffer_past_ns) & (ts <= now_ns + self.opts.buffer_future_ns)
-        if not ok.all():
-            bad = int((~ok).sum())
-            raise ValueError(f"{bad} datapoints outside acceptance window")
+        this shard's append waited for the shard lock.
+
+        A caller that has routed a larger batch here (Database.
+        write_batch) says what it worked out once for the whole batch:
+        `checked`, every row is inside the acceptance window and `ts` /
+        `vals` are int64 / float64 arrays; `block_start`, the one block
+        all rows fall in; `rows`, where `tags` is the larger batch's and
+        row i's tags are `tags[rows[i]]`, gathered here only if a series
+        needs them. True where the append was *fast*: one block, every
+        id known, no tags backfilled."""
+        if not checked:
+            ts = np.asarray(ts, np.int64)
+            vals = np.asarray(vals, np.float64)
+            ok = (ts >= now_ns - self.opts.buffer_past_ns) & (ts <= now_ns + self.opts.buffer_future_ns)
+            if not ok.all():
+                bad = int((~ok).sum())
+                raise ValueError(f"{bad} datapoints outside acceptance window")
         # Fast path: resolve every id against a lock-free registry
         # snapshot; the write lock narrows to the columnar append.
-        sidx = self.registry.lookup_batch(ids)
-        unknown = sidx < 0
-        if tags:
-            # Known series first written untagged (bootstrap, tagless
-            # writes) backfill their tags here, matching the single-write
-            # path and the old get_or_create(sid, tags) behavior.
-            ensure = self.registry.ensure_tags
-            for i in np.flatnonzero(sidx >= 0):
-                t = tags[i]
-                if t is not None:
-                    ensure(int(sidx[i]), t)
-        if not unknown.any():
+        registry = self.registry
+        sidx = registry.lookup_known(ids)
+        unknown = None
+        if sidx is None:
+            sidx = registry.lookup_batch(ids)
+            unknown = sidx < 0
+            if not unknown.any():  # inserted since the first look
+                unknown = None
+        all_known = unknown is None
+        backfilled = 0
+        if tags and (registry.untagged or not all_known):
+            if rows is not None:
+                tags = [tags[i] for i in rows]
+            if registry.untagged:
+                # Known series first written untagged (bootstrap, tagless
+                # writes) backfill their tags here, matching the
+                # single-write path; a series that holds its tags makes
+                # no call.
+                backfilled = registry.ensure_tags_batch(sidx.tolist(), tags)
+        if all_known:
             t0 = _clock() if acc is not None else 0
             with self.write_lock:
                 if acc is not None:
                     acc.add_cost("lock_wait_ns", _clock() - t0)
-                self.buffer.write_batch(sidx, ts, vals)
-            return
+                one_block = self.buffer.write_batch(sidx, ts, vals,
+                                                    block_start)
+            return one_block and not backfilled
         # Slow path: coalesce the first-seen remainder into the insert
         # queue as ONE columnar group (distinct new ids + their pending
         # points). Admission happens BEFORE any buffer append, so a
@@ -202,11 +224,13 @@ class Shard:
         known = ~unknown
         if known.any():
             with self.write_lock:
-                self.buffer.write_batch(sidx[known], ts[known], vals[known])
+                self.buffer.write_batch(sidx[known], ts[known], vals[known],
+                                        block_start)
         if not self.opts.write_new_series_async:
             if not batch.drained:
                 self.insert_queue.drain()
             batch.wait()
+        return False
 
     def _drain_inserts(self, groups: List[InsertGroup]):
         """Insert-queue drain: apply one coalesced batch — register every

@@ -231,8 +231,10 @@ def test_a_shed_request_answers_429_and_writes_nothing(node):
     assert node.wal()[0] == []
 
 
-def test_a_sample_outside_the_window_answers_400_and_logs_what_it_applied(
-        node):
+def test_a_sample_outside_the_window_answers_400_and_applies_nothing(node):
+    """Until PR 31 this pinned a partial application (the shards below
+    the offending one appended, and the rescue logged them). The window
+    is now checked once for the batch before any shard is touched."""
     series = _scrape(30)
     past = node.db.namespace(NS).opts.buffer_past_ns
     tags, _ = series[17]
@@ -240,17 +242,11 @@ def test_a_sample_outside_the_window_answers_400_and_logs_what_it_applied(
     with pytest.raises(urllib.error.HTTPError) as e:
         node.post(series)
     assert e.value.code == 400
-    assert b"outside acceptance window" in e.value.read()
-    # shards are applied in ascending order up to the one holding the
-    # sample; whatever was applied is in the WAL, and nothing else is
-    bad_shard = node.db.shard_set.lookup(_sid(tags))
-    applied = []
-    for tg, samples in series:
-        sid = _sid(tg)
-        t, _v = node.read(sid)
-        assert (len(t) == 1) == (node.db.shard_set.lookup(sid) < bad_shard)
-        applied += [(NS, sid, int(x), samples[0][1]) for x in t]
-    assert sorted(node.wal()[0]) == sorted(applied)
+    assert b"1 datapoints outside acceptance window" in e.value.read()
+    for tg, _samples in series:
+        assert len(node.read(_sid(tg))[0]) == 0
+        assert node.registry_tags(_sid(tg)) is None
+    assert node.wal()[0] == []
 
 
 def test_a_failed_commit_log_append_fails_the_request(tmp_path):
@@ -266,6 +262,303 @@ def test_a_failed_commit_log_append_fails_the_request(tmp_path):
     finally:
         faultfs.uninstall()
         n.close()
+
+
+# ------------------------------------------- the one routing pass (PR 31)
+#
+# Database.write_batch sorts a batch by shard once, checks the window and
+# works out the block start once, and hands each shard contiguous slices.
+# Held to the same rows written one `Database.write` (one `Shard.write`,
+# one `commitlog.write`) at a time: bucket columns in row order, registry
+# ids and tags, commit-log bytes.
+
+APPENDS = "storage.write_batch."
+# T0 is 800 s into its 2-hour block: the boundary below it, and a clock
+# from which both sides of it are inside the acceptance window
+BOUNDARY = T0 - 800 * S
+
+
+def _rows(db, hosts, t_ns, per_shard=None, name=b"cpu", value=1.0):
+    """(id, tags, t, v) rows in host order; `per_shard`: only hosts whose
+    series is among the first `per_shard` of its shard."""
+    out, seen = [], {}
+    for tags, _ in _scrape(hosts, name=name):
+        sid = _sid(tags)
+        shard = db.shard_set.lookup(sid)
+        seen[shard] = seen.get(shard, 0) + 1
+        if per_shard is None or seen[shard] <= per_shard:
+            out.append((sid, tags, t_ns, value + len(out)))
+    return out
+
+
+def _write_batch(node, rows, tagged=True):
+    ids = [r[0] for r in rows]
+    node.db.write_batch(
+        NS, ids, np.array([r[2] for r in rows], np.int64),
+        np.array([r[3] for r in rows]),
+        tags=[r[1] for r in rows] if tagged else None,
+        shard_ids=node.db.shard_set.lookup_memo(ids))
+
+
+def _write_each(node, rows, tagged=True):
+    for sid, tags, t, v in rows:
+        node.db.write(NS, sid, t, v, tags if tagged else None)
+
+
+def _stored(node):
+    """Every shard's registry and open buckets, rows in stored order."""
+    out = {}
+    for shard_id, shard in node.db.namespace(NS).shards.items():
+        reg = shard.registry
+        out[shard_id] = (
+            reg.all_ids(), [reg.tags_of(i) for i in range(len(reg))],
+            {bs: tuple(col.tolist() for col in b.cols.view())
+             for bs, b in sorted(shard.buffer.buckets.items())})
+    return out
+
+
+def _appends(db, writes):
+    """(shard appends, fast ones) the writes should count: an append is
+    fast where its rows share a block, every id is known and no series
+    gets its tags from it."""
+    block = db.namespace(NS).opts.block_size_ns
+    known, untagged, appends, fast = set(), set(), 0, 0
+    for rows, tagged in writes:
+        by_shard = {}
+        for r in rows:
+            by_shard.setdefault(db.shard_set.lookup(r[0]), []).append(r)
+        for part in by_shard.values():
+            ids = {r[0] for r in part}
+            appends += 1
+            fast += (ids <= known and len({r[2] // block for r in part}) == 1
+                     and not (tagged and ids & untagged))
+        ids = {r[0] for r in rows}
+        untagged = untagged - ids if tagged else untagged | (ids - known)
+        known |= ids
+    return appends, fast
+
+
+def _one_block(node):
+    rows = _rows(node.db, 2000, T0, per_shard=8)
+    assert len(rows) == 64 * 8
+    later = [(sid, tags, T0 + 10 * S, v + 0.5) for sid, tags, _t, v in rows]
+    return [(rows, True), (later, True)]
+
+
+def _straddling(node):
+    node.now["t"] = BOUNDARY + 60 * S
+    rows = _rows(node.db, 400, BOUNDARY - 30 * S)
+    # alternate sides of the boundary inside every shard, twice over
+    rows = [(sid, tags, BOUNDARY + (30 if i % 2 else -30) * S, v)
+            for i, (sid, tags, _t, v) in enumerate(rows + rows)]
+    return [(rows[:400], True), (rows[400:], True)]
+
+
+def _known_and_first_seen(node):
+    known = _rows(node.db, 300, T0)
+    # a shard appends its known rows, then drains its first sightings:
+    # known rows first in the batch is the arrival order that matches
+    mixed = [(sid, tags, T0 + 10 * S, v) for sid, tags, _t, v in known] + \
+        _rows(node.db, 200, T0 + 10 * S, name=b"mem")
+    return [(known, True), (mixed, True)]
+
+
+def _backfilled(node):
+    rows = _rows(node.db, 300, T0)
+    again = [(sid, tags, T0 + 10 * S, v) for sid, tags, _t, v in rows]
+    third = [(sid, tags, T0 + 20 * S, v) for sid, tags, _t, v in rows]
+    return [(rows[:120], False), (again, True), (third, True)]
+
+
+@pytest.mark.parametrize("case", [_one_block, _straddling,
+                                  _known_and_first_seen, _backfilled])
+def test_a_routed_batch_stores_what_single_writes_store(tmp_path, case):
+    batch = Node(tmp_path, "batch", shards=64)
+    ref = Node(tmp_path, "ref", shards=64)
+    try:
+        c0 = _counters(APPENDS)
+        for node, write in ((batch, _write_batch), (ref, _write_each)):
+            writes = case(node)
+            for rows, tagged in writes:
+                write(node, rows, tagged)
+        appends, fast = _appends(batch.db, writes)
+        assert _moved(c0, APPENDS) == {APPENDS + "shard_appends": appends,
+                                       APPENDS + "fast_appends": fast}
+        assert 0 < fast < appends
+        assert _stored(batch) == _stored(ref)
+        assert any(buckets for _i, _t, buckets in _stored(batch).values())
+        (entries, tags_b, raw_b), (rentries, tags_r, raw_r) = \
+            batch.wal(), ref.wal()
+        assert entries == rentries and len(entries) == sum(
+            len(rows) for rows, _ in writes)
+        assert tags_b == tags_r
+        assert raw_b == raw_r       # the on-disk format, byte for byte
+    finally:
+        batch.close()
+        ref.close()
+
+
+def test_a_straddling_batch_fills_two_buckets_in_arrival_order(node):
+    writes = _straddling(node)
+    for rows, tagged in writes:
+        _write_batch(node, rows, tagged)
+    for _ids, _tags, buckets in _stored(node).values():
+        assert len(buckets) <= 2
+        for bs, (_sidx, ts, _vals) in buckets.items():
+            assert set(ts) == {BOUNDARY + (30 if bs == BOUNDARY else -30) * S}
+
+
+def test_a_batch_of_tagged_series_calls_ensure_tags_for_none(
+        node, monkeypatch):
+    from m3_tpu.storage.series import SeriesRegistry
+
+    calls = []
+    real = SeriesRegistry.ensure_tags
+    monkeypatch.setattr(
+        SeriesRegistry, "ensure_tags",
+        lambda self, idx, tags: calls.append(idx) or real(self, idx, tags))
+    rows = _rows(node.db, 200, T0)
+    _write_batch(node, rows[:50], tagged=False)   # 50 known, untagged
+    assert sum(sh.registry.untagged
+               for sh in node.db.namespace(NS).shards.values()) == 50
+    _write_batch(node, rows)                 # 150 first seen, 50 backfilled
+    assert len(calls) == 50
+    assert all(node.registry_tags(sid) == tags for sid, tags, _t, _v in rows)
+    assert not any(sh.registry.untagged
+                   for sh in node.db.namespace(NS).shards.values())
+    del calls[:]
+    _write_batch(node, [(sid, tags, T0 + 10 * S, v)
+                        for sid, tags, _t, v in rows])
+    assert calls == []
+
+
+def test_sixteen_threads_backfilling_leave_the_untagged_count_exact(tmp_path):
+    """The count behind "no series here is untagged" is read without a
+    lock and moved by racing writers (tagged batches backfilling the
+    same series, tagless batches creating new ones): a lost update
+    would leave it off its column for good, and a count stuck at 0
+    would stop every later backfill."""
+    import sys
+    import time
+
+    n = Node(tmp_path, shards=2)
+    rows = _rows(n.db, 600, T0)
+    _write_batch(n, rows[:400], tagged=False)
+    errors = []
+    deadline = time.monotonic() + 20
+
+    def work(k):
+        try:
+            for r in range(12):
+                part = rows[(k * 25) % 300:][:150] if k % 4 else \
+                    rows[400 + 12 * k + r:][:1]         # a new one, tagless
+                _write_batch(n, [(sid, tags, T0 + (k * 12 + r) * 1_000_000, v)
+                                 for sid, tags, _t, v in part],
+                             tagged=bool(k % 4))
+                for sh in n.db.namespace(NS).shards.values():
+                    assert sh.registry.untagged >= 0
+                if time.monotonic() > deadline:
+                    raise TimeoutError
+        except BaseException as e:  # noqa: BLE001
+            errors.append(e)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=work, args=(k,)) for k in range(16)]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(timeout=30)
+    finally:
+        sys.setswitchinterval(interval)
+    try:
+        assert not errors and not any(th.is_alive() for th in threads)
+        left = 0
+        for sh in n.db.namespace(NS).shards.values():
+            reg = sh.registry
+            held = [reg.tags_of(i) for i in range(len(reg))]
+            assert reg.untagged == held.count(None)
+            left += reg.untagged
+        # every series a tagged batch touched holds its tags
+        assert all(n.registry_tags(sid) == tags
+                   for sid, tags, _t, _v in rows[25:400])
+        assert 0 < left
+    finally:
+        n.close()
+
+
+@pytest.mark.parametrize("where", ["past", "future"])
+def test_one_row_outside_the_window_refuses_the_batch_whole(node, where):
+    opts = node.db.namespace(NS).opts
+    rows = _rows(node.db, 200, T0)
+    known = rows[:100]
+    _write_batch(node, known)
+    before, c0 = _stored(node), _counters(APPENDS)
+    bad_t = (T0 - opts.buffer_past_ns - S if where == "past"
+             else T0 + opts.buffer_future_ns + S)
+    rows = [(sid, tags, T0 + 10 * S, v) for sid, tags, _t, v in rows]
+    rows[150] = rows[150][:2] + (bad_t, 1.0)     # in the batch's last shards
+    rows[3] = rows[3][:2] + (bad_t, 1.0)
+    with pytest.raises(ValueError, match="^2 datapoints outside acceptance"):
+        _write_batch(node, rows)
+    assert _stored(node) == before               # no shard appended a row
+    assert _moved(c0, APPENDS) == {}
+    assert len(node.wal()[0]) == len(known)      # and none was logged
+
+
+@pytest.mark.parametrize("k", [1, 2, 5, 8])
+def test_a_shed_in_the_kth_shard_logs_the_rows_of_the_shards_before_it(
+        node, monkeypatch, k):
+    from m3_tpu.utils.limits import Backpressure
+
+    rows = _rows(node.db, 120, T0)
+    by_shard = {}
+    for r in rows:
+        by_shard.setdefault(node.db.shard_set.lookup(r[0]), []).append(r)
+    assert len(by_shard) == 8
+    kth = sorted(by_shard)[k - 1]
+
+    def shed(*a, **kw):
+        raise Backpressure("insert queue full")
+
+    monkeypatch.setattr(node.db.namespace(NS).shards[kth], "write_batch", shed)
+    c0 = _counters(APPENDS)
+    with pytest.raises(Backpressure):
+        _write_batch(node, rows)
+    applied = [r for r in rows if node.db.shard_set.lookup(r[0]) < kth]
+    for sid, _tags, t, v in rows:
+        got_t, got_v = node.read(sid)
+        want = node.db.shard_set.lookup(sid) < kth
+        assert (got_t.tolist(), got_v.tolist()) == (([t], [v]) if want
+                                                    else ([], []))
+    assert _moved(c0, APPENDS) == (
+        {APPENDS + "shard_appends": k - 1} if k > 1 else {})
+    entries, wal_tags, _raw = node.wal()
+    # in the request's own order, as an acknowledged batch is logged
+    assert entries == [(NS, sid, t, v) for sid, _tags, t, v in applied]
+    assert wal_tags == {sid: tags for sid, tags, _t, _v in applied}
+
+
+def test_the_append_counters_move_once_a_batch_by_its_totals(
+        node, monkeypatch):
+    from m3_tpu.utils.instrument import Counter
+
+    rows = _rows(node.db, 200, T0)
+    incs = []
+    real = Counter.inc
+    monkeypatch.setattr(Counter, "inc",
+                        lambda self, n=1: incs.append(n) or real(self, n))
+    c0 = _counters(APPENDS)
+    _write_batch(node, rows)                 # first sightings: none fast
+    assert _moved(c0, APPENDS) == {APPENDS + "shard_appends": 8}
+    c0 = _counters(APPENDS)
+    del incs[:]
+    _write_batch(node, [(sid, tags, T0 + 10 * S, v)
+                        for sid, tags, _t, v in rows])
+    assert _moved(c0, APPENDS) == {APPENDS + "shard_appends": 8,
+                                   APPENDS + "fast_appends": 8}
+    assert incs.count(8) == 2 and len(incs) <= 4   # not one a shard
 
 
 # ------------------------------------------------ (d) routing by the memo
