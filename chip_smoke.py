@@ -791,6 +791,68 @@ def phase_served_verdict(ctx: Ctx):
     say(f"served-path verdict clean: {ctx.facts['routes']}")
 
 
+FEW_ROWS = (1, 2, 3, 5, 8, 16)
+
+
+def few_row_twin_faults(seed: int, rows=FEW_ROWS, starts: int = 4) -> list:
+    """Planes of 1-16 rows (a sealed block's first-touch read decodes
+    one row; a thin dashboard read a handful), encoded and decoded on
+    the default route and on the XLA twin, over block starts either
+    side of many low-word wraps of the nanosecond pair: every mismatch
+    with the twin or with the written samples, as a line of text. On a
+    v5e the one-row plane of the block two after T0 read 12 timestamps
+    ~2^31 ns low (PR 32): XLA:TPU lowers the degenerate reshapes of a
+    one-row program as u32 reduce-adds inside the fused unit multiply;
+    tsz.decode_plane builds no one-row program on that route."""
+    from m3_tpu.ops import tsz
+    from m3_tpu.parallel import guard
+    from m3_tpu.storage.block import encode_block
+
+    rng = np.random.default_rng([seed & 0xFFFFFFFF, 16])
+    n, w = max(rows), BLOCK_POINTS
+    at = [T0 + 2 * BLOCK_NS] + [
+        T0 + int(k) * BLOCK_NS for k in rng.integers(0, 1 << 16, starts - 1)]
+    faults = []
+    for bs in at:
+        t = bs + np.arange(w, dtype=np.int64)[None, :] * CADENCE_NS \
+            + np.zeros((n, 1), np.int64)
+        v = np.clip(np.cumsum(rng.integers(-1, 2, (n, w)), 1) + 50,
+                    0, 100).astype(np.float64)
+        v[1::2] = np.round(v[1::2] + rng.random((len(v[1::2]), w)), 2)
+        whole = encode_block(bs, np.arange(n), t, v, np.full(n, w, np.int32))
+        for r in rows:
+            blk = encode_block(bs, np.arange(r), t[:r], v[:r],
+                               np.full(r, w, np.int32))
+            where = f"block {(bs - T0) // BLOCK_NS}, {r} row(s)"
+            if not np.array_equal(np.asarray(blk.words)[:r],
+                                  np.asarray(whole.words)[:r]):
+                faults.append(f"{where}: encoded alone != encoded among {n}")
+            got = {}
+            for route in ("default", "xla"):
+                guard.set_disabled("codec.decode", route == "xla")
+                try:
+                    got[route] = [np.asarray(a)[:, :w] for a in
+                                  tsz.decode_plane(
+                                      np.asarray(whole.words)[:r],
+                                      np.asarray(whole.npoints)[:r],
+                                      window=whole.window,
+                                      unit_nanos=whole.time_unit.nanos)]
+                finally:
+                    guard.set_disabled("codec.decode", False)
+            ts_d, vs_d = got["default"]
+            if not (np.array_equal(ts_d, got["xla"][0]) and np.array_equal(
+                    vs_d.view(np.uint64), got["xla"][1].view(np.uint64))):
+                faults.append(f"{where}: default route != XLA twin")
+            bad = np.argwhere(ts_d != t[:r])
+            if len(bad):
+                faults.append(
+                    f"{where}: {len(bad)} timestamps off, first at "
+                    f"{bad[0].tolist()} by {int((ts_d - t[:r])[tuple(bad[0])])}")
+            if not np.array_equal(vs_d, v[:r]):
+                faults.append(f"{where}: values differ from the written")
+    return faults
+
+
 def phase_codec_twins(ctx: Ctx):
     """Each Pallas codec kernel against its XLA / numpy twin at the
     smoke's served shapes, bit for bit, ON THIS DEVICE (interpret-mode
@@ -839,6 +901,8 @@ def phase_codec_twins(ctx: Ctx):
     check(np.array_equal(dec_default[1][:, :BLOCK_POINTS].view(np.uint64),
                          vals[:, :BLOCK_POINTS].view(np.uint64)),
           "decode: value bits differ from the written samples")
+    faults = few_row_twin_faults(ctx.seed)
+    check(not faults, "few-row planes: " + "; ".join(faults[:5]))
     ids = ctx.ids[:min(sz.series, 20_000)]
     h_default = hashing.hash_batch(ids)
     guard.set_disabled("codec.hash", True)

@@ -14,11 +14,12 @@ from __future__ import annotations
 import enum
 from typing import Dict, List, Sequence, Tuple
 
+import jax.numpy as jnp
 import numpy as np
 
 from ..ops import tsz
 from ..parallel import telemetry
-from ..utils import xtime
+from ..utils import instrument, xtime
 
 
 class ConflictStrategy(enum.Enum):
@@ -73,6 +74,9 @@ def decode_segment_groups(segments: Sequence[dict]) -> List[Tuple[np.ndarray, np
     return out
 
 
+TILE_MIN_ROWS = 8
+
+
 def decode_tile(words, npoints, window: int, time_unit: int
                 ) -> Tuple[np.ndarray, np.ndarray]:
     """Decode one columnar block tile ([rows, max_words] words +
@@ -86,7 +90,10 @@ def decode_tile(words, npoints, window: int, time_unit: int
     words = np.asarray(words)
     npoints = np.asarray(npoints, np.int32)
     n = words.shape[0]
-    rp = 1 << (max(n, 1) - 1).bit_length()
+    # at least 8 rows: a thin read's tiles hold 1-5 series of a shard,
+    # and one shape serves them all (the device pads rows to its 8
+    # sublanes, and the Pallas route to 128 lanes, whatever is asked)
+    rp = max(TILE_MIN_ROWS, 1 << (max(n, 1) - 1).bit_length())
     if rp != n:
         words = np.concatenate([words, np.repeat(words[:1], rp - n, 0)])
         np_pad = np.concatenate([npoints, np.repeat(npoints[:1], rp - n)])
@@ -95,8 +102,14 @@ def decode_tile(words, npoints, window: int, time_unit: int
     telemetry.record_bucket("client.decode_tile",
                             (rp, int(words.shape[-1]), int(window)))
     # Fused decode: tick cumsum + time-unit scaling happen inside the one
-    # decode program; the host just slices the padded rows back off.
-    ts, vs = tsz.decode_plane(words, np_pad, window=window,
+    # decode program; the host just slices the padded rows back off. The
+    # words are put on the device here (the calling thread's: its
+    # scope's, parallel/scope.py) and the dispatch is counted there.
+    jwords = jnp.asarray(words)
+    for dev in jwords.devices():
+        instrument.ROOT.sub_scope("client.decode_tile", device=str(dev.id)
+                                  ).counter("dispatches").inc()
+    ts, vs = tsz.decode_plane(jwords, np_pad, window=window,
                               unit_nanos=xtime.Unit(time_unit).nanos)
     return np.asarray(ts[:n]), np.asarray(vs[:n])
 

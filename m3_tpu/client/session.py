@@ -63,6 +63,32 @@ _PEER_METRICS = ROOT.sub_scope("session.peers")
 
 # ------------------------------------------------------------------ transport
 
+# What one RPC cost on the wire, for the caller that asked: a fan-out
+# worker or a host queue installs a dict here (`_wire_stats`) around its
+# call and Connection.call adds the frame sizes and the encode / decode
+# times to it. The caller folds them into its span's costs on its own
+# thread: three workers adding to one span's dict would lose updates.
+_WIRE = threading.local()
+_clock = tracing.clock_ns
+
+
+def _no_stats() -> dict:
+    return {"encode_ns": 0, "decode_ns": 0, "bytes_out": 0, "bytes_in": 0}
+
+
+class _wire_stats:
+    """`with _wire_stats() as st:` around a HostClient call."""
+
+    def __enter__(self) -> dict:
+        self.prev = getattr(_WIRE, "stats", None)
+        _WIRE.stats = st = _no_stats()
+        return st
+
+    def __exit__(self, *exc):
+        _WIRE.stats = self.prev
+        return False
+
+
 
 class Connection:
     """One framed TCP connection (connection_pool.go conn)."""
@@ -102,10 +128,19 @@ class Connection:
             self.sock.settimeout(deadline.min_timeout(self.request_timeout))
         else:
             self.sock.settimeout(self.request_timeout)
-        wire.write_frame(self.sock, req)
+        st = getattr(_WIRE, "stats", None) or _no_stats()  # nobody asked
+        t0 = _clock()
+        body = wire.encode(req)
+        st["encode_ns"] += _clock() - t0
+        st["bytes_out"] += len(body)
+        wire.write_body(self.sock, body)
         try:
             while True:
-                resp = wire.read_dict_frame(self.sock)
+                body = wire.read_body(self.sock)
+                t0 = _clock()
+                resp = wire.as_dict_frame(wire.decode(body))
+                st["decode_ns"] += _clock() - t0
+                st["bytes_in"] += len(body)
                 rid = resp.get("id", self._msg_id)
                 if rid == self._msg_id:
                     break
@@ -201,11 +236,17 @@ class HostClient:
         self._free: List[Connection] = []
         self._lock = threading.Lock()
         self._sema = threading.Semaphore(pool_size)
+        # Counts failed attempts: a caller that keeps state about the
+        # host (Session's tags-sent-once set) reads it before and after
+        # a call, and trusts the state only if nothing failed between —
+        # a host that restarted fails its old connections first.
+        self.epoch = 0
 
     def _record(self, ok: bool):
         if ok:
             self.breaker.record_success()
         else:
+            self.epoch += 1
             self.breaker.record_failure()
         if self._on_outcome is not None:
             self._on_outcome(ok)
@@ -388,10 +429,134 @@ class _WriteOp:
     priority: Optional[str] = None
 
 
+class _BatchCompletion:
+    """Quorum wait for one columnar batch: one result per HOST, none per
+    datapoint. `sets` are the distinct replica sets of the batch's
+    shards, each with the acks it needs; the batch is acknowledged when
+    every set has them, and fails as soon as one set no longer can."""
+
+    __slots__ = ("sets", "acked", "failed", "_cond")
+
+    def __init__(self, sets: List[Tuple[Tuple[str, ...], int]]):
+        self.sets = sets
+        self.acked: set = set()
+        self.failed: Dict[str, str] = {}
+        self._cond = threading.Condition()
+
+    def done(self, host_id: str, err: Optional[str] = None):
+        with self._cond:
+            if err is None:
+                self.acked.add(host_id)
+            else:
+                self.failed[host_id] = err
+            self._cond.notify_all()
+
+    def _settled(self) -> bool:
+        """True when every set has its acks; raises when one cannot."""
+        ok = True
+        for hosts, required in self.sets:
+            acks = sum(1 for h in hosts if h in self.acked)
+            if acks >= required:
+                continue
+            ok = False
+            if acks + sum(1 for h in hosts if h not in self.failed
+                          and h not in self.acked) < required:
+                raise ConsistencyError(
+                    f"{acks}/{len(hosts)} acks, need {required}: "
+                    f"{sorted(self.failed.items())}")
+        return ok
+
+    def wait(self, timeout: float):
+        deadline = time.monotonic() + timeout
+        with self._cond:
+            while not self._settled():
+                remaining = deadline - time.monotonic()
+                if remaining <= 0:
+                    raise ConsistencyError(
+                        f"timeout: {len(self.acked)} host acks, "
+                        f"{sorted(self.failed.items())}")
+                self._cond.wait(remaining)
+
+
+class _BatchWrite:
+    """One host's share of a columnar batch: ONE write_batch RPC. A
+    series' tags ride only until this host has acknowledged them once
+    (`known`, the session's set for the host): `tags` holds None for a
+    row whose series the host is known to hold tagged, and is left out
+    whole when every row is. A failed attempt between the send and the
+    ack (HostClient.epoch) means the host may have restarted: the
+    session forgets what it believed and the batch goes again with
+    every tag (the same rows twice are the same points)."""
+
+    __slots__ = ("ns", "ids", "ts", "vals", "tags", "shards", "priority",
+                 "host_id", "known", "completion", "stats")
+
+    def __init__(self, ns, ids, ts, vals, tags, shards, priority, host_id,
+                 known: set, completion: _BatchCompletion):
+        self.ns, self.ids, self.ts, self.vals = ns, ids, ts, vals
+        self.tags, self.shards, self.priority = tags, shards, priority
+        self.host_id, self.known, self.completion = host_id, known, completion
+        self.stats: Optional[dict] = None   # set once the RPC has ended
+
+    def _call(self, client: HostClient, tags):
+        _WRITE_RPCS.inc()
+        client.call("write_batch", _priority=self.priority, ns=self.ns,
+                    ids=self.ids, ts=self.ts, vals=self.vals, tags=tags,
+                    shards=self.shards)
+
+    def send(self, client: HostClient):
+        known, tags = self.known, self.tags
+        fresh: List[bytes] = []
+        sent = None
+        if tags is not None:
+            sent = [None if sid in known else tg
+                    for sid, tg in zip(self.ids, tags)]
+            fresh = [sid for sid, tg in zip(self.ids, sent) if tg]
+            if not fresh:
+                sent = None
+        withheld = tags is not None and len(fresh) < len(self.ids)
+        with _wire_stats() as st:
+            try:
+                epoch = client.epoch
+                self._call(client, sent)
+                if client.epoch != epoch and withheld:
+                    known.clear()
+                    fresh = [sid for sid, tg in zip(self.ids, tags) if tg]
+                    self._call(client, list(tags))
+            except Exception as e:  # noqa: BLE001 — propagate via completion
+                known.clear()
+                self.stats = st
+                self.completion.done(self.host_id,
+                                     f"{client.endpoint}: {e}")
+                return
+        if len(known) + len(fresh) > TAGGED_MAX:
+            known.clear()
+        known.update(fresh)
+        self.stats = st
+        self.completion.done(self.host_id)
+
+
+# Series a session remembers, per host, as tagged there (ids come from
+# clients before any validation: bounded, flushed whole when full).
+TAGGED_MAX = 1 << 18
+
+_WRITE_SCOPE = ROOT.sub_scope("client.write_batch")
+_WRITE_SAMPLES = _WRITE_SCOPE.counter("samples")
+_WRITE_RPCS = _WRITE_SCOPE.counter("rpcs")
+_WRITE_SHORT = _WRITE_SCOPE.counter("acked_short_of_all")
+_FETCH_SCOPE = ROOT.sub_scope("client.fetch_tagged")
+_FETCH_REPLICAS = _FETCH_SCOPE.counter("replicas_merged")
+_FETCH_DECODES = _FETCH_SCOPE.counter("decode_dispatches")
+_FETCH_BYTES_IN = _FETCH_SCOPE.counter("bytes_in")
+
+
 class HostQueue:
     """Per-host op queue: batches writes into write_batch RPCs
     (client/host_queue.go). Drains whatever is queued on each wake, so
-    batching emerges under load without adding idle latency."""
+    batching emerges under load without adding idle latency. A columnar
+    batch's share for this host (_BatchWrite) is one op and one RPC of
+    its own, in its place in the queue: a host sees a session's writes
+    in the order the session made them."""
 
     def __init__(self, client: HostClient, max_batch: int = 256):
         self.client = client
@@ -399,6 +564,7 @@ class HostQueue:
         self._ops: List[_WriteOp] = []
         self._cond = threading.Condition()
         self._closed = False
+        self._busy = False
         self._thread = threading.Thread(target=self._run, daemon=True)
         self._thread.start()
 
@@ -417,9 +583,40 @@ class HostQueue:
                 if self._closed and not self._ops:
                     return
                 batch, self._ops = self._ops[: self.max_batch], self._ops[self.max_batch :]
-            self._flush(batch)
+                self._busy = True
+            try:
+                self._flush(batch)
+            finally:
+                with self._cond:
+                    self._busy = False
+                    self._cond.notify_all()
 
-    def _flush(self, batch: List[_WriteOp]):
+    def wait_idle(self, timeout: float) -> bool:
+        """True once nothing is queued or in flight (a straggler's share
+        of an acknowledged batch included)."""
+        deadline = time.monotonic() + timeout
+        with self._cond:
+            while self._ops or self._busy:
+                remaining = deadline - time.monotonic()
+                if remaining <= 0:
+                    return False
+                self._cond.wait(remaining)
+        return True
+
+    def _flush(self, batch: list):
+        run: List[_WriteOp] = []
+        for op in batch:
+            if op.__class__ is _BatchWrite:
+                if run:
+                    self._flush_ops(run)
+                    run = []
+                op.send(self.client)
+            else:
+                run.append(op)
+        if run:
+            self._flush_ops(run)
+
+    def _flush_ops(self, batch: List[_WriteOp]):
         by_ns: Dict[Tuple[bytes, Optional[str]], List[_WriteOp]] = {}
         for op in batch:
             by_ns.setdefault((op.ns, op.priority), []).append(op)
@@ -484,6 +681,16 @@ class SessionOptions:
             is None else self.connect_timeout_s
 
 
+class _ReadCosts:
+    """What one fetch_tagged's decodes and merges cost, summed on the
+    calling thread and put on its span once."""
+
+    __slots__ = ("decode_ns", "decode_n", "d2h_bytes", "merge_ns")
+
+    def __init__(self):
+        self.decode_ns = self.decode_n = self.d2h_bytes = self.merge_ns = 0
+
+
 class Session:
     """client.Session: Write/WriteTagged/Fetch/FetchTagged over a topology."""
 
@@ -496,6 +703,9 @@ class Session:
         self._lock = threading.RLock()  # _queue -> _client nest on this lock
         self._pool = ThreadPoolExecutor(max_workers=opts.fanout_workers)
         self._shard_set: Optional[ShardSet] = None
+        # host id -> ids of series whose tags that host has acknowledged
+        self._tagged: Dict[str, set] = {}
+        self._write_plan: Optional[tuple] = None  # (map, hosts, owns, sets)
         if hasattr(topology, "subscribe"):
             topology.subscribe(lambda _m: None)  # keep map fresh
 
@@ -561,29 +771,104 @@ class Session:
     def write_tagged(self, ns: bytes, id: bytes, tags: dict, t_ns: int, value: float):
         self.write(ns, id, t_ns, value, tags)
 
+    def _plan_writes(self, m) -> tuple:
+        """What a batch needs of one topology map, worked out once a map:
+        the hosts, which shards each takes writes for (bool [hosts,
+        shards]) and each shard's replica set as a tuple of host ids."""
+        plan = self._write_plan
+        if plan is None or plan[0] is not m:
+            hosts = sorted(m.hosts.values(), key=lambda h: h.id)
+            row = {h.id: i for i, h in enumerate(hosts)}
+            owns = np.zeros((len(hosts), m.num_shards), bool)
+            sets: List[Tuple[str, ...]] = []
+            for shard in range(m.num_shards):
+                owners = m.route_shard(shard)
+                sets.append(tuple(h.id for h in owners))
+                for h in owners:
+                    owns[row[h.id], shard] = True
+            plan = self._write_plan = (m, hosts, owns, sets)
+        return plan
+
     def write_batch(self, ns: bytes, ids: Sequence[bytes], ts, vals,
                     tags: Optional[Sequence[Optional[dict]]] = None,
                     priority: Optional[str] = None):
-        """Batched write: one quorum completion per datapoint, ops fanned
-        through the same host queues (host queues re-batch per host)."""
+        """Columnar write: the batch is routed ONCE (the shard memo: a
+        coordinator writes the same series every scrape), each host that
+        owns any of its shards gets ONE write_batch RPC with its rows as
+        columns (ids, int64 ts, f64 vals, the rows' shards; a series'
+        tags only until that host has acknowledged them once), and the
+        batch is acknowledged when every shard in it has the write
+        consistency level's acks among its replicas. One completion a
+        batch, one result a host; nothing per datapoint. A host that
+        refuses the batch (its acceptance window refuses it whole) or
+        cannot be reached counts as that host's failure toward every
+        shard it owns; too few acks raise ConsistencyError. Hosts beyond
+        the quorum finish behind the call (`drain` waits for them)."""
+        n = len(ids)
+        if not n:
+            return
         ts = np.asarray(ts, np.int64)
         vals = np.asarray(vals, np.float64)
-        m = self._map()
-        required = required_acks(self.opts.write_consistency, m.replica_factor)
-        completions = []
-        ss = self._shards()
-        for i, sid in enumerate(ids):
-            hosts = m.route_shard(ss.lookup(sid))
-            if not hosts:
-                raise ConsistencyError(f"no hosts own shard for {sid!r}")
-            c = _Completion(required=min(required, len(hosts)), total=len(hosts))
-            completions.append(c)
-            op = _WriteOp(ns, sid, int(ts[i]), float(vals[i]),
-                          tags[i] if tags else None, c, priority)
-            for h in hosts:
+        with tracing.span("client.write_batch") as sp:
+            t0 = _clock()
+            ids = list(ids)
+            m, hosts, owns, sets = self._plan_writes(self._map())
+            shards = self._shards().lookup_memo(ids)
+            required = required_acks(self.opts.write_consistency,
+                                     m.replica_factor)
+            quorums = []
+            for shard in np.unique(shards).tolist():
+                owners = sets[shard]
+                if not owners:
+                    raise ConsistencyError(f"no hosts own shard {shard}")
+                quorums.append((owners, min(required, len(owners))))
+            completion = _BatchCompletion(sorted(set(quorums)))
+            ops = []
+            for h, mine in zip(hosts, owns):
+                rows = mine[shards]
+                if rows.all():
+                    share = (ids, ts, vals, tags, shards)
+                elif rows.any():
+                    at = np.flatnonzero(rows)
+                    pick = at.tolist()
+                    share = ([ids[i] for i in pick], ts[at], vals[at],
+                             [tags[i] for i in pick] if tags else None,
+                             shards[at])
+                else:
+                    continue
+                op = _BatchWrite(ns, *share, priority, h.id,
+                                 self._tagged.setdefault(h.id, set()),
+                                 completion)
+                ops.append(op)
                 self._queue(h).enqueue(op)
-        for c in completions:
-            c.wait(self.opts.timeout_s)
+            t1 = _clock()
+            try:
+                completion.wait(self.opts.timeout_s)
+            finally:
+                acks = len(completion.acked)
+                _WRITE_SAMPLES.inc(n)
+                if acks < len(ops):
+                    _WRITE_SHORT.inc()
+                if sp.sampled:
+                    sp.set_tag("hosts", len(ops))
+                    sp.set_tag("acks", acks)
+                    sp.add_cost("samples_n", n)
+                    sp.add_cost("route_ns", t1 - t0)
+                    sp.add_cost("quorum_wait_ns", _clock() - t1)
+                    sp.add_cost("wire_encode_ns", sum(
+                        op.stats["encode_ns"] for op in ops
+                        if op.stats is not None))
+
+    def drain(self, timeout_s: Optional[float] = None) -> bool:
+        """Wait until no write is queued or in flight to any host: the
+        replicas a quorum did not wait for have theirs too. True if so
+        within the timeout."""
+        deadline = time.monotonic() + (self.opts.timeout_s
+                                       if timeout_s is None else timeout_s)
+        with self._lock:
+            queues = list(self._queues.values())
+        return all(q.wait_idle(max(0.0, deadline - time.monotonic()))
+                   for q in queues)
 
     # ------------------------------------------------------------------ reads
 
@@ -594,6 +879,12 @@ class Session:
         of server spans back onto `span`) needs the explicit handoff."""
         with tracing.TRACER.activate(span):
             return client.call(method, **kwargs)
+
+    def _measured_call(self, span, client: HostClient, method: str, **kwargs):
+        """`_traced_call` that also returns what the call cost on the
+        wire (`_wire_stats`), for the caller to put on its span."""
+        with _wire_stats() as st:
+            return self._traced_call(span, client, method, **kwargs), st
 
     def fetch(self, ns: bytes, id: bytes, start_ns: int, end_ns: int
               ) -> Tuple[np.ndarray, np.ndarray]:
@@ -661,12 +952,20 @@ class Session:
         results, errs = [], []
         ok_ids = set()
         dl = Deadline.after(self.opts.timeout_s)
+        # The phases of a clustered read are costs of this span, never
+        # children of it (its parent's self time is a reader's): the
+        # wait for coverage, each responder's frame (bytes, decode), the
+        # tile decodes (device dispatches, bytes brought back) and the
+        # merges. The workers hand their wire stats back; this thread
+        # alone writes the span.
         with tracing.span("client.fetch_tagged", hosts=len(hosts)) as csp:
+            t0 = _clock()
             pending = {self._pool.submit(
-                self._traced_call, csp, self._client(h), "fetch_tagged",
+                self._measured_call, csp, self._client(h), "fetch_tagged",
                 _deadline=dl, ns=ns,
                 query=q, start_ns=start_ns, end_ns=end_ns,
                 limit=limit): h for h in hosts}
+            wire_ns = bytes_in = 0
             while pending and not coverage_met(ok_ids):
                 done, _ = futures_wait(
                     set(pending), timeout=max(0.0, dl.remaining()),
@@ -676,32 +975,53 @@ class Session:
                 for fut in done:
                     h = pending.pop(fut)
                     try:
-                        results.append(fut.result())
+                        r, st = fut.result()
+                        results.append(r)
                         ok_ids.add(h.id)
+                        wire_ns += st["decode_ns"]
+                        bytes_in += st["bytes_in"]
                     except Exception as e:  # noqa: BLE001
                         errs.append(f"{h.id}: {e}")
+            t1 = _clock()
             csp.set_tag("responders", len(ok_ids))
-        if not coverage_met(ok_ids):
-            raise ConsistencyError(
-                f"insufficient replica coverage ({len(ok_ids)} responders, "
-                f"need {required} per shard): {errs}")
-        merged: Dict[bytes, dict] = {}
-        for r in results:
-            for entry, (t, v) in zip(r["series"],
-                                     self._columnar_points(r)):
-                sid = entry["id"]
-                cur = merged.get(sid)
-                if cur is None:
-                    merged[sid] = {"tags": entry["tags"], "t": t, "v": v}
-                else:
-                    if not cur["tags"] and entry["tags"]:
-                        cur["tags"] = entry["tags"]
-                    cur["t"], cur["v"] = merge_replica_points(
-                        [cur["t"], t], [cur["v"], v], self.opts.conflict_strategy
-                    )
+            if not coverage_met(ok_ids):
+                raise ConsistencyError(
+                    f"insufficient replica coverage ({len(ok_ids)} "
+                    f"responders, need {required} per shard): {errs}")
+            acc = _ReadCosts()
+            merged: Dict[bytes, dict] = {}
+            strategy = self.opts.conflict_strategy
+            for r in results:
+                points = self._columnar_points(r, acc)
+                t2 = _clock()
+                for entry, (t, v) in zip(r["series"], points):
+                    sid = entry["id"]
+                    cur = merged.get(sid)
+                    if cur is None:
+                        merged[sid] = {"tags": entry["tags"], "t": t, "v": v}
+                    else:
+                        if not cur["tags"] and entry["tags"]:
+                            cur["tags"] = entry["tags"]
+                        cur["t"], cur["v"] = merge_replica_points(
+                            [cur["t"], t], [cur["v"], v], strategy)
+                acc.merge_ns += _clock() - t2
+            _FETCH_REPLICAS.inc(len(results))
+            _FETCH_DECODES.inc(acc.decode_n)
+            _FETCH_BYTES_IN.inc(bytes_in)
+            if csp.sampled:
+                csp.set_tag("replicas_merged", len(results))
+                for kind, n in (("fanout_wait_ns", t1 - t0),
+                                ("wire_decode_ns", wire_ns),
+                                ("bytes_in", bytes_in),
+                                ("decode_ns", acc.decode_ns),
+                                ("decode_n", acc.decode_n),
+                                ("d2h_bytes", acc.d2h_bytes),
+                                ("merge_ns", acc.merge_ns),
+                                ("series_n", len(merged))):
+                    csp.add_cost(kind, n)
         return merged
 
-    def _columnar_points(self, r: dict) -> List[tuple]:
+    def _columnar_points(self, r: dict, acc: "_ReadCosts") -> List[tuple]:
         """Per-series (t, v) from one host's COLUMNAR fetch_tagged frame:
         each sealed-block tile decodes in ONE batched kernel call
         (decode.decode_tile — the wire twin of peer streaming's block
@@ -709,21 +1029,44 @@ class Session:
         contributes offset-sliced views of the concatenated columns.
         Order per series is sealed blocks (ascending start) then the
         mutable buffer — the same precedence the per-series segment path
-        had, so LAST_PUSHED conflict resolution is unchanged."""
+        had, so LAST_PUSHED conflict resolution is unchanged. `acc`
+        takes what the decodes and the per-series merges cost."""
         from .decode import decode_tile
 
         n = len(r["series"])
         parts_t: List[list] = [[] for _ in range(n)]
         parts_v: List[list] = [[] for _ in range(n)]
-        for tile in sorted(r.get("tiles", ()), key=lambda d: d["bs"]):
-            ts, vs = decode_tile(tile["words"], tile["npoints"],
-                                 int(tile["window"]),
-                                 int(tile["time_unit"]))
-            npts = np.asarray(tile["npoints"]).tolist()
-            for j, pos in enumerate(np.asarray(tile["rows"]).tolist()):
-                k = npts[j]
-                parts_t[pos].append(ts[j, :k])
-                parts_v[pos].append(vs[j, :k])
+        # One decode a geometry, not one a tile: a frame carries a tile
+        # per (shard, sealed block) — ~100 of 1-5 rows each behind a
+        # dashboard read — and the decode is row-independent, so tiles
+        # of one window, unit and stream width stack into one call
+        # (3.35 ms of host time a call on the chip's host, PERF.md
+        # section 6, PR 32).
+        tiles = sorted(r.get("tiles", ()), key=lambda d: d["bs"])
+        groups: Dict[tuple, List[dict]] = {}
+        for tile in tiles:
+            groups.setdefault(
+                (int(tile["window"]), int(tile["time_unit"]),
+                 int(np.asarray(tile["words"]).shape[-1])), []).append(tile)
+        for (window, unit, _mw), members in groups.items():
+            t0 = _clock()
+            words = [np.asarray(t["words"]) for t in members]
+            npts = [np.asarray(t["npoints"], np.int32) for t in members]
+            ts, vs = decode_tile(
+                words[0] if len(members) == 1 else np.concatenate(words),
+                npts[0] if len(members) == 1 else np.concatenate(npts),
+                window, unit)
+            acc.decode_ns += _clock() - t0
+            acc.decode_n += 1
+            acc.d2h_bytes += ts.nbytes + vs.nbytes
+            at = 0
+            for tile, k_rows in zip(members, npts):
+                for j, (pos, k) in enumerate(zip(
+                        np.asarray(tile["rows"]).tolist(), k_rows.tolist())):
+                    parts_t[pos].append(ts[at + j, :k])
+                    parts_v[pos].append(vs[at + j, :k])
+                at += len(k_rows)
+        t0 = _clock()
         bufs = r.get("bufs")
         if bufs is not None:
             offs = np.asarray(bufs["offs"]).tolist()
@@ -733,8 +1076,10 @@ class Session:
                     parts_t[j].append(bt[offs[j]:offs[j + 1]])
                     parts_v[j].append(bv[offs[j]:offs[j + 1]])
         strategy = self.opts.conflict_strategy
-        return [merge_replica_points(parts_t[j], parts_v[j], strategy)
-                for j in range(n)]
+        out = [merge_replica_points(parts_t[j], parts_v[j], strategy)
+               for j in range(n)]
+        acc.merge_ns += _clock() - t0
+        return out
 
     def aggregate(self, ns: bytes, query, start_ns: int, end_ns: int,
                   name_only: bool = False, field_filter=(),

@@ -21,6 +21,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 import numpy as np
 
 from ..metrics.metric import MetricType
+from ..parallel import scope as dscope
 from ..query import METRIC_NAME, Engine
 from ..query import render as qrender
 from ..query.block import Block
@@ -42,6 +43,9 @@ class HTTPApi:
         self.engine = engine
         self.writer = writer
         self.admin = admin  # AdminAPI (namespace/placement/database/topic)
+        # parallel.scope.DeviceScope of a coordinator that owns some of
+        # the attached devices: a handler thread works inside it
+        self.device_scope = None
         self.routes: List[Tuple[str, str, Callable]] = [
             ("GET", r"/health", self.health),
             ("GET", r"/api/v1/query_range", self.query_range),
@@ -515,6 +519,10 @@ class HTTPApi:
                 pass
 
             def _dispatch(self):
+                with dscope.entered(api.device_scope):
+                    self._dispatch_scoped()
+
+            def _dispatch_scoped(self):
                 parsed = urllib.parse.urlsplit(self.path)
                 params = urllib.parse.parse_qs(parsed.query)
                 body = b""

@@ -13,6 +13,7 @@ from typing import Dict, Optional
 from ..cluster import kv as cluster_kv
 from ..metrics.matcher import Matcher, RuleSetStore
 from ..metrics.policy import StoragePolicy
+from ..parallel import scope as dscope
 from ..query import Engine, LocalStorage, SessionStorage
 from .admin import AdminAPI
 from .downsample import Downsampler
@@ -59,7 +60,11 @@ def _build(storage, aggregated_storages: Dict[StoragePolicy, object],
            kv_store: Optional[cluster_kv.MemStore],
            rules_namespace: bytes, clock, create_namespace,
            listen=("127.0.0.1", 0),
-           self_scrape_interval_s: Optional[float] = None) -> Coordinator:
+           self_scrape_interval_s: Optional[float] = None,
+           device_scope=None) -> Coordinator:
+    """`device_scope` (parallel/scope.py): the devices this coordinator
+    owns — its engine's query mesh is built over them and its HTTP
+    handler threads work inside it; None owns every attached device."""
     downsampler = None
     if kv_store is not None:
         matcher = Matcher(RuleSetStore(kv_store), rules_namespace, clock=clock)
@@ -87,10 +92,13 @@ def _build(storage, aggregated_storages: Dict[StoragePolicy, object],
         downsampler = Downsampler(matcher, write_aggregated, clock=clock,
                                   write_aggregated_batch=write_aggregated_batch)
     writer = DownsamplerAndWriter(storage, downsampler)
-    engine = Engine(storage)
+    with dscope.entered(device_scope):
+        engine = Engine(storage)
     admin = AdminAPI(kv_store if kv_store is not None else cluster_kv.MemStore(),
                      create_namespace=create_namespace)
-    api = HTTPApi(engine, writer, admin=admin).serve(*listen)
+    api = HTTPApi(engine, writer, admin=admin)
+    api.device_scope = device_scope
+    api.serve(*listen)
     scraper = None
     if self_scrape_interval_s is not None:
         # Dogfooding like the reference: the coordinator's own instrument
@@ -107,7 +115,8 @@ def run_embedded(db, namespace: bytes = b"default",
                  aggregated_namespaces: Optional[Dict[StoragePolicy, bytes]] = None,
                  clock=None, listen=("127.0.0.1", 0),
                  create_namespace=None,
-                 self_scrape_interval_s: Optional[float] = None) -> Coordinator:
+                 self_scrape_interval_s: Optional[float] = None,
+                 device_scope=None) -> Coordinator:
     storage = LocalStorage(db, namespace)
     agg = {
         policy: LocalStorage(db, ns)
@@ -123,7 +132,8 @@ def run_embedded(db, namespace: bytes = b"default",
 
     return _build(storage, agg, kv_store, rules_namespace, clock,
                   create_namespace, listen,
-                  self_scrape_interval_s=self_scrape_interval_s)
+                  self_scrape_interval_s=self_scrape_interval_s,
+                  device_scope=device_scope)
 
 
 def run_clustered(session, namespace: bytes = b"default",
@@ -131,11 +141,13 @@ def run_clustered(session, namespace: bytes = b"default",
                   rules_namespace: bytes = b"default",
                   aggregated_namespaces: Optional[Dict[StoragePolicy, bytes]] = None,
                   clock=None, listen=("127.0.0.1", 0),
-                  self_scrape_interval_s: Optional[float] = None) -> Coordinator:
+                  self_scrape_interval_s: Optional[float] = None,
+                  device_scope=None) -> Coordinator:
     storage = SessionStorage(session, namespace)
     agg = {
         policy: SessionStorage(session, ns)
         for policy, ns in (aggregated_namespaces or {}).items()
     }
     return _build(storage, agg, kv_store, rules_namespace, clock, None,
-                  listen, self_scrape_interval_s=self_scrape_interval_s)
+                  listen, self_scrape_interval_s=self_scrape_interval_s,
+                  device_scope=device_scope)

@@ -1183,6 +1183,17 @@ def decode_plane(words, npoints, *, window: int, unit_nanos: int = 1,
                             route, row_mesh)
     jwords = jnp.asarray(words)
     jnp_ = jnp.asarray(npoints, I32)
+    lone = route == "pallas" and jwords.shape[0] == 1
+    if lone:
+        # No one-row program on this route: the kernel's tile cut to one
+        # lane, [window, 1] -> [1, window], is a degenerate reshape, and
+        # XLA:TPU lowers those as u32 reduce-adds over a one-wide
+        # dimension inside the fused unit multiply; on a v5e that read
+        # 12 of a row's 128 timestamps ~2^31 ns low (PERF.md section 6,
+        # PR 32; the kernel's own ticks, the multiply alone and the XLA
+        # scan were exact). The row goes twice, on the device it is on.
+        jwords = jnp.concatenate([jwords, jwords])
+        jnp_ = jnp.concatenate([jnp_, jnp_])
     if route == "pallas":
         from ..parallel import guard
 
@@ -1229,6 +1240,9 @@ def decode_plane(words, npoints, *, window: int, unit_nanos: int = 1,
             if not f32.flags.writeable:
                 f32 = f32.copy()
             f32[rows] = fixed.astype(np.float32)
+    if lone:
+        ts, vals = ts[:1], vals[:1]
+        f32 = f32[:1] if with_f32 else None
     return (ts, vals, f32) if with_f32 else (ts, vals)
 
 

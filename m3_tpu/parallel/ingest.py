@@ -37,6 +37,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from . import guard as pguard
+from . import scope as dscope
 from . import telemetry
 from ..ops import aggregation as agg
 from ..ops import bits64 as b64
@@ -162,20 +163,27 @@ def make_mesh(n_devices: int | None = None, devices=None) -> Mesh:
     return Mesh(devices.reshape(n_devices // t, t), ("shard", "time"))
 
 
-@functools.lru_cache(maxsize=1)
-def flush_mesh() -> Mesh | None:
-    """The serving flush's shard x time mesh: make_mesh() over every
-    attached device when >1 is present, else None (single-device
-    platforms keep the plain jit path). M3_TPU_MESH_FLUSH=0 disables
-    mesh routing for A/B comparison (write_smoke uses it to prove
-    bit-equality against the single-device encode)."""
+def _make_flush_mesh(sc) -> Mesh | None:
     import os
 
     if os.environ.get("M3_TPU_MESH_FLUSH", "1") == "0":
         return None
-    if len(jax.devices()) <= 1:
+    devices = sc.devices
+    if len(devices) <= 1:
         return None
-    return make_mesh()
+    return make_mesh(devices=list(devices))
+
+
+def flush_mesh() -> Mesh | None:
+    """The serving flush's shard x time mesh: make_mesh() over every
+    device of the calling thread's scope (parallel/scope.py: every
+    attached device, for a service that was given none) when >1 is
+    present, else None (single-device platforms, and a service that
+    owns one chip, keep the plain jit path). M3_TPU_MESH_FLUSH=0
+    disables mesh routing for A/B comparison (write_smoke uses it to
+    prove bit-equality against the single-device encode, and forgets
+    the mesh it saw before: `scope.DEFAULT.clear("flush_mesh")`)."""
+    return dscope.current().owned("flush_mesh", _make_flush_mesh)
 
 
 @telemetry.jit_builder("flush_encoder")

@@ -21,6 +21,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple, Union
 import numpy as np
 
 from ..ops import series_agg, temporal
+from ..parallel import scope as dscope
 from . import corpus as qcorpus
 from . import explain as qexplain
 from . import promql
@@ -71,22 +72,19 @@ class QueryParams:
 
 
 def _default_query_mesh():
-    """One 1-D "shard" mesh over every attached device, or None single-chip.
-    Cached after first use — the serving processes build engines per
-    coordinator but share the device topology."""
-    global _QUERY_MESH
-    if _QUERY_MESH is _UNSET:
-        import jax
-        from jax.sharding import Mesh
-
-        devs = jax.devices()
-        _QUERY_MESH = (Mesh(np.asarray(devs), ("shard",))
-                       if len(devs) > 1 else None)
-    return _QUERY_MESH
+    """One 1-D "shard" mesh over every device of the calling thread's
+    scope (parallel/scope.py), or None where that is one chip. Cached
+    after first use — the serving processes build engines per
+    coordinator but share the device topology; a coordinator that was
+    given devices of its own keeps a mesh of its own."""
+    return dscope.current().owned("query_mesh", _make_query_mesh)
 
 
-_UNSET = object()
-_QUERY_MESH = _UNSET
+def _make_query_mesh(sc):
+    from jax.sharding import Mesh
+
+    devs = sc.devices
+    return Mesh(np.asarray(devs), ("shard",)) if len(devs) > 1 else None
 
 
 class _GridCache:

@@ -102,6 +102,16 @@ class SessionStorage:
               value: float):
         self._session.write_tagged(self._namespace, series_id, tags, t_ns, value)
 
+    def write_batch(self, series_ids: Sequence[bytes], tags: Sequence[dict],
+                    ts, vals):
+        """Columnar write through the cluster: one Session.write_batch —
+        the batch routed once, one write_batch RPC a host, acknowledged
+        at the session's write consistency level (the coordinator ingest
+        batch path, as LocalStorage.write_batch is for an embedded
+        node)."""
+        self._session.write_batch(self._namespace, series_ids, ts, vals,
+                                  tags=tags)
+
     def complete_tags(self, matchers: Sequence[Matcher], start_ns: int,
                       end_ns: int, name_only: bool = False,
                       filter_names: Sequence[bytes] = ()) -> Dict[bytes, set]:

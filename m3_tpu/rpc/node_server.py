@@ -21,6 +21,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from ..parallel import scope as dscope
 from ..storage.database import Database
 from ..storage.series import charge_read
 from ..utils import limits as xlimits
@@ -28,6 +29,7 @@ from ..utils import tracing
 from ..utils.health import AdmissionGate, Priority
 from ..utils.limits import ResourceExhausted
 from ..utils.retry import Deadline, DeadlineExceeded
+from ..utils.tracing import clock_ns as _clock
 from . import wire
 
 
@@ -65,8 +67,10 @@ class NodeService:
     and replication always get through."""
 
     def __init__(self, db: Database, gate: Optional[AdmissionGate] = None,
-                 limits: Optional[xlimits.QueryLimits] = None):
+                 limits: Optional[xlimits.QueryLimits] = None,
+                 host_id: str = ""):
         self.db = db
+        self.host_id = host_id  # the `host` tag of a traced request's span
         # monotonic, not wall clock: uptime is an ELAPSED measurement and
         # must not jump with NTP steps (m3lint wall-clock-latency).
         self.start_ns = time.monotonic_ns()
@@ -94,13 +98,17 @@ class NodeService:
     def dispatch_traced(self, method: str, args: dict,
                         deadline: Optional[Deadline] = None,
                         priority_hint: Optional[str] = None,
-                        trace_ctx=None):
+                        trace_ctx=None, encode: bool = False):
         """dispatch + span plumbing: returns (result, finished span dict
         or None). A request frame carrying the "tr" context gets a
         remote-parented span around its whole dispatch — QueryScope exit
         annotates it with the request's cost tallies — and the finished
         tree rides the response frame back for the caller to graft
-        (tracing module docstring). Untraced requests pay one NOOP test."""
+        (tracing module docstring). Untraced requests pay one NOOP test.
+        `encode` (the TCP handler's): a traced request's result is put
+        in wire form inside its span, which then carries the encode's
+        `wire_encode_ns` and `bytes_out`, and comes back as
+        `wire.Encoded`."""
         fn = getattr(self, "rpc_" + method, None)
         if fn is None:
             raise RPCError(f"unknown method {method!r}")
@@ -122,7 +130,8 @@ class NodeService:
         xlimits.reset_last_totals()
         t0 = time.perf_counter_ns()
         try:
-            with sp:
+            # the handler thread works for this node: its devices
+            with dscope.entered(self.db.scope), sp:
                 with self.gate.held(priority=priority):
                     with ql.scope(f"rpc.{method}"):
                         self._local.deadline = deadline
@@ -137,6 +146,14 @@ class NodeService:
                         finally:
                             self._local.deadline = None
                             self._local.priority = None
+                if sp.sampled:
+                    sp.set_tag("host", self.host_id)
+                    if encode:
+                        t_enc = tracing.clock_ns()
+                        result = wire.Encoded(wire.encode(result))
+                        sp.add_cost("wire_encode_ns",
+                                    tracing.clock_ns() - t_enc)
+                        sp.add_cost("bytes_out", len(result.data))
         except ResourceExhausted:
             tracing.SLOW_QUERIES.maybe(
                 "rpc", method, time.perf_counter_ns() - t0,
@@ -191,9 +208,24 @@ class NodeService:
         return True
 
     def rpc_write_batch(self, ns: bytes, ids: list, ts: np.ndarray, vals: np.ndarray,
-                        tags: Optional[list] = None):
+                        tags: Optional[list] = None,
+                        shards: Optional[np.ndarray] = None):
+        """`shards`: the rows' shards where the client has routed them
+        already (Session.write_batch, once for all replicas, by the same
+        murmur3 over the placement's shard count): the node then hashes
+        nothing and the batch goes straight to the routing pass. Without
+        them the batch is hashed here, the bulk route. `tags`: None, or
+        None for a row whose series this node already holds tagged."""
+        if shards is not None:
+            shards = np.asarray(shards, np.int32)
+            if (shards.shape != (len(ids),) or (len(ids) and (
+                    shards.min() < 0
+                    or shards.max() >= self.db.shard_set.num_shards))):
+                raise RPCError("write_batch: `shards` does not fit the "
+                               "batch or this node's shard count")
         self.db.write_batch(ns, ids, ts, vals, tags,
-                            priority=self._request_priority())
+                            priority=self._request_priority(),
+                            shard_ids=shards)
         return len(ids)
 
     # ------------------------------------------------------------------ reads
@@ -216,7 +248,15 @@ class NodeService:
         per-row python materialization on the hot read fan-in."""
         q = wire.query_from_wire(query)
         nsobj = self.db.namespace(ns)
+        # Under a detailed span (a traced request's rpc.fetch_tagged) the
+        # phases become costs of that span: the index query, the
+        # per-series identity and buffer reads, the tile gathers.
+        acc = tracing.detail()
+        timed = acc is not None
+        t_start = _clock() if timed else 0
         ids = self.db.query_ids(ns, q, start_ns, end_ns, limit=limit)
+        t_index = _clock() if timed else 0
+        tile_ns = 0
         out = []
         by_shard: Dict[int, List[Tuple[int, int]]] = {}  # -> (idx, pos)
         for sid in ids:
@@ -275,6 +315,7 @@ class NodeService:
                 charge_read(n_bytes=sum(
                     buf_t[pos].nbytes + buf_v[pos].nbytes
                     for _, pos in part))
+            t_tiles = _clock() if timed else 0
             for bs in sorted(blocks):
                 blk = blocks[bs]
                 if bs + shard.opts.block_size_ns <= start_ns or bs >= end_ns:
@@ -307,6 +348,8 @@ class NodeService:
                     "window": int(blk.window),
                     "time_unit": int(blk.time_unit),
                 })
+            if timed:
+                tile_ns += _clock() - t_tiles
         offs = np.zeros(n + 1, np.int64)
         if n:
             offs[1:] = np.cumsum([t.size for t in buf_t])
@@ -315,6 +358,11 @@ class NodeService:
             "t": (np.concatenate(buf_t) if n else np.zeros(0, np.int64)),
             "v": (np.concatenate(buf_v) if n else np.zeros(0, np.float64)),
         }
+        if timed:
+            acc.add_cost("series_n", n)
+            acc.add_cost("index_ns", t_index - t_start)
+            acc.add_cost("tile_ns", tile_ns)
+            acc.add_cost("read_ns", _clock() - t_index - tile_ns)
         return {"series": out, "bufs": bufs, "tiles": tiles,
                 "exhaustive": True}
 
@@ -559,7 +607,8 @@ class NodeServer:
                                 deadline=deadline,
                                 priority_hint=pri if
                                 isinstance(pri, str) else None,
-                                trace_ctx=wire.trace_from_frame(req))
+                                trace_ctx=wire.trace_from_frame(req),
+                                encode=True)
                             resp = {"id": msg_id, "ok": True, "r": result}
                             if sp is not None:
                                 # Finished server-side span tree for the

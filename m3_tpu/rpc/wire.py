@@ -49,10 +49,24 @@ class WireTruncated(ConnectionError):
     desynced and a re-send lands on garbage)."""
 
 
+class Encoded:
+    """A value already in wire form (`encode(value)`): spliced into the
+    enclosing frame as it is. The node server encodes a traced
+    request's result inside the request's span, so the span carries
+    what the encode cost (`wire_encode_ns`, `bytes_out`)."""
+
+    __slots__ = ("data",)
+
+    def __init__(self, data: bytes):
+        self.data = data
+
+
 def _enc(out: bytearray, v: Any, depth: int = 0) -> None:
     if depth > MAX_DEPTH:
         raise ValueError(f"wire: nesting deeper than {MAX_DEPTH}")
-    if v is None:
+    if v.__class__ is Encoded:
+        out += v.data
+    elif v is None:
         out += b"\x00"
     elif v is True:
         out += b"\x02"
@@ -190,7 +204,11 @@ def decode(buf: bytes) -> Any:
 
 
 def write_frame(sock: socket.socket, value: Any) -> None:
-    body = encode(value)
+    write_body(sock, encode(value))
+
+
+def write_body(sock: socket.socket, body: bytes) -> None:
+    """One frame whose body the caller encoded (and timed) itself."""
     sock.sendall(_U32.pack(len(body)) + body)
 
 
@@ -213,11 +231,22 @@ def _read_exact(sock: socket.socket, n: int, mid_frame: bool = False) -> bytes:
     return b"".join(parts)
 
 
-def read_frame(sock: socket.socket) -> Any:
+def read_body(sock: socket.socket) -> bytes:
+    """One frame's body, not yet decoded."""
     (n,) = _U32.unpack(_read_exact(sock, 4))
     if n > MAX_FRAME:
         raise ValueError(f"wire: frame too large ({n})")
-    return decode(_read_exact(sock, n, mid_frame=True))
+    return _read_exact(sock, n, mid_frame=True)
+
+
+def read_frame(sock: socket.socket) -> Any:
+    return decode(read_body(sock))
+
+
+def as_dict_frame(v: Any) -> dict:
+    if not isinstance(v, dict):
+        raise ValueError(f"wire: expected dict frame, got {type(v).__name__}")
+    return v
 
 
 def read_dict_frame(sock: socket.socket) -> dict:
@@ -226,10 +255,7 @@ def read_dict_frame(sock: socket.socket) -> dict:
     top type must surface as the SAME ValueError every handler loop
     already treats as drop-the-connection (not an AttributeError
     traceback at the first .get)."""
-    v = read_frame(sock)
-    if not isinstance(v, dict):
-        raise ValueError(f"wire: expected dict frame, got {type(v).__name__}")
-    return v
+    return as_dict_frame(read_frame(sock))
 
 
 # ------------------------------------------------------ deadline propagation

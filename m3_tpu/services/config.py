@@ -78,6 +78,11 @@ class DBNodeConfig:
     kv_path: str = ""          # FileStore path; empty = in-memory
     kv_endpoint: str = ""      # networked KV service; overrides kv_path
     coordinator: Optional["CoordinatorConfig"] = None  # embedded mode
+    # The devices this node owns, as positions in jax.devices(): its
+    # flush mesh, block cache and HBM budget span these alone
+    # (parallel/scope.py). Empty: every attached device — a node that
+    # has its process, and its host's chips, to itself.
+    devices: List[int] = dataclasses.field(default_factory=list)
 
 
 @dataclasses.dataclass
@@ -94,6 +99,10 @@ class CoordinatorConfig:
     # registry written back through its own ingest path each interval
     # (tally-self-reporting analog). Empty disables.
     self_scrape_interval: str = ""
+    # The devices this coordinator owns (query meshes, client-side tile
+    # decode), as DBNodeConfig.devices. Empty: every attached device,
+    # or the node's own when embedded in one.
+    devices: List[int] = dataclasses.field(default_factory=list)
 
     @property
     def self_scrape_interval_s(self) -> Optional[float]:

@@ -21,6 +21,7 @@ from ..cluster import kv_service
 from ..cluster.placement import PlacementService
 from ..cluster.services import LeaderService
 from ..index.namespace_index import NamespaceIndex
+from ..parallel import scope as dscope
 from ..parallel.sharding import ShardSet
 from ..persist.commitlog import CommitLog
 from ..persist.fs import PersistManager
@@ -110,7 +111,8 @@ class DBNodeHandle:
         # Drain every shard's insert queue AFTER the listeners stop
         # accepting writes — queued async inserts are never stranded by
         # teardown (shard_insert_queue.go Stop during server Close).
-        self.db.close()
+        with dscope.entered(self.db.scope):  # its own block cache's entries
+            self.db.close()
         if self.kv is not None and hasattr(self.kv, "close"):
             self.kv.close()  # RemoteStore: stops watch threads + socket
         if self.lock is not None:
@@ -135,7 +137,9 @@ def run_dbnode(cfg: DBNodeConfig, clock=None) -> DBNodeHandle:
 
         commitlog = CommitLog(
             commitlog_dir, strategy=Strategy(cfg.commitlog_strategy))
-    db = Database(ShardSet(cfg.num_shards), commitlog=commitlog, clock=clock)
+    scope = dscope.from_config(cfg.devices, cfg.host_id)
+    db = Database(ShardSet(cfg.num_shards), commitlog=commitlog, clock=clock,
+                  scope=scope)
     for ns_cfg in cfg.namespaces:
         db.ensure_namespace(
             ns_cfg.name.encode(),
@@ -169,7 +173,7 @@ def run_dbnode(cfg: DBNodeConfig, clock=None) -> DBNodeHandle:
     else:
         db.mark_bootstrapped()
     host, port = _host_port(cfg.listen_address)
-    service = NodeService(db)
+    service = NodeService(db, host_id=cfg.host_id)
     server = NodeServer(service, host=host, port=port).start()
     httpjson = None
     if cfg.http_listen_address:
@@ -193,7 +197,10 @@ def run_dbnode(cfg: DBNodeConfig, clock=None) -> DBNodeHandle:
             clock=db.clock, listen=_host_port(cfg.coordinator.listen_address),
             create_namespace=lambda name, retention_ns:
                 ns_watch.add(name, retention_ns),
-            self_scrape_interval_s=cfg.coordinator.self_scrape_interval_s)
+            self_scrape_interval_s=cfg.coordinator.self_scrape_interval_s,
+            device_scope=dscope.from_config(
+                cfg.coordinator.devices, cfg.host_id + ".coordinator")
+            or scope)
     mediator = None
     if cfg.tick_interval:
         from ..storage.mediator import Mediator
@@ -352,18 +359,21 @@ def run_coordinator(cfg: CoordinatorConfig, session=None, db=None,
         raise ValueError("exactly one of session/db required")
     listen = _host_port(cfg.listen_address)
     scrape_s = cfg.self_scrape_interval_s
+    scope = dscope.from_config(cfg.devices, "coordinator")
     if db is not None:
         coord = run_embedded(db, namespace=cfg.namespace.encode(),
                              kv_store=kv_store,
                              rules_namespace=cfg.rules_namespace.encode(),
                              clock=clock, listen=listen,
-                             self_scrape_interval_s=scrape_s)
+                             self_scrape_interval_s=scrape_s,
+                             device_scope=scope or db.scope)
     else:
         coord = run_clustered(session, namespace=cfg.namespace.encode(),
                               kv_store=kv_store,
                               rules_namespace=cfg.rules_namespace.encode(),
                               clock=clock, listen=listen,
-                              self_scrape_interval_s=scrape_s)
+                              self_scrape_interval_s=scrape_s,
+                              device_scope=scope)
     if cfg.remotes:
         stores = [coord.engine.storage] + [RemoteStorage(r) for r in cfg.remotes]
         coord.engine.storage = FanoutStorage(stores)

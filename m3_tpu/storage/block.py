@@ -19,6 +19,7 @@ import numpy as np
 
 from ..ops import tsz
 from ..parallel import ingest as par_ingest
+from ..parallel import scope as dscope
 from ..utils import tracing, xtime
 from ..utils.checksum import adler32_rows
 from ..utils.instrument import ROOT
@@ -301,7 +302,9 @@ def encode_block(block_start: int, series_indices, tdense, vdense, npoints,
     s, w = tdense.shape
     # One span per encoded block when somebody is tracing (the
     # mediator's tick, a traced request); its phases are costs.
-    with tracing.child_span("encode.block", series=s, window=w):
+    with tracing.child_span("encode.block", series=s, window=w) as sp:
+        if sp.sampled:
+            sp.set_tag("device", dscope.device_tag())
         return _encode_block(block_start, series_indices, tdense, vdense,
                              npoints, max_words)
 

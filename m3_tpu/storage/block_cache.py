@@ -49,6 +49,7 @@ from typing import Dict, Optional, Tuple
 
 import numpy as np
 
+from ..parallel import scope as dscope
 from ..utils import instrument, tracing
 from ..utils.hbm import HBMBudget, shared_budget
 
@@ -293,17 +294,15 @@ class DeviceBlockCache:
 
 # ------------------------------------------------------------ process cache
 
-_CACHE: Optional[DeviceBlockCache] = None
-_CACHE_LOCK = threading.Lock()
 _BYPASS = threading.local()
 
 
 def get_cache() -> DeviceBlockCache:
-    global _CACHE
-    with _CACHE_LOCK:
-        if _CACHE is None:
-            _CACHE = DeviceBlockCache()
-        return _CACHE
+    """The block cache of the calling thread's scope (parallel/scope.py):
+    the process's one, or the cache of a service that was given devices
+    of its own, over that service's own HBM budget."""
+    return dscope.current().owned("block_cache",
+                                  lambda _sc: DeviceBlockCache())
 
 
 def active() -> Optional[DeviceBlockCache]:

@@ -47,9 +47,13 @@ def fold_tags(out: Dict[bytes, set], tags, filter_set, name_only: bool):
 
 class Database:
     def __init__(self, shard_set, commitlog=None, clock: Callable[[], int] = None,
-                 retriever=None):
+                 retriever=None, scope=None):
         """shard_set: m3_tpu.sharding.ShardSet; commitlog: persist.CommitLog;
-        retriever: storage.retriever.BlockRetriever for disk-backed reads."""
+        retriever: storage.retriever.BlockRetriever for disk-backed reads;
+        scope: parallel.scope.DeviceScope of a node that owns some of the
+        attached devices — the threads that work for this database (its
+        RPC handlers, its mediator) enter it; None owns them all."""
+        self.scope = scope
         self.shard_set = shard_set
         self.commitlog = commitlog
         self.clock = clock or (lambda: time.time_ns())

@@ -17,6 +17,7 @@ import threading
 import time
 from typing import Dict, Optional
 
+from ..parallel import scope as dscope
 from ..persist.diskio import DiskWriteError
 from ..persist.fs import PersistManager
 from ..storage.block import encode_block
@@ -50,7 +51,9 @@ class Mediator:
         step; below them `encode.block` (storage/block.py) and
         `persist.write` (persist/fs.py) per block; the tick's stats are
         the root's tags."""
-        with tracing.background_span("mediator.tick") as root:
+        with dscope.entered(self.db.scope), \
+                tracing.background_span("mediator.tick") as root:
+            root.set_tag("device", dscope.device_tag())
             now = now_ns if now_ns is not None else self.db.clock()
             with tracing.child_span("mediator.seal"):
                 stats = dict(self.db.tick(now))

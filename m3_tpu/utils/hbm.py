@@ -179,20 +179,22 @@ class HBMBudget:
         return freed
 
 
-_SHARED: Optional[HBMBudget] = None
-_SHARED_LOCK = threading.Lock()
-
-
 def shared_budget() -> HBMBudget:
-    """The process-wide budget (`M3_TPU_HBM_BUDGET_BYTES`, default 2GiB).
-    First use wires `pressure()` into the process HealthTracker as the
-    memory-pressure probe beside the admission gates' depth probes."""
-    global _SHARED
-    with _SHARED_LOCK:
-        if _SHARED is None:
-            _SHARED = HBMBudget(int(os.environ.get(
-                "M3_TPU_HBM_BUDGET_BYTES", str(DEFAULT_BUDGET_BYTES))))
-            from .health import TRACKER
+    """The budget of the calling thread's scope (parallel/scope.py): the
+    process-wide one, or that of a service that was given devices of its
+    own (`M3_TPU_HBM_BUDGET_BYTES` each, default 2GiB). First use wires
+    `pressure()` into the process HealthTracker as the memory-pressure
+    probe beside the admission gates' depth probes."""
+    from ..parallel import scope as dscope
 
-            TRACKER.register("hbm_pressure", _SHARED.pressure)
-        return _SHARED
+    return dscope.current().owned("hbm", _make_budget)
+
+
+def _make_budget(sc) -> HBMBudget:
+    budget = HBMBudget(int(os.environ.get(
+        "M3_TPU_HBM_BUDGET_BYTES", str(DEFAULT_BUDGET_BYTES))))
+    from .health import TRACKER
+
+    TRACKER.register("hbm_pressure" + ("." + sc.name if sc.name else ""),
+                     budget.pressure)
+    return budget

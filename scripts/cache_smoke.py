@@ -29,6 +29,7 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 # Exercise the seal-time device-buffer retention path even on CPU hosts.
 os.environ.setdefault("M3_TPU_BLOCK_CACHE_RETAIN", "1")
 
+from m3_tpu.parallel import scope as dscope  # noqa: E402
 from m3_tpu.parallel.sharding import ShardSet  # noqa: E402
 from m3_tpu.storage import block_cache  # noqa: E402
 from m3_tpu.storage.block_cache import DeviceBlockCache  # noqa: E402
@@ -67,7 +68,7 @@ def main() -> int:
     # --- 1. warm hit-rate + bit-identity + seal retention -----------------
     cache = DeviceBlockCache(budget=HBMBudget(256 * 1024 * 1024),
                              admit_after=2)
-    block_cache._CACHE = cache
+    dscope.DEFAULT.put("block_cache", cache)
     db, ids = build_db(n_series=200, n_blocks=2, ppb=48)
     assert cache.stats()["retained"] >= 2, \
         f"seal did not retain encoded device buffers: {cache.stats()}"
@@ -105,7 +106,7 @@ def main() -> int:
     # the real budget must not defuse the smoke's eviction scenario.
     tiny_bytes = int(os.environ.get("CACHE_SMOKE_TINY_BYTES", "16384"))
     tiny = DeviceBlockCache(budget=HBMBudget(tiny_bytes), admit_after=1)
-    block_cache._CACHE = tiny
+    dscope.DEFAULT.put("block_cache", tiny)
     for j in range(60):
         got = db.read(b"smoke", ids[mix[j]], *span)
         with block_cache.disabled():
@@ -117,7 +118,7 @@ def main() -> int:
     assert tiny.resident_bytes() <= 64 * tiny_bytes, ts
 
     # --- 3. zero residency after namespace close -------------------------
-    block_cache._CACHE = cache
+    dscope.DEFAULT.put("block_cache", cache)
     run_mix()  # re-warm the main cache
     assert cache.stats()["bytes"] > 0
     db.close()

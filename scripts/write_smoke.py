@@ -43,6 +43,7 @@ compile_cache.configure()
 from m3_tpu.index import query as iq  # noqa: E402
 from m3_tpu.index.namespace_index import NamespaceIndex  # noqa: E402
 from m3_tpu.parallel import ingest as par_ingest  # noqa: E402
+from m3_tpu.parallel import scope as dscope  # noqa: E402
 from m3_tpu.parallel.sharding import ShardSet  # noqa: E402
 from m3_tpu.storage import block as storage_block  # noqa: E402
 from m3_tpu.storage.database import Database  # noqa: E402
@@ -173,12 +174,12 @@ def check_mesh_bit_equality(rng) -> str:
     mesh_blk = storage_block.encode_block(T0, series, ts, vals, npts)
     assert counter.value() == before + 1, "flush encode did not route mesh"
     os.environ["M3_TPU_MESH_FLUSH"] = "0"
-    par_ingest.flush_mesh.cache_clear()
+    dscope.DEFAULT.clear("flush_mesh")
     try:
         single_blk = storage_block.encode_block(T0, series, ts, vals, npts)
     finally:
         del os.environ["M3_TPU_MESH_FLUSH"]
-        par_ingest.flush_mesh.cache_clear()
+        dscope.DEFAULT.clear("flush_mesh")
     assert np.array_equal(mesh_blk.words, single_blk.words), \
         "mesh words != single-device words"
     assert np.array_equal(mesh_blk.nbits, single_blk.nbits), \

@@ -9,6 +9,7 @@ across tenants, and the upload-cache counter export."""
 import numpy as np
 import pytest
 
+from m3_tpu.parallel import scope as dscope
 from m3_tpu.storage import block_cache
 from m3_tpu.storage.block import SealedBlock, WiredList, encode_block
 from m3_tpu.storage.block_cache import DeviceBlockCache
@@ -27,7 +28,7 @@ def cache(monkeypatch):
     own budget (no cross-test residency, no shared-budget coupling)."""
     budget = HBMBudget(64 * 1024 * 1024)
     c = DeviceBlockCache(budget=budget, admit_after=2)
-    monkeypatch.setattr(block_cache, "_CACHE", c)
+    monkeypatch.setitem(dscope.DEFAULT._owned, "block_cache", c)
     return c
 
 
@@ -349,7 +350,7 @@ class TestBudget:
     def test_eviction_under_tiny_budget(self, monkeypatch):
         budget = HBMBudget(4096)
         c = DeviceBlockCache(budget=budget, admit_after=1)
-        monkeypatch.setattr(block_cache, "_CACHE", c)
+        monkeypatch.setitem(dscope.DEFAULT._owned, "block_cache", c)
         rng = np.random.default_rng(11)
         blocks = [make_block(rng, s=8, w=64) for _ in range(4)]
         for blk in blocks:

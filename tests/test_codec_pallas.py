@@ -293,3 +293,48 @@ class TestGuardRouteMatrix:
             assert now[f"pallas_{kern}"] == before[f"pallas_{kern}"] + 1
         for a, b in zip(ref, got):
             np.testing.assert_array_equal(self._bits(a), self._bits(b))
+
+
+class TestFewRowPlanes:
+    """Planes of 1-16 rows on the Pallas route (interpret mode here; the
+    same function runs compiled in chip_smoke.py's codec twins): equal
+    to the XLA twin and to the written samples over block starts either
+    side of many low-word wraps, and encoded alone as among sixteen."""
+
+    @pytest.mark.parametrize("rows", [1, 2, 3, 5, 8, 16])
+    def test_few_rows_equal_twin_and_written(self, rows, monkeypatch):
+        import chip_smoke
+
+        monkeypatch.setenv("M3_TPU_PALLAS", "1")
+        before = telemetry.snapshot().get("telemetry.codec.pallas_decode", 0)
+        assert chip_smoke.few_row_twin_faults(32 + rows, rows=(rows,),
+                                              starts=3) == []
+        assert telemetry.snapshot().get(
+            "telemetry.codec.pallas_decode", 0) > before
+
+    @pytest.mark.parametrize("route,rows_built", [("pallas", 2), ("xla", 1)])
+    def test_a_lone_row_goes_twice_on_the_pallas_route_only(
+            self, route, rows_built, monkeypatch):
+        monkeypatch.setenv("M3_TPU_PALLAS", "1" if route == "pallas" else "0")
+        ts, vals, npoints = _corpus(3, 4, 16)
+        words, _ = tsz.encode(ts, vals, max_words=tsz.max_words_for(16))
+        words = np.asarray(words)
+        built = []
+        real = tsz._decode_fused_jit
+
+        def spy(*key):
+            run = real(*key)
+            return lambda w, n: (built.append((key[3], w.shape[0])),
+                                 run(w, n))[1]
+
+        monkeypatch.setattr(tsz, "_decode_fused_jit", spy)
+        one = tsz.decode_plane(words[2:3], npoints[2:3], window=16,
+                               unit_nanos=1, with_f32=True)
+        assert built == [(route, rows_built)]
+        monkeypatch.setattr(tsz, "_decode_fused_jit", real)
+        four = tsz.decode_plane(words, npoints, window=16, unit_nanos=1,
+                                with_f32=True)
+        for a, b in zip(one, four):
+            assert a.shape[0] == 1
+            np.testing.assert_array_equal(a.view(np.uint8),
+                                          b[2:3].view(np.uint8))

@@ -191,8 +191,9 @@ class TestLockdepCheck:
 
     def test_green_on_statically_known_edge(self, tmp_path):
         d = dict(self.BASE)
-        d["edges"] = [{"from": "hbm._SHARED_LOCK", "to": "HealthTracker._lock",
-                       "count": 1, "blocked": 0, "site": "hbm.py:1"}]
+        d["edges"] = [{"from": "Database._ns_lock",
+                       "to": "HealthTracker._lock", "count": 1,
+                       "blocked": 0, "site": "database.py:1"}]
         out = _run_check(tmp_path, d)
         assert out.returncode == 0, out.stdout + out.stderr
         assert "GREEN" in out.stdout

@@ -108,3 +108,29 @@ def test_whole_codec_programs_build(monkeypatch):
     # drop the compiled-mode traces: the CPU backend cannot run them
     tsz._decode_fused_jit.cache_clear()
     tsz._encode_batch.clear_cache()
+
+
+def test_the_program_a_lone_row_gets_holds_no_degenerate_reduce(monkeypatch):
+    """decode_plane sends a lone row twice on the Pallas route, so the
+    program it builds is the two-row one, and that one holds no reduce
+    at all. The one-row program cuts the kernel's tile to one lane, and
+    XLA:TPU lowers the degenerate reshapes that follow ([window, 1] ->
+    [1, window] -> [1, window, 2]) as u32 reduce-adds over a one-wide
+    dimension inside the fused unit multiply; on a v5e that read 12 of
+    a row's 128 timestamps ~2^31 ns low (PR 32)."""
+    dev = _compile_only_device()
+    if dev is None:
+        pytest.skip("no compile-only TPU topology in this installation")
+    monkeypatch.setattr(pc, "_interpret", lambda: False)
+    tsz._decode_fused_jit.cache_clear()
+    window = 128
+    mw = tsz.max_words_for(window)
+    reduces = {}
+    for rows in (2, 8):
+        args = [jax.ShapeDtypeStruct(s, d, sharding=SingleDeviceSharding(dev))
+                for s, d in (((rows, mw), U32), ((rows,), I32))]
+        text = tsz._decode_fused_jit(window, 10**9, False, "pallas").trace(
+            *args).lower(lowering_platforms=("tpu",)).compile().as_text()
+        reduces[rows] = text.count(" reduce(")
+    tsz._decode_fused_jit.cache_clear()
+    assert reduces == {2: 0, 8: 0}

@@ -17,6 +17,7 @@ import pytest
 from m3_tpu.index import query as iq
 from m3_tpu.index.namespace_index import NamespaceIndex
 from m3_tpu.parallel import ingest as par_ingest
+from m3_tpu.parallel import scope as dscope
 from m3_tpu.parallel.sharding import ShardSet
 from m3_tpu.storage import block as storage_block
 from m3_tpu.storage.block import encode_block, merge_same_start
@@ -385,13 +386,13 @@ class TestMeshFlushEncode:
         assert counter.value() == before + 1
         # Single-device reference path.
         monkeypatch.setenv("M3_TPU_MESH_FLUSH", "0")
-        par_ingest.flush_mesh.cache_clear()
+        dscope.DEFAULT.clear("flush_mesh")
         try:
             single_blk = encode_block(T0, series, ts, vals, npts)
             assert counter.value() == before + 1  # did NOT route
         finally:
             monkeypatch.undo()
-            par_ingest.flush_mesh.cache_clear()
+            dscope.DEFAULT.clear("flush_mesh")
         np.testing.assert_array_equal(mesh_blk.words, single_blk.words)
         np.testing.assert_array_equal(mesh_blk.nbits, single_blk.nbits)
         np.testing.assert_array_equal(mesh_blk.npoints, single_blk.npoints)
