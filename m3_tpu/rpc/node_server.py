@@ -17,7 +17,7 @@ import socket
 import socketserver
 import threading
 import time
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional
 
 import numpy as np
 
@@ -27,6 +27,7 @@ from ..storage.series import charge_read
 from ..utils import limits as xlimits
 from ..utils import tracing
 from ..utils.health import AdmissionGate, Priority
+from ..utils.instrument import ROOT
 from ..utils.limits import ResourceExhausted
 from ..utils.retry import Deadline, DeadlineExceeded
 from ..utils.tracing import clock_ns as _clock
@@ -35,6 +36,43 @@ from . import wire
 
 class RPCError(Exception):
     """Server-side error carried back over the wire."""
+
+
+# A fetch_tagged frame, counted: the tiles it carries and the (shard,
+# block) gathers folded into them.
+_FRAME_TILES = ROOT.counter("rpc.fetch_tagged.tiles")
+_FRAME_SHARD_BLOCKS = ROOT.counter("rpc.fetch_tagged.shard_blocks")
+
+# The most rows a fetch_tagged tile carries (~7.8 MB of words at a
+# 120-point block's 475): a read of every series still charges, and can
+# be refused, tile by tile, and concatenates no whole block start.
+TILE_MAX_ROWS = 4096
+# The most series a fetch_tagged buffer sweep reads under one
+# acquisition of a shard's write lock.
+BUFFER_CHUNK = 256
+
+
+def _cut_rows(pieces: list, bound: int):
+    """Lists of (block, rows, positions) pieces of at most `bound` rows
+    each, in order; a piece that straddles a cut is split."""
+    cur, room = [], bound
+    for blk, rows, poss in pieces:
+        while len(rows) >= room:
+            cur.append((blk, rows[:room], poss[:room]))
+            yield cur
+            rows, poss = rows[room:], poss[room:]
+            cur, room = [], bound
+        if len(rows):
+            cur.append((blk, rows, poss))
+            room -= len(rows)
+    if cur:
+        yield cur
+
+
+def _column(parts: list, dtype=np.int32) -> np.ndarray:
+    """A tile's column from its pieces' gathers, converted once."""
+    col = parts[0] if len(parts) == 1 else np.concatenate(parts)
+    return col if dtype is None else col.astype(dtype, copy=False)
 
 
 # Priority classification for admission control: the traffic whose loss
@@ -236,16 +274,23 @@ class NodeService:
 
     def rpc_fetch_tagged(self, ns: bytes, query: dict, start_ns: int, end_ns: int,
                          fetch_data: bool = True, limit: int = 0):
-        """FetchTagged with a COLUMNAR result frame: per-series entries
-        carry only identity (id + tags — host label algebra); the data
-        plane rides beside them as ONE buffer sidecar (concatenated
-        mutable-buffer columns + an offsets vector) and one TILE per
-        (shard, sealed block) — the requested rows fancy-indexed out of
-        the block's word matrix in one numpy op, the same tile shape
-        peer streaming moves (rpc_fetch_block_tiles) and the client's
-        batched device decode consumes (client/decode.decode_tile).
-        Pre-change this loop built one dict of segments per series —
-        per-row python materialization on the hot read fan-in."""
+        """FetchTagged with a COLUMNAR result frame, built in one pass
+        over the query's ids as arrays: per-series entries carry only
+        identity (id + tags — host label algebra); the data plane rides
+        beside them as ONE buffer sidecar (concatenated mutable-buffer
+        columns + an offsets vector) and one TILE PER BLOCK START — the
+        requested rows of every shard's block at that start, fancy-
+        indexed out of the blocks' word matrices and concatenated, with
+        `rows` their positions in `series`: the tile shape peer
+        streaming moves (rpc_fetch_block_tiles) and the client's batched
+        device decode consumes (client/decode.decode_tile). Blocks of
+        one start that differ in window, time unit or words width get a
+        tile each, and a tile is cut at TILE_MAX_ROWS, so a frame is
+        charged, and can be refused, tile by tile. The reference streams
+        per-series segments instead (DIVERGENCES.md). A dashboard read
+        puts a series or two in a shard, so nothing here is paid once a
+        series but its buffer read, nor once a (shard, block) but its
+        row resolve and three gathers."""
         q = wire.query_from_wire(query)
         nsobj = self.db.namespace(ns)
         # Under a detailed span (a traced request's rpc.fetch_tagged) the
@@ -256,37 +301,65 @@ class NodeService:
         t_start = _clock() if timed else 0
         ids = self.db.query_ids(ns, q, start_ns, end_ns, limit=limit)
         t_index = _clock() if timed else 0
-        tile_ns = 0
-        out = []
-        by_shard: Dict[int, List[Tuple[int, int]]] = {}  # -> (idx, pos)
-        for sid in ids:
-            # Mid-loop budget check: fetch_tagged is the expensive fan-in;
-            # a dead caller's request must stop here, not run the whole
-            # result set to completion.
+        # Route once: the ids' shards from the write path's memo, rows
+        # grouped by shard with one stable sort (Database.write_batch's
+        # routing pass, read side).
+        shards = dict(nsobj.shards)
+        shard_ids = self.db.shard_set.lookup_memo(ids)
+        if len(shards) < self.db.shard_set.num_shards:
+            # An id of a shard this node does not hold leaves no row.
+            held = np.isin(shard_ids, np.fromiter(shards, np.int32, len(shards)))
+            ids = [ids[i] for i in np.flatnonzero(held).tolist()]
+            shard_ids = shard_ids[held]
+        n = len(ids)
+        order = np.argsort(shard_ids, kind="stable")
+        by_shard = shard_ids[order]
+        cuts = (np.flatnonzero(by_shard[1:] != by_shard[:-1]) + 1).tolist()
+        order, by_shard = order.tolist(), by_shard.tolist()
+        out: List[Optional[dict]] = [None] * n
+        groups = []  # (shard, registry indices, positions in `out`)
+        owed = owed_n = 0  # identity bytes, and their series, not yet charged
+        for a, b in zip([0] + cuts, cuts + [n]) if n else ():
+            # Once a shard, not once a series: fetch_tagged is the
+            # expensive fan-in, and a dead caller's request must stop
+            # inside it, not run the whole result set to completion.
             self._check_deadline("fetch_tagged")
-            shard_id = self.db.shard_set.lookup(sid)
-            shard = nsobj.shards.get(shard_id)
-            if shard is None:
-                continue
-            idx = shard.registry.get(sid)
-            if idx is None:
+            shard = shards[by_shard[a]]
+            poss = order[a:b]
+            sids = [ids[pos] for pos in poss]
+            idxs = shard.registry.lookup_batch(sids).tolist()
+            if -1 in idxs:
                 # Indexed on another replica's time range but not written
                 # here: identity-only row, no buffer/tile contribution.
-                out.append({"id": sid, "tags": {}})
-                continue
-            # identity cost (id + tag pairs) charges bytes-read before the
-            # segment payloads do — a tags-only fetch is still metered
-            charge_read(n_bytes=shard.registry.entry_bytes(idx))
+                known = []
+                for j, idx in enumerate(idxs):
+                    if idx < 0:
+                        out[poss[j]] = {"id": sids[j], "tags": {}}
+                    else:
+                        known.append(j)
+                poss, sids, idxs = ([col[j] for j in known]
+                                    for col in (poss, sids, idxs))
+                if not idxs:
+                    continue
+            # identity cost (id + tag pairs) charges bytes-read before any
+            # segment payload does — a tags-only fetch is still metered.
+            # Charged whenever a buffer chunk's worth of series is owed
+            # and at the sweep's end, not once a group: a dashboard
+            # read's groups hold a series or two.
+            tags, n_bytes = shard.registry.identities(idxs)
+            owed, owed_n = owed + n_bytes, owed_n + len(idxs)
+            if owed_n >= BUFFER_CHUNK:
+                charge_read(n_bytes=owed)
+                owed = owed_n = 0
+            for pos, sid, tg in zip(poss, sids, tags):
+                out[pos] = {"id": sid, "tags": tg or {}}
             if fetch_data:
-                by_shard.setdefault(shard_id, []).append((idx, len(out)))
-            out.append({"id": sid, "tags": shard.registry.tags_of(idx) or {}})
-        n = len(out)
+                groups.append((shard, idxs, poss))
+        charge_read(n_bytes=owed)
         buf_t = [np.zeros(0, np.int64)] * n
         buf_v = [np.zeros(0, np.float64)] * n
-        tiles: List[dict] = []
-        for shard_id in sorted(by_shard):
-            shard = nsobj.shards[shard_id]
-            members = by_shard[shard_id]
+        snapshots = []
+        for shard, idxs, poss in groups:
             # Buffer reads take the shard write lock in bounded CHUNKS —
             # a dashboard-sized member set must not stall every
             # concurrent write for one uninterrupted sweep (the
@@ -303,53 +376,74 @@ class NodeService:
             # materializes (query_limits.go bytes-read: reject an
             # oversized fetch mid fan-in).
             blocks: Dict[int, object] = {}
-            chunk = 256
-            for c0 in range(0, len(members), chunk):
+            for c0 in range(0, len(idxs), BUFFER_CHUNK):
                 self._check_deadline("fetch_tagged")
-                part = members[c0:c0 + chunk]
+                part = poss[c0:c0 + BUFFER_CHUNK]
                 with shard.write_lock:  # snapshot racing tick's expiry/seal
                     blocks.update(shard.blocks)
-                    for idx, pos in part:
+                    for idx, pos in zip(idxs[c0:c0 + BUFFER_CHUNK], part):
                         buf_t[pos], buf_v[pos] = shard.buffer.read(
                             idx, start_ns, end_ns)
                 charge_read(n_bytes=sum(
-                    buf_t[pos].nbytes + buf_v[pos].nbytes
-                    for _, pos in part))
-            t_tiles = _clock() if timed else 0
-            for bs in sorted(blocks):
-                blk = blocks[bs]
+                    buf_t[pos].nbytes + buf_v[pos].nbytes for pos in part))
+            snapshots.append(blocks)
+        t_tiles = _clock() if timed else 0
+        # A (shard, block)'s rows are resolved in one step and kept as a
+        # PIECE under what a tile's rows must share: block start, window,
+        # time unit, words width.
+        pieces: Dict[tuple, list] = {}
+        shard_blocks_n = 0
+        for (shard, idxs, poss), blocks in zip(groups, snapshots):
+            idxs_a = None
+            for bs, blk in blocks.items():
                 if bs + shard.opts.block_size_ns <= start_ns or bs >= end_ns:
                     continue
-                rows, poss = [], []
-                for idx, pos in members:
-                    row = blk.row_of(idx)
-                    if row is not None:
-                        rows.append(row)
-                        poss.append(pos)
-                if not rows:
+                si = blk.series_indices
+                held_n = len(si)
+                if not held_n:
                     continue
+                if idxs_a is None:
+                    idxs_a, poss_a = np.asarray(idxs), np.asarray(poss)
+                    top = max(idxs)
+                if si[-1] == held_n - 1 and top < held_n:
+                    # sorted, distinct and ending at its length: the block
+                    # holds every index below it, each in its own row
+                    piece = (blk, idxs_a, poss_a)
+                else:
+                    at = np.minimum(si.searchsorted(idxs_a), held_n - 1)
+                    present = si[at] == idxs_a
+                    if not present.any():
+                        continue
+                    piece = (blk, at[present], poss_a[present])
+                shard_blocks_n += 1
+                pieces.setdefault(
+                    (bs, int(blk.window), int(blk.time_unit),
+                     np.shape(blk.words)[-1]), []).append(piece)
+        tiles: List[dict] = []
+        for key in sorted(pieces):
+            bs, window, time_unit, width = key
+            for cut in _cut_rows(pieces[key], TILE_MAX_ROWS):
                 self._check_deadline("fetch_tagged")
                 # Charge BEFORE the tile materializes (query_limits.go
                 # bytes-read): an oversized result must be rejected mid
                 # fan-in, not after every tile copy has been allocated —
                 # the same incremental guard the per-series path had.
-                all_words = np.asarray(blk.words)
-                rows_a = np.asarray(rows, np.int64)
-                charge_read(
-                    n_bytes=len(rows) * all_words.shape[-1]
-                    * all_words.itemsize)
+                charge_read(n_bytes=sum(len(at) for _, at, _ in cut)
+                            * width * np.asarray(cut[0][0].words).itemsize)
                 tiles.append({
                     "bs": bs,
-                    "rows": np.asarray(poss, np.int32),
-                    "words": all_words[rows_a],
-                    "nbits": np.asarray(blk.nbits)[rows_a].astype(np.int32),
-                    "npoints": np.asarray(blk.npoints)[rows_a].astype(
-                        np.int32),
-                    "window": int(blk.window),
-                    "time_unit": int(blk.time_unit),
+                    "rows": _column([poss for _, _, poss in cut]),
+                    "words": _column(
+                        [np.asarray(blk.words)[at] for blk, at, _ in cut],
+                        None),
+                    "nbits": _column(
+                        [np.asarray(blk.nbits)[at] for blk, at, _ in cut]),
+                    "npoints": _column(
+                        [np.asarray(blk.npoints)[at] for blk, at, _ in cut]),
+                    "window": window,
+                    "time_unit": time_unit,
                 })
-            if timed:
-                tile_ns += _clock() - t_tiles
+        tile_ns = _clock() - t_tiles if timed else 0
         offs = np.zeros(n + 1, np.int64)
         if n:
             offs[1:] = np.cumsum([t.size for t in buf_t])
@@ -358,11 +452,15 @@ class NodeService:
             "t": (np.concatenate(buf_t) if n else np.zeros(0, np.int64)),
             "v": (np.concatenate(buf_v) if n else np.zeros(0, np.float64)),
         }
+        _FRAME_TILES.inc(len(tiles))
+        _FRAME_SHARD_BLOCKS.inc(shard_blocks_n)
         if timed:
             acc.add_cost("series_n", n)
             acc.add_cost("index_ns", t_index - t_start)
             acc.add_cost("tile_ns", tile_ns)
             acc.add_cost("read_ns", _clock() - t_index - tile_ns)
+            acc.add_cost("tiles_n", len(tiles))
+            acc.add_cost("shard_blocks_n", shard_blocks_n)
         return {"series": out, "bufs": bufs, "tiles": tiles,
                 "exhaustive": True}
 

@@ -168,18 +168,20 @@ class SeriesRegistry:
     def all_ids(self) -> List[bytes]:
         return list(self._ids)
 
-    def entry_bytes(self, idx: int) -> int:
-        """Approximate wire bytes for serving this series' identity (id +
-        tag pairs) — the per-series floor a tagged fetch pays before any
-        datapoint bytes. Feeds the bytes-read query limit (the registry
-        is the only id-keyed structure on the hot path, so identity-cost
-        accounting lives here with it)."""
-        n = len(self._ids[idx])
-        tags = self._tags[idx]
-        if tags:
-            for k, v in tags.items():
-                n += len(k) + len(v)
-        return n
+    def identities(self, idxs: Sequence[int]
+                   ) -> Tuple[List[Optional[dict]], int]:
+        """A sweep of indices' tags and the approximate wire bytes of
+        serving their identities (ids + tag pairs) — the floor a tagged
+        fetch pays for them before any datapoint bytes. Feeds the
+        bytes-read query limit (the registry is the only id-keyed
+        structure on the hot path, so identity-cost accounting lives
+        here with it). No Python frame a series but the tag walk."""
+        tags = list(map(self._tags.__getitem__, idxs))
+        n = sum(map(len, map(self._ids.__getitem__, idxs)))
+        for t in tags:
+            if t:
+                n += sum(map(len, t)) + sum(map(len, t.values()))
+        return tags, n
 
 
 def charge_read(n_series: int = 0, n_points: int = 0, n_bytes: int = 0):
