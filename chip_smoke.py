@@ -619,7 +619,10 @@ def phase_query_truth(ctx: Ctx):
 def phase_fileset_read(ctx: Ctx):
     """Read a sealed block back from its fileset: attach the disk
     retriever, evict the flushed in-memory blocks, drop the device block
-    cache, and query the oldest block's range."""
+    cache, and query the oldest block's range. The rows the retriever
+    seeks go into the fetch's one batched cold decode
+    (storage/read_batch.py: one dispatch a geometry, rows padded to a
+    bucket, never a one-row program: ROADMAP C16)."""
     from m3_tpu.storage import block_cache
     from m3_tpu.storage.retriever import BlockRetriever
 
@@ -630,6 +633,7 @@ def phase_fileset_read(ctx: Ctx):
     block_cache.get_cache().clear()
     check(evicted >= len(db.namespace(b"default").shards),
           f"evict_flushed dropped {evicted} blocks")
+    c0 = counters()
     start = T0 + 60 * S
     spec = dict(name="fileset_read", kind="range",
                 q='max_over_time(m{host=~"h00."}[1m])',
@@ -637,6 +641,13 @@ def phase_fileset_read(ctx: Ctx):
     phase_queries(ctx, [spec], label="fileset_query")
     check(retr.stats["seeks"] > 0,
           f"retriever stats {retr.stats}: the query never read a fileset")
+    cold = counters()
+    rows, calls = (cold.get(k, 0) - c0.get(k, 0) for k in (
+        "storage.read.cold_rows", "storage.read.cold_dispatches"))
+    check(rows >= retr.stats["seeks"] and 0 < calls < rows,
+          f"{rows} cold rows in {calls} decode dispatches for "
+          f"{retr.stats['seeks']} seeks: the fileset's rows were not "
+          "decoded in batches")
     # and against the written samples themselves (one series, exact)
     i = 7
     ts, vals = ctx.blocks[0][:2]
@@ -651,7 +662,8 @@ def phase_fileset_read(ctx: Ctx):
         want = win.max() if win.size else np.nan
         check(np.isclose(got[k], want, rtol=1e-5, atol=1e-5, equal_nan=True),
               f"fileset_read: step {k} served {got[k]} vs written {want}")
-    ctx.facts["fileset"] = {"evicted_blocks": evicted,
+    ctx.facts["fileset"] = {"evicted_blocks": evicted, "cold_rows": rows,
+                            "cold_dispatches": calls,
                             "retriever": dict(retr.stats)}
     say(f"sealed block served from its fileset: {ctx.facts['fileset']}")
 

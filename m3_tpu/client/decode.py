@@ -37,6 +37,13 @@ class ConflictStrategy(enum.Enum):
     HIGHEST_FREQUENCY_VALUE = "highest_frequency_value"
 
 
+# at least 8 rows a decode: a thin read's tiles hold 1-5 series of a
+# shard and a series' fetch 1-4 segments, and one shape serves them all
+# (the device pads rows to its 8 sublanes, and the Pallas route to 128
+# lanes, whatever is asked)
+TILE_MIN_ROWS = 8
+
+
 def decode_segment_groups(segments: Sequence[dict]) -> List[Tuple[np.ndarray, np.ndarray]]:
     """Decode wire segments -> [(t[int64], v[f64])] aligned with input order.
 
@@ -52,10 +59,13 @@ def decode_segment_groups(segments: Sequence[dict]) -> List[Tuple[np.ndarray, np
                int(seg.get("time_unit", int(xtime.Unit.NANOSECOND))))
         groups.setdefault(key, []).append(i)
     for (window, mw, unit), idxs in groups.items():
-        # Shape-bucket the batch: pad rows to a power of two so one compiled
-        # decode kernel serves every fetch with this block geometry.
+        # Shape-bucket the batch: pad rows to a power of two, at least a
+        # tile's floor, so one compiled decode kernel serves every fetch
+        # with this block geometry and a series' one, two or four
+        # segments are no programs of their own (nor a lone row one:
+        # tsz.decode_plane).
         rows = len(idxs)
-        rp = 1 << (max(rows, 1) - 1).bit_length()
+        rp = max(TILE_MIN_ROWS, 1 << (max(rows, 1) - 1).bit_length())
         words = np.zeros((rp, mw), np.uint32)
         npoints = np.zeros(rp, np.int32)
         for r, i in enumerate(idxs):
@@ -74,9 +84,6 @@ def decode_segment_groups(segments: Sequence[dict]) -> List[Tuple[np.ndarray, np
     return out
 
 
-TILE_MIN_ROWS = 8
-
-
 def decode_tile(words, npoints, window: int, time_unit: int
                 ) -> Tuple[np.ndarray, np.ndarray]:
     """Decode one columnar block tile ([rows, max_words] words +
@@ -90,9 +97,6 @@ def decode_tile(words, npoints, window: int, time_unit: int
     words = np.asarray(words)
     npoints = np.asarray(npoints, np.int32)
     n = words.shape[0]
-    # at least 8 rows: a thin read's tiles hold 1-5 series of a shard,
-    # and one shape serves them all (the device pads rows to its 8
-    # sublanes, and the Pallas route to 128 lanes, whatever is asked)
     rp = max(TILE_MIN_ROWS, 1 << (max(n, 1) - 1).bit_length())
     if rp != n:
         words = np.concatenate([words, np.repeat(words[:1], rp - n, 0)])

@@ -1031,6 +1031,7 @@ class Session:
         mutable buffer — the same precedence the per-series segment path
         had, so LAST_PUSHED conflict resolution is unchanged. `acc`
         takes what the decodes and the per-series merges cost."""
+        from ..storage.tiles import decode_stacked
         from .decode import decode_tile
 
         n = len(r["series"])
@@ -1042,30 +1043,19 @@ class Session:
         # of one window, unit and stream width stack into one call
         # (3.35 ms of host time a call on the chip's host, PERF.md
         # section 6, PR 32).
-        tiles = sorted(r.get("tiles", ()), key=lambda d: d["bs"])
-        groups: Dict[tuple, List[dict]] = {}
-        for tile in tiles:
-            groups.setdefault(
-                (int(tile["window"]), int(tile["time_unit"]),
-                 int(np.asarray(tile["words"]).shape[-1])), []).append(tile)
-        for (window, unit, _mw), members in groups.items():
+        def decode(words, npoints, window, unit):
             t0 = _clock()
-            words = [np.asarray(t["words"]) for t in members]
-            npts = [np.asarray(t["npoints"], np.int32) for t in members]
-            ts, vs = decode_tile(
-                words[0] if len(members) == 1 else np.concatenate(words),
-                npts[0] if len(members) == 1 else np.concatenate(npts),
-                window, unit)
+            ts, vs = decode_tile(words, npoints, window, unit)
             acc.decode_ns += _clock() - t0
             acc.decode_n += 1
             acc.d2h_bytes += ts.nbytes + vs.nbytes
-            at = 0
-            for tile, k_rows in zip(members, npts):
-                for j, (pos, k) in enumerate(zip(
-                        np.asarray(tile["rows"]).tolist(), k_rows.tolist())):
-                    parts_t[pos].append(ts[at + j, :k])
-                    parts_v[pos].append(vs[at + j, :k])
-                at += len(k_rows)
+            return ts, vs
+
+        for tile, ks, ts, vs in decode_stacked(r.get("tiles", ()), decode):
+            for j, (pos, k) in enumerate(zip(
+                    np.asarray(tile["rows"]).tolist(), ks.tolist())):
+                parts_t[pos].append(ts[j, :k])
+                parts_v[pos].append(vs[j, :k])
         t0 = _clock()
         bufs = r.get("bufs")
         if bufs is not None:

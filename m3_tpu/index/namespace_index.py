@@ -192,6 +192,21 @@ class NamespaceIndex:
             known.update(d.id for d in fresh)
             self._block_for(t_ns).insert_many(fresh)
 
+    def index_in_block(self, items: List[Tuple[bytes, dict]],
+                       block_start: int):
+        """Documents of series written in the index block at
+        `block_start` that the block does not hold yet (the reference's
+        entry.IndexedForBlockStart: a series is indexed in every index
+        block it is written in, so a query that overlaps only a later
+        block finds it). The caller — a shard's write path, by its
+        registry's marks — is the gate: `_known` is not consulted, and
+        learns the ids, so a first sighting through `insert` after this
+        is none. The block's mutable segment takes an id once."""
+        docs = [tags_to_doc(sid, tags) for sid, tags in items]
+        with self._lock:
+            self._known.update(d.id for d in docs)
+            self._block_for(block_start).insert_many(docs)
+
     def _snapshot_segments(self, start_ns, end_ns) -> List[ImmutableSegment]:
         """Frozen immutable views of every overlapping block. The lock is
         held only for dict snapshots and doc-list copies; the actual

@@ -57,6 +57,8 @@ CHECKPOINT_FILE = "checkpoint.json"
 SUMMARY_EVERY = 32
 
 _IDX_HEADER = struct.Struct("<IIiiI")  # id_len, row, nbits, npoints, checksum
+_IDX_DTYPE = np.dtype([("id_len", "<u4"), ("row", "<u4"), ("nbits", "<i4"),
+                       ("npoints", "<i4"), ("checksum", "<u4")])
 
 
 def fileset_dir(root: str, namespace: bytes, shard: int, block_start: int,
@@ -84,6 +86,24 @@ class FilesetWriter:
     def write(self, namespace: bytes, shard: int, blk: SealedBlock, registry,
               snapshot_version: Optional[int] = None,
               wal_position: Optional[Tuple[int, int]] = None) -> str:
+        # A block of part of its shard's series (the bucket a scrape
+        # has begun to fill when a snapshot meets it; a block some
+        # series missed) has a row count of its own, and the bloom's
+        # device hash is a program a row count: such a block hashes on
+        # the host, the same bits, and compiles nothing inside a tick.
+        return self.write_rows(
+            namespace, shard, blk,
+            list(map(registry.id_of, blk.series_indices.tolist())),
+            snapshot_version, wal_position,
+            odd_sized=len(blk.series_indices) != len(registry))
+
+    def write_rows(self, namespace: bytes, shard: int, blk: SealedBlock,
+                   ids: Sequence[bytes],
+                   snapshot_version: Optional[int] = None,
+                   wal_position: Optional[Tuple[int, int]] = None,
+                   odd_sized: bool = False) -> str:
+        """`write` for a block whose rows' series ids are in hand (row i
+        is `ids[i]`), with no registry to ask."""
         d = fileset_dir(self.root, namespace, shard, blk.block_start, snapshot_version)
         tmp = d + ".tmp"
         try:
@@ -93,8 +113,8 @@ class FilesetWriter:
                     "persist.write",
                     volume="flush" if snapshot_version is None
                     else "snapshot") as sp:
-                out = self._write(d, tmp, blk, registry, snapshot_version,
-                                  wal_position)
+                out = self._write(d, tmp, blk, ids, snapshot_version,
+                                  wal_position, odd_sized)
                 if sp.sampled:
                     sp.set_tag("bytes", sum(
                         os.path.getsize(os.path.join(out, name))
@@ -108,9 +128,10 @@ class FilesetWriter:
                 raise
             raise classify_write_error(e, d) from e
 
-    def _write(self, d: str, tmp: str, blk: SealedBlock, registry,
-               snapshot_version: Optional[int],
-               wal_position: Optional[Tuple[int, int]]) -> str:
+    def _write(self, d: str, tmp: str, blk: SealedBlock,
+               ids: Sequence[bytes], snapshot_version: Optional[int],
+               wal_position: Optional[Tuple[int, int]],
+               odd_sized: bool = False) -> str:
         os.makedirs(tmp, exist_ok=True)
 
         words = np.ascontiguousarray(blk.words, np.uint32)
@@ -119,26 +140,33 @@ class FilesetWriter:
 
         # Index entries sorted by series id (the write path buffers and sorts,
         # write.go WriteAll) with per-row data checksums — one vectorized
-        # adler pass over the whole codeword matrix, not a per-row loop.
-        ids = [registry.id_of(int(si)) for si in blk.series_indices]
-        order = sorted(range(len(ids)), key=lambda i: ids[i])
-        bloom = BloomFilter.for_capacity(len(ids))
-        bloom.add_batch([ids[i] for i in order])
-        row_sums = adler32_rows(words) if len(ids) else np.zeros(0, np.int64)
-        index_offsets: List[Tuple[bytes, int]] = []
+        # adler pass over the whole codeword matrix, not a per-row loop —
+        # their headers packed as one array and each file written once.
+        n = len(ids)
+        order = sorted(range(n), key=ids.__getitem__)
+        sorted_ids = [ids[i] for i in order]
+        bloom = BloomFilter.for_capacity(n)
+        bloom.add_batch(sorted_ids, on_host=odd_sized)
+        row_sums = adler32_rows(words) if n else np.zeros(0, np.int64)
+        at = np.asarray(order, np.int64)
+        id_lens = np.fromiter(map(len, sorted_ids), np.int64, count=n)
+        heads = np.empty(n, _IDX_DTYPE)
+        heads["id_len"], heads["row"] = id_lens, at
+        heads["nbits"] = np.asarray(blk.nbits)[at]
+        heads["npoints"] = np.asarray(blk.npoints)[at]
+        heads["checksum"] = row_sums[at]
+        packed = heads.tobytes()
+        size = _IDX_HEADER.size
+        entry_at = (np.cumsum(id_lens + size) - id_lens - size).tolist()
         with _io.open(os.path.join(tmp, INDEX_FILE), "wb") as f:
-            for i in order:
-                entry = _IDX_HEADER.pack(
-                    len(ids[i]), i, int(blk.nbits[i]), int(blk.npoints[i]),
-                    int(row_sums[i]),
-                )
-                index_offsets.append((ids[i], f.tell()))
-                f.write(entry)
-                f.write(ids[i])
+            f.write(b"".join(
+                part for j, sid in enumerate(sorted_ids)
+                for part in (packed[j * size:(j + 1) * size], sid)))
         with _io.open(os.path.join(tmp, SUMMARIES_FILE), "wb") as f:
-            for sid, off in index_offsets[::SUMMARY_EVERY]:
-                f.write(struct.pack("<IQ", len(sid), off))
-                f.write(sid)
+            f.write(b"".join(
+                part for j in range(0, n, SUMMARY_EVERY)
+                for part in (struct.pack("<IQ", len(sorted_ids[j]),
+                                         entry_at[j]), sorted_ids[j])))
         with _io.open(os.path.join(tmp, BLOOM_FILE), "wb") as f:
             f.write(bloom.tobytes())
 

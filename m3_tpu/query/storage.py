@@ -12,6 +12,7 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
+from ..storage.read_batch import read_many
 from ..utils import tracing
 from ..utils.tracing import clock_ns as _clock
 from .model import Matcher, matchers_to_index_query
@@ -32,28 +33,21 @@ class LocalStorage:
         ids = self._db.query_ids(self._namespace, q, start_ns, end_ns)
         out: Dict[bytes, dict] = {}
         ns = self._db.namespace(self._namespace)
-        # Under a detailed span (the caller's query.fetch) the loop's
+        # Under a detailed span (the caller's query.fetch) the read's
         # phases become costs of that span, never child spans: its self
-        # time stays the loop's whole time. One flag read per fetch.
+        # time stays the read's whole time. One flag read per fetch.
         acc = tracing.detail()
         timed = acc is not None
         t_loop = _clock() if timed else 0
-        tags_ns = 0
-        for sid in ids:
-            shard_id = self._db.shard_set.lookup(sid)
-            shard = ns.shards.get(shard_id)
-            if shard is None:
-                continue
-            t, v = shard.read(sid, start_ns, end_ns, acc)
-            t0 = _clock() if timed else 0
-            idx = shard.registry.get(sid)
-            tags = shard.registry.tags_of(idx) if idx is not None else {}
-            if timed:
-                tags_ns += _clock() - t0
-            out[sid] = {"tags": tags or {}, "t": t, "v": v}
+        # One routed sweep for all the ids (storage/read_batch.py): the
+        # rows no cache holds are decoded one dispatch a geometry, not
+        # one a (series, block).
+        for sid, got in zip(ids, read_many(ns, self._db.shard_set, ids,
+                                           start_ns, end_ns, acc)):
+            if got is not None:
+                out[sid] = {"tags": got[0] or {}, "t": got[1], "v": got[2]}
         if timed:
             acc.add_cost("series_n", len(ids))
-            acc.add_cost("tags_ns", tags_ns)
             acc.add_cost("read_ns", _clock() - t_loop)
         return out
 

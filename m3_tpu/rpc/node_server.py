@@ -24,6 +24,7 @@ import numpy as np
 from ..parallel import scope as dscope
 from ..storage.database import Database
 from ..storage.series import charge_read
+from ..storage.tiles import gather_tiles, piece_key
 from ..utils import limits as xlimits
 from ..utils import tracing
 from ..utils.health import AdmissionGate, Priority
@@ -50,29 +51,6 @@ TILE_MAX_ROWS = 4096
 # The most series a fetch_tagged buffer sweep reads under one
 # acquisition of a shard's write lock.
 BUFFER_CHUNK = 256
-
-
-def _cut_rows(pieces: list, bound: int):
-    """Lists of (block, rows, positions) pieces of at most `bound` rows
-    each, in order; a piece that straddles a cut is split."""
-    cur, room = [], bound
-    for blk, rows, poss in pieces:
-        while len(rows) >= room:
-            cur.append((blk, rows[:room], poss[:room]))
-            yield cur
-            rows, poss = rows[room:], poss[room:]
-            cur, room = [], bound
-        if len(rows):
-            cur.append((blk, rows, poss))
-            room -= len(rows)
-    if cur:
-        yield cur
-
-
-def _column(parts: list, dtype=np.int32) -> np.ndarray:
-    """A tile's column from its pieces' gathers, converted once."""
-    col = parts[0] if len(parts) == 1 else np.concatenate(parts)
-    return col if dtype is None else col.astype(dtype, copy=False)
 
 
 # Priority classification for admission control: the traffic whose loss
@@ -398,51 +376,30 @@ class NodeService:
             for bs, blk in blocks.items():
                 if bs + shard.opts.block_size_ns <= start_ns or bs >= end_ns:
                     continue
-                si = blk.series_indices
-                held_n = len(si)
-                if not held_n:
+                if not len(blk.series_indices):
                     continue
                 if idxs_a is None:
                     idxs_a, poss_a = np.asarray(idxs), np.asarray(poss)
                     top = max(idxs)
-                if si[-1] == held_n - 1 and top < held_n:
-                    # sorted, distinct and ending at its length: the block
-                    # holds every index below it, each in its own row
-                    piece = (blk, idxs_a, poss_a)
+                at, present = blk.rows_of(idxs_a, top)
+                if present is None:
+                    piece = (blk, at, poss_a)
+                elif len(at):
+                    piece = (blk, at, poss_a[present])
                 else:
-                    at = np.minimum(si.searchsorted(idxs_a), held_n - 1)
-                    present = si[at] == idxs_a
-                    if not present.any():
-                        continue
-                    piece = (blk, at[present], poss_a[present])
+                    continue
                 shard_blocks_n += 1
-                pieces.setdefault(
-                    (bs, int(blk.window), int(blk.time_unit),
-                     np.shape(blk.words)[-1]), []).append(piece)
-        tiles: List[dict] = []
-        for key in sorted(pieces):
-            bs, window, time_unit, width = key
-            for cut in _cut_rows(pieces[key], TILE_MAX_ROWS):
-                self._check_deadline("fetch_tagged")
-                # Charge BEFORE the tile materializes (query_limits.go
-                # bytes-read): an oversized result must be rejected mid
-                # fan-in, not after every tile copy has been allocated —
-                # the same incremental guard the per-series path had.
-                charge_read(n_bytes=sum(len(at) for _, at, _ in cut)
-                            * width * np.asarray(cut[0][0].words).itemsize)
-                tiles.append({
-                    "bs": bs,
-                    "rows": _column([poss for _, _, poss in cut]),
-                    "words": _column(
-                        [np.asarray(blk.words)[at] for blk, at, _ in cut],
-                        None),
-                    "nbits": _column(
-                        [np.asarray(blk.nbits)[at] for blk, at, _ in cut]),
-                    "npoints": _column(
-                        [np.asarray(blk.npoints)[at] for blk, at, _ in cut]),
-                    "window": window,
-                    "time_unit": time_unit,
-                })
+                pieces.setdefault(piece_key(blk), []).append(piece)
+
+        def before_tile(n_bytes: int):
+            # Charge BEFORE the tile materializes (query_limits.go
+            # bytes-read): an oversized result must be rejected mid
+            # fan-in, not after every tile copy has been allocated —
+            # the same incremental guard the per-series path had.
+            self._check_deadline("fetch_tagged")
+            charge_read(n_bytes=n_bytes)
+
+        tiles = gather_tiles(pieces, TILE_MAX_ROWS, before_tile)
         tile_ns = _clock() - t_tiles if timed else 0
         offs = np.zeros(n + 1, np.int64)
         if n:

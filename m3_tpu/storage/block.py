@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import dataclasses
 import itertools
+import threading
 import zlib
 from collections import OrderedDict
 from typing import Dict, Optional, Tuple
@@ -142,8 +143,41 @@ class SealedBlock:
             return i
         return None
 
+    def rows_of(self, idxs: np.ndarray, top: int
+                ) -> Tuple[np.ndarray, Optional[np.ndarray]]:
+        """The rows that hold registry indices `idxs` (an array whose
+        largest is `top`), resolved in one step: (rows, present), where
+        `present` masks the `idxs` this block holds and is None where it
+        holds them all. A block whose sorted indices end at their own
+        length holds every index below it, each in its own row."""
+        si = self.series_indices
+        held = len(si)
+        if held and si[-1] == held - 1 and top < held:
+            return idxs, None
+        if not held:
+            return idxs[:0], np.zeros(len(idxs), bool)
+        at = np.minimum(si.searchsorted(idxs), held - 1)
+        present = si[at] == idxs
+        return at[present], present
+
+    def take(self, rows: np.ndarray, series_indices: np.ndarray
+             ) -> "SealedBlock":
+        """The block of these rows alone, under `series_indices` (sorted):
+        what one encode over many shards' series is cut into, a block a
+        shard. Seal-time boundary metadata goes with its rows."""
+        boundary = self.boundary
+        if boundary is not None:
+            boundary = {k: v[rows] for k, v in boundary.items()}
+        return SealedBlock(
+            block_start=self.block_start, window=self.window,
+            series_indices=np.asarray(series_indices, np.int32),
+            words=self.words[rows], nbits=self.nbits[rows],
+            npoints=self.npoints[rows], time_unit=self.time_unit,
+            boundary=boundary)
+
     def read(self, series_idx: int) -> Optional[Tuple[np.ndarray, np.ndarray]]:
-        """Decode one series' datapoints (device launch batched to 1 row).
+        """Decode one series' datapoints (the per-row read: a miss is a
+        decode of this row alone, padded to the smallest row bucket).
 
         Consults the device block cache first: a hot block's decoded
         planes are resident (admission after repeated touches), turning
@@ -159,11 +193,11 @@ class SealedBlock:
             return None
         cache = block_cache.active()
         if cache is not None:
-            dec = cache.decoded(self)
+            dec = cache.decoded(self, row_read=True)
             if dec is not None:
                 n = int(self.npoints[row])
                 return dec[0][row, :n], dec[1][row, :n]
-        ts, vals = _dispatch_decode(
+        ts, vals = decode_rows(
             self.words[row : row + 1], self.npoints[row : row + 1],
             self.window, self.time_unit.nanos)
         n = int(self.npoints[row])
@@ -195,25 +229,29 @@ class SealedBlock:
                       ) -> Tuple[np.ndarray, np.ndarray]:
         """Whole-block decode to (ts_ns [S, W], vals [S, W]).
 
-        Rows are padded to a power of two (replicating the first stream,
-        always valid) so one compiled decode kernel serves every block
-        with this window geometry — the decode-side twin of
+        Rows are padded to a bucket of ROW_BUCKETS, past the largest to a
+        power of two (replicating the first stream, always valid), so
+        one compiled decode kernel serves every block with this window
+        geometry, and the cold reads of its rows too — the decode-side twin of
         encode_block's shape bucketing; merge/repair paths decode blocks
         of arbitrary series counts without per-count recompiles.
 
         `encoded` is the cache's retained device (words, padded npoints)
         from the seal-time encode: decoding from it skips the H2D
-        re-upload of the stream words entirely (the row padding matches
-        encode_block's, and decode is row-independent, so rows [:S] are
-        bit-identical either way). Planes come back read-only — they may
-        be cache-shared across readers."""
+        re-upload of the stream words entirely (decode is
+        row-independent, so rows [:S] are bit-identical either way). It
+        is used where the encode's row padding is this block's bucket —
+        a block of hundreds of rows; a few rows' words are uploaded
+        instead, so a geometry's decodes stay the buckets' programs.
+        Planes come back read-only — they may be cache-shared across
+        readers."""
         from ..parallel import telemetry
 
         s = len(self.series_indices)
-        if encoded is not None:
+        sp = row_bucket(s)
+        if encoded is not None and np.shape(encoded[0])[0] == sp:
             words, npoints = encoded
         else:
-            sp = _next_pow2(s, floor=1)
             words, npoints = self.words, self.npoints
             if sp != s:
                 words = np.concatenate([words, np.repeat(words[:1], sp - s, 0)])
@@ -284,14 +322,105 @@ def _next_pow2(n: int, floor: int = 8) -> int:
     return 1 << (n - 1).bit_length()
 
 
+# The row counts a cold read's decode is padded to: a lone row is never
+# a program of its own (one-row u32-pair programs are suspect on the
+# chip: tsz.decode_plane), and five shapes a geometry serve every read
+# from one series' one block to a thousand rows; more rows go in calls
+# of the largest, which is also a whole 625-row block's decode. The two
+# smallest are the client's tile shapes for 1-8 and 9-16 rows
+# (client/decode.py::decode_tile), so a node and a session that decode
+# a few rows of one geometry in one process share the program.
+ROW_BUCKETS = (8, 16, 64, 256, 1024)
+
+
+def row_bucket(n: int) -> int:
+    """The row count a decode of `n` rows is padded to."""
+    return next((b for b in ROW_BUCKETS if b >= n), None) or _next_pow2(n)
+
+_COLD_ROWS = ROOT.counter("storage.read.cold_rows")
+_COLD_DISPATCHES = ROOT.counter("storage.read.cold_dispatches")
+_warmed: set = set()
+_warm_lock = threading.Lock()
+
+
+def _warm_buckets(words, npoints, window: int, unit_nanos: int):
+    """On an accelerator a shape's first decode is a compile of seconds
+    inside a served read, and which bucket a read needs depends on what
+    the cache holds at that instant: a geometry's first cold read brings
+    every row bucket through its compile at once, on rows of its own.
+    On the CPU (the gate `block_cache.wants_encoded` uses) a compile is
+    cheap and a shape compiles where it is first met."""
+    key = (int(window), int(unit_nanos), int(np.shape(words)[-1]))
+    if key in _warmed:
+        return
+    with _warm_lock:
+        if key in _warmed:
+            return
+        import jax
+
+        if jax.default_backend() != "cpu":
+            for rows in ROW_BUCKETS:
+                _dispatch_decode(np.repeat(words[:1], rows, 0),
+                                 np.repeat(npoints[:1], rows), window,
+                                 unit_nanos)
+        _warmed.add(key)
+
+
+def decode_rows(words, npoints, window: int, unit_nanos: int, acc=None
+                ) -> Tuple[np.ndarray, np.ndarray]:
+    """Decode rows of one geometry (window, time unit, words width),
+    whichever blocks they come from, to (ts_ns [N, W], vals [N, W]): one
+    dispatch, the rows padded to a bucket of ROW_BUCKETS with copies of
+    the first (always valid), and one more for every 1,024 rows past
+    that. `acc` (a detailed span) receives `cold_rows_n`,
+    `cold_dispatch_n` and `cold_h2d_bytes`; the caller times it."""
+    words = np.asarray(words)
+    npoints = np.asarray(npoints, np.int32)
+    n = len(words)
+    if not n:
+        return (np.zeros((0, window), np.int64),
+                np.zeros((0, window), np.float64))
+    top = ROW_BUCKETS[-1]
+    _warm_buckets(words, npoints, window, unit_nanos)
+    out_t, out_v = [], []
+    h2d = 0
+    for lo in range(0, n, top):
+        w, k = words[lo:lo + top], npoints[lo:lo + top]
+        have = len(w)
+        rows = row_bucket(have)
+        if rows != have:
+            w = np.concatenate([w, np.repeat(w[:1], rows - have, 0)])
+            k = np.concatenate([k, np.repeat(k[:1], rows - have)])
+        h2d += w.nbytes + k.nbytes
+        ts, vals = _dispatch_decode(w, k, window, unit_nanos)
+        out_t.append(ts[:have])
+        out_v.append(vals[:have])
+    dispatches = len(out_t)
+    _COLD_ROWS.inc(n)
+    _COLD_DISPATCHES.inc(dispatches)
+    if acc is not None:
+        acc.add_cost("cold_rows_n", n)
+        acc.add_cost("cold_dispatch_n", dispatches)
+        acc.add_cost("cold_h2d_bytes", h2d)
+    if dispatches == 1:
+        return out_t[0], out_v[0]
+    return np.concatenate(out_t), np.concatenate(out_v)
+
+
 def encode_block(block_start: int, series_indices, tdense, vdense, npoints,
-                 max_words: Optional[int] = None) -> SealedBlock:
+                 max_words: Optional[int] = None, min_rows: int = 0
+                 ) -> SealedBlock:
     """Batch-encode dense tiles (from ShardBuffer.drain) into a SealedBlock.
 
     Tiles are padded to power-of-two (series, window) geometry so XLA
     re-uses one compiled kernel across shards/blocks instead of compiling
     per exact shape (shape bucketing; padding columns replicate the last
     point, padding rows are npoints=1 dummies sliced away afterwards).
+    `min_rows`: rows are padded as if there were at least so many — a
+    snapshot gives its shard's series count, so a bucket that a scrape
+    has only begun to fill (the first after every block boundary) takes
+    the program of a full one and not a row shape of its own, compiled
+    inside the tick; the encoded rows are the same bits either way.
 
     On a multi-device platform the encode routes through the shard x time
     mesh (parallel.ingest.flush_encode_prepared): rows shard across every
@@ -306,14 +435,14 @@ def encode_block(block_start: int, series_indices, tdense, vdense, npoints,
         if sp.sampled:
             sp.set_tag("device", dscope.device_tag())
         return _encode_block(block_start, series_indices, tdense, vdense,
-                             npoints, max_words)
+                             npoints, max_words, min_rows)
 
 
 def _encode_block(block_start: int, series_indices, tdense, vdense, npoints,
-                  max_words: Optional[int]) -> SealedBlock:
+                  max_words: Optional[int], min_rows: int = 0) -> SealedBlock:
     s, w = tdense.shape
     wp = _next_pow2(w)
-    sp = _next_pow2(s, floor=1)
+    sp = _next_pow2(max(s, min_rows), floor=1)
     with tracing.phase("pad"):
         if wp != w:
             padc_t = np.repeat(tdense[:, -1:], wp - w, axis=1)

@@ -65,6 +65,11 @@ _TOUCH_MAX = 8192
 DEFAULT_ADMIT_AFTER = 2
 
 
+def plane_bytes(blk) -> int:
+    """What a block's decoded planes hold: int64 + float64 a cell."""
+    return int(blk.num_series) * int(blk.window) * 16
+
+
 class _Entry:
     __slots__ = ("decoded", "encoded", "nbytes", "meta")
 
@@ -114,37 +119,76 @@ class DeviceBlockCache:
 
     # ---------------------------------------------------------------- serving
 
-    def decoded(self, blk) -> Optional[Tuple[np.ndarray, np.ndarray]]:
+    def decoded(self, blk, row_read: bool = False, rows: int = 1
+                ) -> Optional[Tuple[np.ndarray, np.ndarray]]:
         """The block's decoded (ts_ns [S, W], vals [S, W]) planes — frozen,
         shared — or None when the block hasn't earned admission yet.
         Records the touch either way; an admission decodes the whole block
-        once (from retained device buffers when present)."""
+        once (from retained device buffers when present). `row_read`: the
+        caller wants `rows` rows of it (SealedBlock.read, read_batch), so
+        an admission is a whole block decoded for them, and is made only
+        while the budget has room; a caller that decodes the whole block
+        anyway (read_all) admits whenever the block has earned it."""
+        dec = self.lookup(blk, rows)
+        if dec is None and self.wants(blk) and (
+                not row_read or self.has_room(blk)):
+            dec = self.admit(blk)
+        return dec
+
+    def lookup(self, blk, rows: int = 1
+               ) -> Optional[Tuple[np.ndarray, np.ndarray]]:
+        """The resident planes, or None; `rows` reads of the block are
+        counted as hits, or as misses and touches, either way."""
         gen = blk.gen
         with self._lock:
             e = self._entries.get(gen)
             if e is not None and e.decoded is not None:
                 self._entries.move_to_end(gen)
-                self._n["hits"] += 1
-                self._hits.inc()
-                tracing.count_cost("block_cache_hit")
+                self._n["hits"] += rows
+                self._hits.inc(rows)
+                tracing.count_cost("block_cache_hit", rows)
                 return e.decoded
-            self._n["misses"] += 1
-            self._misses.inc()
+            self._n["misses"] += rows
+            self._misses.inc(rows)
             # Per-span cache attribution: a slow query whose span shows
             # block_cache_miss > 0 gets the typed "cold-cache" reason.
-            tracing.count_cost("block_cache_miss")
+            tracing.count_cost("block_cache_miss", rows)
             if gen in self._dead:
                 return None
-            touches = self._touch.pop(gen, 0) + 1
-            self._touch[gen] = touches
+            self._touch[gen] = self._touch.pop(gen, 0) + rows
             while len(self._touch) > _TOUCH_MAX:
                 self._touch.popitem(last=False)
-            encoded = e.encoded if e is not None else None
-            if touches < self.admit_after or gen in self._decoding:
+            return None
+
+    def wants(self, blk) -> int:
+        """The touches of a block that has earned admission (`admit_after`
+        of them) and that nobody is decoding, else 0."""
+        gen = blk.gen
+        with self._lock:
+            touches = self._touch.get(gen, 0)
+            if touches < self.admit_after or gen in self._decoding \
+                    or gen in self._dead:
+                return 0
+            return touches
+
+    def has_room(self, blk) -> bool:
+        """Whether the block's planes fit under the budget as it stands,
+        so that admitting it evicts nothing."""
+        return self.budget.total() + plane_bytes(blk) <= self.budget.limit
+
+    def admit(self, blk) -> Optional[Tuple[np.ndarray, np.ndarray]]:
+        """Decode the whole block once (single-flight: a loser gets None
+        and reads its rows cold) and keep the planes; the budget then
+        evicts least-recently-used entries until the total fits."""
+        gen = blk.gen
+        with self._lock:
+            if gen in self._decoding or gen in self._dead:
                 return None
+            e = self._entries.get(gen)
+            encoded = e.encoded if e is not None else None
             self._decoding.add(gen)
-        # Admission (single-flight): decode outside the lock (device
-        # launch / host scan), then publish.
+        # Decode outside the lock (device launch / host scan), then
+        # publish.
         try:
             ts, vals = blk._decode_plane(encoded)
             out = self._put_decoded(gen, blk, ts, vals)
@@ -153,6 +197,23 @@ class DeviceBlockCache:
                 self._decoding.discard(gen)
         self.budget.reclaim()
         return out
+
+    def admit_hottest(self, blocks) -> int:
+        """What a fetch's batched read does with the blocks it read cold
+        because they had no room: while admitting means evicting, a fetch
+        admits ONE of them, the most touched — every miss of a fetch
+        whose store is larger than the budget would otherwise decode 625
+        rows to serve one and push out a block as warm as itself. The
+        cache so turns over at the pace of its fetches, and a block
+        earns its place by being asked for more than the others."""
+        best, most = None, 0
+        for blk in blocks:
+            touches = self.wants(blk)
+            if touches > most:
+                best, most = blk, touches
+        if best is None:
+            return 0
+        return 1 if self.admit(best) is not None else 0
 
     def _put_decoded(self, gen: int, blk, ts: np.ndarray, vals: np.ndarray
                      ) -> Optional[Tuple[np.ndarray, np.ndarray]]:

@@ -94,15 +94,32 @@ class FilesystemBootstrapper(Bootstrapper):
         return notes
 
     def bootstrap(self, ns, shard_ranges, ctx):
+        """Under a root span of its own (`bootstrap.filesystem`, a
+        background root: tags `filesets`, `bytes`, `series`; costs
+        `index_ns` — the segments read and, once the blocks are in, the
+        series named and marked from them —, `verify_ns` — a fileset
+        opened, its digests and rows checked —, `install_ns` — its
+        block built, its ids resolved, the block installed)."""
         claimed = ShardTimeRanges()
         if ctx.persist is None:
             return claimed
+        with tracing.background_span("bootstrap.filesystem") as sp:
+            self._bootstrap(ns, shard_ranges, ctx, claimed, sp)
+        return claimed
+
+    def _bootstrap(self, ns, shard_ranges, ctx, claimed, sp):
+        clock = tracing.clock_ns
+        t0 = clock()
+        segments = []
         if ns.index is not None:
             # Index phase: load persisted segments before data blocks
             # (bootstrapper/base_index_step.go).
             from ..index import persist as idx_persist
 
-            idx_persist.bootstrap_index(ctx.persist.root, ns.name, ns.index)
+            segments = idx_persist.bootstrap_index(
+                ctx.persist.root, ns.name, ns.index)
+        index_ns = clock() - t0
+        verify_ns = install_ns = filesets = nbytes = 0
         bsz = ns.opts.block_size_ns
         for shard_id in shard_ranges.shards():
             shard = ns.shards.get(shard_id)
@@ -111,9 +128,11 @@ class FilesystemBootstrapper(Bootstrapper):
             for bs, path in ctx.persist.list_filesets(ns.name, shard_id):
                 if not overlaps(shard_ranges.ranges(shard_id), bs, bs + bsz):
                     continue
+                t1 = clock()
                 try:
                     reader = FilesetReader(path)
                     reader.verify_rows()
+                    t2 = clock()
                     blk, ids = reader.to_block()
                 except FileNotFoundError:
                     continue  # cleanup raced the listing
@@ -141,7 +160,68 @@ class FilesystemBootstrapper(Bootstrapper):
                     remap, _created = shard.registry.get_or_create_batch(ids)
                 shard.load_block(blk, np.asarray(remap, np.int32))
                 claimed.add(shard_id, bs, bs + bsz)
-        return claimed
+                verify_ns += t2 - t1
+                install_ns += clock() - t2
+                filesets += 1
+                nbytes += blk.nbytes()
+        t3 = clock()
+        named = _name_series_from_index(ns, ctx, segments)
+        index_ns += clock() - t3
+        _FS_BOOT_METRICS.counter("filesets").inc(filesets)
+        if sp.sampled:
+            sp.set_tag("filesets", filesets).set_tag("bytes", nbytes)
+            sp.set_tag("series", named)
+            for kind, n in (("index_ns", index_ns), ("verify_ns", verify_ns),
+                            ("install_ns", install_ns)):
+                sp.add_cost(kind, n)
+
+
+def _name_series_from_index(ns, ctx, block_starts) -> int:
+    """A fileset carries ids and no tags: a series a restart brought
+    back takes its tags from the index segments that were read before
+    the data, and the mark of every index block whose segment holds its
+    document, so a live write indexes it again only where it crosses
+    into a block that does not. One pass a segment, oldest first; the
+    ids routed and resolved a shard at a time. Returns the series that
+    hold tags after it."""
+    index = ns.index
+    if index is None or not block_starts:
+        return 0
+    lookup = ctx.shard_lookup
+    if lookup is None:
+        return 0
+    # the shard set's vectorised routing where the lookup is its method
+    lookup_batch = getattr(getattr(lookup, "__self__", None),
+                           "lookup_batch", None)
+    named = 0
+    for bs in sorted(block_starts):
+        blk = index.blocks.get(bs)
+        if blk is None:
+            continue
+        for seg in blk.immutable:
+            docs = seg._docs
+            ids = [d.id for d in docs]
+            if not ids:
+                continue
+            if lookup_batch is not None:
+                shard_ids = np.asarray(lookup_batch(ids), np.int64)
+            else:
+                shard_ids = np.fromiter(map(lookup, ids), np.int64, len(ids))
+            for raw in np.unique(shard_ids).tolist():
+                shard = ns.shards.get(int(raw))
+                if shard is None:
+                    continue
+                at = np.flatnonzero(shard_ids == raw).tolist()
+                reg = shard.registry
+                idxs = reg.lookup_batch([ids[j] for j in at])
+                held = idxs >= 0
+                if reg.untagged:
+                    for j, idx in zip(at, idxs.tolist()):
+                        if idx >= 0 and reg.ensure_tags(
+                                idx, dict(docs[j].fields)):
+                            named += 1
+                reg.mark_indexed(idxs[held], bs)
+    return named
 
 
 def load_snapshots(ns, shard_ranges, ctx) -> Dict[int, Dict[int, Optional[Tuple[int, int]]]]:

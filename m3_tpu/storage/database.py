@@ -100,7 +100,8 @@ class Database:
         if opts.index_enabled:
             from ..index.namespace_index import NamespaceIndex
 
-            index = NamespaceIndex(clock=self.clock)
+            index = NamespaceIndex(opts.index_block_size_ns,
+                                   clock=self.clock)
         try:
             return self.create_namespace(name, opts, index=index)
         except ValueError:
@@ -189,15 +190,15 @@ class Database:
         """One routing pass for the batch; what has one answer for the
         whole batch is worked out once, before any shard is touched: the
         acceptance window (on the batch's min and max timestamp, so a
-        refused batch leaves nothing applied) and the block start (where
-        min and max share a block). Each shard then gets contiguous
+        refused batch leaves nothing applied), the block start and the
+        reverse-index block (where min and max share one). Each shard then gets contiguous
         slices of the columns in shard order. The sort is stable, so a
         shard's rows keep their arrival order (last arrival wins inside
         a bucket) and the rows applied so far are a prefix of `order`."""
         timed = acc is not None
         t0 = _clock() if timed else 0
         n = len(ids)
-        block_start = None
+        block_start = index_block = None
         if n:
             t_min, t_max = int(ts.min()), int(ts.max())
             past, future = now - ns.opts.buffer_past_ns, now + ns.opts.buffer_future_ns
@@ -206,6 +207,9 @@ class Database:
                 raise ValueError(f"{bad} datapoints outside acceptance window")
             block_start = one_block_start(t_min, t_max,
                                           ns.opts.block_size_ns)
+            if block_start is not None and ns.index is not None:
+                index_block = one_block_start(t_min, t_max,
+                                              ns.index.block_size_ns)
         order = np.argsort(shard_ids, kind="stable")
         rows = order.tolist()
         ids_s = list(map(ids.__getitem__, rows))
@@ -225,7 +229,7 @@ class Database:
                 fast += ns.shard_for(sid).write_batch(
                     ids_s[a:b], ts_s[a:b], vals_s[a:b], now, tags=row_tags,
                     priority=pri, acc=acc, rows=rows[a:b], checked=True,
-                    block_start=block_start)
+                    block_start=block_start, index_block=index_block)
                 appends += 1
                 applied = b
         except BaseException:

@@ -135,7 +135,8 @@ class Mediator:
                         continue
                     tracing.count_cost("buckets_n")
                     series, tdense, vdense, npoints = dense
-                    blk = encode_block(bs, series, tdense, vdense, npoints)
+                    blk = encode_block(bs, series, tdense, vdense, npoints,
+                                       min_rows=len(shard.registry))
                     try:
                         self.persist.write_snapshot(
                             ns.name, shard.shard_id, blk, shard.registry,

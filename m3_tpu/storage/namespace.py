@@ -64,6 +64,9 @@ class Namespace:
                    namespace_name=self.name)
         if self.retriever is not None:
             sh.attach_retriever(self.retriever, self.name)
+        if self.index is not None and self.opts.index_enabled:
+            sh.index_block_size_ns = self.index.block_size_ns
+            sh.on_index_batch = self.index.index_in_block
         self.shards[shard_id] = sh
         return sh
 

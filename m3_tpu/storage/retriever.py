@@ -102,7 +102,16 @@ class BlockRetriever:
 
     def retrieve(self, namespace: bytes, shard: int, block_start: int,
                  series_id: bytes) -> Optional[Tuple[np.ndarray, np.ndarray]]:
-        """Decoded (timestamps_ns, values) for one series from disk, or None.
+        """Decoded (timestamps_ns, values) for one series from disk, or
+        None: the per-series read of `block`'s one-row block."""
+        blk = self.block(namespace, shard, block_start, series_id)
+        return blk.read(0) if blk is not None else None
+
+    def block(self, namespace: bytes, shard: int, block_start: int,
+              series_id: bytes) -> Optional[SealedBlock]:
+        """One series' row of a fileset as a one-row sealed block, or
+        None. A read of many series stacks such rows into its one cold
+        decode (storage/read_batch.py) instead of decoding each alone.
 
         WiredList hit skips the seek and the decode stays off the fileset;
         a miss seeks (bloom -> index binary search -> mmap row) and wires
@@ -112,7 +121,7 @@ class BlockRetriever:
         blk = self.wired.get(key)
         if blk is not None:
             self.stats["wired_hits"] += 1
-            return blk.read(0)
+            return blk
         try:
             sk = self._seeker(namespace, shard, block_start)
             if sk is None:
@@ -145,7 +154,7 @@ class BlockRetriever:
             time_unit=xtime.Unit(sk.info["time_unit"]),
         )
         self.wired.put(key, blk)
-        return blk.read(0)
+        return blk
 
     def _quarantine(self, namespace: bytes, shard: int, block_start: int,
                     err: Exception) -> None:

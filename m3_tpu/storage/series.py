@@ -14,12 +14,27 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+# `_indexed` of a series the reverse index holds in no block yet.
+NEVER_INDEXED = -(1 << 62)
+
 
 class SeriesRegistry:
     def __init__(self):
         self._index: Dict[bytes, int] = {}
         self._ids: List[bytes] = []
         self._tags: List[Optional[dict]] = []
+        # Per series, the start of the newest reverse-index block that
+        # holds its document (the reference's entry.IndexedForBlockStart):
+        # a series is indexed again in every index block it is written
+        # in, so a query that overlaps only a later block still finds
+        # it. Capacity doubles; entries past len(_ids) are NEVER_INDEXED.
+        # `index_floor` is a lower bound of the live entries, so "is any
+        # series of this shard behind block B" is one integer test on
+        # the write path; it moves under _untagged_lock. A series
+        # without tags has no document and stays NEVER_INDEXED above the
+        # bound until a write brings its tags (ensure_tags).
+        self._indexed = np.full(1024, NEVER_INDEXED, np.int64)
+        self.index_floor = NEVER_INDEXED
         # How many entries of _tags are None, so "does this batch hold a
         # series to backfill" is one integer test on the write path. It
         # moves under _untagged_lock (a leaf), up BEFORE the series' id
@@ -46,6 +61,7 @@ class SeriesRegistry:
             self.ensure_tags(idx, tags)
             return idx, False
         idx = len(self._ids)
+        self._grow_indexed(1)
         # Lists BEFORE the id map: lock-free readers (lookup_batch, the
         # write fast path) resolve through _index and then read
         # _ids/_tags without the shard lock — an index published first
@@ -81,6 +97,7 @@ class SeriesRegistry:
         id_list = self._ids
         tag_list = self._tags
         base = len(id_list)
+        self._grow_indexed(n)
         if not any(map(index.__contains__, ids)) and \
                 len(dict.fromkeys(ids)) == n:
             out = np.arange(base, base + n, dtype=np.int32)
@@ -147,6 +164,9 @@ class SeriesRegistry:
                 return False
             self._tags[idx] = tags
             self.untagged -= 1
+            # It had no document to index; its next write indexes it.
+            self._indexed[idx] = NEVER_INDEXED
+            self.index_floor = NEVER_INDEXED
         return True
 
     def ensure_tags_batch(self, sidx: Sequence[int],
@@ -164,6 +184,44 @@ class SeriesRegistry:
         if n:
             with self._untagged_lock:
                 self.untagged += n
+
+    # ---------------------------------------------------- reverse-index marks
+
+    def _grow_indexed(self, need: int):
+        """Room for `need` more series, before their ids are published
+        (under the shard lock, like every creation). A writer that
+        marked the array this replaces loses its mark and indexes the
+        series once more: the index takes a document twice."""
+        have = len(self._ids) + need
+        if have > len(self._indexed):
+            grown = np.full(max(2 * len(self._indexed), have),
+                            NEVER_INDEXED, np.int64)
+            with self._untagged_lock:
+                grown[:len(self._indexed)] = self._indexed
+                self._indexed = grown
+
+    def behind(self, sidx: np.ndarray, index_block: int) -> np.ndarray:
+        """Positions in `sidx` of series the index does not hold in
+        `index_block` or a later block. Lock-free."""
+        return np.flatnonzero(self._indexed[sidx] < index_block)
+
+    def mark_indexed(self, idxs, index_block: int):
+        """The index holds these series' documents in `index_block`
+        (a new series' first block may lie under the bound)."""
+        with self._untagged_lock:
+            held = self._indexed
+            held[idxs] = np.maximum(held[idxs], index_block)
+            if index_block < self.index_floor:
+                self.index_floor = index_block
+
+    def raise_index_floor(self):
+        """Recompute the bound once a batch found nobody behind: the
+        least mark among the series that have one."""
+        with self._untagged_lock:
+            marks = self._indexed[:len(self._ids)]
+            marks = marks[marks > NEVER_INDEXED]
+            self.index_floor = int(marks.min()) if len(marks) \
+                else NEVER_INDEXED
 
     def all_ids(self) -> List[bytes]:
         return list(self._ids)

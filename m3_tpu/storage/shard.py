@@ -26,11 +26,14 @@ from ..utils.instrument import ROOT
 from ..utils.tracing import clock_ns as _clock
 from . import block_cache
 from .block import SealedBlock, encode_block, merge_same_start
-from .buffer import ShardBuffer
+from .buffer import ShardBuffer, one_block_start
 from .insert_queue import InsertGroup, InsertQueue
-from .series import SeriesRegistry
+from .series import NEVER_INDEXED, SeriesRegistry
 
 _CORRUPTION = ROOT.sub_scope("storage.corruption")
+# Series a write handed to the reverse index again because they had
+# crossed into an index block that did not hold them yet.
+_REINDEXED = ROOT.counter("index.insert.reindexed")
 
 
 class ShardState(enum.Enum):
@@ -97,6 +100,13 @@ class Shard:
         # the per-series callback.
         self.on_new_series = on_new_series
         self.on_new_series_batch = on_new_series_batch
+        # The reverse index's block size, 0 where nothing indexes this
+        # shard's series, and the hook that takes [(series_id, tags)] a
+        # write found behind an index block (Namespace.assign_shard
+        # binds both): a series is indexed in every index block it is
+        # written in (shard.go writeAndIndex: entry.NeedsIndexUpdate).
+        self.index_block_size_ns = 0
+        self.on_index_batch: Optional[Callable] = None
         # New-series inserts coalesce here; drains apply the whole batch
         # under one write_lock acquisition (shard_insert_queue.go:52).
         self.insert_queue = InsertQueue(
@@ -127,6 +137,10 @@ class Shard:
             self.registry.ensure_tags(idx, tags)
             with self.write_lock:
                 self.buffer.write(idx, t_ns, value)
+            if self.index_block_size_ns:
+                ib = xtime.truncate(t_ns, self.index_block_size_ns)
+                if ib > self.registry.index_floor:
+                    self._index_behind(np.array([idx], np.int32), ib)
             return False
         self.insert_queue.insert(
             InsertGroup([series_id], [tags] if tags is not None else None,
@@ -140,7 +154,8 @@ class Shard:
                     priority: Priority = Priority.NORMAL, acc=None, *,
                     rows: Optional[Sequence[int]] = None,
                     checked: bool = False,
-                    block_start: Optional[int] = None) -> bool:
+                    block_start: Optional[int] = None,
+                    index_block: Optional[int] = None) -> bool:
         """`acc` (a detailed span, utils.tracing.detail, read once a
         batch by Database.write_batch) receives `lock_wait_ns`: the time
         this shard's append waited for the shard lock.
@@ -188,6 +203,7 @@ class Shard:
                     acc.add_cost("lock_wait_ns", _clock() - t0)
                 one_block = self.buffer.write_batch(sidx, ts, vals,
                                                     block_start)
+            self._index_rows(sidx, ts, index_block)
             return one_block and not backfilled
         # Slow path: coalesce the first-seen remainder into the insert
         # queue as ONE columnar group (distinct new ids + their pending
@@ -226,11 +242,50 @@ class Shard:
             with self.write_lock:
                 self.buffer.write_batch(sidx[known], ts[known], vals[known],
                                         block_start)
+            self._index_rows(sidx[known], ts[known], index_block)
         if not self.opts.write_new_series_async:
             if not batch.drained:
                 self.insert_queue.drain()
             batch.wait()
         return False
+
+    def _index_rows(self, sidx: np.ndarray, ts: np.ndarray,
+                    index_block: Optional[int]):
+        """After an append of known series: index those the reverse
+        index does not hold in the rows' index block yet. One integer
+        test where no series of the shard is behind it."""
+        size = self.index_block_size_ns
+        if not size or not len(sidx):
+            return
+        if index_block is None:
+            index_block = one_block_start(int(ts.min()), int(ts.max()), size)
+        if index_block is not None:
+            if index_block > self.registry.index_floor:
+                self._index_behind(sidx, index_block)
+            return
+        blocks = ts - ts % size     # rows that straddle an index boundary
+        for ib in np.unique(blocks).tolist():
+            if ib > self.registry.index_floor:
+                self._index_behind(sidx[blocks == ib], ib)
+
+    def _index_behind(self, sidx: np.ndarray, index_block: int):
+        registry = self.registry
+        behind = registry.behind(sidx, index_block)
+        tags_of = registry.tags_of
+        # a series without tags has no document: it waits for them
+        idxs = [i for i in np.unique(sidx[behind]).tolist()
+                if tags_of(i) is not None] if len(behind) else []
+        if not idxs or self.on_index_batch is None:
+            registry.raise_index_floor()
+            return
+        # the index insert before the mark: a writer that finds the mark
+        # may rely on the document
+        self.on_index_batch([(registry.id_of(i), tags_of(i)) for i in idxs],
+                            index_block)
+        crossed = int((registry._indexed[idxs] > NEVER_INDEXED).sum())
+        registry.mark_indexed(idxs, index_block)
+        if crossed:
+            _REINDEXED.inc(crossed)
 
     def _drain_inserts(self, groups: List[InsertGroup]):
         """Insert-queue drain: apply one coalesced batch — register every
@@ -242,6 +297,7 @@ class Shard:
         same visibility order as the synchronous path, minus the
         cross-component lock coupling)."""
         new_items: List[Tuple[bytes, Optional[dict], int]] = []
+        appended: List[Tuple[np.ndarray, np.ndarray]] = []
         with self.write_lock:
             for g in groups:
                 idxs, created = self.registry.get_or_create_batch_tagged(
@@ -250,12 +306,22 @@ class Shard:
                     sidx = (idxs if g.counts is None
                             else np.repeat(idxs, g.counts).astype(np.int32))
                     self.buffer.write_batch(sidx, g.ts, g.vals)
+                    appended.append((sidx, g.ts))
                 if created:
                     gt = g.tags
                     new_items.extend(
                         (g.ids[j], gt[j] if gt is not None else None,
                          int(idxs[j]))
                         for j in created)
+        if self.index_block_size_ns:
+            # New series are behind every index block: the rows that
+            # rode their inserts index them where they were written, as
+            # a known series' rows would (a series that came without
+            # rows, or where no index is bound, takes the hooks below).
+            for sidx, ts in appended:
+                self._index_rows(sidx, ts, None)
+            new_items = [it for it in new_items
+                         if self.registry._indexed[it[2]] == NEVER_INDEXED]
         if not new_items:
             return
         if self.on_new_series_batch is not None:

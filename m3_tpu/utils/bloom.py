@@ -8,7 +8,7 @@ import math
 
 import numpy as np
 
-from .hashing import hash_batch, murmur3_32
+from .hashing import hash_batch, hash_batch_host, murmur3_32
 
 
 class BloomFilter:
@@ -34,11 +34,15 @@ class BloomFilter:
         pos = self._positions(item)
         np.bitwise_or.at(self.bits, pos >> 3, (1 << (pos & 7)).astype(np.uint8))
 
-    def add_batch(self, items):
+    def add_batch(self, items, on_host: bool = False):
+        """`on_host`: hash without the device route, whose program is
+        keyed by the batch's row count (hashing.hash_batch_host): for a
+        batch of no settled size. The bits are the same."""
         if not len(items):
             return
-        h1 = hash_batch(items).astype(np.uint64)
-        h2 = hash_batch(items, seed=0x9747B28C).astype(np.uint64)
+        hashed = hash_batch_host if on_host else hash_batch
+        h1 = hashed(items).astype(np.uint64)
+        h2 = hashed(items, seed=0x9747B28C).astype(np.uint64)
         i = np.arange(self.k, dtype=np.uint64)[None, :]
         pos = ((h1[:, None] + i * h2[:, None]) % np.uint64(self.m)).astype(np.int64).ravel()
         np.bitwise_or.at(self.bits, pos >> 3, (1 << (pos & 7)).astype(np.uint8))

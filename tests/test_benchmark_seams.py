@@ -53,3 +53,35 @@ def test_every_shipped_name_resolves_to_a_file_with_its_functions(
         assert os.path.dirname(mod.__file__) == os.path.join(BENCH_DIR, kind)
         for attr in spec.PARTS[kind]:
             assert hasattr(mod, attr), (mod.__file__, attr)
+
+
+def _metrics():
+    with open(os.path.join(ROOT_DIR, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    for kind, key in (("end_to_end", "end_to_end"),
+                      ("layer_metrics", "per_layer")):
+        for m in bench[key]:
+            yield pytest.param(kind, m, id=m["name"])
+
+
+@pytest.mark.parametrize("kind,decl", list(_metrics()))
+def test_every_metric_has_its_reader_and_its_declaration(kind, decl,
+                                                         monkeypatch):
+    """A metric of BENCHMARK.json is a reader file found by its name
+    (`read(m)`), and a per-layer one a declaration beside it that mirrors
+    the entry; the cells it lists exist and report what it moves."""
+    monkeypatch.syspath_prepend(BENCH_DIR)
+    from harness import spec
+
+    assert callable(spec.load_reader(kind, decl["name"]))
+    with open(os.path.join(ROOT_DIR, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    cells = {w["name"] for w in bench["workloads"]}
+    assert set(decl.get("workloads", ())) <= cells
+    if kind == "layer_metrics":
+        with open(os.path.join(BENCH_DIR, kind, decl["name"] + ".json")) as f:
+            assert json.load(f) == decl
+        moved = next(m for m in bench["end_to_end"]
+                     if m["name"] == decl["moves"])
+        assert set(decl.get("workloads", ())) <= set(
+            moved.get("workloads", cells))

@@ -15,6 +15,7 @@ from m3_tpu.parallel.sharding import ShardSet
 from m3_tpu.rpc import node_server, wire
 from m3_tpu.rpc.node_server import NodeService
 from m3_tpu.storage.database import Database
+from m3_tpu.storage import tiles
 from m3_tpu.storage.namespace import NamespaceOptions
 from m3_tpu.utils import limits as xlimits
 from m3_tpu.utils import xtime
@@ -223,13 +224,13 @@ def test_a_seal_between_two_buffer_chunks_loses_no_point(monkeypatch):
 def counting_columns(monkeypatch):
     """Counts the tiles the pass has materialised: four columns each."""
     made = []
-    real = node_server._column
+    real = tiles._column
 
     def column(parts, *a):
         made.append(len(parts))
         return real(parts, *a)
 
-    monkeypatch.setattr(node_server, "_column", column)
+    monkeypatch.setattr(tiles, "_column", column)
     return lambda: len(made) // 4
 
 

@@ -1078,8 +1078,13 @@ class TestRuleANoChildWhereSelfTimeIsRead:
         c = fetch.costs
         assert c["series_n"] == 20 and c["block_n"] == 20
         parts = (c["lock_wait_ns"] + c["buffer_ns"] + c["block_ns"]
-                 + c["merge_ns"] + c["tags_ns"])
+                 + c["merge_ns"])
         assert parts <= c["read_ns"] <= fetch.duration_ns
+        # the sealed block's 20 rows: slices of its planes where the
+        # cache admitted it, else one cold dispatch for them all
+        assert (c["cold_rows_n"], c["cold_dispatch_n"]) in ((0, 0), (20, 1))
+        assert c["cold_decode_ns"] <= c["block_ns"]
+        assert (c["cold_h2d_bytes"] > 0) == (c["cold_rows_n"] > 0)
         assert ex.costs["bind_n"] == 1 and ex.costs["bind_ns"] <= ex.duration_ns
         if route == "plan":
             assert ex.costs["dispatch_n"] >= 1

@@ -1,0 +1,251 @@
+"""The embedded read path's batched cold read (storage/read_batch.py):
+its answers are Shard.read's bit for bit, its cold rows cost a dispatch a
+geometry, and a store larger than the block cache's budget evicts,
+re-admits and still answers exactly."""
+
+import numpy as np
+import pytest
+
+from m3_tpu.index.namespace_index import NamespaceIndex
+from m3_tpu.parallel import scope as dscope
+from m3_tpu.parallel.sharding import ShardSet
+from m3_tpu.query.model import Matcher, MatchType
+from m3_tpu.query.storage import LocalStorage
+from m3_tpu.storage import block as block_mod
+from m3_tpu.storage import block_cache
+from m3_tpu.storage.database import Database
+from m3_tpu.storage.namespace import NamespaceOptions
+from m3_tpu.storage.read_batch import read_many
+from m3_tpu.utils import tracing, xtime
+from m3_tpu.utils.hbm import HBMBudget
+from m3_tpu.utils.instrument import ROOT
+
+NS = b"deep"
+BLOCK = 10 * xtime.MINUTE
+STEP = 30 * xtime.SECOND
+T0 = 1_600_000_000 * 10**9
+T0 -= T0 % BLOCK
+SEALED = 5
+
+
+class Store:
+    """`SEALED` sealed blocks and an open buffer over 4 shards. `late-*`
+    series start in block 2; `gap-*` skip block 1; the last sealed
+    block's last scrape is written AGAIN after the seal with another
+    value, so a sealed block and the buffer hold the same timestamps."""
+
+    def __init__(self, n_series=40, seed=5):
+        self.now = T0
+        self.db = Database(ShardSet(4), clock=lambda: self.now)
+        self.db.mark_bootstrapped()
+        opts = NamespaceOptions(block_size_ns=BLOCK,
+                                buffer_past_ns=BLOCK + xtime.MINUTE,
+                                writes_to_commitlog=False)
+        self.db.create_namespace(NS, opts, index=NamespaceIndex(
+            opts.index_block_size_ns, clock=lambda: self.now))
+        self.ids = [b"s-%02d" % i for i in range(n_series)] \
+            + [b"late-%d" % i for i in range(6)] \
+            + [b"gap-%d" % i for i in range(6)]
+        self.tags = {sid: {b"__name__": b"m", b"id": sid} for sid in self.ids}
+        rng = np.random.default_rng(seed)
+        per = BLOCK // STEP
+        for k in range(SEALED * per + 4):
+            t = T0 + k * STEP
+            self.now = t
+            rows = [sid for sid in self.ids
+                    if not (sid.startswith(b"late") and t < T0 + 2 * BLOCK)
+                    and not (sid.startswith(b"gap")
+                             and T0 + BLOCK <= t < T0 + 2 * BLOCK)]
+            self.write(rows, t, rng)
+        # seal every full block, keeping the buffer's newest
+        self.db.tick(T0 + (SEALED + 1) * BLOCK + 2 * xtime.MINUTE)
+        self.now = T0 + SEALED * BLOCK + 4 * STEP
+        # rewrite a sealed timestamp: the buffer's value must win
+        self.dup_t = T0 + SEALED * BLOCK - STEP
+        self.write(self.ids[:10], self.dup_t, rng)
+        self.end = self.now + STEP
+        self.ns = self.db.namespace(NS)
+
+    def write(self, rows, t, rng):
+        self.db.write_batch(
+            NS, rows, np.full(len(rows), t, np.int64),
+            rng.integers(0, 1000, len(rows)).astype(np.float64),
+            [self.tags[sid] for sid in rows])
+
+    def per_row(self, sid, start, end):
+        shard = self.ns.shards[self.db.shard_set.lookup(sid)]
+        return shard.read(sid, start, end)
+
+    def batched(self, ids, start, end, acc=None):
+        return read_many(self.ns, self.db.shard_set, ids, start, end, acc)
+
+
+@pytest.fixture()
+def cache(monkeypatch):
+    """A block cache of the test's own over a budget of its own."""
+    def make(limit_bytes, admit_after=2):
+        c = block_cache.DeviceBlockCache(
+            budget=HBMBudget(limit_bytes), admit_after=admit_after,
+            scope=ROOT.sub_scope("test.read_batch.cache"))
+        monkeypatch.setitem(dscope.DEFAULT._owned, "block_cache", c)
+        return c
+    return make
+
+
+@pytest.fixture(scope="module")
+def store():
+    return Store()
+
+
+RANGES = {
+    "all": (0, (SEALED + 1) * BLOCK),
+    "first-block-only": (0, BLOCK),
+    "inside-two-blocks": (BLOCK + 3 * STEP, 3 * BLOCK - 2 * STEP),
+    "buffer-only": (SEALED * BLOCK, (SEALED + 1) * BLOCK),
+    "before-the-data": (-3 * BLOCK, -BLOCK),
+}
+
+
+def assert_same(store, ids, start, end, acc=None):
+    got = store.batched(ids, start, end, acc)
+    assert len(got) == len(ids)
+    for sid, (tags, t, v) in zip(ids, got):
+        wt, wv = store.per_row(sid, start, end)
+        assert t.dtype == wt.dtype and v.dtype == wv.dtype
+        np.testing.assert_array_equal(t, wt)
+        np.testing.assert_array_equal(v, wv)
+        assert tags == store.tags.get(sid)
+    return got
+
+
+@pytest.mark.parametrize("name", sorted(RANGES))
+def test_batched_read_equals_the_per_row_read(store, cache, name):
+    """Misses only (nothing admitted: admit_after is out of reach)."""
+    cache(1 << 30, admit_after=10**9)
+    lo, hi = RANGES[name]
+    assert_same(store, store.ids, T0 + lo, T0 + hi)
+
+
+def test_hits_and_misses_mix_in_one_fetch(store, cache):
+    c = cache(1 << 30, admit_after=2)
+    ids = store.ids
+    start, end = T0, store.end
+    assert_same(store, ids[:2], start, T0 + BLOCK)     # touches of block 0
+    assert_same(store, ids[:2], start, T0 + BLOCK)
+    assert c.stats()["admitted"] > 0
+    hits0, misses0 = c.stats()["hits"], c.stats()["misses"]
+    # block 0 of those shards is resident, no other block has been
+    # touched: hits and misses in one fetch
+    got = store.batched(ids, start, end)
+    assert c.stats()["hits"] > hits0 and c.stats()["misses"] > misses0
+    for sid, (_tags, t, v) in zip(ids, got):
+        wt, wv = store.per_row(sid, start, end)
+        np.testing.assert_array_equal(t, wt)
+        np.testing.assert_array_equal(v, wv)
+
+
+def test_a_lone_miss_is_one_dispatch_and_never_a_one_row_program(
+        store, cache, monkeypatch):
+    cache(1 << 30, admit_after=10**9)
+    shapes = []
+    real = block_mod._dispatch_decode
+
+    def spy(words, npoints, window, unit_nanos):
+        shapes.append(np.shape(words))
+        return real(words, npoints, window, unit_nanos)
+
+    monkeypatch.setattr(block_mod, "_dispatch_decode", spy)
+    sid = store.ids[3]
+    assert_same(store, [sid], T0 + BLOCK, T0 + 2 * BLOCK)
+    # the batched read's one dispatch, then the per-row read's
+    assert [s[0] for s in shapes] == [block_mod.ROW_BUCKETS[0]] * 2
+
+
+def test_cold_rows_cost_a_dispatch_a_geometry_not_a_pair(store, cache):
+    cache(1 << 30, admit_after=10**9)
+    tracer = tracing.Tracer(sample_rate=1.0)
+    with tracer.background_span("query.fetch") as sp:
+        assert sp.detailed
+        store.batched(store.ids, T0, store.end, sp)
+    costs = sp.to_dict()["costs"]
+    pairs = costs["block_n"]
+    assert pairs > 4 * len(store.ids)           # several blocks a series
+    assert costs["cold_rows_n"] == pairs
+    # the store's blocks share at most a few geometries (window x width)
+    geometries = {(b.window, np.shape(b.words)[-1])
+                  for sh in store.ns.shards.values()
+                  for b in sh.blocks.values()}
+    assert 1 <= costs["cold_dispatch_n"] <= len(geometries)
+    assert costs["cold_h2d_bytes"] > 0 and costs["cold_decode_ns"] > 0
+
+
+def test_unknown_and_unheld_ids(store, cache):
+    cache(1 << 30)
+    got = store.batched([b"nobody", store.ids[0]], T0, store.end)
+    tags, t, v = got[0]
+    assert tags is None and len(t) == 0 and len(v) == 0
+    assert len(got[1][1])
+    # a shard this namespace does not hold leaves no row
+    shard_id = store.db.shard_set.lookup(store.ids[1])
+    shards = dict(store.ns.shards)
+    del shards[shard_id]
+
+    class Held:
+        opts = store.ns.opts
+
+    Held.shards = shards
+    assert read_many(Held, store.db.shard_set, [store.ids[1]], T0,
+                     store.end) == [None]
+
+
+def test_duplicates_across_a_sealed_block_and_the_buffer(store, cache):
+    cache(1 << 30)
+    ids = store.ids[:10]
+    got = assert_same(store, ids, T0, store.end)
+    for (_tags, t, v), sid in zip(got, ids):
+        at = np.flatnonzero(t == store.dup_t)
+        assert len(at) == 1                      # one point a timestamp
+        shard = store.ns.shards[store.db.shard_set.lookup(sid)]
+        bt, bv = shard.buffer.read(shard.registry.get(sid), T0, store.end)
+        assert v[at[0]] == bv[bt == store.dup_t][0]   # the buffer's wins
+
+
+def test_local_storage_fetch_goes_through_it(store, cache):
+    cache(1 << 30)
+    ls = LocalStorage(store.db, NS)
+    got = ls.fetch_raw([Matcher(MatchType.EQUAL, b"__name__", b"m")],
+                       T0, store.end)
+    assert set(got) == set(store.ids)
+    for sid, entry in got.items():
+        wt, wv = store.per_row(sid, T0, store.end)
+        np.testing.assert_array_equal(entry["t"], wt)
+        np.testing.assert_array_equal(entry["v"], wv)
+        assert entry["tags"] == store.tags[sid]
+
+
+def test_a_store_larger_than_the_budget_evicts_readmits_and_answers(
+        store, cache):
+    """More generations than the budget holds: planes are admitted while
+    there is room, then one a fetch, least recently used first out; every
+    answer stays exact, and a block that was evicted comes back."""
+    blocks = [b for sh in store.ns.shards.values() for b in sh.blocks.values()]
+    one = max(block_cache.plane_bytes(b) for b in blocks)
+    c = cache(3 * one + one // 2, admit_after=2)      # room for three
+    ids = store.ids
+    rng = np.random.default_rng(11)
+    seen_resident = set()
+    for _ in range(30):
+        pick = [ids[i] for i in rng.choice(len(ids), 6, replace=False)]
+        assert_same(store, pick, T0, store.end)
+        with c._lock:
+            resident = {g for g, e in c._entries.items()
+                        if e.decoded is not None}
+        assert c.resident_bytes() <= c.budget.limit
+        seen_resident |= resident
+    st = c.stats()
+    assert st["evictions"] > 0
+    assert st["admitted"] - st["evictions"] == len(resident) > 0
+    assert len(seen_resident) > 3       # the cache turned over
+    assert st["hits"] > 0 and st["misses"] > 0
+    # a full sweep over everything still answers exactly
+    assert_same(store, ids, T0, store.end)
