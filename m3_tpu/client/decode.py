@@ -12,13 +12,15 @@ kernel call (ops.tsz.decode), then merged per series on host."""
 from __future__ import annotations
 
 import enum
+import threading
 from typing import Dict, List, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ..ops import tsz
-from ..parallel import telemetry
+from ..parallel import scope as dscope, telemetry
 from ..utils import instrument, xtime
 
 
@@ -118,6 +120,66 @@ def decode_tile(words, npoints, window: int, time_unit: int
     return np.asarray(ts[:n]), np.asarray(vs[:n])
 
 
+# A fetch's stacked decode goes in calls of at most this many rows (the
+# node's own bound, storage/block.py::ROW_BUCKETS[-1]): how many rows a
+# fetch stacks depends on how many replicas had answered when coverage
+# was met, so the programs it can need are the power-of-two buckets from
+# TILE_MIN_ROWS to this bound and no others. Powers of two, not
+# ROW_BUCKETS' steps of four: those would pad a 40-series read's three
+# frames (264-360 rows) to 1,024.
+STACK_MAX_ROWS = 1024
+_warm_lock = threading.Lock()
+
+
+def _compiles_are_dear() -> bool:
+    """On an accelerator a shape's first decode is a compile of seconds
+    inside a served read; on the CPU it is cheap and a shape compiles
+    where it is first met (storage/block.py::_warm_buckets' gate)."""
+    return jax.default_backend() != "cpu"
+
+
+def _warm_stack_buckets(words, npoints, window: int, time_unit: int):
+    """A geometry's first stacked decode on the calling thread's device
+    scope brings every bucket a later stack can need through its compile
+    at once, on rows of its own: a read warmed with two responders meets
+    three in its next request, and a row count no warm-up compiled would
+    compile inside that request. A jitted program is compiled for the
+    device it runs on, so what is warm is kept by the scope."""
+    warmed = dscope.current().owned("client_decode_warmed", lambda _sc: set())
+    key = (int(window), int(time_unit), int(np.shape(words)[-1]))
+    if key in warmed:
+        return
+    with _warm_lock:
+        if key in warmed:
+            return
+        if _compiles_are_dear():
+            rows = TILE_MIN_ROWS
+            while rows <= STACK_MAX_ROWS:
+                decode_tile(np.repeat(words[:1], rows, 0),
+                            np.repeat(npoints[:1], rows), window, time_unit)
+                rows *= 2
+        warmed.add(key)
+
+
+def decode_stack(words, npoints, window: int, time_unit: int
+                 ) -> Tuple[np.ndarray, np.ndarray, int]:
+    """`decode_tile` over the rows a whole fetch stacked (every
+    responder's tiles of one geometry), in calls of at most
+    STACK_MAX_ROWS rows, so the programs a session's decode can need are
+    a small closed set, all compiled at the geometry's first decode.
+    Returns (ts, vals, calls made)."""
+    words = np.asarray(words)
+    npoints = np.asarray(npoints, np.int32)
+    _warm_stack_buckets(words, npoints, window, time_unit)
+    cuts = [decode_tile(words[lo:lo + STACK_MAX_ROWS],
+                        npoints[lo:lo + STACK_MAX_ROWS], window, time_unit)
+            for lo in range(0, len(words), STACK_MAX_ROWS)]
+    if len(cuts) == 1:
+        return (*cuts[0], 1)
+    return (np.concatenate([ts for ts, _ in cuts]),
+            np.concatenate([vs for _, vs in cuts]), len(cuts))
+
+
 def merge_replica_points(
     ts_parts: Sequence[np.ndarray],
     vs_parts: Sequence[np.ndarray],
@@ -137,9 +199,14 @@ def merge_replica_points(
     t, v = t[order], v[order]
     if len(t) < 2:
         return t, v
-    uniq, inverse = np.unique(t, return_inverse=True)
-    if len(uniq) == len(t):
+    # t is sorted: a slot starts where the timestamp changes (what
+    # np.unique(t, return_inverse=True) gives, without its second sort)
+    first = np.empty(len(t), bool)
+    first[0] = True
+    np.not_equal(t[1:], t[:-1], out=first[1:])
+    if first.all():
         return t, v
+    uniq, inverse = t[first], np.cumsum(first) - 1
     if strategy == ConflictStrategy.LAST_PUSHED:
         picked = np.zeros(len(uniq), np.float64)
         picked[inverse] = v  # later writes overwrite earlier per slot
@@ -183,6 +250,6 @@ def merge_replica_points(
 
 # (series_points, the per-series segments+buffer decoder, retired in
 # round 16: fetch_tagged frames are columnar — tiles + one buffer
-# sidecar — decoded by Session._columnar_points via decode_tile.
+# sidecar — decoded by Session._merged_points via decode_stack.
 # decode_segment_groups stays: the bootstrap path still stacks wire
 # segments by geometry.)

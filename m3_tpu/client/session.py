@@ -41,7 +41,7 @@ from ..utils.retry import (
     Retrier,
     RetryOptions,
 )
-from .decode import ConflictStrategy, merge_replica_points
+from .decode import ConflictStrategy, decode_stack, merge_replica_points
 
 
 class ConsistencyError(Exception):
@@ -929,7 +929,10 @@ class Session:
     def fetch_tagged(self, ns: bytes, query, start_ns: int, end_ns: int,
                      limit: int = 0) -> Dict[bytes, dict]:
         """session.go:1091 FetchTagged: fan out, accumulate per-shard
-        consistency, decode + merge replicas. Returns id -> {tags, t, v}."""
+        consistency, then build the points from all the frames held once
+        coverage is met in one pass (`_merged_points`: one decode
+        dispatch a geometry for the whole fetch, one merge a series).
+        Returns id -> {tags, t, v}."""
         m = self._map()
         q = wire.query_to_wire(query)
         hosts = list(m.hosts.values())
@@ -955,9 +958,9 @@ class Session:
         # The phases of a clustered read are costs of this span, never
         # children of it (its parent's self time is a reader's): the
         # wait for coverage, each responder's frame (bytes, decode), the
-        # tile decodes (device dispatches, bytes brought back) and the
-        # merges. The workers hand their wire stats back; this thread
-        # alone writes the span.
+        # fetch's tile decode (device dispatches, bytes brought back)
+        # and the merges. The workers hand their wire stats back; this
+        # thread alone writes the span.
         with tracing.span("client.fetch_tagged", hosts=len(hosts)) as csp:
             t0 = _clock()
             pending = {self._pool.submit(
@@ -989,22 +992,7 @@ class Session:
                     f"insufficient replica coverage ({len(ok_ids)} "
                     f"responders, need {required} per shard): {errs}")
             acc = _ReadCosts()
-            merged: Dict[bytes, dict] = {}
-            strategy = self.opts.conflict_strategy
-            for r in results:
-                points = self._columnar_points(r, acc)
-                t2 = _clock()
-                for entry, (t, v) in zip(r["series"], points):
-                    sid = entry["id"]
-                    cur = merged.get(sid)
-                    if cur is None:
-                        merged[sid] = {"tags": entry["tags"], "t": t, "v": v}
-                    else:
-                        if not cur["tags"] and entry["tags"]:
-                            cur["tags"] = entry["tags"]
-                        cur["t"], cur["v"] = merge_replica_points(
-                            [cur["t"], t], [cur["v"], v], strategy)
-                acc.merge_ns += _clock() - t2
+            merged = self._merged_points(results, acc)
             _FETCH_REPLICAS.inc(len(results))
             _FETCH_DECODES.inc(acc.decode_n)
             _FETCH_BYTES_IN.inc(bytes_in)
@@ -1021,55 +1009,116 @@ class Session:
                     csp.add_cost(kind, n)
         return merged
 
-    def _columnar_points(self, r: dict, acc: "_ReadCosts") -> List[tuple]:
-        """Per-series (t, v) from one host's COLUMNAR fetch_tagged frame:
-        each sealed-block tile decodes in ONE batched kernel call
-        (decode.decode_tile — the wire twin of peer streaming's block
-        tiles) and scatters row slices to its series; the buffer sidecar
-        contributes offset-sliced views of the concatenated columns.
-        Order per series is sealed blocks (ascending start) then the
-        mutable buffer — the same precedence the per-series segment path
-        had, so LAST_PUSHED conflict resolution is unchanged. `acc`
-        takes what the decodes and the per-series merges cost."""
-        from ..storage.tiles import decode_stacked
-        from .decode import decode_tile
+    def _merged_points(self, frames: List[dict], acc: "_ReadCosts"
+                       ) -> Dict[bytes, dict]:
+        """id -> {tags, t, v} from the COLUMNAR fetch_tagged frames of
+        the responders a fetch waited for, in arrival order. A vote
+        (HIGHEST_FREQUENCY_VALUE) of per-responder votes is not the vote
+        of all their parts, so that strategy keeps its fold: each
+        responder's series built alone, then merged into what the
+        earlier ones gave, a series at a time. Every other strategy
+        picks the same point from a timestamp's candidates taken at once
+        or pairwise (the last occurrence, the maximum, the minimum), and
+        builds all the frames in one pass."""
+        strategy = self.opts.conflict_strategy
+        if strategy != ConflictStrategy.HIGHEST_FREQUENCY_VALUE:
+            return self._one_pass_points(frames, acc)
+        merged: Dict[bytes, dict] = {}
+        for r in frames:
+            points = self._one_pass_points([r], acc)
+            t0 = _clock()
+            for sid, new in points.items():
+                cur = merged.get(sid)
+                if cur is None:
+                    merged[sid] = new
+                    continue
+                if not cur["tags"] and new["tags"]:
+                    cur["tags"] = new["tags"]
+                cur["t"], cur["v"] = merge_replica_points(
+                    [cur["t"], new["t"]], [cur["v"], new["v"]], strategy)
+            acc.merge_ns += _clock() - t0
+        return merged
 
-        n = len(r["series"])
-        parts_t: List[list] = [[] for _ in range(n)]
-        parts_v: List[list] = [[] for _ in range(n)]
-        # One decode a geometry, not one a tile: a frame carries a tile
-        # per (shard, sealed block) — ~100 of 1-5 rows each behind a
-        # dashboard read — and the decode is row-independent, so tiles
-        # of one window, unit and stream width stack into one call
-        # (3.35 ms of host time a call on the chip's host, PERF.md
-        # section 6, PR 32).
+    def _one_pass_points(self, frames: List[dict], acc: "_ReadCosts"
+                         ) -> Dict[bytes, dict]:
+        """The frames' series in one pass: ONE decode a fetch and ONE
+        merge a series, not one of each for every replica that answered.
+
+        Slots: one a distinct series id over the frames' `series` lists
+        (first sighting's order, the first non-empty tags kept); a
+        frame's positions map to slots. Decode: every frame's
+        sealed-block tiles go into one `decode_stacked` call, so the
+        tiles of one geometry are one `decode_stack` dispatch whichever
+        replica sent them (the decode is row-independent: stacking
+        changes no bit of a row). Merge: a slot's parts are laid down
+        frame by frame in arrival order, inside a frame its sealed
+        blocks (ascending start) and then its slice of the buffer
+        sidecar (views of the concatenated columns) — the order a
+        per-responder fold visits them in, so one stable
+        `merge_replica_points` picks what the fold picks. `acc` takes
+        what the decodes and the merges cost."""
+        from ..storage.tiles import decode_stacked
+
+        t0 = _clock()
+        slot_of: Dict[bytes, int] = {}
+        tags: List[dict] = []
+        slots: List[np.ndarray] = []
+        for r in frames:
+            at = []
+            for entry in r["series"]:
+                slot = slot_of.setdefault(entry["id"], len(tags))
+                if slot == len(tags):
+                    tags.append(entry["tags"])
+                elif not tags[slot] and entry["tags"]:
+                    tags[slot] = entry["tags"]
+                at.append(slot)
+            slots.append(np.asarray(at, np.int64))
+        acc.merge_ns += _clock() - t0
+
+        # One decode a geometry for the whole fetch: a frame carries a
+        # tile a block start, a call is its fixed cost and not its rows
+        # (5.5 ms of the coordinator's host time for 54 us of device
+        # time, ledger PR 34), and the decode is row-independent.
         def decode(words, npoints, window, unit):
             t0 = _clock()
-            ts, vs = decode_tile(words, npoints, window, unit)
+            ts, vs, calls = decode_stack(words, npoints, window, unit)
             acc.decode_ns += _clock() - t0
-            acc.decode_n += 1
+            acc.decode_n += calls
             acc.d2h_bytes += ts.nbytes + vs.nbytes
             return ts, vs
 
-        for tile, ks, ts, vs in decode_stacked(r.get("tiles", ()), decode):
-            for j, (pos, k) in enumerate(zip(
-                    np.asarray(tile["rows"]).tolist(), ks.tolist())):
-                parts_t[pos].append(ts[j, :k])
-                parts_v[pos].append(vs[j, :k])
-        t0 = _clock()
-        bufs = r.get("bufs")
-        if bufs is not None:
+        decoded: List[list] = [[] for _ in frames]
+        for tile, ks, ts, vs in decode_stacked(
+                [dict(tile, frame=f) for f, r in enumerate(frames)
+                 for tile in r.get("tiles", ())], decode):
+            decoded[tile["frame"]].append((tile, ks, ts, vs))
+        parts_t: List[list] = [[] for _ in tags]
+        parts_v: List[list] = [[] for _ in tags]
+        for r, at, tiles in zip(frames, slots, decoded):
+            for tile, ks, ts, vs in tiles:
+                for j, (slot, k) in enumerate(zip(
+                        at[np.asarray(tile["rows"])].tolist(), ks.tolist())):
+                    parts_t[slot].append(ts[j, :k])
+                    parts_v[slot].append(vs[j, :k])
+            bufs = r.get("bufs")
+            if bufs is None:
+                continue
+            t0 = _clock()
             offs = np.asarray(bufs["offs"]).tolist()
             bt, bv = bufs["t"], bufs["v"]
-            for j in range(n):
-                if offs[j + 1] > offs[j]:
-                    parts_t[j].append(bt[offs[j]:offs[j + 1]])
-                    parts_v[j].append(bv[offs[j]:offs[j + 1]])
+            for pos, slot in enumerate(at.tolist()):
+                if offs[pos + 1] > offs[pos]:
+                    parts_t[slot].append(bt[offs[pos]:offs[pos + 1]])
+                    parts_v[slot].append(bv[offs[pos]:offs[pos + 1]])
+            acc.merge_ns += _clock() - t0
+        t0 = _clock()
         strategy = self.opts.conflict_strategy
-        out = [merge_replica_points(parts_t[j], parts_v[j], strategy)
-               for j in range(n)]
+        merged: Dict[bytes, dict] = {}
+        for sid, slot in slot_of.items():
+            t, v = merge_replica_points(parts_t[slot], parts_v[slot], strategy)
+            merged[sid] = {"tags": tags[slot], "t": t, "v": v}
         acc.merge_ns += _clock() - t0
-        return out
+        return merged
 
     def aggregate(self, ns: bytes, query, start_ns: int, end_ns: int,
                   name_only: bool = False, field_filter=(),

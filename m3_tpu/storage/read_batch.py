@@ -10,7 +10,7 @@ decoded planes the block cache holds answers with row slices. The rows
 of every other block are kept as PIECES (storage/tiles.py), gathered
 into tiles and decoded ONE DISPATCH A GEOMETRY for the whole fetch
 (`tiles.decode_stacked` over `block.decode_rows`: the mechanism the
-client's `Session._columnar_points` decodes a replica's frame with),
+client's `Session._one_pass_points` decodes a fetch's frames with),
 never one a (series, block).
 
 Admission. A block earns its place by touches (`admit_after`, a row

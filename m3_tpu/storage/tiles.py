@@ -10,8 +10,10 @@ caller's series list) under what a tile's rows must share — block start,
 window, time unit, words width; `gather_tiles` then makes one tile a
 key, cut at a row bound. The decode side stacks tiles of one geometry
 (window, time unit, words width) into one call (`decode_stacked`),
-whoever's decode it is: the client's `decode_tile` over a frame, the
-node's `decode_rows` over its own cold rows."""
+whoever's decode it is: the client's `decode_stack` over ALL the frames
+a fetch holds (one decode a fetch, whichever replicas sent the tiles:
+client/session.py::_one_pass_points), the node's `decode_rows` over its
+own cold rows."""
 
 from __future__ import annotations
 

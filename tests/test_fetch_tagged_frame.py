@@ -98,8 +98,8 @@ def client_points(frame):
     session = Session.__new__(Session)
     session.opts = SessionOptions()
     frame = wire.decode(wire.encode(frame))
-    points = session._columnar_points(frame, _ReadCosts())
-    return {e["id"]: tv for e, tv in zip(frame["series"], points)}
+    merged = session._merged_points([frame], _ReadCosts())
+    return {sid: (e["t"], e["v"]) for sid, e in merged.items()}
 
 
 QUERIES = {
