@@ -313,7 +313,8 @@ class RawTCPServer:
         return f"{h}:{p}"
 
     def start(self) -> "RawTCPServer":
-        threading.Thread(target=self._server.serve_forever, daemon=True).start()
+        threading.Thread(target=self._server.serve_forever,
+                         name="accept-aggregator-rawtcp", daemon=True).start()
         return self
 
     def close(self):
@@ -471,7 +472,8 @@ class HTTPAdminServer:
         return f"http://{h}:{p}"
 
     def start(self) -> "HTTPAdminServer":
-        threading.Thread(target=self._server.serve_forever, daemon=True).start()
+        threading.Thread(target=self._server.serve_forever,
+                         name="accept-aggregator-admin", daemon=True).start()
         return self
 
     def close(self):

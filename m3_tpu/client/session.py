@@ -565,7 +565,8 @@ class HostQueue:
         self._cond = threading.Condition()
         self._closed = False
         self._busy = False
-        self._thread = threading.Thread(target=self._run, daemon=True)
+        self._thread = threading.Thread(
+            target=self._run, name="host-queue", daemon=True)
         self._thread.start()
 
     def enqueue(self, op: _WriteOp):
@@ -701,7 +702,8 @@ class Session:
         self._clients: Dict[str, HostClient] = {}
         self._queues: Dict[str, HostQueue] = {}
         self._lock = threading.RLock()  # _queue -> _client nest on this lock
-        self._pool = ThreadPoolExecutor(max_workers=opts.fanout_workers)
+        self._pool = ThreadPoolExecutor(max_workers=opts.fanout_workers,
+                                        thread_name_prefix="fanout")
         self._shard_set: Optional[ShardSet] = None
         # host id -> ids of series whose tags that host has acknowledged
         self._tagged: Dict[str, set] = {}

@@ -141,7 +141,8 @@ class KVServer:
         return f"{h}:{p}"
 
     def start(self) -> "KVServer":
-        threading.Thread(target=self._server.serve_forever, daemon=True).start()
+        threading.Thread(target=self._server.serve_forever,
+                         name="accept-kv", daemon=True).start()
         return self
 
     def close(self):
@@ -329,7 +330,8 @@ class RemoteStore:
     def _ensure_watch_thread(self, key: str):
         if key in self._watch_threads:
             return
-        t = threading.Thread(target=self._watch_loop, args=(key,), daemon=True)
+        t = threading.Thread(target=self._watch_loop, args=(key,),
+                             name="kv-watch", daemon=True)
         self._watch_threads[key] = t
         t.start()
 

@@ -50,7 +50,7 @@ class CarbonServer:
 
     def start(self) -> "CarbonServer":
         self._thread = threading.Thread(target=self._server.serve_forever,
-                                        daemon=True)
+                                        name="accept-carbon", daemon=True)
         self._thread.start()
         return self
 

@@ -414,7 +414,7 @@ class HTTPApi:
         with tracing.child_span("remote_write.append") as sp:
             # the request as ONE batch: one admission, one append per
             # shard touched, one commit-log append. A traced request's
-            # phases (`id_ns`, `buffer_ns`, `commitlog_ns`) land on this
+            # phases (`buffer_ns`, `commitlog_ns`) land on this
             # span once, from the layers below. A row's tags and id are
             # the label memo's: shared with every other request that
             # carries the series, so nothing below may write to them.
@@ -589,7 +589,8 @@ class HTTPApi:
             do_GET = do_POST = do_DELETE = do_PUT = _dispatch
 
         self._server = _StampingServer((host, port), Handler)
-        threading.Thread(target=self._server.serve_forever, daemon=True).start()
+        threading.Thread(target=self._server.serve_forever,
+                         name="accept-coordinator-http", daemon=True).start()
         return self
 
     @property

@@ -12,7 +12,6 @@ from typing import Callable, Dict, Optional, Sequence
 from ..aggregator.handler import decode_aggregated_batch
 from ..metrics import id as metric_id
 from ..metrics.metric import MetricType
-from ..utils import tracing
 from ..utils.health import AdmissionGate, Priority
 from ..utils.instrument import ROOT
 from .downsample import Downsampler
@@ -109,8 +108,7 @@ class DownsamplerAndWriter:
 
     def _storage_write_batch(self, samples: Sequence[tuple], sids):
         if sids is None:
-            with tracing.phase("id"):  # `id_ns` of a detailed span
-                sids = [_series_id(tags) for tags, _t, _v in samples]
+            sids = [_series_id(tags) for tags, _t, _v in samples]
         batch_write = getattr(self._storage, "write_batch", None)
         if batch_write is not None:
             batch_write(sids, [s[0] for s in samples],

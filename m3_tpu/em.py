@@ -259,7 +259,8 @@ class AgentServer:
     def start(self) -> "AgentServer":
         import threading
 
-        threading.Thread(target=self._server.serve_forever, daemon=True).start()
+        threading.Thread(target=self._server.serve_forever,
+                         name="accept-em", daemon=True).start()
         return self
 
     def close(self):

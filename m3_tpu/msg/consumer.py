@@ -193,7 +193,8 @@ class Consumer:
 
     def start(self):
         self._thread = threading.Thread(
-            target=self._server.serve_forever, daemon=True)
+            target=self._server.serve_forever, name="accept-msg-consumer",
+            daemon=True)
         self._thread.start()
         return self
 

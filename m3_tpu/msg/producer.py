@@ -143,7 +143,8 @@ class MessageWriter:
             self._breaker.record_failure()
             return False
         self._breaker.record_success()
-        self._reader = threading.Thread(target=self._read_acks, daemon=True)
+        self._reader = threading.Thread(
+            target=self._read_acks, name="producer-acks", daemon=True)
         self._reader.start()
         return True
 

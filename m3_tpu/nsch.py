@@ -121,7 +121,8 @@ class Agent:
                 if sleep > 0:
                     self._stop.wait(sleep)
 
-        self._thread = threading.Thread(target=loop, daemon=True)
+        self._thread = threading.Thread(target=loop, name="nsch",
+                                        daemon=True)
         self._thread.start()
         return self
 

@@ -40,6 +40,7 @@ import numpy as np
 from . import bits64 as b64
 from .bits64 import U32
 from .ref_codec import REWRITE_THRESHOLD
+from ..utils import tracing
 
 I32 = jnp.int32
 
@@ -1181,8 +1182,14 @@ def decode_plane(words, npoints, *, window: int, unit_nanos: int = 1,
     row_mesh = _row_mesh(words)
     run = _decode_fused_jit(int(window), int(unit_nanos), bool(with_f32),
                             route, row_mesh)
-    jwords = jnp.asarray(words)
-    jnp_ = jnp.asarray(npoints, I32)
+    # The call's anatomy, as stretches of the detailed span it runs
+    # under (the session's client.fetch_tagged, a cold read's
+    # query.fetch): `h2d`, `launch`, `device_wait` (the device's work and
+    # the first fetch), `d2h` (the other fetches), `layout`. No stretch
+    # synchronises anything the call did not wait for already.
+    with tracing.phase("h2d"):
+        jwords = jnp.asarray(words)
+        jnp_ = jnp.asarray(npoints, I32)
     lone = route == "pallas" and jwords.shape[0] == 1
     if lone:
         # No one-row program on this route: the kernel's tile cut to one
@@ -1217,29 +1224,38 @@ def decode_plane(words, npoints, *, window: int, unit_nanos: int = 1,
                                    bool(with_f32), "xla", row_mesh)
             return fb(jwords, jnp_)
 
-        out = guard.dispatch("codec.decode", _pallas_decode, _xla_decode)
+        with tracing.phase("launch"):
+            out = guard.dispatch("codec.decode", _pallas_decode, _xla_decode)
     else:
-        out = run(jwords, jnp_)
-    # ascontiguousarray: a TPU array of minor dimension 2 comes back with
-    # the device layout's strides, and a dtype view needs the pair axis
-    # contiguous (a no-op where the fetch is already C-ordered).
-    pairs_ts = np.ascontiguousarray(out["ts"])
-    pairs_v = np.ascontiguousarray(out["vals"])
-    ts = pairs_ts.view(np.int64)[..., 0]
-    vals = pairs_v.view(np.float64)[..., 0]
-    f32 = np.asarray(out["f32"]) if with_f32 else None
-    rows = np.flatnonzero(np.asarray(out["fix"]))
-    if rows.size:
-        k = np.asarray(out["k"])[rows].astype(np.float64)
-        raw = np.ascontiguousarray(pairs_v[rows]).view(np.int64)[..., 0]
-        fixed = raw.astype(np.float64) / np.power(10.0, k)[:, None]
-        if not vals.flags.writeable:
-            vals = vals.copy()
-        vals[rows] = fixed
-        if with_f32:
-            if not f32.flags.writeable:
-                f32 = f32.copy()
-            f32[rows] = fixed.astype(np.float32)
+        with tracing.phase("launch"):
+            out = run(jwords, jnp_)
+    with tracing.phase("device_wait"):
+        pairs_ts = np.asarray(out["ts"])
+    with tracing.phase("d2h"):
+        pairs_v = np.asarray(out["vals"])
+        f32 = np.asarray(out["f32"]) if with_f32 else None
+        rows = np.flatnonzero(np.asarray(out["fix"]))
+        k = np.asarray(out["k"])[rows].astype(np.float64) if rows.size \
+            else None
+    with tracing.phase("layout"):
+        # ascontiguousarray: a TPU array of minor dimension 2 comes back
+        # with the device layout's strides, and a dtype view needs the
+        # pair axis contiguous (a no-op where the fetch is already
+        # C-ordered).
+        pairs_ts = np.ascontiguousarray(pairs_ts)
+        pairs_v = np.ascontiguousarray(pairs_v)
+        ts = pairs_ts.view(np.int64)[..., 0]
+        vals = pairs_v.view(np.float64)[..., 0]
+        if rows.size:
+            raw = np.ascontiguousarray(pairs_v[rows]).view(np.int64)[..., 0]
+            fixed = raw.astype(np.float64) / np.power(10.0, k)[:, None]
+            if not vals.flags.writeable:
+                vals = vals.copy()
+            vals[rows] = fixed
+            if with_f32:
+                if not f32.flags.writeable:
+                    f32 = f32.copy()
+                f32[rows] = fixed.astype(np.float32)
     if lone:
         ts, vals = ts[:1], vals[:1]
         f32 = f32[:1] if with_f32 else None

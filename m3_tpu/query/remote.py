@@ -105,7 +105,8 @@ class RemoteStorageServer:
         return f"{h}:{p}"
 
     def start(self) -> "RemoteStorageServer":
-        threading.Thread(target=self._server.serve_forever, daemon=True).start()
+        threading.Thread(target=self._server.serve_forever,
+                         name="accept-query-remote", daemon=True).start()
         return self
 
     def close(self):

@@ -60,11 +60,10 @@ class LocalStorage:
         """Columnar write: one shard-routed db.write_batch append instead
         of a per-sample write loop (the coordinator ingest batch path).
         What a coordinator writes it writes again every scrape, so the
-        rows are routed through the shard memo (`id_ns` of a detailed
-        span) and the node hashes nothing."""
+        rows are routed through the shard memo and the node hashes
+        nothing."""
         series_ids = list(series_ids)
-        with tracing.phase("id"):
-            shard_ids = self._db.shard_set.lookup_memo(series_ids)
+        shard_ids = self._db.shard_set.lookup_memo(series_ids)
         self._db.write_batch(self._namespace, series_ids, ts, vals,
                              tags=list(tags), shard_ids=shard_ids)
 

@@ -143,7 +143,8 @@ class HTTPJSONServer:
         return f"http://{h}:{p}"
 
     def start(self) -> "HTTPJSONServer":
-        threading.Thread(target=self._server.serve_forever, daemon=True).start()
+        threading.Thread(target=self._server.serve_forever,
+                         name="accept-node-httpjson", daemon=True).start()
         return self
 
     def close(self):

@@ -42,7 +42,6 @@ class RPCError(Exception):
 # A fetch_tagged frame, counted: the tiles it carries and the (shard,
 # block) gathers folded into them.
 _FRAME_TILES = ROOT.counter("rpc.fetch_tagged.tiles")
-_FRAME_SHARD_BLOCKS = ROOT.counter("rpc.fetch_tagged.shard_blocks")
 
 # The most rows a fetch_tagged tile carries (~7.8 MB of words at a
 # 120-point block's 475): a read of every series still charges, and can
@@ -140,7 +139,10 @@ class NodeService:
         # and release them all on the way out — 1k rejected queries leak
         # zero budget (asserted by scripts/overload_smoke.py).
         priority = method_priority(method, priority_hint)
-        sp = tracing.TRACER.span_from(trace_ctx, "rpc." + method)
+        # the node rides from the start: the runtime probe counts this
+        # thread's CPU under the role `rpc` of that node
+        sp = tracing.TRACER.span_from(trace_ctx, "rpc." + method,
+                                      host=self.host_id)
         # A shed BEFORE the scope runs (gate full) must log empty costs,
         # not the previous request's on this reused serving thread.
         xlimits.reset_last_totals()
@@ -163,7 +165,6 @@ class NodeService:
                             self._local.deadline = None
                             self._local.priority = None
                 if sp.sampled:
-                    sp.set_tag("host", self.host_id)
                     if encode:
                         t_enc = tracing.clock_ns()
                         result = wire.Encoded(wire.encode(result))
@@ -337,6 +338,7 @@ class NodeService:
         buf_t = [np.zeros(0, np.int64)] * n
         buf_v = [np.zeros(0, np.float64)] * n
         snapshots = []
+        buffer_ns = 0   # the chunks' `ShardBuffer.read` loops, inside read_ns
         for shard, idxs, poss in groups:
             # Buffer reads take the shard write lock in bounded CHUNKS —
             # a dashboard-sized member set must not stall every
@@ -359,9 +361,12 @@ class NodeService:
                 part = poss[c0:c0 + BUFFER_CHUNK]
                 with shard.write_lock:  # snapshot racing tick's expiry/seal
                     blocks.update(shard.blocks)
+                    t_buf = _clock() if timed else 0
                     for idx, pos in zip(idxs[c0:c0 + BUFFER_CHUNK], part):
                         buf_t[pos], buf_v[pos] = shard.buffer.read(
                             idx, start_ns, end_ns)
+                    if timed:
+                        buffer_ns += _clock() - t_buf
                 charge_read(n_bytes=sum(
                     buf_t[pos].nbytes + buf_v[pos].nbytes for pos in part))
             snapshots.append(blocks)
@@ -370,7 +375,6 @@ class NodeService:
         # PIECE under what a tile's rows must share: block start, window,
         # time unit, words width.
         pieces: Dict[tuple, list] = {}
-        shard_blocks_n = 0
         for (shard, idxs, poss), blocks in zip(groups, snapshots):
             idxs_a = None
             for bs, blk in blocks.items():
@@ -388,7 +392,6 @@ class NodeService:
                     piece = (blk, at, poss_a[present])
                 else:
                     continue
-                shard_blocks_n += 1
                 pieces.setdefault(piece_key(blk), []).append(piece)
 
         def before_tile(n_bytes: int):
@@ -410,14 +413,13 @@ class NodeService:
             "v": (np.concatenate(buf_v) if n else np.zeros(0, np.float64)),
         }
         _FRAME_TILES.inc(len(tiles))
-        _FRAME_SHARD_BLOCKS.inc(shard_blocks_n)
         if timed:
             acc.add_cost("series_n", n)
             acc.add_cost("index_ns", t_index - t_start)
             acc.add_cost("tile_ns", tile_ns)
             acc.add_cost("read_ns", _clock() - t_index - tile_ns)
+            acc.add_cost("buffer_ns", buffer_ns)
             acc.add_cost("tiles_n", len(tiles))
-            acc.add_cost("shard_blocks_n", shard_blocks_n)
         return {"series": out, "bufs": bufs, "tiles": tiles,
                 "exhaustive": True}
 
@@ -718,7 +720,9 @@ class NodeServer:
         return f"{self.address[0]}:{self.address[1]}"
 
     def start(self):
-        self._thread = threading.Thread(target=self._server.serve_forever, daemon=True)
+        self._thread = threading.Thread(
+            target=self._server.serve_forever, name="accept-node-rpc",
+            daemon=True)
         self._thread.start()
         return self
 

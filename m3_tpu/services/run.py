@@ -341,7 +341,8 @@ def run_aggregator(cfg: AggregatorConfig, flush_handler=None,
             except Exception:  # noqa: BLE001 - keep the loop alive
                 pass
 
-    handle.flush_thread = threading.Thread(target=flush_loop, daemon=True)
+    handle.flush_thread = threading.Thread(
+        target=flush_loop, name="aggregator-flush", daemon=True)
     handle.flush_thread.start()
     return handle
 

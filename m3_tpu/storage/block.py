@@ -372,8 +372,8 @@ def decode_rows(words, npoints, window: int, unit_nanos: int, acc=None
     whichever blocks they come from, to (ts_ns [N, W], vals [N, W]): one
     dispatch, the rows padded to a bucket of ROW_BUCKETS with copies of
     the first (always valid), and one more for every 1,024 rows past
-    that. `acc` (a detailed span) receives `cold_rows_n`,
-    `cold_dispatch_n` and `cold_h2d_bytes`; the caller times it."""
+    that. `acc` (a detailed span) receives `cold_rows_n` and
+    `cold_dispatch_n`; the caller times it."""
     words = np.asarray(words)
     npoints = np.asarray(npoints, np.int32)
     n = len(words)
@@ -383,7 +383,6 @@ def decode_rows(words, npoints, window: int, unit_nanos: int, acc=None
     top = ROW_BUCKETS[-1]
     _warm_buckets(words, npoints, window, unit_nanos)
     out_t, out_v = [], []
-    h2d = 0
     for lo in range(0, n, top):
         w, k = words[lo:lo + top], npoints[lo:lo + top]
         have = len(w)
@@ -391,7 +390,6 @@ def decode_rows(words, npoints, window: int, unit_nanos: int, acc=None
         if rows != have:
             w = np.concatenate([w, np.repeat(w[:1], rows - have, 0)])
             k = np.concatenate([k, np.repeat(k[:1], rows - have)])
-        h2d += w.nbytes + k.nbytes
         ts, vals = _dispatch_decode(w, k, window, unit_nanos)
         out_t.append(ts[:have])
         out_v.append(vals[:have])
@@ -401,7 +399,6 @@ def decode_rows(words, npoints, window: int, unit_nanos: int, acc=None
     if acc is not None:
         acc.add_cost("cold_rows_n", n)
         acc.add_cost("cold_dispatch_n", dispatches)
-        acc.add_cost("cold_h2d_bytes", h2d)
     if dispatches == 1:
         return out_t[0], out_v[0]
     return np.concatenate(out_t), np.concatenate(out_v)

@@ -248,7 +248,8 @@ class Mediator:
                 except Exception:  # noqa: BLE001 — background loop survives
                     pass
 
-        self._thread = threading.Thread(target=loop, daemon=True)
+        self._thread = threading.Thread(target=loop, name="mediator",
+                                        daemon=True)
         self._thread.start()
         return self
 

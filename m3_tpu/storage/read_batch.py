@@ -73,8 +73,8 @@ def read_many(ns, shard_set, ids: Sequence[bytes], start_ns: int,
     `lock_wait_ns`, `buffer_ns`, `block_ns` / `block_n` (the sealed
     blocks' part, a (series, block) pair counting one: resolve, cache
     lookup, gather, decode), `merge_ns`, and of the cold rows
-    `cold_decode_ns` beside decode_rows' own `cold_rows_n`,
-    `cold_dispatch_n`, `cold_h2d_bytes`."""
+    `cold_decode_ns` beside decode_rows' own `cold_rows_n` and
+    `cold_dispatch_n`."""
     n = len(ids)
     out: List[Optional[tuple]] = [None] * n
     if not n:
@@ -222,7 +222,7 @@ def read_many(ns, shard_set, ids: Sequence[bytes], start_ns: int,
         acc.add_cost("block_n", block_n)
         acc.add_cost("cold_decode_ns", cold_ns)
         if not pieces:      # a fetch that read nothing cold says so
-            for kind in ("cold_rows_n", "cold_dispatch_n", "cold_h2d_bytes"):
+            for kind in ("cold_rows_n", "cold_dispatch_n"):
                 acc.add_cost(kind, 0)
         acc.add_cost("merge_ns", _clock() - t4)
     return out

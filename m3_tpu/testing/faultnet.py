@@ -122,6 +122,7 @@ class FaultProxy:
 
     def start(self) -> "FaultProxy":
         self._accept_thread = threading.Thread(target=self._accept_loop,
+                                               name="accept-faultnet",
                                                daemon=True)
         self._accept_thread.start()
         return self
@@ -151,7 +152,7 @@ class FaultProxy:
                 _rst_close(client)
                 continue
             threading.Thread(target=self._serve, args=(client, conn_idx),
-                             daemon=True).start()
+                             name="faultnet-serve", daemon=True).start()
 
     def _serve(self, client: socket.socket, conn_idx: int):
         try:
@@ -176,7 +177,7 @@ class FaultProxy:
                                     (upstream, client, S2C)):
             threading.Thread(target=self._pump,
                              args=(src, dst, conn_idx, direction, dead),
-                             daemon=True).start()
+                             name="faultnet-pump", daemon=True).start()
 
     # ----------------------------------------------------------------- pump
 

@@ -162,7 +162,7 @@ class LoadGen:
             if delay > 0:
                 time.sleep(delay)
             th = threading.Thread(target=fire, args=(due, kind, phase),
-                                  daemon=True)
+                                  name="loadgen-fire", daemon=True)
             th.start()
             threads.append(th)
         deadline = time.monotonic() + join_timeout_s

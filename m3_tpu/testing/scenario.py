@@ -707,7 +707,8 @@ class KillRestartScenario:
                     ready.set()
             ready.set()  # EOF: the child died before becoming ready
 
-        threading.Thread(target=_read, daemon=True).start()
+        threading.Thread(target=_read, name="scenario-read",
+                         daemon=True).start()
         ready.wait(timeout=max(60.0, self.opts.restart_budget_s))
         endpoint = state.get("endpoint")
         if endpoint is None:

@@ -28,7 +28,8 @@ class Panicmon:
 
     def start(self) -> "Panicmon":
         self._proc = subprocess.Popen(self.argv)
-        self._thread = threading.Thread(target=self._watch, daemon=True)
+        self._thread = threading.Thread(target=self._watch, name="panicmon",
+                                        daemon=True)
         self._thread.start()
         return self
 

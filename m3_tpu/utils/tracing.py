@@ -49,19 +49,33 @@ dump (the goroutine-dump analog of /debug/pprof/goroutine?debug=2).
 `PROFILER` runs the sampling loop on ONE shared background thread with a
 hard seconds cap (`M3_TPU_PROFILE_MAX_S`) so a /debug/pprof/profile
 request can neither stall a serving thread past its deadline nor stack N
-concurrent sampling loops."""
+concurrent sampling loops.
+
+Runtime: while somebody asks for traces, the tracer runs ONE probe thread
+(`RuntimeProbe`, the analog of the Go runtime readings — goroutines, GC,
+scheduler — the reference's services report through their `instrument`
+scope) that accounts for the one GIL the process's Python threads share:
+how late each of its wakes got to run (what a handler thread whose
+socket turned readable pays before its first bytecode), CPU and
+run-queue time by thread role, and every stall with the thread that
+caused it. It starts on an asked-for root and ends itself; with tracing
+off no thread exists and nothing is read."""
 
 from __future__ import annotations
 
 import collections
 import contextlib
+import gc
 import os
 import random as _random
+import resource
 import sys
 import threading
 import time
 import traceback
 from typing import Dict, List, NamedTuple, Optional
+
+from . import instrument
 
 # ---------------------------------------------------------------- spans
 
@@ -251,6 +265,381 @@ def _env_rate() -> float:
         return 1.0
 
 
+# --------------------------------------------------------------- runtime
+#
+# One GIL serves every Python thread of the process, and nothing else in
+# the program measures it. While traces are asked for, the probe below
+# wakes on a fixed schedule on the spans' clock and keeps three books:
+#
+# - each wake's LATENESS: how long after it was due the probe got to
+#   run — what any thread that turns runnable (a handler whose socket
+#   became readable) pays before its first bytecode. Net of the probe's
+#   own run-queue time (the machine's part) it is GIL wait;
+# - CPU and run-queue time BY THREAD ROLE, once a second and at every
+#   stall. A thread's role is the kind of the last root span it opened
+#   (`http.` request, `rpc.` rpc of a node, `mediator.tick` tick,
+#   `bootstrap.` bootstrap), else what its name says (accept, fanout,
+#   prep, probe, main), else `python-other`; a thread of the process that is
+#   no Python thread is `native` (XLA's, the TPU runtime's). A request's
+#   thread lives 10-70 ms, so a root counts its thread's CPU when it
+#   ends and the sample counts whatever a live thread has used past
+#   that mark: every thread's CPU clock is counted once, up to a mark;
+# - every STALL (a wake later than STALL_NS) with the thread whose CPU
+#   clock advanced most across it.
+#
+# What adds up goes to counters under `runtime.` on instrument.ROOT
+# (/debug/vars; the benchmark snapshots them around its window); each
+# wake and each stall go to rings of the probe's own (/debug/traces,
+# key `runtime`).
+
+PROBE_PERIOD_NS = 40_000_000        # a wake, so one forced GIL hand-off
+PROBE_SNAPSHOT_WAKES = 3            # Python threads' CPU clocks, every 120 ms
+PROBE_SAMPLE_NS = 1_000_000_000     # CPU by role
+STALL_NS = 100_000_000              # a wake later than this is a stall
+PROBE_IDLE_EXIT_NS = 10_000_000_000  # no asked-for root for this long: end
+PROBE_THREAD_NAME = "runtime-probe"
+
+NATIVE = ("native", "")
+_ROOT_ROLES = (("http.", "request"), ("rpc.", "rpc"),
+               ("mediator.tick", "tick"), ("bootstrap.", "bootstrap"))
+_NAME_ROLES = (("accept", "accept"), ("fanout", "fanout"),
+               ("tsz-prep", "prep"), (PROBE_THREAD_NAME, "probe"),
+               ("MainThread", "main"))
+_ADDITIVE = ("process_cpu_ns", "probe.wakes", "probe.late_ns",
+             "probe.wall_ns", "stalls", "stall_ns", "faults.major",
+             "faults.minor", "switches.voluntary", "switches.involuntary")
+_RUSAGE = (("faults.major", "ru_majflt"), ("faults.minor", "ru_minflt"),
+           ("switches.voluntary", "ru_nvcsw"),
+           ("switches.involuntary", "ru_nivcsw"))
+
+
+def _root_role(span) -> Optional[tuple]:
+    """(role, node) of the thread that opened this root, None for a kind
+    of root that names no role. An rpc root carries its node (`host`)."""
+    name = span.name
+    for prefix, role in _ROOT_ROLES:
+        if name.startswith(prefix):
+            return (role, str(span.tags.get("host", ""))
+                    if role == "rpc" else "")
+    return None
+
+
+def _name_role(name: str) -> tuple:
+    for prefix, role in _NAME_ROLES:
+        if name.startswith(prefix):
+            return (role, "")
+    return ("python-other", "")
+
+
+def _role_label(role: tuple) -> str:
+    return "@".join(r for r in role if r)
+
+
+def _frame_label(f) -> str:
+    code = f.f_code
+    return (f"{code.co_name} "
+            f"({code.co_filename.rsplit('/', 1)[-1]}:{f.f_lineno})")
+
+
+def _thread_cpu_ns(tid: int) -> Optional[int]:
+    """A thread's CPU clock by its kernel id (Linux's per-thread clock
+    id, what pthread_getcpuclockid computes — without reading a thread
+    descriptor that may be gone). No GIL release; None for a dead id."""
+    try:
+        return time.clock_gettime_ns((~tid << 3) | 6)
+    except OSError:
+        return None
+
+
+def _schedstat(tid: int) -> Optional[tuple]:
+    """(on-CPU ns, run-queue ns) of a thread of this process, None once
+    it has gone (or on a kernel without the file)."""
+    try:
+        with open(f"/proc/self/task/{tid}/schedstat", "rb") as f:
+            cpu, runq = f.read().split()[:2]
+        return int(cpu), int(runq)
+    except (OSError, ValueError):
+        return None
+
+
+class RuntimeProbe:
+    """The tracer's probe thread and its books (section comment above).
+    `asked()` starts it; it ends itself PROBE_IDLE_EXIT_NS after the last
+    asked-for root. Nothing here runs, and no counter exists, before the
+    first `asked()`."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.running = False
+        self._asked_ns = 0
+        # (due_ns, late_ns, the probe's run-queue ns across the wake or
+        # None), ~160 s of wakes; and the stalls, as dicts
+        self.wakes = collections.deque(maxlen=8192)
+        self.stalls = collections.deque(maxlen=256)
+        self.threads_by_role: Dict[str, int] = {}  # the newest sample's
+        self._role_of: Dict[int, tuple] = {}   # kernel thread id -> role
+        self._cpu_seen: Dict[int, int] = {}    # ... -> CPU ns counted
+        self._runq_seen: Dict[int, int] = {}
+        self._counters: Dict[tuple, instrument.Counter] = {}
+        self._has_runq = False
+        self._rusage = None
+
+    # ------------------------------------------------------- the tracer's
+
+    def asked(self):
+        """Somebody asked for a trace: run, or keep running."""
+        with self._lock:
+            self._asked_ns = clock_ns()
+            if not self.running:
+                self._start()
+
+    def root_opened(self, span):
+        role = _root_role(span)
+        if role is not None:
+            with self._lock:
+                self._role_of[threading.get_native_id()] = role
+
+    def root_ended(self, span):
+        """The root's thread, counted up to here under the root's role:
+        a thread a connection is gone before any sample sees it, so a
+        request's root reads its thread's run-queue time too (one file,
+        after the response's last byte; an rpc handler outlives its
+        roots and is left to the samples)."""
+        role = _root_role(span)
+        if role is None or "cpu_ns" not in span.tags:
+            return      # (a root that names a role is detailed)
+        cpu = span._cpu0 + span.tags["cpu_ns"]  # the thread's CPU clock
+        tid = threading.get_native_id()
+        runq = None
+        if role[0] == "request" and self._has_runq:
+            cpu, runq = _schedstat(tid) or (cpu, None)
+        with self._lock:
+            self._role_of[tid] = role
+            self._count(tid, role, cpu, runq)
+
+    def snapshot(self) -> dict:
+        """What /debug/traces serves under `runtime`."""
+        late = sorted(w[1] for w in list(self.wakes))
+        return {
+            "running": self.running,
+            "period_ms": PROBE_PERIOD_NS / 1e6,
+            "wakes": len(late),
+            "late_ms": {q: round(late[min(len(late) - 1,
+                                          int(len(late) * f))] / 1e6, 3)
+                        for q, f in (("p50", .5), ("p95", .95), ("max", 1))}
+            if late else {},
+            "threads": dict(self.threads_by_role),
+            "stalls": list(self.stalls),
+        }
+
+    # ------------------------------------------------------------ the books
+
+    def _counter(self, kind: str, role: tuple = ("", "")):
+        c = self._counters.get((kind, role))
+        if c is None:
+            tags = {k: v for k, v in zip(("role", "node"), role) if v}
+            c = self._counters[kind, role] = instrument.ROOT.sub_scope(
+                "runtime", **tags).counter(kind)
+        return c
+
+    def _count(self, tid: int, role: tuple, cpu: int, runq: Optional[int],
+               baseline: bool = False):
+        """Move a thread's marks to (cpu, runq), counting what lies past
+        them under `role`. A thread without marks was born after the
+        baseline (which lists every thread): all of its time counts; a
+        clock below its mark is a new thread on a reused id."""
+        for seen, kind, now in ((self._cpu_seen, "cpu_ns", cpu),
+                                (self._runq_seen, "runq_ns", runq)):
+            if now is None:
+                continue
+            had = seen.get(tid, 0)
+            if not baseline and now != had:
+                self._counter(kind, role).inc(now - had if now > had else now)
+            seen[tid] = now
+
+    def _python_threads(self) -> Dict[int, threading.Thread]:
+        return {t.native_id: t for t in threading.enumerate()
+                if t.native_id is not None}
+
+    def _read_sample(self) -> tuple:
+        """CPU and run-queue time of every thread of the process, and the
+        process's faults and context switches. Holds no lock: a file
+        read gives the GIL away."""
+        py = self._python_threads()
+        try:
+            tids = [int(d) for d in os.listdir("/proc/self/task")]
+        except (OSError, ValueError):
+            tids = list(py)
+        clocks = {}
+        for tid in tids:
+            if tid in py and self._has_runq:
+                got = _schedstat(tid)
+            else:
+                cpu = _thread_cpu_ns(tid)
+                got = None if cpu is None else (cpu, None)
+            if got is not None:
+                clocks[tid] = got
+        return py, clocks, resource.getrusage(resource.RUSAGE_SELF)
+
+    def _count_sample(self, py, clocks, usage, baseline: bool = False):
+        """Under the lock: what `_read_sample` read, counted by role."""
+        by_role: Dict[str, int] = {}
+        for tid, (cpu, runq) in clocks.items():
+            role = self._role_of.get(tid) or (
+                _name_role(py[tid].name) if tid in py else NATIVE)
+            self._count(tid, role, cpu, runq, baseline)
+            label = _role_label(role)
+            by_role[label] = by_role.get(label, 0) + 1
+        for book in (self._role_of, self._cpu_seen, self._runq_seen):
+            for tid in [t for t in book if t not in clocks]:
+                del book[tid]
+        if not baseline:
+            for kind, field in _RUSAGE:
+                self._counter(kind).inc(
+                    getattr(usage, field) - getattr(self._rusage, field))
+        self._rusage = usage
+        self.threads_by_role = by_role
+
+    def _sample(self):
+        read = self._read_sample()
+        with self._lock:
+            self._count_sample(*read)
+
+    def _python_cpu(self) -> Dict[int, int]:
+        out = {}
+        for tid in self._python_threads():
+            cpu = _thread_cpu_ns(tid)
+            if cpu is not None:
+                out[tid] = cpu
+        return out
+
+    # ------------------------------------------------------------ the thread
+
+    def _start(self):
+        """Under the lock, by the thread that asked: the baseline every
+        later count starts from, then the thread."""
+        got = _schedstat(threading.get_native_id())
+        self._has_runq = got is not None and got[0] > 0
+        for kind in _ADDITIVE:
+            self._counter(kind)
+        self._count_sample(*self._read_sample(), baseline=True)
+        self.running = True
+        threading.Thread(target=self._run, name=PROBE_THREAD_NAME,
+                         daemon=True).start()
+
+    def _run(self):
+        me = threading.get_native_id()
+        fd = -1
+        if self._has_runq:  # one read a wake: the file stays open
+            fd = os.open(f"/proc/self/task/{me}/schedstat", os.O_RDONLY)
+        try:
+            self._loop(me, fd)
+        finally:
+            if fd >= 0:
+                os.close(fd)
+
+    def _loop(self, me: int, fd: int):
+        def own_runq():
+            return int(os.pread(fd, 64, 0).split()[1]) if fd >= 0 else None
+
+        def books():    # Python threads' CPU clocks, the process's, and
+            # the collections so far by generation: who had the CPU
+            # across a stall, and whether it was the collector
+            return (self._python_cpu(), time.process_time_ns(),
+                    [g["collections"] for g in gc.get_stats()])
+
+        # A wake does little: two clock reads, one file read, one ring
+        # entry. What adds up is handed to the counters with each
+        # sample, so the books close on one instant.
+        n = late_sum = 0
+        t_books = last = clock_ns()
+        due = last + PROBE_PERIOD_NS
+        next_sample = last + PROBE_SAMPLE_NS
+        runq0 = own_runq()
+        before, cpu0, gcs0 = books()
+        cpu_counted = cpu0
+        while True:
+            time.sleep(max(0, due - clock_ns()) / 1e9)
+            now = clock_ns()
+            late = max(0, now - due)
+            runq1 = own_runq()
+            runq = None if runq1 is None else runq1 - runq0
+            runq0 = runq1
+            self.wakes.append((due, late, runq))
+            n += 1
+            late_sum += late
+            stalled = late > STALL_NS
+            idle = now - self._asked_ns > PROBE_IDLE_EXIT_NS
+            if stalled:
+                after, cpu1, gcs1 = books()
+                # (a young collection runs every few hundred allocations
+                # and takes microseconds: it explains no stall)
+                gens = [g for g in range(1, len(gcs1)) if gcs1[g] > gcs0[g]]
+                self._stall(due, now, runq, cpu1 - cpu0, before, after, me,
+                            max(gens, default=None))
+                before, cpu0, gcs0 = after, cpu1, gcs1
+            elif n % PROBE_SNAPSHOT_WAKES == 0:
+                before, cpu0, gcs0 = books()
+            if idle:
+                with self._lock:    # `asked` may have come in between
+                    idle = now - self._asked_ns > PROBE_IDLE_EXIT_NS
+                    if idle:
+                        self.running = False
+            if idle or stalled or now >= next_sample:
+                cpu1 = time.process_time_ns()
+                for kind, v in (("probe.wakes", n), ("probe.late_ns", late_sum),
+                                ("probe.wall_ns", now - t_books),
+                                ("process_cpu_ns", cpu1 - cpu_counted)):
+                    self._counter(kind).inc(v)
+                n = late_sum = 0
+                t_books, cpu_counted = now, cpu1
+                self._sample()
+                next_sample = now + PROBE_SAMPLE_NS
+                if idle:
+                    return
+            due += PROBE_PERIOD_NS
+            now = clock_ns()
+            if due <= now:      # what the probe's own work took is no lateness
+                due = now + PROBE_PERIOD_NS
+
+    def _stall(self, due: int, now: int, runq: Optional[int], cpu_ns: int,
+               before: Dict[int, int], after: Dict[int, int], me: int,
+               gc_generation: Optional[int]):
+        """One record: who had the CPU across the late wake, by the
+        Python threads' CPU clocks `before` (at most 120 ms before the
+        wake was due) and `after`; `cpu_ns` is the whole process's over
+        the same stretch, `gc_generation` the oldest generation past
+        the youngest that the collector finished over it
+        (`gc.get_stats`: no callback, so a collection costs nothing
+        more while the probe runs). Σ CPU far
+        below the stall's length with `runq_ns` high: the machine took
+        the CPU; with it low: a thread slept in C holding the GIL."""
+        threads = self._python_threads()
+        cpu, tid = max(((cpu - before.get(tid, 0), tid)
+                        for tid, cpu in after.items() if tid != me),
+                       default=(0, 0))
+        rec = {"start_ns": due, "end_ns": now, "late_ns": now - due,
+               "cpu_ns": cpu_ns, "runq_ns": runq, "held_by": None}
+        if cpu > 0:
+            th = threads.get(tid)
+            with self._lock:
+                role = self._role_of.get(tid) or _name_role(
+                    th.name if th is not None else "")
+            frames, f = [], th is not None and \
+                sys._current_frames().get(th.ident)
+            while f and len(frames) < 5:
+                frames.append(_frame_label(f))
+                f = f.f_back
+            rec["held_by"] = {"role": _role_label(role),
+                              "thread": th.name if th is not None else "?",
+                              "cpu_ns": cpu, "frames": frames}
+        if gc_generation is not None:
+            rec["gc"] = gc_generation
+        self.stalls.append(rec)
+        self._counter("stalls").inc()
+        self._counter("stall_ns").inc(now - due)
+
+
 class Tracer:
     """Per-process tracer; thread-local span stacks, bounded root history,
     head-based root sampling."""
@@ -261,6 +650,7 @@ class Tracer:
         self._lock = threading.Lock()
         self._recent = collections.deque(maxlen=max_traces)
         self.sample_rate = _env_rate() if sample_rate is None else sample_rate
+        self.runtime = RuntimeProbe()
 
     def set_sample_rate(self, rate: float):
         self.sample_rate = min(1.0, max(0.0, float(rate)))
@@ -298,6 +688,7 @@ class Tracer:
         root is detailed."""
         if ctx is None:
             return NOOP_SPAN
+        self.runtime.asked()
         return Span(name, self, None, tags, remote=ctx, start_ns=start_ns,
                     cpu_start_ns=cpu_start_ns)
 
@@ -330,11 +721,15 @@ class Tracer:
     def _push(self, span: Span):
         if span._parent is not None:
             span._parent.children.append(span)
+        elif self.runtime.running:
+            self.runtime.root_opened(span)
         self._local.current = span
 
     def _pop(self, span: Span):
         self._local.current = span._parent
         if span._parent is None:
+            if self.runtime.running:
+                self.runtime.root_ended(span)
             with self._lock:
                 self._recent.append(span)
 
@@ -597,8 +992,7 @@ def profile(seconds: float = 1.0, hz: int = 100,
             stack = []
             f = frame
             while f is not None:
-                code = f.f_code
-                stack.append(f"{code.co_name} ({code.co_filename.rsplit('/', 1)[-1]}:{f.f_lineno})")
+                stack.append(_frame_label(f))
                 f = f.f_back
             counts[tuple(reversed(stack))] += 1
             total += 1
@@ -681,9 +1075,11 @@ PROFILER = ProfileRunner()
 
 def debug_traces_payload(trace_id: Optional[int] = None) -> dict:
     """/debug/traces body: recent span trees (optionally one trace) +
-    the slow-query ring."""
+    the slow-query ring + the runtime probe's ring (its own: a record a
+    second would push request trees out of the traces' 128)."""
     return {"traces": TRACER.recent_traces(trace_id=trace_id),
-            "slow": SLOW_QUERIES.entries()}
+            "slow": SLOW_QUERIES.entries(),
+            "runtime": TRACER.runtime.snapshot()}
 
 
 def debug_profile_payload(seconds: float) -> dict:

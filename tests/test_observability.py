@@ -1045,8 +1045,10 @@ class TestTracedRequestTree:
         c = append.costs
         assert c["samples_n"] == 60
         assert c["lock_wait_ns"] <= c["buffer_ns"]
-        assert c["id_ns"] + c["buffer_ns"] + c["commitlog_ns"] \
-            <= append.duration_ns
+        assert c["buffer_ns"] + c["commitlog_ns"] <= append.duration_ns
+        # the memo probe has no stretch of its own (0.28 us a sample):
+        # shard_memo_hit_share is its reading
+        assert "id_ns" not in c
 
     def test_error_answers_keep_their_status(self, served):
         coord, _now, tracer, _ = served
@@ -1084,7 +1086,13 @@ class TestRuleANoChildWhereSelfTimeIsRead:
         # cache admitted it, else one cold dispatch for them all
         assert (c["cold_rows_n"], c["cold_dispatch_n"]) in ((0, 0), (20, 1))
         assert c["cold_decode_ns"] <= c["block_ns"]
-        assert (c["cold_h2d_bytes"] > 0) == (c["cold_rows_n"] > 0)
+        # the decode call's anatomy, from inside tsz.decode_plane (the
+        # cold rows' call, or the admission's whole-block one): five
+        # stretches of this span
+        anatomy = ("h2d_ns", "launch_ns", "device_wait_ns", "d2h_ns",
+                   "layout_ns")
+        assert all(k in c for k in anatomy)
+        assert sum(c[k] for k in anatomy) <= c["block_ns"]
         assert ex.costs["bind_n"] == 1 and ex.costs["bind_ns"] <= ex.duration_ns
         if route == "plan":
             assert ex.costs["dispatch_n"] >= 1

@@ -161,9 +161,11 @@ def test_a_lone_miss_is_one_dispatch_and_never_a_one_row_program(
     assert [s[0] for s in shapes] == [block_mod.ROW_BUCKETS[0]] * 2
 
 
-def test_cold_rows_cost_a_dispatch_a_geometry_not_a_pair(store, cache):
+def test_cold_rows_cost_a_dispatch_a_geometry_not_a_pair(store, cache,
+                                                         monkeypatch):
     cache(1 << 30, admit_after=10**9)
     tracer = tracing.Tracer(sample_rate=1.0)
+    monkeypatch.setattr(tracing, "TRACER", tracer)  # decode_plane's phases
     with tracer.background_span("query.fetch") as sp:
         assert sp.detailed
         store.batched(store.ids, T0, store.end, sp)
@@ -176,7 +178,10 @@ def test_cold_rows_cost_a_dispatch_a_geometry_not_a_pair(store, cache):
                   for sh in store.ns.shards.values()
                   for b in sh.blocks.values()}
     assert 1 <= costs["cold_dispatch_n"] <= len(geometries)
-    assert costs["cold_h2d_bytes"] > 0 and costs["cold_decode_ns"] > 0
+    # each dispatch is one decode_plane call, its stretches on the span
+    assert costs["launch_n"] == costs["layout_n"] == costs["cold_dispatch_n"]
+    assert 0 < costs["device_wait_ns"] + costs["d2h_ns"] + costs["layout_ns"] \
+        <= costs["cold_decode_ns"]
 
 
 def test_unknown_and_unheld_ids(store, cache):
