@@ -362,9 +362,9 @@ class NodeService:
                 with shard.write_lock:  # snapshot racing tick's expiry/seal
                     blocks.update(shard.blocks)
                     t_buf = _clock() if timed else 0
-                    for idx, pos in zip(idxs[c0:c0 + BUFFER_CHUNK], part):
-                        buf_t[pos], buf_v[pos] = shard.buffer.read(
-                            idx, start_ns, end_ns)
+                    for pos, (t, v) in zip(part, shard.buffer.read_many(
+                            idxs[c0:c0 + BUFFER_CHUNK], start_ns, end_ns)):
+                        buf_t[pos], buf_v[pos] = t, v
                     if timed:
                         buffer_ns += _clock() - t_buf
                 charge_read(n_bytes=sum(

@@ -15,16 +15,32 @@ with last-arrival-wins on duplicate timestamps matching the reference's
 "latest write wins within a bucket" drain behavior. The acceptance window
 (buffer_past/buffer_future) bounds live buckets to ~3, mirroring
 buffer.go:51's bucketsLen=3 invariant structurally rather than by fixed
-array."""
+array.
+
+A read is the other half: a bucket's rows are grouped by series once, by
+the first read that meets them (`BlockBucket.group`), and a series' read
+is a slice of that grouping — the reference reads a series' own encoders
+(buffer.go ReadEncoded); here the per-series view is derived lazily so
+that the append stays three slice stores."""
 
 from __future__ import annotations
 
 import dataclasses
+from bisect import bisect_left
 from typing import Dict, Iterable, List, Optional, Tuple
 
 import numpy as np
 
 from ..utils import xtime
+from ..utils.instrument import ROOT
+
+# Reads of one series in one bucket: answered from the bucket's index by
+# series alone, or with a scan of the rows appended since it was built.
+_READ_INDEXED = ROOT.counter("storage.buffer.read.indexed")
+_READ_TAIL_SCANS = ROOT.counter("storage.buffer.read.tail_scans")
+_INDEX_BUILDS = ROOT.counter("storage.buffer.index.builds")
+_INDEX_ROWS = ROOT.counter("storage.buffer.index.rows")
+_NO_ROWS = np.zeros(0, np.intp)
 
 
 class _Cols:
@@ -77,10 +93,39 @@ class BlockBucket:
     # Rows already drained to a snapshot (exclusive); snapshot persistence
     # reuses the same columns without copying.
     snapshotted_rows: int = 0
+    # The index by series over rows [0:indexed_n), built by reads and
+    # never by an append: `order` is the stable argsort of the series
+    # column's prefix (a series' rows stay in arrival order), `bounds[i]:
+    # bounds[i + 1]` series i's slice of it. The columns only ever
+    # append, so the prefix never changes; positions, not views, so a
+    # `_grow` leaves it right. Rows past indexed_n are the tail.
+    indexed_n: int = 0
+    order: Optional[np.ndarray] = None
+    bounds: Optional[np.ndarray] = None
 
     @property
     def num_writes(self) -> int:
         return self.cols.n
+
+    def group(self) -> int:
+        """Bring the index up to date for a read (under the shard lock)
+        and return the tail's length. No index yet, or a tail longer
+        than the indexed prefix: the whole bucket is grouped again, which
+        is amortised as the columns' own doubling is. Otherwise the
+        index stands and the read scans the tail."""
+        n = self.cols.n
+        tail = n - self.indexed_n
+        if self.order is not None and tail <= self.indexed_n:
+            return tail
+        sidx = self.cols.sidx[:n]
+        self.order = np.argsort(sidx, kind="stable")
+        counts = np.bincount(sidx)
+        self.bounds = np.zeros(len(counts) + 1, np.intp)
+        np.cumsum(counts, out=self.bounds[1:])
+        self.indexed_n = n
+        _INDEX_BUILDS.inc()
+        _INDEX_ROWS.inc(n)
+        return 0
 
 
 def dedup_sorted(sidx, ts, vals):
@@ -172,23 +217,61 @@ class ShardBuffer:
         return False
 
     def read(self, series_idx: int, start_ns: int, end_ns: int) -> Tuple[np.ndarray, np.ndarray]:
-        """Merged in-order datapoints for one series in [start, end)."""
-        all_ts: List[np.ndarray] = []
-        all_vals: List[np.ndarray] = []
+        """Merged in-order datapoints for one series in [start, end), under
+        the shard lock: ascending timestamps, one point a timestamp, the
+        last arrival winning a duplicate. The series' rows in a bucket
+        are its slice of the bucket's index and, where rows were appended
+        since the index was built, its rows of that tail after them
+        (arrival order either way). Scrapes arrive in time order, so the
+        rows are checked for that and only a series that fails goes
+        through `dedup_sorted`."""
+        parts: List[Tuple[np.ndarray, np.ndarray]] = []
         for bs in sorted(self.buckets):
             if bs + self.block_size_ns <= start_ns or bs >= end_ns:
                 continue
-            sidx, ts, vals = self.buckets[bs].cols.view()
-            m = sidx == series_idx
-            if not m.any():
+            b = self.buckets[bs]
+            if not b.cols.n:
                 continue
-            s, t, v = dedup_sorted(sidx[m], ts[m], vals[m])
-            keep = (t >= start_ns) & (t < end_ns)
-            all_ts.append(t[keep])
-            all_vals.append(v[keep])
-        if not all_ts:
+            tail = b.group()
+            cols = b.cols
+            rows = _NO_ROWS
+            if series_idx + 1 < len(b.bounds):
+                rows = b.order[b.bounds[series_idx]:b.bounds[series_idx + 1]]
+            if tail:
+                _READ_TAIL_SCANS.inc()
+                late = np.flatnonzero(
+                    cols.sidx[b.indexed_n:cols.n] == series_idx)
+                if len(late):
+                    late += b.indexed_n
+                    rows = np.concatenate((rows, late))
+            else:
+                _READ_INDEXED.inc()
+            if not len(rows):
+                continue
+            t, v = cols.ts[rows], cols.vals[rows]
+            if len(t) > 1 and not (t[1:] > t[:-1]).all():
+                _, t, v = dedup_sorted(np.zeros(len(t), np.int32), t, v)
+            # every row of a bucket lies in its block: a range that
+            # covers the block clips nothing
+            if start_ns > bs or end_ns < bs + self.block_size_ns:
+                # bisect, not searchsorted: a numpy call that lets the
+                # GIL go costs a hand-off when other handlers wait for it
+                lo = bisect_left(t, max(start_ns, bs))
+                hi = bisect_left(t, min(end_ns, bs + self.block_size_ns), lo)
+                t, v = t[lo:hi], v[lo:hi]
+            parts.append((t, v))
+        if not parts:
             return np.zeros(0, np.int64), np.zeros(0, np.float64)
-        return np.concatenate(all_ts), np.concatenate(all_vals)
+        if len(parts) == 1:
+            return parts[0]
+        return (np.concatenate([t for t, _ in parts]),
+                np.concatenate([v for _, v in parts]))
+
+    def read_many(self, series_idxs, start_ns: int,
+                  end_ns: int) -> List[Tuple[np.ndarray, np.ndarray]]:
+        """`read` for each series of this shard, under one hold of its
+        lock."""
+        return [self.read(idx, start_ns, end_ns) for idx in series_idxs]
 
     def sealable(self, now_ns: int) -> List[int]:
         """Block starts no longer writable (block fully past buffer_past)."""

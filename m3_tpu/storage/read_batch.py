@@ -159,9 +159,9 @@ def read_many(ns, shard_set, ids: Sequence[bytes], start_ns: int,
         with shard.write_lock:
             t1 = _clock() if timed else 0
             blocks = dict(shard.blocks)
-            read = shard.buffer.read
-            for idx, pos in zip(idx_list, poss):
-                bufs[pos] = read(idx, start_ns, end_ns)
+            for pos, got in zip(poss, shard.buffer.read_many(
+                    idx_list, start_ns, end_ns)):
+                bufs[pos] = got
         t2 = _clock() if timed else 0
         for pos, tg in zip(poss, map(registry.tags_of, idx_list)):
             tags[pos] = tg
