@@ -567,12 +567,17 @@ def phase_queries(ctx: Ctx, specs: Optional[List[dict]] = None,
     for ?explain=true and requires the compiled route; pass 2 is the
     plain columnar render and must not compile. Both passes are compared
     with the retained interpreter (execute_range_ref)."""
+    from m3_tpu.storage import block_cache
+
     specs = specs if specs is not None else smoke_queries(ctx)
     log = ctx.compile_log
     before = counters()
     with concurrent.futures.ThreadPoolExecutor(4) as pool:
         c0 = log.compiles
         pass1 = list(pool.map(lambda s: _run_query(ctx, s, True), specs))
+        # (the blocks pass 1 touched are decoded whole by the block
+        # cache's own thread: its work is pass 1's)
+        block_cache.get_cache().wait_filled()
         c1 = log.compiles
         pass2 = list(pool.map(lambda s: _run_query(ctx, s, False), specs))
         c2 = log.compiles
@@ -622,7 +627,10 @@ def phase_fileset_read(ctx: Ctx):
     cache, and query the oldest block's range. The rows the retriever
     seeks go into the fetch's one batched cold decode
     (storage/read_batch.py: one dispatch a geometry, rows padded to a
-    bucket, never a one-row program: ROADMAP C16)."""
+    bucket, never a one-row program: ROADMAP C16). The cache admits
+    nothing meanwhile: the phase is about the cold read, and a second
+    touch's whole-block decode would be the second pass's compile where
+    a shape compiles when it is first met (the CPU)."""
     from m3_tpu.storage import block_cache
     from m3_tpu.storage.retriever import BlockRetriever
 
@@ -630,7 +638,9 @@ def phase_fileset_read(ctx: Ctx):
     retr = BlockRetriever(ctx.handle.persist)
     db.set_retriever(retr)
     evicted = db.evict_flushed()
-    block_cache.get_cache().clear()
+    cache = block_cache.get_cache()
+    cache.clear()
+    admit_after, cache.admit_after = cache.admit_after, 1 << 62
     check(evicted >= len(db.namespace(b"default").shards),
           f"evict_flushed dropped {evicted} blocks")
     c0 = counters()
@@ -639,6 +649,7 @@ def phase_fileset_read(ctx: Ctx):
                 q='max_over_time(m{host=~"h00."}[1m])',
                 start=start, end=start + 10 * 60 * S, step=10 * S)
     phase_queries(ctx, [spec], label="fileset_query")
+    cache.admit_after = admit_after
     check(retr.stats["seeks"] > 0,
           f"retriever stats {retr.stats}: the query never read a fileset")
     cold = counters()

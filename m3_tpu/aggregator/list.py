@@ -456,7 +456,10 @@ class MetricList:
         dead = None
         n = 0
         dropped = 0
-        for elem in self._elems.values():
+        # (a snapshot: a handler thread adds a new series' elem beside
+        # this drain, and a dict that grows under its iterator raises
+        # with the windows popped so far in hand, emitted nowhere)
+        for elem in list(self._elems.values()):
             b = elem._buckets
             if b:
                 # Lock-free drain: only this drain ever REMOVES keys

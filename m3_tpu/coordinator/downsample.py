@@ -26,6 +26,7 @@ batched (write_aggregated_batch when the coordinator provides one)."""
 
 from __future__ import annotations
 
+import threading
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 from ..aggregator import Aggregator
@@ -34,6 +35,15 @@ from ..metrics import id as metric_id
 from ..metrics.matcher import Matcher
 from ..metrics.metric import MetricType, MetricUnion
 from ..metrics.policy import DropPolicy
+from ..utils import instrument, tracing
+from ..utils.tracing import clock_ns as _clock
+
+# Rows a failed sink leaves for the next round, at most; the oldest
+# past that are dropped, and counted.
+_HELD_ROWS_MAX = 1 << 20
+_SCOPE = instrument.ROOT.sub_scope("coordinator.downsample")
+_ROWS_HELD = _SCOPE.counter("rows_held")
+_ROWS_DROPPED = _SCOPE.counter("rows_dropped")
 
 
 class _ColumnarFlushHandler(Handler):
@@ -83,6 +93,13 @@ class Downsampler:
             flush_handler=_ColumnarFlushHandler(self))
         self.samples_matched = 0
         self.samples_dropped = 0
+        # One flush round at a time: the coordinator's cadence and a
+        # caller's own flush never interleave, and a flush that returns
+        # has sunk every window its clock had closed.
+        self._flush_lock = threading.Lock()
+        # A round's rows whose sink raised: the aggregator has given up
+        # their windows, so they are the next round's first rows.
+        self._held: List[tuple] = []
 
     # -- ingest: compiled batch path ---------------------------------------
 
@@ -197,7 +214,10 @@ class Downsampler:
     # -- flush -------------------------------------------------------------
 
     def flush(self, now_nanos: Optional[int] = None) -> int:
-        return self._agg.flush(now_nanos)
+        with self._flush_lock:
+            if self._held:      # a round that closes no window sinks them
+                self._on_flushed_columnar(())
+            return self._agg.flush(now_nanos)
 
     def _decoded_tags(self, mid: bytes) -> Dict[bytes, bytes]:
         tags = self._decode_memo.get(mid)
@@ -217,20 +237,38 @@ class Downsampler:
     def _on_flushed_columnar(self, groups):
         """One flush round's columnar groups -> one storage sink call.
         Decode is memoized across rounds (standing series pay it once);
-        rows assemble per group and sink batched."""
-        rows: List[tuple] = []
-        for ids, times, values, policy in groups:
-            for mid, t, v in zip(ids, _tolist(times), _tolist(values)):
-                rows.append((mid, self._decoded_tags(mid), t, v, policy))
-        self._sink_rows(rows)
+        rows assemble per group and sink batched. A round that emitted
+        is a `downsample.flush` root span (costs `rows_n`, `policies_n`,
+        `sink_ns`: the rows sunk, the storage policies among them, the
+        time inside the storage writes); an empty round opens none. A
+        sink that raises loses nothing: the rows wait for the next round
+        (a point written twice is one point), and the caller hears."""
+        with tracing.background_span("downsample.flush"):
+            rows, self._held = self._held, []
+            for ids, times, values, policy in groups:
+                for mid, t, v in zip(ids, _tolist(times), _tolist(values)):
+                    rows.append((mid, self._decoded_tags(mid), t, v, policy))
+            try:
+                self._sink_rows(rows)
+            except Exception:
+                self._held = rows[-_HELD_ROWS_MAX:]
+                _ROWS_HELD.inc(len(self._held))
+                _ROWS_DROPPED.inc(len(rows) - len(self._held))
+                raise
 
     def _sink_rows(self, rows: List[tuple]):
+        acc = tracing.detail()
+        t0 = _clock() if acc is not None else 0
         if self._write_rows is not None:
             self._write_rows(rows)
-            return
-        write = self._write
-        for mid, tags, t, v, policy in rows:
-            write(mid, tags, t, v, policy)
+        else:
+            write = self._write
+            for mid, tags, t, v, policy in rows:
+                write(mid, tags, t, v, policy)
+        if acc is not None:
+            acc.add_cost("sink_ns", _clock() - t0)
+            acc.add_cost("rows_n", len(rows))
+            acc.add_cost("policies_n", len({row[4] for row in rows}))
 
 
 def _encode_tags(tags: Dict[bytes, bytes]) -> bytes:

@@ -28,7 +28,7 @@ from ..query.block import Block
 from ..query.model import Matcher, MatchType
 from ..query import promql
 from ..query.promql import parse_duration_ns
-from ..utils import tracing
+from ..utils import foreground, tracing
 from ..utils.limits import ResourceExhausted
 from .ingest import DownsamplerAndWriter
 
@@ -519,7 +519,7 @@ class HTTPApi:
                 pass
 
             def _dispatch(self):
-                with dscope.entered(api.device_scope):
+                with dscope.entered(api.device_scope), foreground.serving:
                     self._dispatch_scoped()
 
             def _dispatch_scoped(self):

@@ -8,19 +8,27 @@ main.go:69); run_clustered() goes through the replicating client session."""
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, Optional
+import logging
+import threading
+from typing import Callable, Dict, Optional, Sequence
 
 from ..cluster import kv as cluster_kv
+from ..metrics.filters import MATCH_ALL
 from ..metrics.matcher import Matcher, RuleSetStore
-from ..metrics.policy import StoragePolicy
+from ..metrics.policy import Resolution, StoragePolicy
+from ..metrics.rules import MappingRuleSnapshot, Rule
 from ..parallel import scope as dscope
-from ..query import Engine, LocalStorage, SessionStorage
+from ..query import (Engine, LocalStorage, NamespaceAttrs, ResolvingStorage,
+                     SessionStorage)
+from ..utils import instrument
 from .admin import AdminAPI
 from .downsample import Downsampler
 from .http_api import HTTPApi
 from .ingest import DownsamplerAndWriter
 from .rules_engine import RulesEngine
 from .selfscrape import SelfScraper
+
+_LOG = logging.getLogger(__name__)
 
 
 @dataclasses.dataclass
@@ -34,6 +42,8 @@ class Coordinator:
     # deployment enables it; tests/smokes drive scrape_once() directly.
     self_scraper: Optional[SelfScraper] = None
     clock: Optional[object] = None
+    _flush_stop: Optional[threading.Event] = None
+    _flush_thread: Optional[threading.Thread] = None
 
     @property
     def endpoint(self) -> str:
@@ -41,6 +51,33 @@ class Coordinator:
 
     def flush_downsampler(self, now_nanos: Optional[int] = None) -> int:
         return self.downsampler.flush(now_nanos) if self.downsampler else 0
+
+    def start_downsample_flush(self):
+        """The embedded downsampler's flush on a cadence (the reference's
+        downsampler flushes itself; a second is the standalone
+        aggregator's default `flush_interval`): what a closed window
+        holds reaches its aggregated namespace within a second of the
+        coordinator's clock passing the window's end. Started by the
+        services for a coordinator whose namespace list has aggregated
+        namespaces; stopped by `close`. A round whose sink fails is
+        counted (`coordinator.downsample.flush_errors`) and logged, and
+        its rows are the next round's (`Downsampler._held`)."""
+        if self.downsampler is None or self._flush_thread is not None:
+            return
+        stop = self._flush_stop = threading.Event()
+        errors = instrument.ROOT.counter("coordinator.downsample.flush_errors")
+
+        def loop():
+            while not stop.wait(1.0):
+                try:
+                    self.downsampler.flush()
+                except Exception:   # the loop outlives a failing sink
+                    errors.inc()
+                    _LOG.exception("downsample flush")
+
+        self._flush_thread = threading.Thread(
+            target=loop, name="downsample-flush", daemon=True)
+        self._flush_thread.start()
 
     def rules_engine(self, **kw) -> RulesEngine:
         """Standing recording/alert rules over this coordinator: PromQL
@@ -51,9 +88,36 @@ class Coordinator:
         return RulesEngine(self.engine, self.writer.write_batch, **kw)
 
     def close(self):
+        if self._flush_thread is not None:
+            self._flush_stop.set()
+            self._flush_thread.join()
         if self.self_scraper is not None:
             self.self_scraper.stop()
         self.api.close()
+
+
+def _policy_of(attrs: NamespaceAttrs) -> StoragePolicy:
+    return StoragePolicy(Resolution(attrs.resolution_ns), attrs.retention_ns)
+
+
+def _storages(make_store: Callable[[bytes], object], namespace: bytes,
+              cluster_namespaces: Optional[Sequence[NamespaceAttrs]], clock):
+    """(the engine's and the writer's storage, the downsampler's targets
+    by policy, the policies every metric is downsampled to). With a
+    namespace list (the reference's `clusters.namespaces`) both halves
+    come from it: each aggregated namespace is the target of its
+    resolution:retention policy, a `downsample.all` one of the default
+    mapping rule too, and reads resolve over all of them. One namespace:
+    the member itself, no resolver in the path."""
+    if not cluster_namespaces:
+        return make_store(namespace), {}, ()
+    members = [(a, make_store(a.name)) for a in cluster_namespaces]
+    agg = {_policy_of(a): store for a, store in members if a.aggregated}
+    auto = tuple(_policy_of(a) for a, _s in members
+                 if a.aggregated and a.complete)
+    if len(members) == 1:
+        return members[0][1], agg, auto
+    return ResolvingStorage(members, clock), agg, auto
 
 
 def _build(storage, aggregated_storages: Dict[StoragePolicy, object],
@@ -61,13 +125,26 @@ def _build(storage, aggregated_storages: Dict[StoragePolicy, object],
            rules_namespace: bytes, clock, create_namespace,
            listen=("127.0.0.1", 0),
            self_scrape_interval_s: Optional[float] = None,
-           device_scope=None) -> Coordinator:
+           device_scope=None,
+           downsample_all: Sequence[StoragePolicy] = ()) -> Coordinator:
     """`device_scope` (parallel/scope.py): the devices this coordinator
     owns — its engine's query mesh is built over them and its HTTP
-    handler threads work inside it; None owns every attached device."""
+    handler threads work inside it; None owns every attached device.
+    `downsample_all`: the policies of the `downsample.all` namespaces,
+    installed as the default mapping rule (every metric, the metric
+    type's default aggregation — `last` for a gauge) beside whatever
+    rule set the KV store holds."""
     downsampler = None
-    if kv_store is not None:
-        matcher = Matcher(RuleSetStore(kv_store), rules_namespace, clock=clock)
+    if kv_store is not None or downsample_all:
+        auto = ()
+        if downsample_all:
+            auto = (Rule([MappingRuleSnapshot(
+                "downsample-all", 0, MATCH_ALL,
+                storage_policies=tuple(downsample_all))]),)
+        matcher = Matcher(
+            RuleSetStore(kv_store if kv_store is not None
+                         else cluster_kv.MemStore()),
+            rules_namespace, clock=clock, auto_mapping_rules=auto)
 
         def write_aggregated(mid, tags, t_ns, value, policy):
             target = aggregated_storages.get(policy, storage)
@@ -112,16 +189,16 @@ def _build(storage, aggregated_storages: Dict[StoragePolicy, object],
 def run_embedded(db, namespace: bytes = b"default",
                  kv_store: Optional[cluster_kv.MemStore] = None,
                  rules_namespace: bytes = b"default",
-                 aggregated_namespaces: Optional[Dict[StoragePolicy, bytes]] = None,
                  clock=None, listen=("127.0.0.1", 0),
                  create_namespace=None,
                  self_scrape_interval_s: Optional[float] = None,
-                 device_scope=None) -> Coordinator:
-    storage = LocalStorage(db, namespace)
-    agg = {
-        policy: LocalStorage(db, ns)
-        for policy, ns in (aggregated_namespaces or {}).items()
-    }
+                 device_scope=None,
+                 cluster_namespaces: Optional[Sequence[NamespaceAttrs]] = None
+                 ) -> Coordinator:
+    """`cluster_namespaces`: the coordinator's namespace list
+    (`_storages`); given, it stands for `namespace`."""
+    storage, agg, auto = _storages(
+        lambda ns: LocalStorage(db, ns), namespace, cluster_namespaces, clock)
 
     if create_namespace is None:
         def create_namespace(name: bytes, retention_ns: int):
@@ -133,21 +210,20 @@ def run_embedded(db, namespace: bytes = b"default",
     return _build(storage, agg, kv_store, rules_namespace, clock,
                   create_namespace, listen,
                   self_scrape_interval_s=self_scrape_interval_s,
-                  device_scope=device_scope)
+                  device_scope=device_scope, downsample_all=auto)
 
 
 def run_clustered(session, namespace: bytes = b"default",
                   kv_store: Optional[cluster_kv.MemStore] = None,
                   rules_namespace: bytes = b"default",
-                  aggregated_namespaces: Optional[Dict[StoragePolicy, bytes]] = None,
                   clock=None, listen=("127.0.0.1", 0),
                   self_scrape_interval_s: Optional[float] = None,
-                  device_scope=None) -> Coordinator:
-    storage = SessionStorage(session, namespace)
-    agg = {
-        policy: SessionStorage(session, ns)
-        for policy, ns in (aggregated_namespaces or {}).items()
-    }
+                  device_scope=None,
+                  cluster_namespaces: Optional[Sequence[NamespaceAttrs]] = None
+                  ) -> Coordinator:
+    storage, agg, auto = _storages(
+        lambda ns: SessionStorage(session, ns), namespace,
+        cluster_namespaces, clock)
     return _build(storage, agg, kv_store, rules_namespace, clock, None,
                   listen, self_scrape_interval_s=self_scrape_interval_s,
-                  device_scope=device_scope)
+                  device_scope=device_scope, downsample_all=auto)

@@ -10,13 +10,14 @@ from __future__ import annotations
 
 import json
 import threading
-from typing import Callable, Dict, Optional
+from typing import Callable, Dict, Optional, Sequence
 
 from ..cluster import kv as cluster_kv
 from .filters import TagsFilter
 from .pipeline import Op, Pipeline
 from .policy import StoragePolicy
 from .rules import (
+    ActiveRuleSet,
     MappingRuleSnapshot,
     MatchResult,
     RollupRuleSnapshot,
@@ -167,7 +168,13 @@ class Matcher:
 
     def __init__(self, store: RuleSetStore, namespace: bytes,
                  clock: Optional[Callable[[], int]] = None,
-                 cache_capacity: int = 1 << 20):
+                 cache_capacity: int = 1 << 20,
+                 auto_mapping_rules: Sequence = ()):
+        """`auto_mapping_rules`: mapping rules of the process's own
+        (the coordinator's default rule for its `downsample.all`
+        namespaces, the reference's downsampler auto mapping rules):
+        active beside whatever rule set the store holds, and alone
+        where it holds none."""
         import time as _time
 
         self._store = store
@@ -180,15 +187,23 @@ class Matcher:
         self._capacity = cache_capacity
         self._generation = 0
         self._compiled = None  # CompiledRuleSet for _generation, or None
-        rs = store.get(namespace)
-        self._active = rs.active_set() if rs is not None else None
+        self._auto = tuple(auto_mapping_rules)
+        self._active = self._activate(store.get(namespace))
         store.on_change(namespace, self._on_ruleset_change)
         self.hits = 0
         self.misses = 0
 
+    def _activate(self, rs: Optional[RuleSet]):
+        if not self._auto:
+            return rs.active_set() if rs is not None else None
+        if rs is None:
+            return ActiveRuleSet(0, self._auto, ())
+        return ActiveRuleSet(rs.version, [*rs.mapping_rules, *self._auto],
+                             rs.rollup_rules)
+
     def _on_ruleset_change(self, rs: RuleSet):
         with self._lock:
-            self._active = rs.active_set()
+            self._active = self._activate(rs)
             self._cache.clear()  # new generation invalidates everything
             self._compiled = None
             self._generation += 1

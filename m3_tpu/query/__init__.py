@@ -6,7 +6,8 @@ from .block import Block, BlockMeta, block_from_series, consolidate
 from .executor import Engine, QueryError, QueryParams
 from .model import Matcher, MatchType, METRIC_NAME, Tags, matchers_to_index_query
 from .promql import parse, ParseError
-from .storage import FanoutStorage, LocalStorage, SessionStorage
+from .storage import (FanoutStorage, LocalStorage, NamespaceAttrs,
+                      ResolvingStorage, SessionStorage)
 
 __all__ = [
     "Block", "BlockMeta", "Engine", "FanoutStorage", "LocalStorage",

@@ -31,10 +31,19 @@ class NamespaceConfig:
     # drills seal blocks in seconds instead of hours.
     buffer_past: str = "10m"
     buffer_future: str = "2m"
+    # The reverse index's block (indexOptions.blockSize in the
+    # reference's namespace options); empty: the program's default.
+    index_block_size: str = ""
 
     @property
     def retention_ns(self) -> int:
         return parse_duration_ns(self.retention)
+
+    @property
+    def index_block_size_ns(self) -> Optional[int]:
+        if not self.index_block_size:
+            return None
+        return parse_duration_ns(self.index_block_size)
 
     @property
     def block_size_ns(self) -> int:
@@ -86,9 +95,66 @@ class DBNodeConfig:
 
 
 @dataclasses.dataclass
+class DownsampleConfig:
+    # Every metric is downsampled into the namespace (the default
+    # mapping rule); false: only what a rule set sends there, and the
+    # resolver treats the namespace as partial.
+    all: bool = True
+
+
+@dataclasses.dataclass
+class ClusterNamespaceConfig:
+    """One entry of a coordinator's `namespaces` (the reference's
+    `clusters.namespaces`, m3coordinator-cluster-template.yml)."""
+
+    namespace: str = "default"
+    type: str = "unaggregated"      # or "aggregated"
+    retention: str = "48h"
+    resolution: str = ""            # aggregated only
+    downsample: Optional[DownsampleConfig] = None   # aggregated only
+
+    @property
+    def aggregated(self) -> bool:
+        return self.type == "aggregated"
+
+    @property
+    def retention_ns(self) -> int:
+        return parse_duration_ns(self.retention)
+
+    @property
+    def resolution_ns(self) -> int:
+        return parse_duration_ns(self.resolution) if self.resolution else 0
+
+    @property
+    def downsample_all(self) -> bool:
+        return self.aggregated and (self.downsample is None
+                                    or self.downsample.all)
+
+    def validate(self):
+        if self.type not in ("unaggregated", "aggregated"):
+            raise ConfigError(f"namespace {self.namespace!r}: unknown type "
+                              f"{self.type!r}")
+        if self.aggregated and not self.resolution:
+            raise ConfigError(f"aggregated namespace {self.namespace!r} "
+                              "needs a resolution")
+        if not self.aggregated and (self.resolution
+                                    or self.downsample is not None):
+            raise ConfigError(f"unaggregated namespace {self.namespace!r} "
+                              "takes no resolution and no downsample block")
+
+
+@dataclasses.dataclass
 class CoordinatorConfig:
     listen_address: str = "127.0.0.1:0"
+    # One unaggregated namespace, read and written; or `namespaces`.
     namespace: str = "default"
+    # The cluster namespaces: one `type: unaggregated` and any number of
+    # `type: aggregated`, each with its retention (and resolution). The
+    # embedded downsampler writes into the aggregated ones, and every
+    # query is answered by the namespace(s) its range resolves to
+    # (query/storage.py resolve). Empty: `namespace` alone.
+    namespaces: List[ClusterNamespaceConfig] = dataclasses.field(
+        default_factory=list)
     rules_namespace: str = "default"
     carbon_listen_address: str = ""    # empty = disabled
     remotes: List[str] = dataclasses.field(default_factory=list)
@@ -109,6 +175,18 @@ class CoordinatorConfig:
         if not self.self_scrape_interval:
             return None
         return parse_duration_ns(self.self_scrape_interval) / 1e9
+
+    def validate(self):
+        if not self.namespaces:
+            return
+        for ns in self.namespaces:
+            ns.validate()
+        names = [ns.namespace for ns in self.namespaces]
+        if len(set(names)) != len(names):
+            raise ConfigError(f"coordinator namespaces repeat a name: {names}")
+        if sum(1 for ns in self.namespaces if not ns.aggregated) != 1:
+            raise ConfigError("coordinator namespaces need exactly one "
+                              "of type unaggregated")
 
 
 @dataclasses.dataclass
@@ -167,16 +245,33 @@ def _hydrate(cls, obj: Dict[str, Any]):
     if unknown:
         raise ConfigError(
             f"unknown config keys for {cls.__name__}: {sorted(unknown)}")
+    if cls is CoordinatorConfig and "namespace" in obj and obj.get(
+            "namespaces"):
+        raise ConfigError("coordinator: give `namespace` or `namespaces`, "
+                          "not both")
     kwargs = {}
     for name, value in obj.items():
-        f = fields[name]
-        if name == "namespaces":
-            kwargs[name] = [_hydrate(NamespaceConfig, v) for v in value]
-        elif name == "coordinator" and value is not None:
-            kwargs[name] = _hydrate(CoordinatorConfig, value)
-        else:
+        nested = _NESTED.get((cls, name))
+        if nested is None or value is None:
             kwargs[name] = value
-    return cls(**kwargs)
+        elif isinstance(value, list):
+            kwargs[name] = [_hydrate(nested, v) for v in value]
+        else:
+            kwargs[name] = _hydrate(nested, value)
+    out = cls(**kwargs)
+    validate = getattr(out, "validate", None)
+    if validate is not None:
+        validate()
+    return out
+
+
+# (class, key) -> the dataclass its value (or each item of it) hydrates to
+_NESTED = {
+    (DBNodeConfig, "namespaces"): NamespaceConfig,
+    (DBNodeConfig, "coordinator"): CoordinatorConfig,
+    (CoordinatorConfig, "namespaces"): ClusterNamespaceConfig,
+    (ClusterNamespaceConfig, "downsample"): DownsampleConfig,
+}
 
 
 def load_file(path: str, service: str):

@@ -32,6 +32,7 @@ from ..storage.namespace import NamespaceOptions
 from .config import (
     AggregatorConfig,
     CollectorConfig,
+    ConfigError,
     CoordinatorConfig,
     DBNodeConfig,
 )
@@ -113,10 +114,35 @@ class DBNodeHandle:
         # teardown (shard_insert_queue.go Stop during server Close).
         with dscope.entered(self.db.scope):  # its own block cache's entries
             self.db.close()
+        if self.db.commitlog is not None:
+            # A graceful stop leaves nothing acknowledged in the log's
+            # buffer: under write_behind what came since the last flush
+            # interval is written here, and the next start replays it.
+            self.db.commitlog.close()
         if self.kv is not None and hasattr(self.kv, "close"):
             self.kv.close()  # RemoteStore: stops watch threads + socket
         if self.lock is not None:
             self.lock.release()
+
+
+def _check_coordinator_namespaces(cfg: DBNodeConfig):
+    """The resolver trusts what the list says a namespace holds: a
+    namespace the node does not have, or keeps for less long, is a fault
+    of the configuration, found before anything is opened or served."""
+    if cfg.coordinator is None:
+        return
+    held = {ns_cfg.name: ns_cfg for ns_cfg in cfg.namespaces}
+    for cns in cfg.coordinator.namespaces:
+        node_ns = held.get(cns.namespace)
+        if node_ns is None:
+            raise ConfigError(
+                f"coordinator namespace {cns.namespace!r} is not one of "
+                f"the node's ({sorted(held)})")
+        if node_ns.retention_ns < cns.retention_ns:
+            raise ConfigError(
+                f"coordinator namespace {cns.namespace!r}: retention "
+                f"{cns.retention} is longer than the node's "
+                f"{node_ns.retention}")
 
 
 def run_dbnode(cfg: DBNodeConfig, clock=None) -> DBNodeHandle:
@@ -125,6 +151,7 @@ def run_dbnode(cfg: DBNodeConfig, clock=None) -> DBNodeHandle:
     (filesystem filesets -> commitlog snapshots + WAL) BEFORE the
     listeners open — the cold-restart path the kill -9 drill exercises;
     serving-ready is printed with the bootstrap wall time."""
+    _check_coordinator_namespaces(cfg)
     os.makedirs(cfg.data_dir, exist_ok=True)
     # One process per data dir (x/lockfile; server.go takes it on startup).
     from ..utils.lockfile import Lockfile
@@ -141,13 +168,15 @@ def run_dbnode(cfg: DBNodeConfig, clock=None) -> DBNodeHandle:
     db = Database(ShardSet(cfg.num_shards), commitlog=commitlog, clock=clock,
                   scope=scope)
     for ns_cfg in cfg.namespaces:
-        db.ensure_namespace(
-            ns_cfg.name.encode(),
-            NamespaceOptions(retention_ns=ns_cfg.retention_ns,
-                             block_size_ns=ns_cfg.block_size_ns,
-                             buffer_past_ns=ns_cfg.buffer_past_ns,
-                             buffer_future_ns=ns_cfg.buffer_future_ns,
-                             index_enabled=ns_cfg.index_enabled))
+        opts = NamespaceOptions(retention_ns=ns_cfg.retention_ns,
+                                block_size_ns=ns_cfg.block_size_ns,
+                                buffer_past_ns=ns_cfg.buffer_past_ns,
+                                buffer_future_ns=ns_cfg.buffer_future_ns,
+                                index_enabled=ns_cfg.index_enabled)
+        if ns_cfg.index_block_size_ns is not None:
+            opts = dataclasses.replace(
+                opts, index_block_size_ns=ns_cfg.index_block_size_ns)
+        db.ensure_namespace(ns_cfg.name.encode(), opts)
     persist = PersistManager(os.path.join(cfg.data_dir, "data"))
     boot_results = None
     if cfg.bootstrap_enabled:
@@ -200,7 +229,9 @@ def run_dbnode(cfg: DBNodeConfig, clock=None) -> DBNodeHandle:
             self_scrape_interval_s=cfg.coordinator.self_scrape_interval_s,
             device_scope=dscope.from_config(
                 cfg.coordinator.devices, cfg.host_id + ".coordinator")
-            or scope)
+            or scope,
+            cluster_namespaces=_cluster_namespaces(cfg.coordinator))
+        _start_downsample_flush(coordinator, cfg.coordinator)
     mediator = None
     if cfg.tick_interval:
         from ..storage.mediator import Mediator
@@ -347,6 +378,25 @@ def run_aggregator(cfg: AggregatorConfig, flush_handler=None,
     return handle
 
 
+def _cluster_namespaces(cfg: CoordinatorConfig):
+    """The coordinator's namespace list as the resolver's attributes
+    (query/storage.py NamespaceAttrs); None where the configuration has
+    the single `namespace` key."""
+    from ..query.storage import NamespaceAttrs
+
+    if not cfg.namespaces:
+        return None
+    return [NamespaceAttrs(ns.namespace.encode(), ns.aggregated,
+                           ns.retention_ns, ns.resolution_ns,
+                           complete=not ns.aggregated or ns.downsample_all)
+            for ns in cfg.namespaces]
+
+
+def _start_downsample_flush(coord, cfg: CoordinatorConfig):
+    if any(ns.aggregated for ns in cfg.namespaces):
+        coord.start_downsample_flush()
+
+
 def run_coordinator(cfg: CoordinatorConfig, session=None, db=None,
                     kv_store=None, clock=None):
     """Standalone coordinator over a client session (or an in-process db
@@ -361,20 +411,24 @@ def run_coordinator(cfg: CoordinatorConfig, session=None, db=None,
     listen = _host_port(cfg.listen_address)
     scrape_s = cfg.self_scrape_interval_s
     scope = dscope.from_config(cfg.devices, "coordinator")
+    members = _cluster_namespaces(cfg)
     if db is not None:
         coord = run_embedded(db, namespace=cfg.namespace.encode(),
                              kv_store=kv_store,
                              rules_namespace=cfg.rules_namespace.encode(),
                              clock=clock, listen=listen,
                              self_scrape_interval_s=scrape_s,
-                             device_scope=scope or db.scope)
+                             device_scope=scope or db.scope,
+                             cluster_namespaces=members)
     else:
         coord = run_clustered(session, namespace=cfg.namespace.encode(),
                               kv_store=kv_store,
                               rules_namespace=cfg.rules_namespace.encode(),
                               clock=clock, listen=listen,
                               self_scrape_interval_s=scrape_s,
-                              device_scope=scope)
+                              device_scope=scope,
+                              cluster_namespaces=members)
+    _start_downsample_flush(coord, cfg)
     if cfg.remotes:
         stores = [coord.engine.storage] + [RemoteStorage(r) for r in cfg.remotes]
         coord.engine.storage = FanoutStorage(stores)

@@ -18,7 +18,11 @@ same bytes. `DeviceBlockCache` closes that loop:
       arrays, shared across readers); a miss on a HOT block (admission:
       `admit_after` touches per generation, the RecentlyRead policy's
       "promote on re-read") decodes the whole block ONCE — from the
-      retained device buffers when present — and caches the planes.
+      retained device buffers when present — and caches the planes. A
+      fetch's batched read (storage/read_batch.py) never pays for more
+      than one such decode: while the budget has room the blocks it
+      read cold go to the cache's fill thread (`offer`; the reference's
+      block retriever fetches beside its requests too).
   (c) bound — residency is charged to the process-wide `HBMBudget`
       (utils/hbm.py) shared with the selector-grid upload caches, evicted
       LRU under one global ceiling, and invalidated through the same
@@ -42,15 +46,16 @@ gauges, and budget pressure is the HealthTracker memory-pressure probe.
 from __future__ import annotations
 
 import contextlib
+import logging
 import os
 import threading
-from collections import OrderedDict
+from collections import OrderedDict, deque
 from typing import Dict, Optional, Tuple
 
 import numpy as np
 
 from ..parallel import scope as dscope
-from ..utils import instrument, tracing
+from ..utils import foreground, instrument, tracing
 from ..utils.hbm import HBMBudget, shared_budget
 
 __all__ = ["DeviceBlockCache", "get_cache", "active", "disabled"]
@@ -63,6 +68,14 @@ _DEAD_GENS_MAX = 4096
 _TOUCH_MAX = 8192
 
 DEFAULT_ADMIT_AFTER = 2
+# utils/tracing.py counts the fill thread's CPU under this name's role.
+FILL_THREAD_NAME = "block-cache-fill"
+# How long the fill thread waits for a moment with no request in service
+# before each block (utils/foreground.py): a server that is never quiet
+# still fills, at some 15 blocks a second.
+FILL_STANDS_BACK_S = 0.05
+
+_LOG = logging.getLogger(__name__)
 
 
 def plane_bytes(blk) -> int:
@@ -102,6 +115,14 @@ class DeviceBlockCache:
         # stampede N whole-block decodes — losers fall back to the plain
         # per-row path until the winner publishes).
         self._decoding: set = set()
+        # Blocks `offer` claimed for the fill thread, the planes' bytes
+        # they will take, and the thread while it lives (it ends with
+        # its queue; `_idle` tells `wait_filled`). Bounded by bytes:
+        # `offer` queues no more than the budget has room for.
+        self._fill: deque = deque()  # m3lint: disable=unbounded-queue
+        self._fill_bytes = 0
+        self._filler: Optional[threading.Thread] = None
+        self._idle = threading.Condition(self._lock)
         self._bytes = 0
         scope = scope or instrument.ROOT.sub_scope("storage.block_cache")
         self._hits = scope.counter("hits")
@@ -110,6 +131,7 @@ class DeviceBlockCache:
         self._invalidations = scope.counter("invalidations")
         self._admitted = scope.counter("admitted")
         self._retained = scope.counter("retained")
+        self._fill_errors = scope.counter("fill_errors")
         self._bytes_gauge = scope.gauge("bytes")
         # Per-instance tallies (the instrument scope aggregates
         # process-wide by name — the postings-cache convention).
@@ -119,17 +141,18 @@ class DeviceBlockCache:
 
     # ---------------------------------------------------------------- serving
 
-    def decoded(self, blk, row_read: bool = False, rows: int = 1
+    def decoded(self, blk, row_read: bool = False
                 ) -> Optional[Tuple[np.ndarray, np.ndarray]]:
         """The block's decoded (ts_ns [S, W], vals [S, W]) planes — frozen,
         shared — or None when the block hasn't earned admission yet.
         Records the touch either way; an admission decodes the whole block
         once (from retained device buffers when present). `row_read`: the
-        caller wants `rows` rows of it (SealedBlock.read, read_batch), so
-        an admission is a whole block decoded for them, and is made only
-        while the budget has room; a caller that decodes the whole block
-        anyway (read_all) admits whenever the block has earned it."""
-        dec = self.lookup(blk, rows)
+        caller wants one row of it (SealedBlock.read), so an admission is
+        a whole block decoded for that row, and is made only while the
+        budget has room; a caller that decodes the whole block anyway
+        (read_all) admits whenever the block has earned it. (A fetch's
+        batched read: `lookup`, then `offer`.)"""
+        dec = self.lookup(blk)
         if dec is None and self.wants(blk) and (
                 not row_read or self.has_room(blk)):
             dec = self.admit(blk)
@@ -184,9 +207,17 @@ class DeviceBlockCache:
         with self._lock:
             if gen in self._decoding or gen in self._dead:
                 return None
+            self._decoding.add(gen)
+        return self._decode_claimed(blk)
+
+    def _decode_claimed(self, blk
+                        ) -> Optional[Tuple[np.ndarray, np.ndarray]]:
+        """The one decode of a generation in `_decoding`, by whoever put
+        it there (`admit`, or `offer` for the fill thread)."""
+        gen = blk.gen
+        with self._lock:
             e = self._entries.get(gen)
             encoded = e.encoded if e is not None else None
-            self._decoding.add(gen)
         # Decode outside the lock (device launch / host scan), then
         # publish.
         try:
@@ -198,22 +229,82 @@ class DeviceBlockCache:
         self.budget.reclaim()
         return out
 
-    def admit_hottest(self, blocks) -> int:
-        """What a fetch's batched read does with the blocks it read cold
-        because they had no room: while admitting means evicting, a fetch
-        admits ONE of them, the most touched — every miss of a fetch
-        whose store is larger than the budget would otherwise decode 625
-        rows to serve one and push out a block as warm as itself. The
-        cache so turns over at the pace of its fetches, and a block
-        earns its place by being asked for more than the others."""
-        best, most = None, 0
-        for blk in blocks:
-            touches = self.wants(blk)
-            if touches > most:
-                best, most = blk, touches
-        if best is None:
-            return 0
+    def offer(self, blocks) -> int:
+        """What a fetch's batched read does with the blocks it read
+        cold; returns how many it queued or admitted.
+
+        While the budget has room, every one that has earned its place
+        goes to the fill thread, which decodes them one at a time
+        between the requests: a restarted node's first panels touch
+        hundreds of blocks twice, and decoded on the request's thread
+        they cost a panel seconds. No more is queued than fits.
+
+        Once admitting means evicting, the fetch admits ONE itself, the
+        most touched — every miss of a fetch whose store is larger than
+        the budget would otherwise decode 625 rows to serve one and push
+        out a block as warm as itself. The cache so turns over at the
+        pace of its fetches, and a block earns its place by being asked
+        for more than the others."""
+        best, most, queued = None, 0, 0
+        room = self.budget.limit - self.budget.total()
+        with self._lock:
+            room -= self._fill_bytes
+            for blk in blocks:
+                gen = blk.gen
+                touches = self._touch.get(gen, 0)
+                if touches < self.admit_after or gen in self._decoding \
+                        or gen in self._dead:
+                    continue
+                need = plane_bytes(blk)
+                if need <= room:
+                    self._decoding.add(gen)
+                    self._fill.append(blk)
+                    self._fill_bytes += need
+                    room -= need
+                    queued += 1
+                elif touches > most:
+                    best, most = blk, touches
+            start = queued and self._filler is None
+            if start:
+                self._filler = threading.Thread(
+                    target=self._fill_loop, args=(dscope.current(),),
+                    name=FILL_THREAD_NAME, daemon=True)
+        if start:
+            self._filler.start()
+        if queued or best is None:
+            return queued
         return 1 if self.admit(best) is not None else 0
+
+    def _fill_loop(self, scope):
+        """The fill thread: the queue's blocks in turn, each in a moment
+        when no request is being served if one comes soon, in the scope
+        of the fetch that started it (the cache's own); ends with the
+        queue."""
+        with scope:
+            while True:
+                foreground.wait_quiet(FILL_STANDS_BACK_S)
+                with self._lock:
+                    if not self._fill:
+                        self._filler = None
+                        self._idle.notify_all()
+                        return
+                    blk = self._fill.popleft()
+                try:
+                    self._decode_claimed(blk)
+                except Exception:   # the block stays cold, and says so
+                    self._fill_errors.inc()
+                    _LOG.exception("block cache fill: block %d of gen %d",
+                                   blk.block_start, blk.gen)
+                finally:
+                    with self._lock:
+                        self._fill_bytes -= plane_bytes(blk)
+
+    def wait_filled(self, timeout: float = 30.0) -> bool:
+        """Block until the fill thread has ended (tests, and a caller
+        that wants the cache's state settled)."""
+        with self._lock:
+            return self._idle.wait_for(lambda: self._filler is None,
+                                       timeout)
 
     def _put_decoded(self, gen: int, blk, ts: np.ndarray, vals: np.ndarray
                      ) -> Optional[Tuple[np.ndarray, np.ndarray]]:

@@ -417,6 +417,13 @@ def replay_wal(ns, shard_ranges, ctx,
                         ids_kept, tags_kept)
                     shard.buffer.write_batch(
                         np.asarray(sidx, np.int32), tss[keep], vs[m][keep])
+                # As the write path does after an append (B-m11): a
+                # replayed series is indexed in the index block its rows
+                # lie in. The index block open at the crash has no
+                # persisted segment, and a series the filesystem source
+                # already named and tagged is not `fresh` below — without
+                # this its replayed rows are held and found by no query.
+                shard._index_rows(np.asarray(sidx, np.int32), tss[keep], None)
                 if tags_kept is not None:
                     # Tags come from the REGISTRY after resolution, not
                     # from the created position: a series first seen

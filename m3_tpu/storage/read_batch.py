@@ -13,13 +13,14 @@ into tiles and decoded ONE DISPATCH A GEOMETRY for the whole fetch
 client's `Session._one_pass_points` decodes a fetch's frames with),
 never one a (series, block).
 
-Admission. A block earns its place by touches (`admit_after`, a row
-read counting one), as before. While the budget has room a block that
-has earned it is decoded whole and kept, as before. Once admitting
-means evicting, a fetch admits ONE block, the most-touched of those it
-read cold (`DeviceBlockCache.admit_hottest`): a store larger than the
-budget would otherwise decode 625 rows to serve one at every miss and
-push out a block as warm as the one it brings in.
+Admission (`DeviceBlockCache.offer`, after the fetch's cold decode). A
+block earns its place by touches (`admit_after`, a row read counting
+one). While the budget has room the blocks of a fetch that have earned
+it go to the cache's fill thread: the fetch itself decodes only the
+rows it wants. Once admitting means evicting, a fetch admits ONE block
+itself, the most-touched of those it read cold: a store larger than
+the budget would otherwise decode 625 rows to serve one at every miss
+and push out a block as warm as the one it brings in.
 
 The answers are `Shard.read`'s bit for bit: the same parts (sealed
 blocks, disk, then the buffer, whose value wins a duplicate timestamp),
@@ -115,8 +116,7 @@ def read_many(ns, shard_set, ids: Sequence[bytes], start_ns: int,
         piece of the fetch's cold decode."""
         nonlocal block_n
         block_n += len(rows)
-        dec = cache.decoded(blk, row_read=True, rows=len(rows)) \
-            if cache is not None else None
+        dec = cache.lookup(blk, len(rows)) if cache is not None else None
         if dec is None:
             pieces.setdefault(piece_key(blk), []).append((blk, rows, at))
             missed.append(blk)
@@ -203,7 +203,7 @@ def read_many(ns, shard_set, ids: Sequence[bytes], start_ns: int,
             scatter(tile["bs"], ts, vs, range(len(ks)),
                     tile["rows"].tolist(), ks.tolist())
         if cache is not None:
-            cache.admit_hottest(missed)
+            cache.offer(missed)
     t4 = _clock() if timed else 0
     for pos in range(n):
         if not held[pos]:

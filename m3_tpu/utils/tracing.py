@@ -279,7 +279,7 @@ def _env_rate() -> float:
 #   stall. A thread's role is the kind of the last root span it opened
 #   (`http.` request, `rpc.` rpc of a node, `mediator.tick` tick,
 #   `bootstrap.` bootstrap), else what its name says (accept, fanout,
-#   prep, probe, main), else `python-other`; a thread of the process that is
+#   prep, cache-fill, probe, main), else `python-other`; a thread of the process that is
 #   no Python thread is `native` (XLA's, the TPU runtime's). A request's
 #   thread lives 10-70 ms, so a root counts its thread's CPU when it
 #   ends and the sample counts whatever a live thread has used past
@@ -303,7 +303,8 @@ NATIVE = ("native", "")
 _ROOT_ROLES = (("http.", "request"), ("rpc.", "rpc"),
                ("mediator.tick", "tick"), ("bootstrap.", "bootstrap"))
 _NAME_ROLES = (("accept", "accept"), ("fanout", "fanout"),
-               ("tsz-prep", "prep"), (PROBE_THREAD_NAME, "probe"),
+               ("tsz-prep", "prep"), ("block-cache-fill", "cache-fill"),
+               (PROBE_THREAD_NAME, "probe"),
                ("MainThread", "main"))
 _ADDITIVE = ("process_cpu_ns", "probe.wakes", "probe.late_ns",
              "probe.wall_ns", "stalls", "stall_ns", "faults.major",

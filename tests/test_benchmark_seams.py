@@ -55,6 +55,67 @@ def test_every_shipped_name_resolves_to_a_file_with_its_functions(
             assert hasattr(mod, attr), (mod.__file__, attr)
 
 
+def _named_classes():
+    """Every query class a traffic file names outside its mix (the mix's
+    own are loaded by `load_cell`): `warm_first` entries and the cases
+    of a `boundary` block."""
+    for path in sorted(glob.glob(os.path.join(BENCH_DIR, "traffic",
+                                              "*.json"))):
+        with open(path) as f:
+            traffic = json.load(f)
+        named = [w["class"] for w in traffic.get("warm_first", [])]
+        named += [case["class"] for case in traffic.get("boundary",
+                                                        {}).values()
+                  if isinstance(case, dict)]
+        for name in named:
+            yield pytest.param(name, id=os.path.basename(path)[:-5] + "."
+                               + name)
+
+
+@pytest.mark.parametrize("name", list(_named_classes()))
+def test_every_class_a_traffic_file_names_is_a_class_file(name, monkeypatch):
+    monkeypatch.syspath_prepend(BENCH_DIR)
+    from harness import spec
+
+    cls = spec.load_class(name)
+    assert {"endpoint", "promql", "reference"} <= set(cls)
+
+
+def test_the_namespace_list_cell_ships_what_it_names():
+    """`aggns-query-3d`: its configuration's deployment kind, its mix's
+    set-up, its checks and its reference are the files this PR added, and
+    the configuration states what the issue asks of it."""
+    with open(os.path.join(BENCH_DIR, "configs", "m3-aggns-tsbs-4k.json")) as f:
+        cfg = json.load(f)
+    with open(os.path.join(BENCH_DIR, "traffic", "tsbs-range-aggns.json")) as f:
+        traffic = json.load(f)
+    assert cfg["deployment_kind"] == "dbnode-aggns"
+    assert traffic["setup"]["via"] == "filesets-aggns"
+    assert traffic["reference"] == "aggns_ref"
+    assert set(traffic["checks"]) >= {"aggregated_readback",
+                                      "unaggregated_readback",
+                                      "resolver_boundary"}
+    for kind, name in (("deployments", "dbnode-aggns"),
+                       ("setups", "filesets-aggns"),
+                       ("reference", "aggns_ref"),
+                       ("checks", "aggregated_readback"),
+                       ("checks", "unaggregated_readback"),
+                       ("checks", "resolver_boundary")):
+        assert os.path.isfile(os.path.join(BENCH_DIR, kind, name + ".py"))
+    for key in ("source", "reduced", "assumed", "guarantees", "held",
+                "resolver_rule", "aggregation", "run_values",
+                "source_values"):
+        assert cfg[key], key
+    assert len(cfg["source"]) <= 200 and cfg["architecture"] is None
+    assert {"aggregation", "resolution"} <= set(cfg["guarantees"])
+    # the published shapes are uncut
+    assert (cfg["scale"], cfg["cadence_s"], cfg["dbnode"]["num_shards"]) == (
+        4000, 10, 64)
+    sizes = {ns["name"]: ns["block_size"]
+             for ns in cfg["dbnode"]["namespaces"]}
+    assert sizes == {"default": "20m", "metrics_1m_72h": "2h"}
+
+
 def _metrics():
     with open(os.path.join(ROOT_DIR, "BENCHMARK.json")) as f:
         bench = json.load(f)

@@ -5,6 +5,8 @@ downsample packages; docker-integration-tests/simple is the model for the
 HTTP round trip)."""
 
 import json
+import threading
+import time
 import urllib.parse
 import urllib.request
 
@@ -20,6 +22,7 @@ from m3_tpu.metrics.matcher import RuleSetStore
 from m3_tpu.metrics.policy import StoragePolicy
 from m3_tpu.metrics.rules import MappingRuleSnapshot, Rule, RuleSet
 from m3_tpu.parallel.sharding import ShardSet
+from m3_tpu.query import NamespaceAttrs
 from m3_tpu.storage.database import Database
 from m3_tpu.storage.namespace import NamespaceOptions
 
@@ -51,9 +54,11 @@ def coord():
             "downsample-api", 0, TagsFilter({"service": "api"}),
             magg.AggID.compress([magg.AggType.MAX]), (TEN_S,))])])
     RuleSetStore(store).publish(rs)
-    c = run_embedded(db, kv_store=store,
-                     aggregated_namespaces={TEN_S: b"agg_10s"},
-                     clock=lambda: now["t"])
+    c = run_embedded(db, kv_store=store, cluster_namespaces=[
+        NamespaceAttrs(b"default", retention_ns=TEN_S.retention_ns),
+        NamespaceAttrs(b"agg_10s", True, TEN_S.retention_ns,
+                       TEN_S.resolution.window_ns, complete=False)],
+        clock=lambda: now["t"])
     yield c, db, now
     c.close()
 
@@ -171,6 +176,46 @@ class TestDownsampler:
         assert len(ids_unagg) == 1
 
 
+    def test_a_sink_that_fails_loses_no_closed_window(self, coord,
+                                                      monkeypatch):
+        """The aggregator gives a window up when it is collected: a round
+        whose sink raises keeps its rows for the next round, the flush
+        thread counts and logs it and lives on."""
+        from m3_tpu.utils import instrument
+
+        c, db, now = coord
+        for i in range(12):
+            now["t"] = T0 + i * 2 * S
+            c.writer.write({b"__name__": b"lat", b"service": b"api"},
+                           T0 + i * 2 * S, float(i))
+        ns = db.namespace(b"agg_10s")
+        real, fails = db.write_batch, [2]
+
+        def failing(namespace, *a, **kw):
+            if namespace == b"agg_10s" and fails[0]:
+                fails[0] -= 1
+                raise OSError("disk full")
+            return real(namespace, *a, **kw)
+
+        monkeypatch.setattr(db, "write_batch", failing)
+        now["t"] = T0 + 40 * S
+        with pytest.raises(OSError):
+            c.flush_downsampler()        # the caller's own flush hears
+        assert len(c.downsampler._held) == 3
+        errors = instrument.ROOT.counter("coordinator.downsample.flush_errors")
+        before = errors.value()
+        c.start_downsample_flush()       # a round a second
+        deadline = time.monotonic() + 10
+        while c.downsampler._held and time.monotonic() < deadline:
+            time.sleep(0.01)
+        c.close()
+        assert errors.value() - before == 1 and not c.downsampler._held
+        from m3_tpu.index import query as iq
+        (sid,) = db.query_ids(b"agg_10s", iq.new_term(b"service", b"api"))
+        t, v = ns.shards[db.shard_set.lookup(sid)].read(sid, T0, T0 + 60 * S)
+        np.testing.assert_array_equal(v, [4.0, 9.0, 11.0])
+
+
 class TestAdmin:
     def test_database_create_quickstart(self, coord):
         c, db, now = coord
@@ -218,3 +263,34 @@ def test_instant_scalar_result_type(coord):
     r = http("GET", base + "/api/v1/query?query=vector(42)&time=1700000000")
     assert r["data"]["resultType"] == "vector"
     assert r["data"]["result"][0]["value"][1] == "42"
+
+
+def test_a_flush_beside_the_first_writes_of_new_series_loses_nothing(coord):
+    """The flush thread drains the aggregator's lists while handler
+    threads add the elems of series they have not seen: every closed
+    window is emitted once, and no round raises."""
+    c, db, now = coord
+    ds = c.downsampler
+    errors, stop = [], threading.Event()
+
+    def flusher():
+        while not stop.is_set():
+            try:
+                ds.flush()
+            except Exception as e:     # what the flush thread would log
+                errors.append(e)
+
+    t = threading.Thread(target=flusher)
+    t.start()
+    n = 4000
+    for i in range(n):
+        c.writer.write({b"__name__": b"lat", b"service": b"api",
+                        b"inst": b"%05d" % i}, T0 + S, float(i))
+    stop.set()
+    t.join()
+    assert not errors
+    now["t"] = T0 + 40 * S
+    ds.flush()
+    from m3_tpu.index import query as iq
+    ids = db.query_ids(b"agg_10s", iq.new_term(b"service", b"api"))
+    assert len(ids) == n

@@ -3,6 +3,8 @@ its answers are Shard.read's bit for bit, its cold rows cost a dispatch a
 geometry, and a store larger than the block cache's budget evicts,
 re-admits and still answers exactly."""
 
+import threading
+
 import numpy as np
 import pytest
 
@@ -132,7 +134,7 @@ def test_hits_and_misses_mix_in_one_fetch(store, cache):
     start, end = T0, store.end
     assert_same(store, ids[:2], start, T0 + BLOCK)     # touches of block 0
     assert_same(store, ids[:2], start, T0 + BLOCK)
-    assert c.stats()["admitted"] > 0
+    assert c.wait_filled() and c.stats()["admitted"] > 0
     hits0, misses0 = c.stats()["hits"], c.stats()["misses"]
     # block 0 of those shards is resident, no other block has been
     # touched: hits and misses in one fetch
@@ -231,8 +233,9 @@ def test_local_storage_fetch_goes_through_it(store, cache):
 def test_a_store_larger_than_the_budget_evicts_readmits_and_answers(
         store, cache):
     """More generations than the budget holds: planes are admitted while
-    there is room, then one a fetch, least recently used first out; every
-    answer stays exact, and a block that was evicted comes back."""
+    there is room (by the fill thread), then one a fetch, least recently
+    used first out; every answer stays exact, and a block that was
+    evicted comes back."""
     blocks = [b for sh in store.ns.shards.values() for b in sh.blocks.values()]
     one = max(block_cache.plane_bytes(b) for b in blocks)
     c = cache(3 * one + one // 2, admit_after=2)      # room for three
@@ -242,6 +245,7 @@ def test_a_store_larger_than_the_budget_evicts_readmits_and_answers(
     for _ in range(30):
         pick = [ids[i] for i in rng.choice(len(ids), 6, replace=False)]
         assert_same(store, pick, T0, store.end)
+        assert c.wait_filled()
         with c._lock:
             resident = {g for g, e in c._entries.items()
                         if e.decoded is not None}
@@ -254,3 +258,107 @@ def test_a_store_larger_than_the_budget_evicts_readmits_and_answers(
     assert st["hits"] > 0 and st["misses"] > 0
     # a full sweep over everything still answers exactly
     assert_same(store, ids, T0, store.end)
+
+
+def test_while_there_is_room_the_fill_thread_admits_not_the_fetch(
+        store, cache, monkeypatch):
+    """A restarted node's first fetches: the blocks they touch twice are
+    decoded whole by the cache's own thread; the fetch's thread decodes
+    the rows it wants in one dispatch and nothing else."""
+    c = cache(1 << 30, admit_after=2)
+    whole = []
+    real = block_mod.SealedBlock._decode_plane
+
+    def spy(self, encoded=None):
+        whole.append(threading.current_thread().name)
+        return real(self, encoded)
+
+    monkeypatch.setattr(block_mod.SealedBlock, "_decode_plane", spy)
+    assert_same(store, store.ids, T0, store.end)    # rows >= 2 a block
+    assert c.wait_filled()
+    assert whole and set(whole) == {block_cache.FILL_THREAD_NAME}
+    st = c.stats()
+    assert st["admitted"] == len(whole) and st["evictions"] == 0
+    hits0 = st["hits"]
+    assert_same(store, store.ids, T0, store.end)    # now from the planes
+    assert c.stats()["hits"] > hits0
+    assert c.stats()["admitted"] == len(whole)
+
+
+def test_no_more_is_queued_than_fits_and_a_full_cache_admits_one_a_fetch(
+        store, cache, monkeypatch):
+    blocks = [b for sh in store.ns.shards.values() for b in sh.blocks.values()]
+    one = max(block_cache.plane_bytes(b) for b in blocks)
+    c = cache(2 * one + one // 2, admit_after=1)      # room for two
+    started = threading.Event()
+    release = threading.Event()
+    real = block_mod.SealedBlock._decode_plane
+
+    def held(self, encoded=None):
+        if threading.current_thread().name == block_cache.FILL_THREAD_NAME:
+            started.set()
+            assert release.wait(30)
+        return real(self, encoded)
+
+    monkeypatch.setattr(block_mod.SealedBlock, "_decode_plane", held)
+    store.batched(store.ids, T0, store.end)     # touches every block
+    assert started.wait(30)
+    with c._lock:
+        queued = len(c._fill) + 1
+        assert 2 <= queued < len(blocks)
+        assert c._fill_bytes <= c.budget.limit < c._fill_bytes + one
+    # what the queue has promised is room no more: this fetch admits the
+    # one hottest itself, on its own thread
+    admitted0 = c.stats()["admitted"]
+    store.batched(store.ids, T0, store.end)
+    assert c.stats()["admitted"] == admitted0 + 1
+    release.set()
+    assert c.wait_filled()
+    assert c.resident_bytes() <= c.budget.limit and c._fill_bytes == 0
+    assert c.stats()["admitted"] == queued + 1
+    assert_same(store, store.ids, T0, store.end)
+
+
+def test_a_block_dropped_while_it_waits_for_the_fill_thread_is_not_pinned(
+        store, cache, monkeypatch):
+    c = cache(1 << 30, admit_after=1)
+    release = threading.Event()
+    real = block_mod.SealedBlock._decode_plane
+
+    def held(self, encoded=None):
+        assert release.wait(30)
+        return real(self, encoded)
+
+    monkeypatch.setattr(block_mod.SealedBlock, "_decode_plane", held)
+    store.batched(store.ids[:4], T0, T0 + BLOCK)
+    with c._lock:
+        waiting = [b.gen for b in c._fill] + sorted(c._decoding)
+    assert waiting
+    for gen in waiting:
+        c.invalidate(gen)
+    release.set()
+    assert c.wait_filled()
+    assert len(c) == 0 and c.resident_bytes() == 0
+    assert c.stats()["admitted"] == 0
+
+
+def test_the_fill_thread_stands_back_while_a_request_is_served(
+        store, cache, monkeypatch):
+    from m3_tpu.utils import foreground
+
+    monkeypatch.setattr(block_cache, "FILL_STANDS_BACK_S", 30.0)
+    c = cache(1 << 30, admit_after=1)
+    with foreground.serving:
+        assert not foreground.wait_quiet(0.01)
+        store.batched(store.ids, T0, store.end)
+        with c._lock:
+            assert c._fill and c._filler is not None
+        assert not c.wait_filled(0.2) and c.stats()["admitted"] == 0
+    assert foreground.wait_quiet(0.01)
+    assert c.wait_filled() and c.stats()["admitted"] > 0
+    # and never for long: a server that is never quiet still fills
+    monkeypatch.setattr(block_cache, "FILL_STANDS_BACK_S", 0.01)
+    c = cache(1 << 30, admit_after=1)
+    with foreground.serving:
+        store.batched(store.ids, T0, store.end)
+        assert c.wait_filled() and c.stats()["admitted"] > 0

@@ -70,25 +70,30 @@ def test_nothing_runs_and_nothing_is_counted_until_a_trace_is_asked_for(
 
 def test_an_asked_for_root_starts_it_and_it_ends_itself(tracer, books,
                                                         monkeypatch):
+    # (room for a worker that shares its cores: a first wake 60 ms late
+    # would find the probe idle before it had counted five)
     monkeypatch.setattr(tracing, "PROBE_PERIOD_NS", 5_000_000)
-    monkeypatch.setattr(tracing, "PROBE_IDLE_EXIT_NS", 60_000_000)
-    n0 = len(probes())
+    monkeypatch.setattr(tracing, "PROBE_IDLE_EXIT_NS", 300_000_000)
+    # (its own thread, not a count by name: an earlier file's probe may
+    # still run in this worker, and end itself while this test waits)
+    others = set(probes())
     with tracer.span_from(CTX, "http.GET /x"):
-        assert tracer.runtime.running and len(probes()) == n0 + 1
+        mine = set(probes()) - others
+        assert tracer.runtime.running and len(mine) == 1
     # every additive counter exists from the start, and counts from there
     keys = runtime_keys(books)
     assert {"runtime." + k for k in tracing._ADDITIVE} <= set(keys)
-    assert wait_for(lambda: not tracer.runtime.running)
-    assert wait_for(lambda: len(probes()) == n0)
+    assert wait_for(lambda: not tracer.runtime.running, 5)
+    assert wait_for(lambda: not any(t.is_alive() for t in mine), 5)
     keys = runtime_keys(books)
     assert keys["runtime.probe.wakes"] >= 5
-    assert keys["runtime.probe.wall_ns"] >= 50_000_000
+    assert keys["runtime.probe.wall_ns"] >= 250_000_000
     assert keys["runtime.cpu_ns{role=probe}"] > 0
     # asked again, it runs again on the counters it had
     with tracer.span_from(CTX, "http.GET /x"):
         assert tracer.runtime.running
     assert wait_for(lambda: runtime_keys(books)["runtime.probe.wakes"]
-                    > keys["runtime.probe.wakes"])
+                    > keys["runtime.probe.wakes"], 5)
 
 
 def spin_count(ms: float) -> int:
@@ -105,7 +110,10 @@ def spin_count(ms: float) -> int:
 
 
 def test_a_stall_names_the_thread_that_kept_the_gil(tracer, books):
-    n = spin_count(300)
+    # (600 ms by the calibration: on a worker that shares its cores the
+    # three timings can all be slow, and a hold sized by them has run
+    # 110 ms where 300 were meant — under the 150 asserted below)
+    n = spin_count(600)
     with tracer.span_from(CTX, "http.GET /x"):
         pass
     assert wait_for(lambda: len(tracer.runtime.wakes) >= 2)
@@ -177,6 +185,11 @@ def test_a_thread_is_counted_under_the_role_of_the_root_it_opened(
 
 def test_a_thread_that_exits_between_two_samples_breaks_nothing(
         tracer, books, monkeypatch):
+    # (the samples below are this test's alone: a late wake on a loaded
+    # worker is a stall, and the probe's own sample at it would put the
+    # thread back between the test's sample and its look)
+    monkeypatch.setattr(tracing, "PROBE_SAMPLE_NS", 10**12)
+    monkeypatch.setattr(tracing, "STALL_NS", 10**12)
     rt = tracer.runtime
     with tracer.span_from(CTX, "http.GET /x"):
         pass
@@ -222,6 +235,7 @@ def test_debug_traces_serves_the_ring_and_keeps_the_request_trees(
 @pytest.mark.parametrize("name,role", [
     ("accept-coordinator-http", "accept"), ("accept-node-rpc", "accept"),
     ("fanout_3", "fanout"), ("tsz-prep_0", "prep"),
+    ("block-cache-fill", "cache-fill"),
     (tracing.PROBE_THREAD_NAME, "probe"), ("MainThread", "main"),
     ("mediator", "python-other"),
     ("Thread-7 (process_request_thread)", "python-other")])
