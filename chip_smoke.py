@@ -815,6 +815,9 @@ def phase_served_verdict(ctx: Ctx):
 
 
 FEW_ROWS = (1, 2, 3, 5, 8, 16)
+# the other row buckets of a cold read and of a whole block's decode
+# (storage/block.py::ROW_BUCKETS)
+BUCKET_ROWS = (64, 256, 1024)
 
 
 def few_row_twin_faults(seed: int, rows=FEW_ROWS, starts: int = 4) -> list:
@@ -826,7 +829,11 @@ def few_row_twin_faults(seed: int, rows=FEW_ROWS, starts: int = 4) -> list:
     v5e the one-row plane of the block two after T0 read 12 timestamps
     ~2^31 ns low (PR 32): XLA:TPU lowers the degenerate reshapes of a
     one-row program as u32 reduce-adds inside the fused unit multiply;
-    tsz.decode_plane builds no one-row program on that route."""
+    tsz.decode_plane builds no one-row program on that route. The
+    program weaves each pair into rows of 64-bit cells on the device (a
+    reshape of u32 pairs, the same family of lowering): the planes must
+    come back C-contiguous each, which only a chip's layout can deny,
+    and the smoke runs the larger row buckets too (BUCKET_ROWS)."""
     from m3_tpu.ops import tsz
     from m3_tpu.parallel import guard
     from m3_tpu.storage.block import encode_block
@@ -854,14 +861,17 @@ def few_row_twin_faults(seed: int, rows=FEW_ROWS, starts: int = 4) -> list:
             for route in ("default", "xla"):
                 guard.set_disabled("codec.decode", route == "xla")
                 try:
-                    got[route] = [np.asarray(a)[:, :w] for a in
-                                  tsz.decode_plane(
-                                      np.asarray(whole.words)[:r],
-                                      np.asarray(whole.npoints)[:r],
-                                      window=whole.window,
-                                      unit_nanos=whole.time_unit.nanos)]
+                    planes = tsz.decode_plane(
+                        np.asarray(whole.words)[:r],
+                        np.asarray(whole.npoints)[:r], window=whole.window,
+                        unit_nanos=whole.time_unit.nanos)
                 finally:
                     guard.set_disabled("codec.decode", False)
+                if not all(isinstance(a, np.ndarray) and a.flags.c_contiguous
+                           and a.shape == (r, whole.window) for a in planes):
+                    faults.append(f"{where}: {route} route's planes are not "
+                                  f"C-contiguous [rows, window] arrays")
+                got[route] = [a[:, :w] for a in planes]
             ts_d, vs_d = got["default"]
             if not (np.array_equal(ts_d, got["xla"][0]) and np.array_equal(
                     vs_d.view(np.uint64), got["xla"][1].view(np.uint64))):
@@ -924,7 +934,7 @@ def phase_codec_twins(ctx: Ctx):
     check(np.array_equal(dec_default[1][:, :BLOCK_POINTS].view(np.uint64),
                          vals[:, :BLOCK_POINTS].view(np.uint64)),
           "decode: value bits differ from the written samples")
-    faults = few_row_twin_faults(ctx.seed)
+    faults = few_row_twin_faults(ctx.seed, rows=FEW_ROWS + BUCKET_ROWS)
     check(not faults, "few-row planes: " + "; ".join(faults[:5]))
     ids = ctx.ids[:min(sz.series, 20_000)]
     h_default = hashing.hash_batch(ids)

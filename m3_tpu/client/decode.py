@@ -16,7 +16,6 @@ import threading
 from typing import Dict, List, Sequence, Tuple
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from ..ops import tsz
@@ -109,15 +108,17 @@ def decode_tile(words, npoints, window: int, time_unit: int
                             (rp, int(words.shape[-1]), int(window)))
     # Fused decode: tick cumsum + time-unit scaling happen inside the one
     # decode program; the host just slices the padded rows back off. The
-    # words are put on the device here (the calling thread's: its
-    # scope's, parallel/scope.py) and the dispatch is counted there.
-    jwords = jnp.asarray(words)
-    for dev in jwords.devices():
+    # launch carries the words to the calling thread's device (its
+    # scope's, parallel/scope.py) and the dispatch is counted where the
+    # result lies.
+    ran_on: list = []
+    ts, vs = tsz.decode_plane(words, np_pad, window=window,
+                              unit_nanos=xtime.Unit(time_unit).nanos,
+                              ran_on=ran_on)
+    for dev in ran_on:
         instrument.ROOT.sub_scope("client.decode_tile", device=str(dev.id)
                                   ).counter("dispatches").inc()
-    ts, vs = tsz.decode_plane(jwords, np_pad, window=window,
-                              unit_nanos=xtime.Unit(time_unit).nanos)
-    return np.asarray(ts[:n]), np.asarray(vs[:n])
+    return ts[:n], vs[:n]
 
 
 # A fetch's stacked decode goes in calls of at most this many rows (the

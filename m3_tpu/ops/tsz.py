@@ -40,7 +40,7 @@ import numpy as np
 from . import bits64 as b64
 from .bits64 import U32
 from .ref_codec import REWRITE_THRESHOLD
-from ..utils import tracing
+from ..utils import instrument, tracing
 
 I32 = jnp.int32
 
@@ -1102,30 +1102,42 @@ def _row_mesh(words):
     return sh.mesh, spec[0]
 
 
+_DECODE_CALLS = instrument.ROOT.counter("codec.decode.calls")
+_DECODE_FETCHES = instrument.ROOT.counter("codec.decode.fetches")
+_DECODE_UPLOADS = instrument.ROOT.counter("codec.decode.uploads")
+
+
 @functools.lru_cache(maxsize=None)
 def _decode_fused_jit(window: int, unit_nanos: int, with_f32: bool,
                       route: str, rows=None):
     """Jitted fused decode program for one static (window, unit, route):
     stream scan + tick cumsum + unit-nanos multiply (mul64_const — minute
     units exceed u32 range) + exact on-device int->f64 bit conversion for
-    k=0 int rows, emitting PAIR_HI-ordered [N, W, 2] u32 planes the host
-    views zero-copy as int64/f64. k>0 rows (fixed-decimal gauges) keep
-    raw mantissa pairs; `fix` marks them for the host's exact /10^k.
+    k=0 int rows, and ONE u32 result of [2N + E, 2W]: rows [0, N) are the
+    timestamps and rows [N, 2N) the values, each row the 2W words of its
+    W 64-bit cells in native order (PAIR_HI), so the fetched buffer is
+    C-ordered and the host views the two planes as int64 / float64 where
+    they lie; the E = ceil(N / 2W) trailing rows hold one word a
+    decoded row, its decimal exponent k where the row is int-mode with
+    k > 0 (fixed-decimal gauges: the values plane keeps their raw
+    mantissas for the host's exact /10^k) and 0 elsewhere. `with_f32`
+    adds the float32 plane as a second result.
 
     `rows` = _row_mesh(words): row-partitioned input decodes as an
     explicit shard_map over those rows (decode is row-independent), each
-    device scanning its own slice. Left to GSPMD, the Pallas route's
+    device scanning its own slice, and the planes are gathered into the
+    one result on the mesh. Left to GSPMD, the Pallas route's
     pallas_call is opaque to the partitioner, which all-gathers the
     streams and runs the full kernel on every device."""
     hi = b64.PAIR_HI
 
-    def stack(pair):
+    def weave(pair):
         parts = [None, None]
         parts[hi] = pair[0]
         parts[1 - hi] = pair[1]
-        return jnp.stack(parts, axis=-1)
+        return jnp.stack(parts, axis=-1).reshape(-1, 2 * window)
 
-    def run(words, npoints):
+    def scan_rows(words, npoints):
         if route == "pallas":
             from . import pallas_codec
 
@@ -1137,27 +1149,40 @@ def _decode_fused_jit(window: int, unit_nanos: int, with_f32: bool,
         fb = b64.i64_pair_to_f64_bits((out["vhi"], out["vlo"]))
         vhi = jnp.where(k0[:, None], fb[0], out["vhi"])
         vlo = jnp.where(k0[:, None], fb[1], out["vlo"])
-        res = {"ts": stack(ts_ns), "vals": stack((vhi, vlo)),
-               "fix": out["int_mode"] & (out["k"] > 0), "k": out["k"]}
+        fix_k = jnp.where(out["int_mode"] & (out["k"] > 0), out["k"], 0)
+        res = (weave(ts_ns), weave((vhi, vlo)), fix_k.astype(U32))
         if with_f32:
-            res["f32"] = b64.f64_bits_to_f32(vhi, vlo)
+            res += (b64.f64_bits_to_f32(vhi, vlo),)
         return res
 
+    scan, placed = scan_rows, None
     if rows is not None:
-        from jax.sharding import PartitionSpec as P
+        from jax.sharding import NamedSharding, PartitionSpec as P
 
         mesh, axes = rows
-        out_specs = {"ts": P(axes, None, None), "vals": P(axes, None, None),
-                     "fix": P(axes), "k": P(axes)}
+        # one copy of the result on every device of the mesh: the fetch
+        # reads one of them
+        placed = NamedSharding(mesh, P())
+        out_specs = (P(axes, None), P(axes, None), P(axes))
         if with_f32:
-            out_specs["f32"] = P(axes, None)
-        run = jax.shard_map(run, mesh=mesh, in_specs=(P(axes, None), P(axes)),
-                            out_specs=out_specs, check_vma=False)
-    return jax.jit(run)
+            out_specs += (P(axes, None),)
+        scan = jax.shard_map(scan_rows, mesh=mesh,
+                             in_specs=(P(axes, None), P(axes)),
+                             out_specs=out_specs, check_vma=False)
+
+    # `run` by name: the device trace calls the Pallas kernel after the
+    # jitted function it sits in (benchmark/layer_metrics/decode_roofline.py)
+    def run(words, npoints):
+        ts, vals, fix_k, *f32 = scan(words, npoints)
+        meta = jnp.pad(fix_k, (0, -ts.shape[0] % (2 * window)))
+        return (jnp.concatenate([ts, vals, meta.reshape(-1, 2 * window)]),
+                *f32)
+
+    return jax.jit(run, out_shardings=placed)
 
 
 def decode_plane(words, npoints, *, window: int, unit_nanos: int = 1,
-                 with_f32: bool = False):
+                 with_f32: bool = False, ran_on: list | None = None):
     """Fused whole-plane decode -> (ts int64 [N, W] nanos, vals f64
     [N, W][, vals_f32 [N, W]]).
 
@@ -1165,16 +1190,26 @@ def decode_plane(words, npoints, *, window: int, unit_nanos: int = 1,
     paid per plane (int64 cumsum, time-unit multiply, u64 view merge,
     int->float convert, mode select): timestamps accumulate in the scan
     carry and are unit-scaled on device, int-mode k=0 values convert to
-    exact f64 bits on device (|m| < 2^53, no rounding), and the outputs
-    land as native-order pairs so the host just reinterprets the buffer.
-    Only rows with decimal exponent k>0 pay a host fixup — f64 division
-    by 10^k has no exact integer formulation. Returned arrays may be
-    read-only zero-copy views of the fetched buffers.
+    exact f64 bits on device (|m| < 2^53, no rounding), and the planes
+    leave the device as rows of native-order 64-bit cells, so the host
+    reinterprets the one buffer it fetched. A call is one upload, one
+    program and one fetch (`with_f32` makes it two): host arrays go to
+    the program as they are, so the launch carries them up and nobody
+    waits for them (a `device_put` of the pair before the launch made
+    the call 0.1 ms longer on the chip's host: PERF.md section 7);
+    inputs already on a device are used where they are. Counters
+    `codec.decode.calls` / `.uploads` / `.fetches`. Only rows with
+    decimal exponent k>0 pay a host fixup — f64 division by 10^k has no
+    exact integer formulation — and only then is the values plane a copy.
+    Returned planes are C-contiguous each on its own, and may be
+    read-only views of the fetched buffer.
 
     with_f32 additionally returns the float32 downcast plane computed on
     device (bits64.f64_bits_to_f32, bit-identical to numpy's astype) —
     the plan compiler's `value` fetch staging consumes this instead of
-    running its own downcast pass."""
+    running its own downcast pass. `ran_on`, a list, receives the
+    devices that hold the result (a caller that counts where its rows
+    were decoded: client/decode.py::decode_tile)."""
     from ..parallel import telemetry
 
     route = _decode_route()
@@ -1182,25 +1217,40 @@ def decode_plane(words, npoints, *, window: int, unit_nanos: int = 1,
     row_mesh = _row_mesh(words)
     run = _decode_fused_jit(int(window), int(unit_nanos), bool(with_f32),
                             route, row_mesh)
+    # No one-row program on the Pallas route: the kernel's tile cut to
+    # one lane, [window, 1] -> [1, window], is a degenerate reshape, and
+    # XLA:TPU lowers those as u32 reduce-adds over a one-wide dimension
+    # inside the fused unit multiply; on a v5e that read 12 of a row's
+    # 128 timestamps ~2^31 ns low (PERF.md section 6, PR 32; the
+    # kernel's own ticks, the multiply alone and the XLA scan were
+    # exact). The row goes twice: doubled on the host before it goes up,
+    # or on the device it is on.
+    lone = route == "pallas" and int(np.shape(words)[0]) == 1
     # The call's anatomy, as stretches of the detailed span it runs
     # under (the session's client.fetch_tagged, a cold read's
-    # query.fetch): `h2d`, `launch`, `device_wait` (the device's work and
-    # the first fetch), `d2h` (the other fetches), `layout`. No stretch
-    # synchronises anything the call did not wait for already.
+    # query.fetch): `h2d` (the host's part of the upload: the launch
+    # carries the arrays up), `launch`, `device_wait` (the device's work
+    # and the one fetch), `d2h` (the f32 plane's, when asked for),
+    # `layout` (the views and the k > 0 fix-up). No stretch synchronises
+    # anything the call did not wait for already.
     with tracing.phase("h2d"):
-        jwords = jnp.asarray(words)
-        jnp_ = jnp.asarray(npoints, I32)
-    lone = route == "pallas" and jwords.shape[0] == 1
-    if lone:
-        # No one-row program on this route: the kernel's tile cut to one
-        # lane, [window, 1] -> [1, window], is a degenerate reshape, and
-        # XLA:TPU lowers those as u32 reduce-adds over a one-wide
-        # dimension inside the fused unit multiply; on a v5e that read
-        # 12 of a row's 128 timestamps ~2^31 ns low (PERF.md section 6,
-        # PR 32; the kernel's own ticks, the multiply alone and the XLA
-        # scan were exact). The row goes twice, on the device it is on.
-        jwords = jnp.concatenate([jwords, jwords])
-        jnp_ = jnp.concatenate([jnp_, jnp_])
+        held = isinstance(words, jax.Array), isinstance(npoints, jax.Array)
+        if not held[0]:
+            words = np.asarray(words)
+        if not held[1]:
+            npoints = np.asarray(npoints, np.int32)
+        if lone:
+            words = (jnp if held[0] else np).concatenate([words, words])
+            npoints = (jnp if held[1] else np).concatenate([npoints, npoints])
+    uploads = 0 if all(held) else 1
+    fetches = 2 if with_f32 else 1
+    _DECODE_CALLS.inc()
+    _DECODE_UPLOADS.inc(uploads)
+    _DECODE_FETCHES.inc(fetches)
+    sp = tracing.detail()
+    if sp is not None:
+        sp.add_cost("upload_n", uploads)
+        sp.add_cost("fetch_n", fetches)
     if route == "pallas":
         from ..parallel import guard
 
@@ -1208,7 +1258,7 @@ def decode_plane(words, npoints, *, window: int, unit_nanos: int = 1,
             key = (int(window), int(unit_nanos), bool(with_f32), route)
             timed = key not in _DECODE_TIMED
             t_start = time.perf_counter() if timed else 0.0
-            res = run(jwords, jnp_)
+            res = run(words, npoints)
             if timed:
                 _DECODE_TIMED.add(key)
                 jax.block_until_ready(res)
@@ -1222,33 +1272,28 @@ def decode_plane(words, npoints, *, window: int, unit_nanos: int = 1,
             # cache key, so no cache surgery is needed to reroute).
             fb = _decode_fused_jit(int(window), int(unit_nanos),
                                    bool(with_f32), "xla", row_mesh)
-            return fb(jwords, jnp_)
+            return fb(words, npoints)
 
         with tracing.phase("launch"):
             out = guard.dispatch("codec.decode", _pallas_decode, _xla_decode)
     else:
         with tracing.phase("launch"):
-            out = run(jwords, jnp_)
+            out = run(words, npoints)
+    if ran_on is not None:
+        ran_on.extend(out[0].devices())
     with tracing.phase("device_wait"):
-        pairs_ts = np.asarray(out["ts"])
+        buf = np.asarray(out[0])
     with tracing.phase("d2h"):
-        pairs_v = np.asarray(out["vals"])
-        f32 = np.asarray(out["f32"]) if with_f32 else None
-        rows = np.flatnonzero(np.asarray(out["fix"]))
-        k = np.asarray(out["k"])[rows].astype(np.float64) if rows.size \
-            else None
+        f32 = np.asarray(out[1]) if with_f32 else None
     with tracing.phase("layout"):
-        # ascontiguousarray: a TPU array of minor dimension 2 comes back
-        # with the device layout's strides, and a dtype view needs the
-        # pair axis contiguous (a no-op where the fetch is already
-        # C-ordered).
-        pairs_ts = np.ascontiguousarray(pairs_ts)
-        pairs_v = np.ascontiguousarray(pairs_v)
-        ts = pairs_ts.view(np.int64)[..., 0]
-        vals = pairs_v.view(np.float64)[..., 0]
+        n = words.shape[0]
+        ts = buf[:n].view(np.int64)
+        vals = buf[n:2 * n].view(np.float64)
+        fix_k = buf[2 * n:].reshape(-1)[:n]
+        rows = np.flatnonzero(fix_k)
         if rows.size:
-            raw = np.ascontiguousarray(pairs_v[rows]).view(np.int64)[..., 0]
-            fixed = raw.astype(np.float64) / np.power(10.0, k)[:, None]
+            fixed = vals[rows].view(np.int64).astype(np.float64) \
+                / np.power(10.0, fix_k[rows].astype(np.float64))[:, None]
             if not vals.flags.writeable:
                 vals = vals.copy()
             vals[rows] = fixed

@@ -201,8 +201,7 @@ class SealedBlock:
             self.words[row : row + 1], self.npoints[row : row + 1],
             self.window, self.time_unit.nanos)
         n = int(self.npoints[row])
-        t_out = np.ascontiguousarray(ts[0, :n])
-        v_out = np.ascontiguousarray(vals[0, :n])
+        t_out, v_out = ts[0, :n], vals[0, :n]
         t_out.setflags(write=False)
         v_out.setflags(write=False)
         return t_out, v_out
@@ -259,15 +258,14 @@ class SealedBlock:
                     [npoints, np.repeat(npoints[:1], sp - s)])
         telemetry.record_bucket(
             "block.decode_plane",
-            (int(np.asarray(words).shape[0]),
-             int(np.asarray(words).shape[-1]), int(self.window)))
+            (int(np.shape(words)[0]), int(np.shape(words)[-1]),
+             int(self.window)))
         # Fused plane decode: the tick cumsum, unit-nanos scaling and
         # int->f64 select all run inside the ONE decode program
         # (tsz.decode_plane) instead of as host passes over [S, W] planes.
         ts, vals = _dispatch_decode(words, npoints, self.window,
                                     self.time_unit.nanos)
-        ts = np.ascontiguousarray(ts[:s])
-        vals = np.ascontiguousarray(vals[:s])
+        ts, vals = ts[:s], vals[:s]
         ts.setflags(write=False)
         vals.setflags(write=False)
         return ts, vals

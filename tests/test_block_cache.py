@@ -94,6 +94,36 @@ class TestCachedDecodeBitIdentity:
         with pytest.raises(ValueError):
             t[0, 0] = 1
 
+    @pytest.mark.parametrize("rows", [5, 8, 40])
+    def test_cached_planes_are_c_contiguous_views_of_one_fetch(self, cache,
+                                                               rows):
+        """What the cache keeps of a whole-block decode: the two planes
+        as the one fetched buffer holds them — [S, W] each, C-contiguous
+        on its own, read-only — whether the block's rows fill their
+        bucket (8) or are padded to it (5, 40)."""
+        from m3_tpu.storage import block as block_mod
+        from m3_tpu.utils.instrument import ROOT
+
+        blk = make_block(np.random.default_rng(rows), s=rows, w=16)
+        fetches = ROOT.counter("codec.decode.fetches")
+        fetches0 = fetches.value()
+        blk.read_all()
+        t, v, _ = blk.read_all()              # the second touch admits
+        assert cache.stats()["admitted"] == 1
+        # two cold whole-block decodes' worth at most: each one fetch
+        assert 1 <= fetches.value() - fetches0 <= 2
+        assert t.shape == v.shape == (rows, blk.window)
+        for plane in (t, v):
+            assert plane.flags.c_contiguous and not plane.flags.writeable
+        assert t.dtype == np.int64 and v.dtype == np.float64
+        padded = block_mod.row_bucket(rows) != rows
+        assert (t.base is not None and t.base.nbytes > t.nbytes) or not padded
+        with block_cache.disabled():
+            want = blk.read_all()
+        np.testing.assert_array_equal(t, want[0])
+        np.testing.assert_array_equal(v.view(np.uint64),
+                                      want[1].view(np.uint64))
+
     def test_admission_requires_repeat_touch(self, cache):
         blk = make_block(np.random.default_rng(1))
         assert blk.read(0) is not None  # touch 1: no admission

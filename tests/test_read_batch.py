@@ -180,10 +180,48 @@ def test_cold_rows_cost_a_dispatch_a_geometry_not_a_pair(store, cache,
                   for sh in store.ns.shards.values()
                   for b in sh.blocks.values()}
     assert 1 <= costs["cold_dispatch_n"] <= len(geometries)
-    # each dispatch is one decode_plane call, its stretches on the span
-    assert costs["launch_n"] == costs["layout_n"] == costs["cold_dispatch_n"]
+    # each dispatch is one decode_plane call, its stretches on the span,
+    # and a call is one upload and one fetch
+    calls = costs["cold_dispatch_n"]
+    assert all(costs[k + "_n"] == calls for k in ANATOMY)
+    assert costs["fetch_n"] == costs["upload_n"] == calls
     assert 0 < costs["device_wait_ns"] + costs["d2h_ns"] + costs["layout_ns"] \
         <= costs["cold_decode_ns"]
+
+
+ANATOMY = ("h2d", "launch", "device_wait", "d2h", "layout")
+
+
+def test_an_admission_is_one_call_with_all_five_stretches(store, cache,
+                                                          monkeypatch):
+    """A full cache admits one block a fetch on the fetch's own thread:
+    the whole-block decode is a decode_plane call like the cold rows',
+    with the same five stretches, one upload and one fetch, and the
+    planes the cache keeps of it are read-only and C-contiguous."""
+    blocks = [b for sh in store.ns.shards.values() for b in sh.blocks.values()]
+    one = max(block_cache.plane_bytes(b) for b in blocks)
+    c = cache(2 * one + one // 2, admit_after=1)      # room for two
+    store.batched(store.ids, T0, store.end)           # fills it
+    assert c.wait_filled()
+    tracer = tracing.Tracer(sample_rate=1.0)
+    monkeypatch.setattr(tracing, "TRACER", tracer)
+    admitted0 = c.stats()["admitted"]
+    with tracer.background_span("query.fetch") as sp:
+        store.batched(store.ids, T0, store.end, sp)
+    assert c.stats()["admitted"] == admitted0 + 1     # inline, by this fetch
+    costs = sp.to_dict()["costs"]
+    calls = costs["cold_dispatch_n"] + 1
+    assert all(costs[k + "_n"] == calls and costs[k + "_ns"] > 0
+               for k in ANATOMY)
+    assert costs["fetch_n"] == costs["upload_n"] == calls
+    with c._lock:
+        kept = [e.decoded for e in c._entries.values()
+                if e.decoded is not None]
+    assert kept
+    for ts, vals in kept:
+        for plane in (ts, vals):
+            assert plane.flags.c_contiguous and not plane.flags.writeable
+        assert ts.dtype == np.int64 and vals.dtype == np.float64
 
 
 def test_unknown_and_unheld_ids(store, cache):
