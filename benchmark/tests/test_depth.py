@@ -34,28 +34,13 @@ def depth_cell(**traffic_overrides):
     return cell
 
 
-def warm_decode_buckets(handle):
-    """On the CPU the program compiles a decode shape where a read first
-    meets it (on an accelerator a geometry's first cold read brings every
-    row bucket through its compile); which bucket a read needs depends on
-    what the cache holds, so the test meets them all before the window."""
-    from m3_tpu.storage import block
-
-    ns = handle.db.namespace(handle.namespace)
-    blk = next(iter(next(iter(ns.shards.values())).blocks.values()))
-    for rows in block.ROW_BUCKETS:
-        at = [0] * rows
-        block.decode_rows(blk.words[at], blk.npoints[at], blk.window,
-                          blk.time_unit.nanos)
-
-
 @pytest.fixture(scope="module")
 def run():
     r = cellrun.CellRun(depth_cell(), SEED, time.perf_counter_ns(),
                         trace=True, need_chip=False)
     try:
         r.facts = r.setup(3.0)
-        warm_decode_buckets(r.server.handle)
+        tiny.warm_decode_buckets(r.server.handle)
         r.m = r.window(3.0)
         yield r
     finally:
@@ -126,11 +111,61 @@ def test_a_run_is_correct_and_every_range_starts_past_the_boundary(run):
            "blocks_read_per_series", "merge_us_per_series", "bootstrap_fs_s",
            "fileset_build_s"}
     assert new <= set(result["metrics"]), sorted(result["metrics"])
-    deep = {k for k in result["metrics"] if k.endswith(".deep")}
-    # the device trace's two read nothing on the CPU (and no query runs
-    # on the interpreter: the cell reports no interpreter metric)
-    want = {m_["name"] for m_ in cell.per_layer if m_["name"].endswith(".deep")}
-    assert want - deep <= {"decode_roofline.deep", "device_idle_share.deep"}
+    # the cell's own list (35 readings, each under the one name it has in
+    # every cell that reports it): the device trace's two read nothing on
+    # the CPU, and three seconds at 6/s may draw no range that ends in the
+    # open buffer's two minutes of the last four hours (no query runs on
+    # the interpreter: the cell reports no interpreter metric)
+    want = {m_["name"] for m_ in cell.per_layer}
+    assert len(want) == 35 and not any(n.endswith(".deep") for n in want)
+    assert want - set(result["metrics"]) <= {
+        "decode_roofline", "device_idle_share.query",
+        "buffer_index_hit_share"}
+
+
+def test_a_folded_reading_reads_what_its_twin_read(run, tmp_path):
+    """benchmark/tools/fold_check.py on this window: an older tree in
+    which the cell's shared readings were forwarding twins (`<name>.deep`,
+    one line: the base's reader) reads, on the one Measurement, what this
+    tree's line carries under the folded names; and a twin that reads
+    something else is told apart."""
+    import json
+    import shutil
+    import sys
+
+    sys.path.insert(0, os.path.join(spec.BENCH_DIR, "tools"))
+    import fold_check
+
+    folded = {old: new for old, new in fold_check.folded_names().items()
+              if old.endswith(".deep")}
+    assert len(folded) == 18
+    bench = spec.load_benchmark()
+    by_name = {m_["name"]: m_ for m_ in bench["per_layer"]}
+    lm = tmp_path / "benchmark" / "layer_metrics"
+    shutil.copytree(os.path.join(spec.BENCH_DIR, "layer_metrics"), lm)
+    for old, new in folded.items():
+        base = by_name[new]
+        assert CELL in base["workloads"]
+        base["workloads"] = [w for w in base["workloads"] if w != CELL]
+        bench["per_layer"].append(dict(base, name=old, workloads=[CELL]))
+        (lm / (old + ".py")).write_text(
+            "from harness import spec\n\n"
+            f"read = spec.load_reader('layer_metrics', '{new}')\n")
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    m = run.m
+    result = run.result(m, *run.check(m))
+    rows = fold_check.compare(m, CELL, str(tmp_path), result["metrics"])
+    assert len(rows) == 35
+    assert {r["old"] for r in rows if r["old"] != r["new"]} == set(folded)
+    assert all(r["same"] for r in rows), [r for r in rows if not r["same"]]
+    read = [r for r in rows if r["old_value"] is not None]
+    assert len(read) >= 32
+    (lm / "front_in_ms.deep.py").write_text(
+        "def read(m):\n    return -1.0\n")
+    rows = fold_check.compare(m, CELL, str(tmp_path), result["metrics"])
+    assert [r["old"] for r in rows if not r["same"]] == ["front_in_ms.deep"]
+    assert spec.BENCH_DIR == os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))
 
 
 @pytest.mark.parametrize("control,rows", [

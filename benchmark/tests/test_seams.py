@@ -194,3 +194,90 @@ def test_every_shipped_name_resolves_to_a_file_with_its_functions(workload,
         mod = spec.load_part(kind, name)
         for attr in spec.PARTS[kind]:
             assert hasattr(mod, attr), (mod.__file__, attr)
+
+
+def _pairs():
+    """(kind, entry, cell) for every metric of BENCHMARK.json, once for
+    each cell that reports it (an entry with no list: every cell)."""
+    bench = spec.load_benchmark()
+    cells = [w["name"] for w in bench["workloads"]]
+    for kind, key in (("end_to_end", "end_to_end"),
+                      ("layer_metrics", "per_layer")):
+        for m in bench[key]:
+            for c in m.get("workloads", cells):
+                yield pytest.param(kind, m, c, id=m["name"] + "@" + c)
+
+
+@pytest.mark.parametrize("kind,decl,workload", list(_pairs()))
+def test_every_reading_of_every_cell_has_its_reader_and_its_declaration(
+        kind, decl, workload):
+    """One case a (metric, cell) pair, so a reading folded into its base's
+    `workloads` keeps its case: the reader loads, the cell exists and
+    reports what the reading moves, `load_cell` hands the entry to the
+    cell's run, and a per-layer entry's declaration beside the reader is
+    the entry (its `workloads` too, while tier-1's
+    tests/test_benchmark_seams.py and test_runtime_probe.py hold that
+    mirror: PERF.md section 7)."""
+    bench = spec.load_benchmark()
+    assert callable(spec.load_reader(kind, decl["name"]))
+    assert workload in {w["name"] for w in bench["workloads"]}
+    cell = spec.load_cell(workload, bench)
+    if kind == "end_to_end":
+        assert decl in cell.end_to_end
+        return
+    assert decl in cell.per_layer
+    assert decl["moves"] in {m["name"] for m in cell.end_to_end}
+    assert spec.layer_metric_declarations()[decl["name"]] == decl
+
+
+def test_the_per_layer_list_fits_the_drivers_limit():
+    """`per_layer`: 1 to 128 metrics (the builder's instructions; README:
+    "A layer metric")."""
+    assert 1 <= len(spec.load_benchmark()["per_layer"]) <= 128
+
+
+def test_a_folded_name_is_no_entry_and_its_base_lists_its_cells():
+    """One entry a reading: a twin that a `benchmark` PR folded into its
+    base (tools/folded_names.json) has no entry, declaration or reader
+    any more, and the base it names is an entry."""
+    with open(os.path.join(spec.BENCH_DIR, "tools",
+                           "folded_names.json")) as f:
+        folded = json.load(f)
+    names = {m["name"] for m in spec.load_benchmark()["per_layer"]}
+    assert folded and not set(folded) & names
+    assert set(folded.values()) <= names
+    for old in folded:
+        for ext in (".json", ".py"):
+            assert not os.path.exists(os.path.join(
+                spec.BENCH_DIR, "layer_metrics", old + ext)), old + ext
+
+
+def test_a_twin_still_to_fold_reads_with_its_bases_code():
+    """The twins this tree still holds (an entry whose `moves`, unit,
+    source and layer are another entry's and whose name is that entry's
+    stem plus a cell's suffix) stay safe to fold: each is a forwarder to
+    its base's reader or a copy of its body, `device_idle_share.rf3`
+    alone apart (the busiest device, which on one chip is the chip)."""
+    import ast
+
+    def body(name):
+        path = os.path.join(spec.BENCH_DIR, "layer_metrics", name + ".py")
+        with open(path) as f:
+            tree = ast.parse(f.read())
+        return [ast.dump(n) for n in tree.body
+                if not (isinstance(n, ast.Expr)
+                        and isinstance(n.value, ast.Constant))]
+
+    by_name = {m["name"]: m for m in spec.load_benchmark()["per_layer"]}
+    for name, m in by_name.items():
+        stem, _, suffix = name.rpartition(".")
+        base = by_name.get(stem) or by_name.get(stem + ".query")
+        if not suffix or base is None or base is m or any(
+                base[k] != m[k] for k in ("unit", "better", "source",
+                                          "layer", "moves")):
+            continue
+        read = spec.load_reader("layer_metrics", name)
+        forwarded = os.path.basename(read.__code__.co_filename) \
+            == base["name"] + ".py"
+        assert forwarded or body(name) == body(base["name"]) \
+            or name == "device_idle_share.rf3", name

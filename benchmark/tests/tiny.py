@@ -41,3 +41,20 @@ def cell(workload: str, **traffic_overrides):
     c = spec.load_cell(workload, bench())
     c.traffic.update(traffic_overrides)
     return c
+
+
+def warm_decode_buckets(handle, namespaces=None):
+    """On the CPU the program compiles a decode shape where a read first
+    meets it (on an accelerator a geometry's first cold read brings every
+    row bucket through its compile); which bucket a read needs depends on
+    what the cache holds, so the test meets them all before the window,
+    in the handle's namespace or in each of `namespaces`."""
+    from m3_tpu.storage import block
+
+    for name in namespaces or (handle.namespace,):
+        ns = handle.db.namespace(name)
+        blk = next(iter(next(iter(ns.shards.values())).blocks.values()))
+        for rows in block.ROW_BUCKETS:
+            at = [0] * rows
+            block.decode_rows(blk.words[at], blk.npoints[at], blk.window,
+                              blk.time_unit.nanos)
