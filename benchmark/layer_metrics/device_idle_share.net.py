@@ -1,0 +1,6 @@
+"""`device_idle_share.query`'s reading in the cell of the `net` counters behind rate()
+panels (`net4k-query-rate`)."""
+
+from harness import spec
+
+read = spec.load_reader("layer_metrics", "device_idle_share.query")

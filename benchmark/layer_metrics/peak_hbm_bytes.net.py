@@ -1,0 +1,6 @@
+"""`peak_hbm_bytes.query`'s reading in the cell of the `net` counters behind rate()
+panels (`net4k-query-rate`)."""
+
+from harness import spec
+
+read = spec.load_reader("layer_metrics", "peak_hbm_bytes.query")

@@ -1,0 +1,6 @@
+"""`plan_route_share`'s reading in the cell of the `net` counters behind rate()
+panels (`net4k-query-rate`)."""
+
+from harness import spec
+
+read = spec.load_reader("layer_metrics", "plan_route_share")

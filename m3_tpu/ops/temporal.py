@@ -15,15 +15,23 @@ back on the host in f64 (sum += count*baseline, ...). Quantiles return
 window *indices* from the device and the host gathers exact f64 values —
 the same split the aggregator flush uses (m3_tpu/aggregator/list.py).
 
-Window convention: prom range selector (t-R, t] at step s with data grid at
-the same step: W = R/s cells, window w covers offsets (w+1-W)*s relative to
-the output time; column j of the extended grid is time
-start - (W-1)*s + j*s, so output step t reads columns [t, t+W).
+Window convention: prom range selector (t-R, t]. The caller lays the
+samples out (query/window.py for a plain selector, the executor's
+subquery grid) as [series x lanes]: a window is W consecutive lanes and
+output step t reads lanes [t*stride, t*stride + W). Where sample TIMES
+matter (the rate family's extrapolation, irate's dt, the regressions) a
+kernel takes them one of three ways: from lane positions alone (lane
+width `step_s`, the window exactly W lanes long: subqueries); from
+positions plus `edge` = (lead_s, tail_s), the first lane's distance from
+the window's open start and the window's end's from the last lane (a
+plain selector's raw samples on their cadence); or from `trel`, every
+lane's own time as seconds before its window's end (packed raw samples
+on no grid, W == stride).
 
 Result-transfer strategy (the result plane is what comes back to the host):
 every kernel takes a `stride` and consolidates to the query's OUTPUT step
-grid on device — when the window grid is finer than the query step (gcd
-gridding), the subsample happens before the transfer, not after. Counts
+grid on device — the lanes are finer than the query step wherever the
+samples are, and the subsample happens before the transfer, not after. Counts
 ship as uint16 (window populations, exact), results as f32, and the
 *_async variants start the device->host copy eagerly so it overlaps the
 next query's host prep (double-buffering across a dashboard burst)."""
@@ -43,6 +51,7 @@ import numpy as np
 
 from ..parallel import guard
 from ..parallel import telemetry
+from ..utils import tracing
 from ..utils.instrument import ROOT
 
 _F32 = jnp.float32
@@ -267,16 +276,6 @@ def _cached_put(arr: np.ndarray):
     return dev
 
 
-def extend_window_cells(range_ns: int, step_ns: int) -> int:
-    """Number of grid cells per window: ceil-less R/s (prom half-open
-    (t-R, t] with samples gridded at s)."""
-    if range_ns % step_ns:
-        raise ValueError(
-            f"range {range_ns} not a multiple of step {step_ns}; "
-            "the storage adapter grids at a divisor of the query step")
-    return max(1, range_ns // step_ns)
-
-
 def center(values: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
     """Split [S, T] f64 grid into (residual f32, baseline f64 [S])."""
     finite = np.isfinite(values)
@@ -376,10 +375,12 @@ def _rate_fn(W: int, step_s: float, range_s: float, is_counter: bool,
         is_counter=is_counter, is_rate=is_rate, stride=stride))
 
 
-def rate_math(adj, finite, grid32=None, *, W, step_s, range_s, is_counter,
-              is_rate, stride=1):
+def rate_math(adj, finite, grid32=None, edge=None, trel=None, *, W, step_s,
+              range_s, is_counter, is_rate, stride=1):
     """The traceable body of the fused rate kernel — importable by sharded
-    query paths (m3_tpu/parallel/query.py wraps it in shard_map)."""
+    query paths (m3_tpu/parallel/query.py wraps it in shard_map). `edge`
+    / `trel` say where the first and last sample of a window lie in time
+    (module docstring); neither: lane positions alone."""
     T = finite.shape[-1]
     T_out = (T - W) // stride + 1
     # Strided from the primitives down: every windowed reduce and the
@@ -398,22 +399,53 @@ def rate_math(adj, finite, grid32=None, *, W, step_s, range_s, is_counter,
     fcnt = cnt
     fi = (fa - t_off).astype(_F32)
     li = (la - t_off).astype(_F32)
-    dur_start = (fi + 1) * step_s
-    dur_end = (W - 1 - li) * step_s
-    sampled = (li - fi) * step_s
-    avg_dur = sampled / jnp.maximum(fcnt - 1, 1)
-    threshold = avg_dur * 1.1
+    if trel is not None:
+        before_first, before_last = _take_t(trel, fa), _take_t(trel, la)
+        dur_start = range_s - before_first
+        dur_end = before_last
+        sampled = before_first - before_last
+    else:
+        dur_start = (fi + 1) * step_s
+        dur_end = (W - 1 - li) * step_s
+        sampled = (li - fi) * step_s
+        if edge is not None:
+            dur_start = fi * step_s + edge[0]
+            dur_end = dur_end + edge[1]
+    gaps = jnp.maximum(fcnt - 1, 1)
+    avg_dur = sampled / gaps
+    if edge is None and trel is None:
+        threshold = avg_dur * 1.1
+
+        def near(dur):
+            return dur < threshold
+    else:
+        # "Within 1.1 mean sample intervals of the window's edge" decides
+        # between two extrapolations that differ by half an interval, so
+        # it must not hang on a rounding: over raw samples on a cadence,
+        # queried at whole seconds, exact ties are common (a first sample
+        # 11 s inside the window at a 10 s cadence). Cross-multiplied the
+        # rule is exact in f32 for whole-second durations, on any backend.
+        def near(dur, span=sampled):
+            return dur * gaps * 10.0 < span * 11.0
+    start_near = near(dur_start)
     if is_counter:
         abs_first = _take_t(grid32, fa)
+        clamps = (increase > 0) & (abs_first >= 0)
         dur_zero = jnp.where(
-            (increase > 0) & (abs_first >= 0),
+            clamps,
             sampled * (abs_first / jnp.where(increase > 0, increase, 1.0)),
             jnp.inf)
+        if edge is None and trel is None:
+            start_near = near(jnp.minimum(dur_start, dur_zero))
+        else:
+            # near(min(a, b)) is near(a) or near(b); the clamp's own test
+            # is sampled * first / increase < 1.1 * sampled / gaps.
+            start_near = start_near | (clamps & near(abs_first, increase))
         dur_start = jnp.minimum(dur_start, dur_zero)
     extrap = (
         sampled
-        + jnp.where(dur_start < threshold, dur_start, avg_dur / 2)
-        + jnp.where(dur_end < threshold, dur_end, avg_dur / 2)
+        + jnp.where(start_near, dur_start, avg_dur / 2)
+        + jnp.where(near(dur_end), dur_end, avg_dur / 2)
     )
     out = increase * (extrap / jnp.where(sampled > 0, sampled, 1.0))
     if is_rate:
@@ -483,23 +515,41 @@ def _rate_args(grid: np.ndarray, is_counter: bool):
     return _derived(grid, f"rate:{is_counter}", build)
 
 
+def _lane_times(edge, trel):
+    """(edge, trel) as kernel arguments: at most one is set; a packed
+    plane's lane times upload through the content-keyed cache."""
+    if trel is not None:
+        return None, _cached_put(trel)
+    return (None if edge is None else np.asarray(edge, np.float32)), None
+
+
 def _extrapolated_async(grid: np.ndarray, W: int, step_ns: int, range_ns: int,
-                        is_counter: bool, is_rate: bool, stride: int):
+                        is_counter: bool, is_rate: bool, stride: int,
+                        edge=None, trel=None):
     """Dispatch side of rate/increase/delta: the f64 diff pass feeds the
     fused device kernel; returns a fetch closure for the one f32 result
     (already output-strided), whose async copy is started here."""
     fn = _rate_fn(W, step_ns / 1e9, range_ns / 1e9, is_counter, is_rate,
                   stride)
-    out = fn(*_rate_args(grid, is_counter))
+    args = _rate_args(grid, is_counter)
+    out = fn(args[0], args[1], args[2] if is_counter else None,
+             *_lane_times(edge, trel))
     _copy_async(out)
-    return lambda: np.asarray(out).astype(np.float64)
+    return lambda: _fetched(out).astype(np.float64)
+
+
+def _fetched(dev) -> np.ndarray:
+    """The device->host read of a window program's result: where the
+    interpreter's route waits for the device (`device_wait_ns`)."""
+    with tracing.phase("device_wait"):
+        return np.asarray(dev)
 
 
 def _extrapolated(grid: np.ndarray, W: int, step_ns: int, range_ns: int,
-                  is_counter: bool, is_rate: bool,
-                  stride: int = 1) -> np.ndarray:
+                  is_counter: bool, is_rate: bool, stride: int = 1,
+                  edge=None, trel=None) -> np.ndarray:
     return _extrapolated_async(grid, W, step_ns, range_ns, is_counter,
-                               is_rate, stride)()
+                               is_rate, stride, edge, trel)()
 
 
 def _ffill(vol, mask):
@@ -516,34 +566,39 @@ def _gather_last(vol, run):
 
 
 def rate(grid: np.ndarray, W: int, step_ns: int, range_ns: int,
-         stride: int = 1) -> np.ndarray:
-    return _extrapolated(grid, W, step_ns, range_ns, True, True, stride)
+         stride: int = 1, edge=None, trel=None) -> np.ndarray:
+    return _extrapolated(grid, W, step_ns, range_ns, True, True, stride,
+                         edge, trel)
 
 
 def rate_async(grid: np.ndarray, W: int, step_ns: int, range_ns: int,
-               stride: int = 1):
-    return _extrapolated_async(grid, W, step_ns, range_ns, True, True, stride)
+               stride: int = 1, edge=None, trel=None):
+    return _extrapolated_async(grid, W, step_ns, range_ns, True, True, stride,
+                               edge, trel)
 
 
 def increase(grid: np.ndarray, W: int, step_ns: int, range_ns: int,
-             stride: int = 1) -> np.ndarray:
-    return _extrapolated(grid, W, step_ns, range_ns, True, False, stride)
+             stride: int = 1, edge=None, trel=None) -> np.ndarray:
+    return _extrapolated(grid, W, step_ns, range_ns, True, False, stride,
+                         edge, trel)
 
 
 def increase_async(grid: np.ndarray, W: int, step_ns: int, range_ns: int,
-                   stride: int = 1):
-    return _extrapolated_async(grid, W, step_ns, range_ns, True, False, stride)
+                   stride: int = 1, edge=None, trel=None):
+    return _extrapolated_async(grid, W, step_ns, range_ns, True, False, stride,
+                               edge, trel)
 
 
 def delta(grid: np.ndarray, W: int, step_ns: int, range_ns: int,
-          stride: int = 1) -> np.ndarray:
-    return _extrapolated(grid, W, step_ns, range_ns, False, False, stride)
+          stride: int = 1, edge=None, trel=None) -> np.ndarray:
+    return _extrapolated(grid, W, step_ns, range_ns, False, False, stride,
+                         edge, trel)
 
 
 def delta_async(grid: np.ndarray, W: int, step_ns: int, range_ns: int,
-                stride: int = 1):
-    return _extrapolated_async(grid, W, step_ns, range_ns, False, False,
-                               stride)
+                stride: int = 1, edge=None, trel=None):
+    return _extrapolated_async(grid, W, step_ns, range_ns, False, False, stride,
+                               edge, trel)
 
 
 @guard.guarded_builder("temporal.last_two_idx")
@@ -564,20 +619,25 @@ def _last_two_idx_fn(W: int, stride: int = 1):
 
 
 def _instant(grid: np.ndarray, W: int, step_ns: int, is_rate: bool,
-             stride: int = 1) -> np.ndarray:
+             stride: int = 1, trel=None) -> np.ndarray:
     """temporal/rate.go irateFn / promql instantValue: last two valid
-    samples; a counter reset (v_last < v_prev) rates from zero. Values are
-    gathered from the f64 grid by device-computed indices."""
+    samples; a counter reset (v_last < v_prev) rates from zero. Values
+    (and, packed, the two samples' own times) are gathered on the host by
+    device-computed indices."""
     finite = np.isfinite(grid)
-    packed = np.asarray(_last_two_idx_fn(W, stride)(_cached_put(finite)))
+    packed = _fetched(_last_two_idx_fn(W, stride)(_cached_put(finite)))
     last_i, prev_i = packed[0], packed[1]
     ok = prev_i >= 0
     S, T_out = last_i.shape
     rows = np.arange(S)[:, None]
     t_base = np.arange(T_out)[None, :] * stride
-    v_last = grid[rows, t_base + np.clip(last_i, 0, W - 1)]
-    v_prev = grid[rows, t_base + np.clip(prev_i, 0, W - 1)]
-    dt = (last_i - prev_i) * (step_ns / 1e9)
+    at_last = t_base + np.clip(last_i, 0, W - 1)
+    at_prev = t_base + np.clip(prev_i, 0, W - 1)
+    v_last, v_prev = grid[rows, at_last], grid[rows, at_prev]
+    if trel is not None:
+        dt = trel[rows, at_prev].astype(np.float64) - trel[rows, at_last]
+    else:
+        dt = (last_i - prev_i) * (step_ns / 1e9)
     with np.errstate(divide="ignore", invalid="ignore"):
         if is_rate:
             dv = np.where(v_last < v_prev, v_last, v_last - v_prev)
@@ -588,8 +648,8 @@ def _instant(grid: np.ndarray, W: int, step_ns: int, is_rate: bool,
 
 
 def irate(grid: np.ndarray, W: int, step_ns: int,
-          stride: int = 1) -> np.ndarray:
-    return _instant(grid, W, step_ns, True, stride)
+          stride: int = 1, trel=None) -> np.ndarray:
+    return _instant(grid, W, step_ns, True, stride, trel)
 
 
 def idelta(grid: np.ndarray, W: int, step_ns: int,
@@ -751,13 +811,13 @@ def over_time_async(grid: np.ndarray, W: int, kind: str, stride: int = 1,
     if finish == "device":
         out = _over_time_finish_fn(W, kind, stride)(resid, base32)
         _copy_async(out)
-        return lambda: np.asarray(out).astype(np.float64)
+        return lambda: _fetched(out).astype(np.float64)
     stat_dev, cnt_dev = _over_time_fn(W, stat_name, stride)(resid)
     _copy_async(stat_dev, cnt_dev)
 
     def fetch() -> np.ndarray:
-        stat = np.asarray(stat_dev).astype(np.float64)
-        cnt = np.asarray(cnt_dev).astype(np.float64)
+        stat = _fetched(stat_dev).astype(np.float64)
+        cnt = _fetched(cnt_dev).astype(np.float64)
         out = _finish_over_time(np, kind, stat, cnt, base[:, None])
         return np.where(cnt > 0, out, np.nan)
 
@@ -797,7 +857,7 @@ def _quantile_idx_fn(W: int, stride: int = 1):
 def quantile_over_time(grid: np.ndarray, W: int, q: float,
                        stride: int = 1) -> np.ndarray:
     resid, _, _ = _resid_args(grid)
-    packed = np.asarray(
+    packed = _fetched(
         _quantile_idx_fn(W, stride)(resid, np.float32(q)))
     lo_idx, hi_idx = packed[0].astype(np.int64), packed[1].astype(np.int64)
     frac, cnt = packed[2], packed[3]
@@ -839,27 +899,34 @@ def _changes_resets_fn(W: int, count_resets: bool, stride: int = 1):
 
 def changes(grid: np.ndarray, W: int, stride: int = 1) -> np.ndarray:
     resid, _, _ = _resid_args(grid)
-    return np.asarray(_changes_resets_fn(W, False, stride)(resid))
+    return _fetched(_changes_resets_fn(W, False, stride)(resid))
 
 
 def resets(grid: np.ndarray, W: int, stride: int = 1) -> np.ndarray:
     resid, _, _ = _resid_args(grid)
-    return np.asarray(_changes_resets_fn(W, True, stride)(resid))
+    return _fetched(_changes_resets_fn(W, True, stride)(resid))
 
 
-def regression_math(resid, *, W: int, step_s: float,
+def regression_math(resid, edge=None, trel=None, *, W: int, step_s: float,
                     predict_offset_s: float, is_deriv: bool,
                     stride: int = 1):
     """Traceable deriv()/predict_linear() body — fusable prepared form.
     Least-squares over valid (t, v) window points; t relative to the
     window's first valid sample for stability (promql linearRegression;
     temporal/linear_regression.go). predict_linear results are in
-    RESIDUAL space: callers add the per-series baseline back."""
+    RESIDUAL space: callers add the per-series baseline back. `edge` /
+    `trel`: where the samples lie in time (module docstring)."""
     vol = _window_volume(resid, W, stride)
     mask = jnp.isfinite(vol)
     first_i, last_i, cnt = _first_last(mask)
     ok = cnt >= 2
-    t = (jnp.arange(W)[None, None, :] - first_i[..., None]).astype(_F32) * step_s
+    if trel is not None:
+        before = _window_volume(trel, W, stride)
+        before_first = _take_w(before, first_i)
+        t = before_first[..., None] - before
+    else:
+        t = (jnp.arange(W)[None, None, :]
+             - first_i[..., None]).astype(_F32) * step_s
     tm = jnp.where(mask, t, 0.0)
     v = jnp.where(mask, vol, 0.0)
     n = cnt.astype(_F32)
@@ -872,9 +939,14 @@ def regression_math(resid, *, W: int, step_s: float,
     if is_deriv:
         return jnp.where(ok, slope, jnp.nan)
     intercept = (sv - slope * st) / n
-    # Evaluate at output time + offset: output time is the last window
-    # cell, i.e. t = (W-1-first_i)*step relative to the reference point.
-    t_eval = (W - 1 - first_i).astype(_F32) * step_s + predict_offset_s
+    # Evaluate at output time + offset, relative to the reference point
+    # (the first valid sample): the output time is the window's end.
+    if trel is not None:
+        t_eval = before_first + predict_offset_s
+    else:
+        t_eval = (W - 1 - first_i).astype(_F32) * step_s + predict_offset_s
+        if edge is not None:
+            t_eval = t_eval + edge[1]
     return jnp.where(ok, intercept + slope * t_eval, jnp.nan)
 
 
@@ -890,17 +962,19 @@ def _regression_fn(W: int, step_s: float, predict_offset_s: float,
 
 
 def deriv(grid: np.ndarray, W: int, step_ns: int,
-          stride: int = 1) -> np.ndarray:
+          stride: int = 1, trel=None) -> np.ndarray:
     resid, _, _ = _resid_args(grid)
-    return np.asarray(
-        _regression_fn(W, step_ns / 1e9, 0.0, True, stride)(resid))
+    return _fetched(_regression_fn(W, step_ns / 1e9, 0.0, True, stride)(
+        resid, *_lane_times(None, trel)))
 
 
 def predict_linear(grid: np.ndarray, W: int, step_ns: int,
-                   offset_s: float, stride: int = 1) -> np.ndarray:
+                   offset_s: float, stride: int = 1, edge=None,
+                   trel=None) -> np.ndarray:
     resid, base, _ = _resid_args(grid)
-    out = np.asarray(_regression_fn(
-        W, step_ns / 1e9, float(offset_s), False, stride)(resid))
+    out = _fetched(_regression_fn(
+        W, step_ns / 1e9, float(offset_s), False, stride)(
+            resid, *_lane_times(edge, trel)))
     return out + base[:, None]
 
 
@@ -943,7 +1017,7 @@ def _holt_winters_fn(W: int, sf: float, tf: float, stride: int = 1):
 def holt_winters(grid: np.ndarray, W: int, sf: float, tf: float,
                  stride: int = 1) -> np.ndarray:
     resid, base, _ = _resid_args(grid)
-    return np.asarray(
+    return _fetched(
         _holt_winters_fn(W, float(sf), float(tf), stride)(resid)
     ) + base[:, None]
 
@@ -998,8 +1072,8 @@ def rate_inputs_math(plane, is_counter: bool):
     return adj, finite, z
 
 
-def instant_math(resid, grid32, *, W: int, step_s: float, is_rate: bool,
-                 stride: int = 1):
+def instant_math(resid, grid32, trel=None, *, W: int, step_s: float,
+                 is_rate: bool, stride: int = 1):
     """Traced irate()/idelta() (temporal/rate.go irateFn): last two valid
     samples per window. Differences compute in RESIDUAL space (exact for
     the small consecutive deltas even at 1e9 counter magnitudes — the
@@ -1022,7 +1096,11 @@ def instant_math(resid, grid32, *, W: int, step_s: float, is_rate: bool,
     gvol = _window_volume(grid32, W, stride)
     g_last = _take_w(gvol, last_i)
     dv = jnp.where(r_last < r_prev, g_last, r_last - r_prev)
-    dt = (last_i - prev_i).astype(_F32) * step_s
+    if trel is not None:
+        before = _window_volume(trel, W, stride)
+        dt = _take_w(before, prev_i) - _take_w(before, last_i)
+    else:
+        dt = (last_i - prev_i).astype(_F32) * step_s
     return jnp.where(ok, dv / jnp.where(ok, dt, 1.0), jnp.nan)
 
 

@@ -201,21 +201,34 @@ def geometry_for(bound: "qplan.Bound", n_shard: int) -> Geometry:
 #   resid: (resid, base32)         *_over_time / regression / exact sums
 #   value: (value32,)              elementwise / binary / min-max-count
 #   value2: (hi, lo)               exact double-f32 split (topk ranking)
-_KIND_ARITY = {"ratec": 3, "rated": 2, "resid": 2, "value": 1, "value2": 2}
+#   trel: (trel,)                  packed range lanes' own times
+_KIND_ARITY = {"ratec": 3, "rated": 2, "resid": 2, "value": 1, "value2": 2,
+               "trel": 1}
 _RATE_COUNTER = frozenset({"rate", "increase"})
+# Range functions that read sample TIMES (ops/temporal.py): over a packed
+# fetch they take its lane-time plane, over a dense one the window edge.
+_TIMED_FUNCS = frozenset({"rate", "increase", "delta", "irate", "deriv",
+                          "predict_linear"})
+
+
+def _reads_lane_times(node: Optional[PlanNode]) -> bool:
+    """A range function that needs its packed fetch's `trel` plane."""
+    return (isinstance(node, RangeFunc) and node.arg.packed
+            and node.func in _TIMED_FUNCS)
 
 
 def _consumer_kinds(consumer: Optional[PlanNode]) -> Tuple[str, ...]:
     """Which staged-input kinds one consumer reads off a direct Fetch."""
     if isinstance(consumer, (RangeFunc, SubqueryFunc)):
         f = consumer.func
+        timed = ("trel",) if _reads_lane_times(consumer) else ()
         if f in ("rate", "increase", "delta"):
-            return ("ratec",) if f in _RATE_COUNTER else ("rated",)
+            return (("ratec",) if f in _RATE_COUNTER else ("rated",)) + timed
         if f in ("irate", "idelta"):
             # residual-space diffs + the absolute plane for the counter
             # reset branch (temporal.instant_math)
-            return ("resid", "value")
-        return ("resid",)
+            return ("resid", "value") + timed
+        return ("resid",) + timed
     if isinstance(consumer, Aggregate) and consumer.exact:
         return ("resid",)
     if isinstance(consumer, RankAgg) and consumer.op != "quantile":
@@ -323,6 +336,10 @@ def _stage_fetch(bf: "qplan.BoundFetch", kinds: Tuple[str, ...],
                 hi = gp.astype(np.float32)
                 lo = (gp - hi.astype(np.float64)).astype(np.float32)
                 arrs += [hi, lo]
+            elif kind == "trel":
+                lanes = np.zeros((s_pad, ext_pad), np.float32)
+                lanes[:bf.trel.shape[0], :bf.trel.shape[1]] = bf.trel
+                arrs.append(lanes)
             else:  # "value"
                 arrs.append(stage_value_plane(g, s_pad, ext_pad))
         if mesh is not None:
@@ -408,23 +425,25 @@ def _lower_fetch(ctx: _Ctx, node: Fetch):
 
 def _range_body(ctx: _Ctx, f: str, ins: Dict[str, tuple], *, W: int,
                 stride: int, step_s: float, range_s: float,
-                params: Tuple[float, ...]):
+                params: Tuple[float, ...], edge=None, trel=None):
     """The shared windowed-kernel ladder: one range function over
     prepared inputs (`ins` maps kind -> arrays already sliced/gathered
     to the window layout). Serves both RangeFunc (host-staged selector
-    inputs) and SubqueryFunc (inner-plane inputs, possibly packed)."""
+    inputs; `edge` or `trel` place its raw samples in time) and
+    SubqueryFunc (inner-plane inputs, possibly packed; lane positions
+    are the times)."""
     if f in ("rate", "increase", "delta"):
         adj, finite = ins["diff"][0], ins["diff"][1]
         grid32 = ins["diff"][2] if f in _RATE_COUNTER else None
         return temporal.rate_math(
-            adj, finite, grid32, W=W, step_s=step_s, range_s=range_s,
-            is_counter=f in _RATE_COUNTER, is_rate=f == "rate",
-            stride=stride)
+            adj, finite, grid32, edge, trel, W=W, step_s=step_s,
+            range_s=range_s, is_counter=f in _RATE_COUNTER,
+            is_rate=f == "rate", stride=stride)
     if f in ("irate", "idelta"):
         resid, grid32 = ins["instant"]
         return temporal.instant_math(
-            resid, grid32, W=W, step_s=step_s, is_rate=f == "irate",
-            stride=stride)
+            resid, grid32, trel if f == "irate" else None, W=W,
+            step_s=step_s, is_rate=f == "irate", stride=stride)
     resid, base32 = ins["resid"]
     if f == "quantile_over_time":
         return temporal.quantile_ot_math(resid, base32, W=W,
@@ -437,11 +456,12 @@ def _range_body(ctx: _Ctx, f: str, ins: Dict[str, tuple], *, W: int,
             resid, W=W, count_resets=f == "resets", stride=stride)
     if f == "deriv":
         return temporal.regression_math(
-            resid, W=W, step_s=step_s, predict_offset_s=0.0,
+            resid, None, trel, W=W, step_s=step_s, predict_offset_s=0.0,
             is_deriv=True, stride=stride)
     if f == "predict_linear":
         return temporal.regression_math(
-            resid, W=W, step_s=step_s, predict_offset_s=float(params[0]),
+            resid, edge, trel, W=W, step_s=step_s,
+            predict_offset_s=float(params[0]),
             is_deriv=False, stride=stride) + base32[:, None]
     # holt_winters (lowering admits nothing else)
     return temporal.holt_winters_math(
@@ -480,9 +500,15 @@ def _lower_rangefunc(ctx: _Ctx, node: RangeFunc):
     else:
         resid, base32 = staged["resid"]
         ins["resid"] = (resid[:, :ext], base32)
+    # Where the window's raw samples lie in time: a packed fetch's own
+    # lane times, else the edge this query's phase leaves on the cadence.
+    timed = _reads_lane_times(node)
+    trel = staged["trel"][0][:, :ext] if timed else None
+    (edge,) = ctx.aux_ins[ctx.path_of[id(node)]]
     out = _range_body(ctx, f, ins, W=W, stride=stride,
                       step_s=node.step_ns / 1e9,
-                      range_s=node.range_ns / 1e9, params=node.params)
+                      range_s=node.range_ns / 1e9, params=node.params,
+                      edge=None if timed else edge, trel=trel)
     return out[:, :w_out]
 
 
@@ -759,8 +785,9 @@ def _aux_layout(root: PlanNode) -> List[Tuple[int, int]]:
     """(preorder path, arity) per aux-consuming node: aggregates take one
     group-id array; vector-vector binaries two index arrays; rank
     aggregations a perm + inverse-perm pair; packed subqueries one
-    column map; timestamp() one step-time vector. The stager and the
-    trace-time unflattener both follow this order."""
+    column map; timestamp() one step-time vector; a range function its
+    window edge. The stager and the trace-time unflattener both follow
+    this order."""
     nodes: List[PlanNode] = []
     _preorder(root, nodes)
     out = []
@@ -774,6 +801,8 @@ def _aux_layout(root: PlanNode) -> List[Tuple[int, int]]:
         elif isinstance(n, SubqueryFunc) and n.packed:
             out.append((i, 1))
         elif isinstance(n, InstantFunc) and n.func == "timestamp":
+            out.append((i, 1))
+        elif isinstance(n, RangeFunc):
             out.append((i, 1))
     return out
 
@@ -842,6 +871,8 @@ def _plan_executable(stripped: PlanNode, geom: Geometry,
         elif isinstance(n, SubqueryFunc) and n.packed:
             aux_specs.append(P())
         elif isinstance(n, InstantFunc) and n.func == "timestamp":
+            aux_specs.append(P())
+        elif isinstance(n, RangeFunc):
             aux_specs.append(P())
     aux_specs = tuple(aux_specs)
     root_edge = stripped.edge
@@ -953,6 +984,8 @@ def execute(bound: "qplan.Bound", mesh: Optional[Mesh]):
             times = np.zeros(width_of[id(n)], dtype=np.float32)
             times[:len(a["times"])] = a["times"]
             aux_flat.append(times)
+        elif isinstance(n, RangeFunc):
+            aux_flat.append(bound.aux[id(n)]["edge"])
 
     slots = np.asarray(bound.slots, dtype=np.float32)
     if slots.size == 0:

@@ -36,7 +36,8 @@ AGG_OPS = ("sum", "avg", "count", "min", "max")
 @telemetry.jit_builder("sharded_agg_rate")
 @functools.lru_cache(maxsize=64)
 def make_sharded_agg_rate(mesh: Mesh, *, op: str, func: str, W: int,
-                          step_ns: int, range_ns: int, stride: int = 1):
+                          step_ns: int, range_ns: int, stride: int = 1,
+                          lanes: str = "grid"):
     """jit one dashboard-shaped aggregation over the mesh: inputs [S, T]
     sharded on the "shard" axis; output the dense [T_out] global
     aggregate-by-step plus the contributing-series count (replicated).
@@ -51,6 +52,11 @@ def make_sharded_agg_rate(mesh: Mesh, *, op: str, func: str, W: int,
     about 2e-5 at 100k series — where the host path is exact f64
     (DIVERGENCES.md).
 
+    `lanes` says how the kernel learns the samples' times
+    (ops/temporal.py): "grid" from lane positions alone, "edge" with a
+    replicated (lead_s, tail_s) pair, "packed" with a sharded plane of
+    each lane's own time.
+
     lru-cached on (mesh, shape params): repeated dashboard queries reuse
     the compiled executable instead of retracing (Mesh is hashable)."""
     if op not in AGG_OPS:
@@ -61,8 +67,10 @@ def make_sharded_agg_rate(mesh: Mesh, *, op: str, func: str, W: int,
         range_s=range_ns / 1e9, is_counter=is_counter, is_rate=is_rate,
         stride=stride)
 
-    def local(adj, finite, grid32):
-        out = math(adj, finite, grid32)  # [S_local, T_out]
+    def local(adj, finite, grid32, *times):
+        out = math(adj, finite, grid32,     # [S_local, T_out]
+                   times[0] if lanes == "edge" else None,
+                   times[0] if lanes == "packed" else None)
         fin = jnp.isfinite(out)
         n = jax.lax.psum(fin.sum(axis=0), "shard")
         if op in ("sum", "avg"):
@@ -81,7 +89,9 @@ def make_sharded_agg_rate(mesh: Mesh, *, op: str, func: str, W: int,
         return total, n
 
     spec = P("shard", None)
-    fn = jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
+    times_spec = {"grid": (), "edge": (P(),), "packed": (spec,)}[lanes]
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(spec, spec, spec) + times_spec,
                        out_specs=(P(), P()), check_vma=False)
     return jax.jit(fn)
 
@@ -114,14 +124,27 @@ def shard_grid(grid: np.ndarray, mesh: Mesh, is_counter: bool = True):
 
 
 def agg_rate(grid: np.ndarray, mesh: Mesh, *, op: str, func: str, W: int,
-             step_ns: int, range_ns: int, stride: int = 1) -> np.ndarray:
+             step_ns: int, range_ns: int, stride: int = 1, edge=None,
+             trel=None) -> np.ndarray:
     """op(func(...)) over the mesh, NaN where no series had a full window
     — the serving entry the query executor dispatches dashboard
-    aggregations through (query/executor.py _eval_sharded_agg)."""
+    aggregations through (query/executor.py _eval_sharded_agg). `edge` /
+    `trel`: a plain range selector's lane times (query/window.py)."""
     is_counter, _ = RANGE_FUNCS[func]
     args = shard_grid(grid, mesh, is_counter)
+    lanes = "packed" if trel is not None else (
+        "edge" if edge is not None else "grid")
+    if trel is not None:
+        pad = args[0].shape[0] - trel.shape[0]
+        if pad:
+            trel = np.concatenate(
+                [trel, np.zeros((pad, trel.shape[1]), trel.dtype)], axis=0)
+        args += (jax.device_put(  # m3lint: disable=unbudgeted-device-put
+            trel, NamedSharding(mesh, P("shard", None))),)
+    elif edge is not None:
+        args += (np.asarray(edge, np.float32),)
     fn = make_sharded_agg_rate(mesh, op=op, func=func, W=W, step_ns=step_ns,
-                               range_ns=range_ns, stride=stride)
+                               range_ns=range_ns, stride=stride, lanes=lanes)
     telemetry.mesh_dispatch("agg_rate", cells=int(np.asarray(grid).size))
     total, n = fn(*args)
     total = np.asarray(total, np.float64)

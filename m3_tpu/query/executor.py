@@ -5,14 +5,13 @@ transform consumes and produces a dense [series x steps] Block, with the
 sliding-window/temporal math in m3_tpu.ops.temporal and cross-series
 aggregation in m3_tpu.ops.series_agg running as jitted device kernels).
 
-Matrix selectors grid at gcd(step, range) so sub-step samples inside a
-window survive consolidation (the reference's block consolidation has the
-same step-alignment semantics, src/query/ts/values.go)."""
+A matrix selector's windows hold the raw samples of (T - range, T], laid
+out by query/window.py (dense on the samples' own cadence, or packed
+where they lie on no grid) — the one binding the compiled route shares."""
 
 from __future__ import annotations
 
 import dataclasses
-import math
 import os
 import threading
 import time
@@ -25,6 +24,7 @@ from ..parallel import scope as dscope
 from . import corpus as qcorpus
 from . import explain as qexplain
 from . import promql
+from . import window as qwindow
 from ..utils import limits as xlimits
 from ..utils import tracing
 from ..utils.retry import DeadlineExceeded
@@ -523,28 +523,20 @@ class Engine:
         return out
 
     def _eval_range_selector(self, sel: VectorSelector, params: QueryParams
-                             ) -> Tuple[Block, int, int]:
-        """Fetch + grid a matrix selector: returns (extended block at the
-        window grid, W cells per window, stride to subsample back to the
-        query step)."""
+                             ) -> qwindow.RangeWindows:
+        """Fetch a matrix selector's raw samples and lay them out for the
+        windowed kernels: every output step's window holds exactly the
+        samples of (T - range, T] (query/window.py)."""
         key, hit = self._sel_overlay_get("range", sel, params)
         if hit is not None:
             return hit
-        off = sel.offset_ns
-        wgrid = math.gcd(params.step_ns, sel.range_ns)
-        W = sel.range_ns // wgrid
-        stride = params.step_ns // wgrid
-        meta = params.meta()
-        # Extended grid: (W-1) cells of history before the first output step.
-        ext_start = meta.start_ns - (W - 1) * wgrid - off
-        ext_steps = (W - 1) + (meta.steps - 1) * stride + 1
-        ext_meta = BlockMeta(ext_start, wgrid, ext_steps)
-        series = self._fetch(sel, ext_start - wgrid, meta.end_ns - off + 1)
-        # Range selectors see raw samples (no lookback): a cell holds the
-        # latest sample within its grid cell only.
-        tags_list, values = self._consolidate_cached(
-            sel, series, ext_meta, wgrid)
-        out = (Block(ext_meta, tags_list, values), W, stride)
+        x0 = params.start_ns - sel.offset_ns
+        last = x0 + (params.steps - 1) * params.step_ns
+        series = self._fetch(sel, x0 - sel.range_ns + 1, last + 1)
+        out = qwindow.range_windows(
+            series, params, sel.range_ns, sel.offset_ns,
+            lambda meta, cell: self._consolidate_cached(sel, series, meta,
+                                                        cell))
         if key is not None:
             self._local.sel_overlay[key] = out
         return out
@@ -577,7 +569,7 @@ class Engine:
         return tags_list, values
 
     def _eval_subquery_grid(self, sub: Subquery, params: QueryParams
-                            ) -> Tuple[Block, int, int]:
+                            ) -> qwindow.RangeWindows:
         """Evaluate `expr[range:res]`: run the inner expression as ONE
         instant-style evaluation over a fine grid of resolution-aligned
         timestamps covering every outer step's trailing window, then hand
@@ -647,7 +639,9 @@ class Engine:
             W = stride = Wmax
         assert block.meta.steps == (W - 1) + (params.steps - 1) * stride + 1, (
             block.meta.steps, W, stride, params.steps)
-        return block, W, stride
+        # Lane positions ARE the sample times on a resolution grid: no
+        # edge, no per-lane times (the kernels' position arithmetic).
+        return qwindow.RangeWindows(block, W, stride, res, None, None)
 
     # -- functions ---------------------------------------------------------
 
@@ -678,9 +672,10 @@ class Engine:
         if sel.at_ns is not None:
             return self._pin_at(node, sel, params)
         if isinstance(sel, Subquery):
-            ext, W, stride = self._eval_subquery_grid(sel, params)
+            rw = self._eval_subquery_grid(sel, params)
         else:
-            ext, W, stride = self._eval_range_selector(sel, params)
+            rw = self._eval_range_selector(sel, params)
+        ext, W, stride, edge, trel = rw.block, rw.W, rw.stride, rw.edge, rw.trel
         grid = ext.values
         step_ns = ext.meta.step_ns
         # Every kernel consolidates to the query's output step grid ON
@@ -691,22 +686,24 @@ class Engine:
         f = node.func
         fetch = None
         if f == "rate":
-            fetch = temporal.rate_async(grid, W, step_ns, sel.range_ns, stride)
+            fetch = temporal.rate_async(grid, W, step_ns, sel.range_ns, stride,
+                                        edge, trel)
         elif f == "increase":
             fetch = temporal.increase_async(
-                grid, W, step_ns, sel.range_ns, stride)
+                grid, W, step_ns, sel.range_ns, stride, edge, trel)
         elif f == "delta":
             fetch = temporal.delta_async(
-                grid, W, step_ns, sel.range_ns, stride)
+                grid, W, step_ns, sel.range_ns, stride, edge, trel)
         elif f == "irate":
-            out = temporal.irate(grid, W, step_ns, stride)
+            out = temporal.irate(grid, W, step_ns, stride, trel)
         elif f == "idelta":
             out = temporal.idelta(grid, W, step_ns, stride)
         elif f == "deriv":
-            out = temporal.deriv(grid, W, step_ns, stride)
+            out = temporal.deriv(grid, W, step_ns, stride, trel)
         elif f == "predict_linear":
             out = temporal.predict_linear(
-                grid, W, step_ns, _const_param(node.args[1]), stride)
+                grid, W, step_ns, _const_param(node.args[1]), stride, edge,
+                trel)
         elif f == "holt_winters":
             out = temporal.holt_winters(
                 grid, W, _const_param(node.args[1]), _const_param(node.args[2]),
@@ -874,12 +871,14 @@ class Engine:
                 or sel_args[-1].at_ns is not None):
             return None
         sel = sel_args[-1]
-        ext, W, stride = self._eval_range_selector(sel, params)
+        rw = self._eval_range_selector(sel, params)
+        ext = rw.block
         if ext.n_series == 0:
             return Block(params.meta(), [], np.zeros((0, params.steps)))
-        out = pq.agg_rate(ext.values, self.mesh, op=node.op, func=func, W=W,
-                          step_ns=ext.meta.step_ns, range_ns=sel.range_ns,
-                          stride=stride)
+        out = pq.agg_rate(ext.values, self.mesh, op=node.op, func=func,
+                          W=rw.W, step_ns=ext.meta.step_ns,
+                          range_ns=sel.range_ns, stride=rw.stride,
+                          edge=rw.edge, trel=rw.trel)
         from ..utils.instrument import ROOT
 
         ROOT.counter("query.sharded_agg").inc()

@@ -112,6 +112,8 @@ def _plan_detail(node: PlanNode) -> str:
     if isinstance(node, Fetch):
         name = node.sel.name.decode(errors="replace") if node.sel.name \
             else "{...}"
+        if node.role == "range" and not node.W:
+            return f"{name} role=range (window lanes follow the samples)"
         return f"{name} role={node.role} W={node.W} stride={node.stride}"
     if isinstance(node, RangeFunc):
         return node.func

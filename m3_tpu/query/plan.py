@@ -28,7 +28,6 @@ from __future__ import annotations
 import collections
 import dataclasses
 import enum
-import math
 import os
 import threading
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -76,21 +75,26 @@ class PlanNode:
 
 @dataclasses.dataclass(frozen=True)
 class Fetch(PlanNode):
-    """A gridded selector: the consolidated [S, ext_T] grid at the window
-    grid (range role) or the step grid with lookback (instant role).
-    `sel` carries the source selector for binding; the compile key strips
-    it (the traced program depends only on the physical fields). `ctx`
-    distinguishes otherwise-equal selectors gridded in DIFFERENT time
-    contexts (each subquery's inner grid gets a fresh ctx id), so
-    binding/staging never conflates an outer step-grid fetch with the
-    same selector on a subquery's resolution grid."""
+    """A selector's plane: the raw samples of every window laid out as
+    [S, lanes] (range role, query/window.py) or the step grid with
+    lookback (instant role). A range fetch's geometry follows from the
+    SAMPLES (their cadence, or that they lie on no grid), so the lowerer
+    leaves it open (W = 0) and bind() fills it in from the fetch — the
+    interpreter's own binding. `sel` carries the source selector for
+    binding; the compile key strips it (the traced program depends only
+    on the physical fields). `ctx` distinguishes otherwise-equal
+    selectors gridded in DIFFERENT time contexts (each subquery's inner
+    grid gets a fresh ctx id), so binding/staging never conflates an
+    outer step-grid fetch with the same selector on a subquery's
+    resolution grid."""
 
     sel: VectorSelector
     role: str                 # "range" | "instant"
-    W: int                    # cells per window (1 for instant)
-    stride: int               # window-grid cells per output step
-    wgrid_ns: int             # grid cell width
+    W: int                    # lanes per window (1 instant; 0 until bound)
+    stride: int               # lanes per output step
+    wgrid_ns: int             # a lane's width (0: packed, or not yet bound)
     ctx: int = 0              # subquery grid context (0 = outer query)
+    packed: bool = False      # range: lanes carry their own times (trel)
 
     @property
     def edge(self) -> Edge:
@@ -484,12 +488,9 @@ class _Lowerer:
             if not sel.range_ns:
                 raise NotCompilable(FallbackReason.SELECTOR_SHAPE,
                                     f"{f} over an instant selector", node)
-            p = self.params
-            wgrid = math.gcd(p.step_ns, sel.range_ns)
-            W = sel.range_ns // wgrid
-            stride = p.step_ns // wgrid
-            fetch = Fetch(sel, "range", W, stride, wgrid, self._ctx)
-            return RangeFunc(f, fetch, wgrid, sel.range_ns,
+            # The window geometry is the samples' (bind() fills it in).
+            fetch = Fetch(sel, "range", 0, 0, 0, self._ctx)
+            return RangeFunc(f, fetch, 0, sel.range_ns,
                              self._func_params(f, node))
         if f == "timestamp":
             if not node.args:
@@ -693,11 +694,13 @@ def _mesh_ok(node: PlanNode) -> bool:
 @dataclasses.dataclass
 class BoundFetch:
     fetch: Fetch
-    grid: np.ndarray          # [S, ext_T] f64 consolidated grid
+    grid: np.ndarray          # [S, lanes] f64 plane
     tags: List[Tags]
     W: int
     stride: int
     step_ns: int
+    edge: Optional[np.ndarray] = None   # range, dense: f32 (lead_s, tail_s)
+    trel: Optional[np.ndarray] = None   # range, packed: [S, lanes] f32
 
 
 @dataclasses.dataclass
@@ -826,20 +829,32 @@ def bind(plan: Plan, engine, params,
     params_of = node_params_map(plan.root, params)
 
     fetches: Dict[Fetch, BoundFetch] = {}
+    bound_as: Dict[Fetch, Fetch] = {}
     total = 0
     for f in plan.fetches:
         fp = params_of[id(f)]
         if f.role == "range":
-            blk, W, stride = engine._eval_range_selector(f.sel, fp)
+            rw = engine._eval_range_selector(f.sel, fp)
+            blk = rw.block
+            bound_as[f] = f = dataclasses.replace(
+                f, W=rw.W, stride=rw.stride, wgrid_ns=rw.cell_ns,
+                packed=rw.packed)
             bf = BoundFetch(f, np.asarray(blk.values, dtype=np.float64),
-                            blk.series_tags, W, stride,
-                            blk.meta.step_ns)
+                            blk.series_tags, rw.W, rw.stride,
+                            blk.meta.step_ns, rw.edge, rw.trel)
         else:
             blk = engine._eval_instant_selector(f.sel, fp)
             bf = BoundFetch(f, np.asarray(blk.values, dtype=np.float64),
                             blk.series_tags, 1, 1, blk.meta.step_ns)
         fetches[f] = bf
         total += bf.grid.size
+    if bound_as:
+        # The plan with its range fetches' geometry filled in: what the
+        # compile key, the staging and the tag walk below read.
+        plan = dataclasses.replace(
+            plan, root=_with_fetches(plan.root, bound_as),
+            fetches=tuple(bound_as.get(f, f) for f in plan.fetches))
+        params_of = node_params_map(plan.root, params)
 
     slots = np.zeros(plan.n_slots, dtype=np.float64)
     for i, v in enumerate(slot_values):
@@ -859,7 +874,7 @@ def bind(plan: Plan, engine, params,
         nodes = _preorder(plan.root, [])
         node_tags = {id(n): t for n, t in zip(nodes, tags_seq)}
         aux = {id(n): a for n, a in zip(nodes, aux_seq) if a is not None}
-        _merge_param_aux(plan, params_of, aux)
+        _merge_param_aux(plan, params_of, aux, fetches)
         return Bound(plan, params, fetches, slots, node_tags, aux, total,
                      node_tags[id(plan.root)], out_kind)
 
@@ -992,19 +1007,48 @@ def bind(plan: Plan, engine, params,
                                 plan.root.edge.kind)
         while len(_BIND_MEMO) > _BIND_MEMO_MAX:
             _BIND_MEMO.popitem(last=False)
-    _merge_param_aux(plan, params_of, aux)
+    _merge_param_aux(plan, params_of, aux, fetches)
     return Bound(plan, params, fetches, slots, node_tags, aux, total,
                  out_tags, plan.root.edge.kind)
 
 
+def _with_fetches(node: PlanNode, bound_as: Dict[Fetch, Fetch]) -> PlanNode:
+    """The tree with every Fetch in `bound_as` replaced by its bound twin
+    (a RangeFunc's lane width follows its fetch's)."""
+    if isinstance(node, Fetch):
+        return bound_as.get(node, node)
+    changed = {}
+    for f in dataclasses.fields(node):
+        v = getattr(node, f.name)
+        if isinstance(v, PlanNode):
+            nv = _with_fetches(v, bound_as)
+        elif isinstance(v, tuple) and any(isinstance(x, PlanNode) for x in v):
+            nv = tuple(_with_fetches(x, bound_as) if isinstance(x, PlanNode)
+                       else x for x in v)
+            nv = v if all(a is b for a, b in zip(nv, v)) else nv
+        else:
+            continue
+        if nv is not v:
+            changed[f.name] = nv
+    if isinstance(node, RangeFunc) and "arg" in changed:
+        changed["step_ns"] = changed["arg"].wgrid_ns
+    return dataclasses.replace(node, **changed) if changed else node
+
+
 def _merge_param_aux(plan: Plan, params_of: Dict[int, object],
-                     aux: Dict[int, dict]) -> None:
+                     aux: Dict[int, dict],
+                     fetches: Dict[Fetch, BoundFetch]) -> None:
     """Params-DEPENDENT aux entries, recomputed on every bind (never
     memoized — the bind memo is keyed on plan structure + tag lists, and
     a sliding dashboard window changes these while hitting it): packed
-    subquery column maps and timestamp() step-time vectors."""
+    subquery column maps, timestamp() step-time vectors and a range
+    function's window edge (where the query's phase meets the samples')."""
     for n in _preorder(plan.root, []):
-        if isinstance(n, SubqueryFunc) and n.packed:
+        if isinstance(n, RangeFunc):
+            edge = fetches[n.arg].edge
+            aux.setdefault(id(n), {})["edge"] = (
+                edge if edge is not None else np.zeros(2, np.float32))
+        elif isinstance(n, SubqueryFunc) and n.packed:
             aux.setdefault(id(n), {})["cols"] = _packed_cols(
                 n, params_of[id(n)])
         elif isinstance(n, InstantFunc) and n.func == "timestamp":
@@ -1088,7 +1132,7 @@ def strip(node: PlanNode, fetch_index: Dict[Fetch, int]) -> PlanNode:
     if isinstance(node, Fetch):
         idx = fetch_index[node]
         return Fetch(VectorSelector(b"%d" % idx), node.role, node.W,
-                     node.stride, node.wgrid_ns)
+                     node.stride, node.wgrid_ns, 0, node.packed)
     if isinstance(node, RangeFunc):
         return RangeFunc(node.func, strip(node.arg, fetch_index),
                          node.step_ns, node.range_ns, node.params)

@@ -716,18 +716,16 @@ class TestRemainingFunctionConformance:
         # constant series: population variance over any window is 0
         blk = run(engine, "stdvar_over_time(memory_bytes[2m])")
         np.testing.assert_allclose(blk.values, 0.0, atol=1e-9)
-        # Linear counter 10/15s. The engine grids the selector at
-        # gcd(step=30s, range=1m)=30s with latest-sample-per-cell
-        # consolidation (DIVERGENCES.md "Range selectors grid raw
-        # samples"): the 1m window holds k=2 cells with gap g=20, and
-        # stdvar of k evenly spaced points is g^2*(k^2-1)/12 = 100.
+        # Linear counter 10/15s. A window holds every raw sample of
+        # (T-1m, T] whatever the step (query/window.py; before PR 42 a
+        # 30s step saw 2 of the 4): k=4 points with gap g=10, and stdvar
+        # of k evenly spaced points is g^2*(k^2-1)/12 = 125.
         blk = run(engine, "stdvar_over_time(http_requests_total[1m])")
-        k, g = 2, 20.0
+        k, g = 4, 10.0
         want = g * g * (k * k - 1) / 12.0
         filled = blk.values[0][np.isfinite(blk.values[0])]
         np.testing.assert_allclose(filled[2:], want, rtol=1e-6)
-        # At a step that divides the cadence the window sees every raw
-        # sample (upstream-exact regime): 15s step, [1m] -> k=4, gap 10.
+        # The same at a step that divides the cadence: 15s step, [1m].
         fine = engine.execute_range(
             "stdvar_over_time(http_requests_total[1m])",
             5 * MIN, 8 * MIN, 15 * S)
