@@ -631,10 +631,26 @@ def phase_fileset_read(ctx: Ctx):
     nothing meanwhile: the phase is about the cold read, and a second
     touch's whole-block decode would be the second pass's compile where
     a shape compiles when it is first met (the CPU)."""
+    from m3_tpu.persist.fs import FilesetReader
     from m3_tpu.storage import block_cache
     from m3_tpu.storage.retriever import BlockRetriever
 
     db = ctx.handle.db
+    # first, every flushed tile as its fileset gives it back: data.bin
+    # holds a row's used words, and zero-filling them has to restore what
+    # this platform's pack route left in memory, to the bit
+    tiles = 0
+    for sid, shard in db.namespace(b"default").shards.items():
+        for bs, path in ctx.handle.persist.list_filesets(b"default", sid):
+            held = shard.blocks.get(bs)
+            if held is not None:
+                back, _ = FilesetReader(path).to_block()
+                tiles += 1
+                check(np.array_equal(back.words, np.asarray(held.words)),
+                      f"shard {sid} block {bs}: the fileset's tile differs "
+                      "from the sealed block it was written from")
+    check(tiles > 0, "no flushed block was still held to compare with "
+          "its fileset")
     retr = BlockRetriever(ctx.handle.persist)
     db.set_retriever(retr)
     evicted = db.evict_flushed()
@@ -673,7 +689,8 @@ def phase_fileset_read(ctx: Ctx):
         want = win.max() if win.size else np.nan
         check(np.isclose(got[k], want, rtol=1e-5, atol=1e-5, equal_nan=True),
               f"fileset_read: step {k} served {got[k]} vs written {want}")
-    ctx.facts["fileset"] = {"evicted_blocks": evicted, "cold_rows": rows,
+    ctx.facts["fileset"] = {"tiles_equal": tiles,
+                            "evicted_blocks": evicted, "cold_rows": rows,
                             "cold_dispatches": calls,
                             "retriever": dict(retr.stats)}
     say(f"sealed block served from its fileset: {ctx.facts['fileset']}")
@@ -1007,7 +1024,10 @@ def results_digest(ctx: Ctx) -> dict:
 
 
 def sealed_checksum(ctx: Ctx) -> str:
-    """sha256 over every flushed fileset's packed codewords."""
+    """sha256 over every flushed fileset's packed codewords, as data.bin
+    lays them out: each row's used words, then the rows' counts
+    (persist/fs.py). The padded-tile layout of an older tree hashed other
+    bytes, so a reference run made by one compares unequal."""
     persist = ctx.handle.persist
     h = hashlib.sha256()
     for sid in sorted(ctx.handle.db.namespace(b"default").shards):
@@ -1022,7 +1042,9 @@ def compare_runs(results: dict, ref_path: str):
         ref = json.load(f)
     check(ref["sealed_sha256"] == results["sealed_sha256"],
           "sealed filesets differ from the reference run "
-          f"({ref['device']} vs {results['device']})")
+          f"({ref['device']} vs {results['device']}); a reference made "
+          "before data.bin held used words hashed the padded tiles and "
+          "cannot compare equal: make it again on this tree")
     for name, want in ref["queries"].items():
         got = results["queries"][name]
         check(got["labels"] == want["labels"],

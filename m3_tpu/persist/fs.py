@@ -4,7 +4,13 @@ One fileset per (namespace, shard, block start), same seven-file invariant
 structure as the reference's writer (persist/fs/write.go:53-78):
 
   info.json        fileset metadata (block start, window, time unit, counts)
-  data.bin         packed u32 codewords, row-major [S, MW] (mmap-read)
+  data.bin         packed u32 codewords: each row's used words back to
+                   back in row order, then the rows' word counts (u32 [S]).
+                   A row's count is one more than the index of its last
+                   non-zero word, so zero-filling to info.json's max_words
+                   gives back the [S, MW] tile bit for bit (mmap-read).
+                   info.json's "data_layout" names the layout; a fileset
+                   without the key holds the padded tile, row-major [S, MW]
   index.bin        per-series entries sorted by id: {id, row, nbits,
                    npoints, data checksum} (write.go:283-290 equivalent)
   summaries.bin    every Nth index entry for coarse seek (summaries file)
@@ -13,14 +19,16 @@ structure as the reference's writer (persist/fs/write.go:53-78):
   checkpoint.json  digest-of-digests, written LAST — a fileset without a
                    valid checkpoint is incomplete and ignored (write.go:44)
 
-Readers mmap data.bin (np.memmap; x/mmap analog); the Seeker answers
-point-id lookups via bloom -> summaries -> index binary search -> row slice
-(seek.go:159,332 flow). Volumes: snapshots write the same structure under a
+Readers mmap data.bin (np.memmap; x/mmap analog) and hand every consumer
+the padded [S, MW] tile; the Seeker answers point-id lookups via bloom ->
+summaries -> index binary search -> row slice (seek.go:159,332 flow) and
+pads the one row it returns. Volumes: snapshots write the same structure under a
 `snapshot-<version>` suffix with snapshot metadata (snapshot_metadata_write.go)."""
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
 import os
 import shutil
@@ -47,6 +55,12 @@ _io = diskio.DEFAULT
 # shared by name with the storage-side readers (storage/retriever.py).
 _CORRUPTION = ROOT.sub_scope("storage.corruption")
 
+# How much the used-words layout engages, moved once a fileset: the u32
+# words data.bin holds (rows' words and their counts) against S x MW.
+_FS = ROOT.sub_scope("persist.fs")
+_DATA_WORDS = _FS.counter("data_words")
+_TILE_WORDS = _FS.counter("tile_words")
+
 INFO_FILE = "info.json"
 DATA_FILE = "data.bin"
 INDEX_FILE = "index.bin"
@@ -55,6 +69,11 @@ BLOOM_FILE = "bloom.bin"
 DIGEST_FILE = "digest.json"
 CHECKPOINT_FILE = "checkpoint.json"
 SUMMARY_EVERY = 32
+
+# info.json's "data_layout": how data.bin lays its rows out. Absent on
+# filesets written before the key existed (the padded [S, MW] tile).
+LAYOUT_KEY = "data_layout"
+LAYOUT_USED_WORDS = "used_words"
 
 _IDX_HEADER = struct.Struct("<IIiiI")  # id_len, row, nbits, npoints, checksum
 _IDX_DTYPE = np.dtype([("id_len", "<u4"), ("row", "<u4"), ("nbits", "<i4"),
@@ -65,6 +84,28 @@ def fileset_dir(root: str, namespace: bytes, shard: int, block_start: int,
                 snapshot_version: Optional[int] = None) -> str:
     kind = f"snapshot-{snapshot_version}" if snapshot_version is not None else "fileset"
     return os.path.join(root, namespace.decode(), f"shard-{shard:05d}", f"{kind}-{block_start}")
+
+
+def used_word_counts(words: np.ndarray) -> np.ndarray:
+    """u32 [S]: one more than the index of each row's last non-zero word
+    (0 for an all-zero row) — whatever a pack backend left past nbits
+    included, so zero-filling restores the tile exactly."""
+    s, mw = words.shape
+    if not words.size:
+        return np.zeros(s, np.uint32)
+    nz = words != 0
+    last = mw - np.argmax(nz[:, ::-1], axis=1)
+    return np.where(nz.any(axis=1), last, 0).astype(np.uint32)
+
+
+def _used_cells(counts: np.ndarray, max_words: int) -> np.ndarray:
+    """int64 [sum(counts)]: where each row's used words lie in the flat
+    [S * MW] tile, in row order — data.bin's order. One gather (the
+    writer) or scatter (the reader) through it moves the used words
+    alone, whatever the tile's width."""
+    counts = counts.astype(np.int64)
+    row_shift = np.arange(len(counts)) * max_words - (np.cumsum(counts) - counts)
+    return np.arange(int(counts.sum())) + np.repeat(row_shift, counts)
 
 
 def _adler(path: str) -> int:
@@ -135,8 +176,13 @@ class FilesetWriter:
         os.makedirs(tmp, exist_ok=True)
 
         words = np.ascontiguousarray(blk.words, np.uint32)
+        counts = used_word_counts(words)
         with _io.open(os.path.join(tmp, DATA_FILE), "wb") as f:
-            f.write(words.tobytes())
+            f.write(np.concatenate(
+                [words.reshape(-1)[_used_cells(counts, words.shape[1])],
+                 counts]).tobytes())
+        _DATA_WORDS.inc(int(counts.sum(dtype=np.int64)) + len(counts))
+        _TILE_WORDS.inc(words.size)
 
         # Index entries sorted by series id (the write path buffers and sorts,
         # write.go WriteAll) with per-row data checksums — one vectorized
@@ -176,6 +222,7 @@ class FilesetWriter:
             "time_unit": int(blk.time_unit),
             "num_series": len(ids),
             "max_words": int(words.shape[1]),
+            LAYOUT_KEY: LAYOUT_USED_WORDS,
             "block_checksum": blk.checksum,
             "bloom_m": bloom.m,
             "bloom_k": bloom.k,
@@ -292,11 +339,61 @@ class FilesetReader:
                 if _adler(os.path.join(path, name)) != want:
                     raise CorruptionError(
                         f"digest mismatch for {name} in {path}", path=path)
-        self._words = _io.memmap(
-            os.path.join(path, DATA_FILE), dtype=np.uint32,
-            shape=(self.info["num_series"], self.info["max_words"]),
-        )
+        self._padded, self._flat, self._counts = self._map_data()
+        self._starts = None if self._flat is None \
+            else np.cumsum(self._counts) - self._counts
         self.entries = list(self._read_index())
+
+    def _map_data(self):
+        """Map data.bin as info.json says it is laid out: (the padded
+        [S, MW] mapping, None, None), or (None, the flat used words,
+        int64 counts [S]). The counts are checked here, before anything
+        gathers by them: a rotten count must never read as a short or
+        shifted row."""
+        dpath = os.path.join(self.path, DATA_FILE)
+        s, mw = self.info["num_series"], self.info["max_words"]
+        layout = self.info.get(LAYOUT_KEY)
+        if layout is None:
+            return _io.memmap(dpath, dtype=np.uint32, shape=(s, mw)), None, None
+        if layout != LAYOUT_USED_WORDS:
+            raise ValueError(f"unknown data layout {layout!r} in {self.path}")
+        size = os.path.getsize(dpath)
+        n = size // 4
+        if size % 4 or n < s:
+            raise CorruptionError(
+                f"data file of {size} bytes cannot hold {s} row counts "
+                f"in {self.path}", path=self.path)
+        flat = (_io.memmap(dpath, dtype=np.uint32, shape=(n,)) if n
+                else np.zeros(0, np.uint32))
+        held = n - s
+        counts = np.array(flat[held:], np.int64)
+        counted = int(counts.sum())
+        if counts.max(initial=0) > mw or counted != held:
+            raise CorruptionError(
+                f"row word counts disagree with the data file ({counted} "
+                f"words counted, {held} held, width {mw}) in {self.path}",
+                path=self.path)
+        return None, flat[:held], counts
+
+    @functools.cached_property
+    def _words(self) -> np.ndarray:
+        """The padded [S, MW] tile: the mapping itself for a padded
+        fileset, the flat words scattered into zeros (once) otherwise."""
+        if self._flat is None:
+            return self._padded
+        s, mw = self.info["num_series"], self.info["max_words"]
+        tile = np.zeros((s, mw), np.uint32)
+        tile.reshape(-1)[_used_cells(self._counts, mw)] = self._flat
+        return tile
+
+    def row_words(self, row: int) -> np.ndarray:
+        """One padded [MW] row, without expanding the fileset."""
+        if self._flat is None:
+            return np.asarray(self._padded[row])
+        out = np.zeros(self.info["max_words"], np.uint32)
+        lo, c = int(self._starts[row]), int(self._counts[row])
+        out[:c] = self._flat[lo:lo + c]
+        return out
 
     def wal_position(self) -> Optional[Tuple[int, int]]:
         """The commit log position recorded at snapshot time, or None
@@ -401,7 +498,8 @@ class Seeker:
     """persist/fs/seek.go: point-id lookup without loading the fileset.
 
     bloom (negative fast path) -> in-memory sorted index (summaries would
-    page the index; ours is small enough to hold) -> mmap row slice."""
+    page the index; ours is small enough to hold) -> mmap row slice,
+    zero-filled to the tile's width."""
 
     def __init__(self, path: str):
         reader = FilesetReader(path, verify=False)
@@ -420,7 +518,7 @@ class Seeker:
                                            self.info["bloom_k"])
         self._entries = sorted(reader.entries, key=lambda e: e.id)
         self._ids = [e.id for e in self._entries]
-        self._words = reader._words
+        self._reader = reader
 
     def seek(self, series_id: bytes) -> Optional[Tuple[np.ndarray, int, int]]:
         """-> (packed words row, nbits, npoints) or None (seek.go:332 SeekByID)."""
@@ -432,7 +530,7 @@ class Seeker:
         if i >= len(self._ids) or self._ids[i] != series_id:
             return None
         e = self._entries[i]
-        row = np.asarray(self._words[e.row])
+        row = self._reader.row_words(e.row)
         if zlib.adler32(row.tobytes()) != e.checksum:
             _CORRUPTION.counter("seek_mismatch").inc()
             raise CorruptionError(
