@@ -5,13 +5,14 @@ from __future__ import annotations
 
 import dataclasses
 import re
-from typing import Tuple
+from typing import Optional, Tuple
 
 # Bytes that start regex syntax; a literal prefix scan stops at the first
 # one (mirrors regexp/syntax LiteralPrefix consumed by fst/regexp's
 # prefix-range prune).
 _META = frozenset(b".^$*+?{}[]\\|()")
 _QUANT = frozenset(b"*?{")
+_META_BUT_BAR = bytes(sorted(_META - {0x7C}))
 
 
 def literal_prefix(pattern: bytes) -> bytes:
@@ -36,6 +37,28 @@ def literal_prefix(pattern: bytes) -> bytes:
     return bytes(out)
 
 
+def literal_terms(pattern: bytes) -> Optional[Tuple[bytes, ...]]:
+    """Every term a pattern can match, where its own bytes say so, else
+    None (a scan decides).
+
+    The test: no member of _META but `|`, so no escape, group, anchor,
+    class, quantifier or flag can be present; the pattern is then a
+    literal, or an alternation of literals of which each matches itself
+    alone (Prometheus' FastRegexMatcher turns the same shape into set
+    membership). An empty branch is the literal b"". Branches come back
+    de-duplicated in the pattern's order. Conservative like
+    literal_prefix: None only costs the scan."""
+    if len(pattern.translate(None, _META_BUT_BAR)) != len(pattern):
+        return None
+    return tuple(dict.fromkeys(pattern.split(b"|")))
+
+
+def literal_alternatives(pattern: bytes) -> Optional[Tuple[bytes, ...]]:
+    """literal_terms of a pattern that IS an alternation (`host_1|host_22`);
+    None for everything else, a lone literal included."""
+    return literal_terms(pattern) if 0x7C in pattern else None
+
+
 class Query:
     pass
 
@@ -58,11 +81,28 @@ class RegexpQuery(Query):
 
     def __post_init__(self):
         # Compile ONCE at construction (idx.NewRegexpQuery compiles the
-        # automaton up front); every per-segment execution reuses it.
-        object.__setattr__(self, "_compiled", re.compile(self.pattern))
+        # automaton up front, and an invalid pattern fails here); every
+        # per-segment scan reuses it. A pattern without metacharacters,
+        # or an alternation of such, is always valid and resolves by
+        # lookups in a frozen segment: its automaton waits for a caller
+        # that asks (a mutable segment's dict walk, the oracle).
+        no_scan = literal_terms(self.pattern) is not None
+        object.__setattr__(self, "_compiled",
+                           None if no_scan else re.compile(self.pattern))
 
     def compiled(self):
-        return self._compiled
+        c = self._compiled
+        if c is None:
+            c = re.compile(self.pattern)
+            object.__setattr__(self, "_compiled", c)
+        return c
+
+    @property
+    def fullmatch(self):
+        """With `pattern`, what a segment's regexp_postings reads of a
+        compiled pattern: a query stands in for one, and compiles only
+        if a scan asks for the matcher."""
+        return self.compiled().fullmatch
 
 
 @dataclasses.dataclass(frozen=True)
@@ -85,7 +125,7 @@ def new_term(field: bytes, value: bytes) -> TermQuery:
 
 
 def new_regexp(field: bytes, pattern: bytes) -> RegexpQuery:
-    return RegexpQuery(field, pattern)  # constructor compiles eagerly
+    return RegexpQuery(field, pattern)  # constructor validates eagerly
 
 
 def new_conjunction(*queries: Query) -> Query:

@@ -4,14 +4,16 @@ MutableSegment mirrors segment/mem (hash-map terms dict -> postings); the
 ImmutableSegment is the TPU-idiomatic stand-in for the FST segment
 (segment/fst/segment.go), array-native end to end:
 
-  * Each field's sorted terms live as ONE concatenated uint8 buffer +
-    offsets, mirrored into a zero-padded (n_terms, width) matrix; term
-    lookup is vectorized binary search over the matrix (TermDict), the
-    counterpart of the FST's shared-prefix byte walk.
-  * Regexp evaluation extracts the pattern's literal prefix and prunes to
-    the [prefix, successor) TERM RANGE first (the fst/regexp prefix-range
-    idiom, regexp/regexp.go LiteralPrefix), then runs the compiled
-    automaton over only the survivors.
+  * Each field's terms live as one sorted list of bytes (TermDict),
+    searched by bisection: the counterpart of the FST's shared-prefix
+    byte walk, one C call a lookup.
+  * A regexp whose bytes name its terms (no metacharacter, or an
+    alternation of such literals) resolves as that many exact lookups,
+    as Prometheus' FastRegexMatcher does. Any other extracts the
+    pattern's literal prefix and prunes to the [prefix, successor) TERM
+    RANGE first (the fst/regexp prefix-range idiom, regexp/regexp.go
+    LiteralPrefix), then runs the compiled automaton over only the
+    survivors.
   * Postings resolve into dual-form PostingsLists (m3_tpu/index/postings):
     sorted int32 arrays AND packed uint64 bitmaps, with union/intersect/
     difference choosing the representation by density — the roaring-
@@ -27,10 +29,12 @@ test_index_property.py proves them result-identical)."""
 from __future__ import annotations
 
 import threading
+from bisect import bisect_left
 from typing import Dict, Iterable, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..utils import instrument, tracing
 from . import postings as pl
 from .query import (
     AllQuery,
@@ -41,9 +45,29 @@ from .query import (
     RegexpQuery,
     TermQuery,
     literal_prefix,
+    literal_terms,
 )
 
 EMPTY = np.zeros(0, np.int32)
+
+# How a frozen segment's matchers resolved their terms (process totals,
+# /debug/vars): exact terms found by bisection, regexps whose bytes
+# named their terms outright, regexps that walked a term range and how
+# many terms they ran the automaton over.
+_TERMS_SCOPE = instrument.ROOT.sub_scope("index.terms")
+_LOOKUPS = _TERMS_SCOPE.counter("lookups")
+_LITERAL_SETS = _TERMS_SCOPE.counter("literal_sets")
+_SCANS = _TERMS_SCOPE.counter("scans")
+_TERMS_SCANNED = _TERMS_SCOPE.counter("terms_scanned")
+
+
+def _count(counter, cost: str, n: int):
+    """A process total, and the same as a cost of a detailed span."""
+    counter.inc(n)
+    acc = tracing.detail()
+    if acc is not None:
+        acc.add_cost(cost, n)
+
 
 # Process-unique ImmutableSegment generation ids: the postings-list
 # cache keys on them, so a sealed/merged/expired segment's entries can
@@ -71,105 +95,22 @@ class Document(NamedTuple):
 
 
 class TermDict:
-    """Sorted term dictionary in array form.
+    """Sorted term dictionary: the segment's stand-in for the FST.
 
-    terms (sorted unique bytes) are stored as a concatenated uint8
-    buffer + int64 offsets plus a zero-padded (n, width) uint8 matrix.
-    Ordering over the matrix is (padded row, true length) lexicographic,
-    which equals bytes ordering for ALL byte strings (a zero-padded row
-    tie means one term is the other plus trailing NULs — exactly the
-    case the length tiebreak resolves), so embedded/trailing NUL bytes
-    are handled, unlike numpy's S dtype.
+    `terms` is the field's sorted unique bytes, and every lookup is a
+    bisection of that list (one C call; bytes ordering is the
+    dictionary's ordering for ALL byte strings, embedded and trailing
+    NULs included). No derived structure is built at a freeze."""
 
-    The matrix width is capped at WIDTH_CAP so one outlier-long term
-    cannot inflate the whole field's dictionary to n * max_len bytes;
-    rows that tie at the cap with bytes still unread fall back to an
-    exact per-lane compare (rare by construction — ties require a
-    WIDTH_CAP-byte shared prefix)."""
-
-    WIDTH_CAP = 64
-
-    __slots__ = ("terms", "n", "buf", "offs", "lens", "width", "padded")
+    __slots__ = ("terms", "n")
 
     def __init__(self, terms: List[bytes]):
-        self.terms = terms  # sorted; kept for survivors/persist/terms()
+        self.terms = terms  # sorted; also read by survivors/persist/terms()
         self.n = len(terms)
-        self.lens = np.fromiter((len(t) for t in terms), np.int64, self.n)
-        self.offs = np.zeros(self.n + 1, np.int64)
-        np.cumsum(self.lens, out=self.offs[1:])
-        joined = b"".join(terms)
-        self.buf = (np.frombuffer(joined, np.uint8) if joined
-                    else np.zeros(0, np.uint8))
-        self.width = min(int(self.lens.max()) if self.n else 0,
-                         self.WIDTH_CAP)
-        padded = np.zeros((self.n, max(self.width, 1)), np.uint8)
-        if self.n and self.width:
-            cols = np.arange(self.width)
-            clipped = np.minimum(self.lens, self.width)
-            mask = cols[None, :] < clipped[:, None]
-            # Row-major mask order == buffer order only for uncapped
-            # terms; gather capped rows through explicit offsets instead.
-            if int(clipped.sum()) == len(self.buf):
-                padded[mask] = self.buf
-            else:
-                idx = self.offs[:-1, None] + cols[None, :]
-                padded[mask] = self.buf[np.minimum(idx, len(self.buf) - 1)[mask]]
-        self.padded = padded
-
-    def _pad_queries(self, qs: Sequence[bytes]) -> Tuple[np.ndarray,
-                                                         np.ndarray]:
-        """Queries -> (k, width) matrix (truncated to width — ties fall to
-        the true-length tiebreak) + true lengths."""
-        w = max(self.width, 1)
-        out = np.zeros((len(qs), w), np.uint8)
-        lens = np.zeros(len(qs), np.int64)
-        for i, q in enumerate(qs):
-            head = q[: self.width]
-            out[i, : len(head)] = np.frombuffer(head, np.uint8)
-            lens[i] = len(q)
-        return out, lens
-
-    def rank(self, qs: Sequence[bytes]) -> np.ndarray:
-        """Vectorized binary search: bisect_left insertion point for each
-        query, all lanes advancing together — each of the log2(n) steps
-        gathers one candidate row per lane and compares the whole batch
-        in a handful of numpy ops."""
-        k = len(qs)
-        if self.n == 0 or k == 0:
-            return np.zeros(k, np.int64)
-        qp, qlens = self._pad_queries(qs)
-        lanes = np.arange(k)
-        lo = np.zeros(k, np.int64)
-        hi = np.full(k, self.n, np.int64)
-        for _ in range(int(self.n).bit_length()):
-            active = lo < hi
-            if not active.any():
-                break
-            # Clamp for lanes already settled at lo == hi == n: they
-            # gather a dummy row and are masked out of the updates.
-            mid = np.minimum((lo + hi) >> 1, self.n - 1)
-            rows = self.padded[mid]                      # (k, width)
-            neq = rows != qp
-            any_neq = neq.any(axis=1)
-            first = np.where(any_neq, neq.argmax(axis=1), 0)
-            rb = rows[lanes, first]
-            qb = qp[lanes, first]
-            less = np.where(any_neq, rb < qb, self.lens[mid] < qlens)
-            # Capped-width tie with unread bytes on either side: the
-            # matrix can't decide — compare the actual terms exactly.
-            amb = active & ~any_neq & ((self.lens[mid] > self.width)
-                                       | (qlens > self.width))
-            for j in np.flatnonzero(amb):
-                less[j] = self.terms[int(mid[j])] < qs[j]
-            go_right = active & less
-            go_left = active & ~less
-            lo = np.where(go_right, mid + 1, lo)
-            hi = np.where(go_left, mid, hi)
-        return lo
 
     def find(self, term: bytes) -> int:
         """Index of term, or -1."""
-        i = int(self.rank([term])[0])
+        i = bisect_left(self.terms, term)
         if i < self.n and self.terms[i] == term:
             return i
         return -1
@@ -178,11 +119,11 @@ class TermDict:
         """[lo, hi) of terms starting with prefix (whole dict for b'')."""
         if not prefix:
             return 0, self.n
+        lo = bisect_left(self.terms, prefix)
         succ = _prefix_successor(prefix)
         if succ is None:
-            return int(self.rank([prefix])[0]), self.n
-        lo, hi = self.rank([prefix, succ])
-        return int(lo), int(hi)
+            return lo, self.n
+        return lo, bisect_left(self.terms, succ, lo)
 
 
 def dedup_sorted_ids(ids: np.ndarray) -> np.ndarray:
@@ -314,7 +255,8 @@ class MutableSegment:
         vals = self._ensure_terms().get(field)
         if not vals:
             return EMPTY
-        out = [np.asarray(p, np.int32) for v, p in vals.items() if pattern.fullmatch(v)]
+        match = pattern.fullmatch
+        out = [np.asarray(p, np.int32) for v, p in vals.items() if match(v)]
         if not out:
             return EMPTY
         return np.unique(np.concatenate(out))
@@ -446,30 +388,43 @@ class ImmutableSegment:
         if entry is None:
             return EMPTY
         td, offs, cat = entry
+        _count(_LOOKUPS, "lookups_n", 1)
         i = td.find(value)
         if i < 0:
             return EMPTY
         return cat[offs[i] : offs[i + 1]]
 
-    def regexp_postings(self, field: bytes, pattern,
-                        prefix: Optional[bytes] = None) -> np.ndarray:
-        """Automaton over the term range surviving the literal-prefix
-        prune; parts concatenate via one union over span slices."""
+    def regexp_postings(self, field: bytes, pattern) -> np.ndarray:
+        """The union of the postings of every term the pattern matches
+        whole. `pattern` is a pattern compiled with no flags, or a
+        RegexpQuery: its `pattern` bytes decide the route, and
+        `fullmatch` is asked for only by a scan. Literals are looked up; anything else runs the
+        automaton over the term range surviving the literal-prefix
+        prune, and parts concatenate via one union over span slices."""
         entry = self._fields.get(field)
         if entry is None:
             return EMPTY
         td, offs, cat = entry
-        if prefix is None:
-            prefix = literal_prefix(pattern.pattern)
-        lo, hi = td.prefix_range(prefix)
+        literals = literal_terms(pattern.pattern)
+        if literals is not None:
+            _LITERAL_SETS.inc()
+            _count(_LOOKUPS, "lookups_n", len(literals))
+            found = [i for i in map(td.find, literals) if i >= 0]
+            if not found:
+                return EMPTY
+            if len(found) == 1:
+                i = found[0]
+                return cat[offs[i] : offs[i + 1]]
+            return np.unique(np.concatenate(
+                [cat[offs[i] : offs[i + 1]] for i in found]))
+        lo, hi = td.prefix_range(literal_prefix(pattern.pattern))
         if lo >= hi:
             return EMPTY
-        if prefix and len(prefix) == len(pattern.pattern):
-            # Fully-literal pattern: the range IS the single exact term.
-            if lo + 1 == hi and td.terms[lo] == prefix:
-                return cat[offs[lo] : offs[lo + 1]]
+        _SCANS.inc()
+        _count(_TERMS_SCANNED, "terms_scanned_n", hi - lo)
         match = pattern.fullmatch
-        keep = [i for i in range(lo, hi) if match(td.terms[i])]
+        terms = td.terms
+        keep = [i for i in range(lo, hi) if match(terms[i])]
         if not keep:
             return EMPTY
         if len(keep) == hi - lo:
@@ -517,7 +472,7 @@ def _exec(seg, query: Query, n: int, cache) -> pl.PostingsList:
     if isinstance(query, RegexpQuery):
         arr = _leaf_postings(
             seg, query.field, "regexp", query.pattern,
-            lambda: seg.regexp_postings(query.field, query.compiled()), cache)
+            lambda: seg.regexp_postings(query.field, query), cache)
         return pl.PostingsList(n, arr=arr)
     if isinstance(query, ConjunctionQuery):
         neg = [q for q in query.queries if isinstance(q, NegationQuery)]

@@ -88,9 +88,9 @@ def _padded_words(ids: Sequence[bytes]):
     padded = maxlen + (-maxlen) % 4
     buf = np.zeros((n, padded), np.uint8)
     # One concatenated buffer + boolean scatter instead of a frombuffer
-    # per id: row-major mask order equals concatenation order (the
-    # TermDict padding trick) — this runs per write batch on the shard
-    # routing path, so the per-id Python loop was measurable.
+    # per id: row-major mask order equals concatenation order — this
+    # runs per write batch on the shard routing path, so the per-id
+    # Python loop was measurable.
     joined = b"".join(ids)
     if joined:
         mask = np.arange(padded)[None, :] < lens[:, None]

@@ -169,7 +169,7 @@ class TestBitmapVsSetAlgebra:
 
 
 class TestTermDict:
-    def test_rank_matches_python_bisect(self):
+    def test_find_matches_python_bisect(self):
         import bisect
 
         rng = np.random.default_rng(42)
@@ -178,33 +178,34 @@ class TestTermDict:
                             for _ in range(int(rng.integers(0, 50)))})
             td = TermDict(terms)
             queries = [_rand_value(rng) for _ in range(20)] + terms[:5]
-            got = td.rank(queries)
-            for q, g in zip(queries, got):
-                assert int(g) == bisect.bisect_left(terms, q), (terms, q)
+            for q in queries:
+                at = bisect.bisect_left(terms, q)
                 i = td.find(q)
                 if q in terms:
-                    assert terms[i] == q
+                    assert i == at and terms[i] == q, (terms, q)
                 else:
-                    assert i == -1
+                    assert i == -1, (terms, q)
+                # a one-term prefix range starts where bisect_left lands
+                assert td.prefix_range(q)[0] == (at if q else 0), (terms, q)
 
-    def test_width_cap_long_terms(self):
-        """Terms beyond WIDTH_CAP tie in the matrix and resolve via the
-        exact-compare fallback; the padded matrix never exceeds the cap."""
+    def test_long_terms_and_nuls(self):
+        """Terms past 64 bytes that share their first 64, trailing NULs
+        and the empty term: the list's own bytes ordering decides, with
+        no width at which two terms tie."""
         import bisect
 
-        cap = TermDict.WIDTH_CAP
-        base = b"P" * cap
+        base = b"P" * 64
         terms = sorted({base, base + b"a", base + b"ab", base + b"\x00",
                         base + b"z" * 100, base[:-1], b"Q" * 200,
                         b"Q" * 200 + b"x", b"short", b""})
         td = TermDict(terms)
-        assert td.width == cap and td.padded.shape[1] == cap
+        assert td.n == len(terms) and td.terms is terms
         queries = terms + [base + b"b", base + b"\x00\x00", b"Q" * 199,
                            b"Q" * 201, b"P", b"R", base + b"z" * 99]
         for q in queries:
-            assert int(td.rank([q])[0]) == bisect.bisect_left(terms, q), q
             assert (td.find(q) >= 0) == (q in terms), q
             if q in terms:
+                assert td.find(q) == bisect.bisect_left(terms, q), q
                 assert terms[td.find(q)] == q
         for prefix in (base, base + b"a", b"Q" * 100, b"P", b""):
             lo, hi = td.prefix_range(prefix)
