@@ -832,9 +832,6 @@ def phase_served_verdict(ctx: Ctx):
 
 
 FEW_ROWS = (1, 2, 3, 5, 8, 16)
-# the other row buckets of a cold read and of a whole block's decode
-# (storage/block.py::ROW_BUCKETS)
-BUCKET_ROWS = (64, 256, 1024)
 
 
 def few_row_twin_faults(seed: int, rows=FEW_ROWS, starts: int = 4) -> list:
@@ -850,8 +847,11 @@ def few_row_twin_faults(seed: int, rows=FEW_ROWS, starts: int = 4) -> list:
     program weaves each pair into rows of 64-bit cells on the device (a
     reshape of u32 pairs, the same family of lowering): the planes must
     come back C-contiguous each, which only a chip's layout can deny,
-    and the smoke runs the larger row buckets too (BUCKET_ROWS)."""
+    and the smoke runs every rung a caller's rows are padded to
+    (ROW_BUCKETS). What `decode_rows` hands a caller for the same rows
+    (padded to their rung, cut back) must be the default route's bits."""
     from m3_tpu.ops import tsz
+    from m3_tpu.ops.decode_rows import decode_rows
     from m3_tpu.parallel import guard
     from m3_tpu.storage.block import encode_block
 
@@ -893,6 +893,13 @@ def few_row_twin_faults(seed: int, rows=FEW_ROWS, starts: int = 4) -> list:
             if not (np.array_equal(ts_d, got["xla"][0]) and np.array_equal(
                     vs_d.view(np.uint64), got["xla"][1].view(np.uint64))):
                 faults.append(f"{where}: default route != XLA twin")
+            ts_r, vs_r, calls = decode_rows(
+                np.asarray(whole.words)[:r], np.asarray(whole.npoints)[:r],
+                whole.window, whole.time_unit.nanos)
+            if not (calls == 1 and np.array_equal(ts_r[:, :w], ts_d)
+                    and np.array_equal(vs_r[:, :w].view(np.uint64),
+                                       vs_d.view(np.uint64))):
+                faults.append(f"{where}: decode_rows != the plane decode")
             bad = np.argwhere(ts_d != t[:r])
             if len(bad):
                 faults.append(
@@ -951,7 +958,10 @@ def phase_codec_twins(ctx: Ctx):
     check(np.array_equal(dec_default[1][:, :BLOCK_POINTS].view(np.uint64),
                          vals[:, :BLOCK_POINTS].view(np.uint64)),
           "decode: value bits differ from the written samples")
-    faults = few_row_twin_faults(ctx.seed, rows=FEW_ROWS + BUCKET_ROWS)
+    from m3_tpu.ops.decode_rows import ROW_BUCKETS
+
+    faults = few_row_twin_faults(
+        ctx.seed, rows=tuple(sorted(set(FEW_ROWS + ROW_BUCKETS))))
     check(not faults, "few-row planes: " + "; ".join(faults[:5]))
     ids = ctx.ids[:min(sz.series, 20_000)]
     h_default = hashing.hash_batch(ids)

@@ -1,6 +1,6 @@
 """Smart replicating client (reference: src/dbnode/client)."""
 
-from .decode import ConflictStrategy, decode_segment_groups, merge_replica_points
+from .decode import ConflictStrategy, merge_replica_points
 from .session import (
     ConsistencyError,
     HostClient,
@@ -16,6 +16,5 @@ __all__ = [
     "RemoteError",
     "Session",
     "SessionOptions",
-    "decode_segment_groups",
     "merge_replica_points",
 ]

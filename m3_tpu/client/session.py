@@ -26,6 +26,7 @@ from ..cluster.topology import (
     required_acks,
     required_reads,
 )
+from ..ops.decode_rows import decode_rows, decode_stacked
 from ..parallel.sharding import ShardSet
 from ..rpc import wire
 from ..utils import tracing
@@ -41,7 +42,7 @@ from ..utils.retry import (
     Retrier,
     RetryOptions,
 )
-from .decode import ConflictStrategy, decode_stack, merge_replica_points
+from .decode import ConflictStrategy, merge_replica_points
 
 
 class ConsistencyError(Exception):
@@ -1050,7 +1051,7 @@ class Session:
         (first sighting's order, the first non-empty tags kept); a
         frame's positions map to slots. Decode: every frame's
         sealed-block tiles go into one `decode_stacked` call, so the
-        tiles of one geometry are one `decode_stack` dispatch whichever
+        tiles of one geometry are one `decode_rows` call whichever
         replica sent them (the decode is row-independent: stacking
         changes no bit of a row). Merge: a slot's parts are laid down
         frame by frame in arrival order, inside a frame its sealed
@@ -1059,8 +1060,6 @@ class Session:
         per-responder fold visits them in, so one stable
         `merge_replica_points` picks what the fold picks. `acc` takes
         what the decodes and the merges cost."""
-        from ..storage.tiles import decode_stacked
-
         t0 = _clock()
         slot_of: Dict[bytes, int] = {}
         tags: List[dict] = []
@@ -1081,13 +1080,20 @@ class Session:
         # tile a block start, a call is its fixed cost and not its rows
         # (5.5 ms of the coordinator's host time for 54 us of device
         # time, ledger PR 34), and the decode is row-independent.
-        def decode(words, npoints, window, unit):
+        def decode(words, npoints, window, unit_nanos):
             t0 = _clock()
-            ts, vs, calls = decode_stack(words, npoints, window, unit)
+            ran_on: list = []
+            ts, vs, calls = decode_rows(words, npoints, window, unit_nanos,
+                                        ran_on=ran_on)
             acc.decode_ns += _clock() - t0
             acc.decode_n += calls
             acc.d2h_bytes += ts.nbytes + vs.nbytes
-            return ts, vs
+            # counted where the result lies: the session's thread's
+            # device (its scope's, parallel/scope.py)
+            for dev in ran_on:
+                ROOT.sub_scope("client.decode_tile", device=str(dev.id)
+                               ).counter("dispatches").inc()
+            return ts, vs, calls
 
         decoded: List[list] = [[] for _ in frames]
         for tile, ks, ts, vs in decode_stacked(

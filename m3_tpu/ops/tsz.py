@@ -1209,7 +1209,7 @@ def decode_plane(words, npoints, *, window: int, unit_nanos: int = 1,
     the plan compiler's `value` fetch staging consumes this instead of
     running its own downcast pass. `ran_on`, a list, receives the
     devices that hold the result (a caller that counts where its rows
-    were decoded: client/decode.py::decode_tile)."""
+    were decoded: client/session.py::_one_pass_points)."""
     from ..parallel import telemetry
 
     route = _decode_route()

@@ -262,7 +262,7 @@ class NodeService:
         indexed out of the blocks' word matrices and concatenated, with
         `rows` their positions in `series`: the tile shape peer
         streaming moves (rpc_fetch_block_tiles) and the client's batched
-        device decode consumes (client/decode.decode_tile). Blocks of
+        device decode consumes (ops/decode_rows.py). Blocks of
         one start that differ in window, time unit or words width get a
         tile each, and a tile is cut at TILE_MAX_ROWS, so a frame is
         charged, and can be refused, tile by tile. The reference streams

@@ -11,7 +11,6 @@ from __future__ import annotations
 
 import dataclasses
 import itertools
-import threading
 import zlib
 from collections import OrderedDict
 from typing import Dict, Optional, Tuple
@@ -19,6 +18,7 @@ from typing import Dict, Optional, Tuple
 import numpy as np
 
 from ..ops import tsz
+from ..ops.decode_rows import ROW_BUCKETS, decode_rows
 from ..parallel import ingest as par_ingest
 from ..parallel import scope as dscope
 from ..utils import tracing, xtime
@@ -177,7 +177,7 @@ class SealedBlock:
 
     def read(self, series_idx: int) -> Optional[Tuple[np.ndarray, np.ndarray]]:
         """Decode one series' datapoints (the per-row read: a miss is a
-        decode of this row alone, padded to the smallest row bucket).
+        decode of this row alone, padded to the smallest rung).
 
         Consults the device block cache first: a hot block's decoded
         planes are resident (admission after repeated touches), turning
@@ -197,9 +197,10 @@ class SealedBlock:
             if dec is not None:
                 n = int(self.npoints[row])
                 return dec[0][row, :n], dec[1][row, :n]
-        ts, vals = decode_rows(
+        ts, vals, calls = decode_rows(
             self.words[row : row + 1], self.npoints[row : row + 1],
             self.window, self.time_unit.nanos)
+        count_cold(1, calls)
         n = int(self.npoints[row])
         t_out, v_out = ts[0, :n], vals[0, :n]
         t_out.setflags(write=False)
@@ -210,7 +211,7 @@ class SealedBlock:
         """Decode every series in one batched launch: (ts [S, W], vals, npoints).
 
         Hot blocks serve from the device block cache; cold blocks decode
-        via _decode_plane's pow2 row bucketing. The planes are READ-ONLY
+        via _decode_plane. The planes are READ-ONLY
         on every path (cache hits share them across readers — the
         fetch-result immutability contract the query layer already
         relies on; the cold path freezes so the contract is observable
@@ -226,45 +227,26 @@ class SealedBlock:
 
     def _decode_plane(self, encoded: Optional[tuple] = None
                       ) -> Tuple[np.ndarray, np.ndarray]:
-        """Whole-block decode to (ts_ns [S, W], vals [S, W]).
-
-        Rows are padded to a bucket of ROW_BUCKETS, past the largest to a
-        power of two (replicating the first stream, always valid), so
-        one compiled decode kernel serves every block with this window
-        geometry, and the cold reads of its rows too — the decode-side twin of
-        encode_block's shape bucketing; merge/repair paths decode blocks
-        of arbitrary series counts without per-count recompiles.
+        """Whole-block decode to (ts_ns [S, W], vals [S, W]) through
+        `decode_rows`: the programs of a geometry's cold reads serve its
+        blocks too, and merge/repair paths decode blocks of arbitrary
+        series counts without per-count recompiles.
 
         `encoded` is the cache's retained device (words, padded npoints)
         from the seal-time encode: decoding from it skips the H2D
         re-upload of the stream words entirely (decode is
         row-independent, so rows [:S] are bit-identical either way). It
-        is used where the encode's row padding is this block's bucket —
-        a block of hundreds of rows; a few rows' words are uploaded
-        instead, so a geometry's decodes stay the buckets' programs.
-        Planes come back read-only — they may be cache-shared across
+        is used where the encode's row padding is a rung of ROW_BUCKETS
+        (a block of 5 to 1,024 rows); the block's own words are uploaded
+        otherwise, so a geometry's decodes stay the rungs' programs.
+        Planes come back read-only: they may be cache-shared across
         readers."""
-        from ..parallel import telemetry
-
         s = len(self.series_indices)
-        sp = row_bucket(s)
-        if encoded is not None and np.shape(encoded[0])[0] == sp:
+        words, npoints = self.words, self.npoints
+        if encoded is not None and np.shape(encoded[0])[0] in ROW_BUCKETS:
             words, npoints = encoded
-        else:
-            words, npoints = self.words, self.npoints
-            if sp != s:
-                words = np.concatenate([words, np.repeat(words[:1], sp - s, 0)])
-                npoints = np.concatenate(
-                    [npoints, np.repeat(npoints[:1], sp - s)])
-        telemetry.record_bucket(
-            "block.decode_plane",
-            (int(np.shape(words)[0]), int(np.shape(words)[-1]),
-             int(self.window)))
-        # Fused plane decode: the tick cumsum, unit-nanos scaling and
-        # int->f64 select all run inside the ONE decode program
-        # (tsz.decode_plane) instead of as host passes over [S, W] planes.
-        ts, vals = _dispatch_decode(words, npoints, self.window,
-                                    self.time_unit.nanos)
+        ts, vals, _calls = decode_rows(words, npoints, self.window,
+                                       self.time_unit.nanos)
         ts, vals = ts[:s], vals[:s]
         ts.setflags(write=False)
         vals.setflags(write=False)
@@ -274,132 +256,25 @@ class SealedBlock:
         return int(self.words.nbytes)
 
 
-def _decode_plane_host(words, npoints, window: int, unit_nanos: int):
-    """Host oracle decode (ops/ref_codec, row by row) — the block-decode
-    route's fallback when the device decode faults or its breaker is
-    open. Bit-identical on the valid region by the property-corpus
-    contract; padding cells are zero (consumers never read past
-    npoints[r])."""
-    from ..ops import ref_codec
-
-    words = np.asarray(words)
-    npoints = np.asarray(npoints)
-    s = words.shape[0]
-    ts = np.zeros((s, window), np.int64)
-    vals = np.zeros((s, window), np.float64)
-    for r in range(s):
-        n = int(npoints[r])
-        if n == 0:
-            continue
-        t, v = ref_codec.decode(ref_codec.EncodedBlock(
-            words=words[r], nbits=0, npoints=n))
-        ts[r, :n] = np.asarray(t, np.int64) * unit_nanos
-        vals[r, :n] = np.asarray(v, np.float64)
-    return ts, vals
+_COLD_ROWS = ROOT.counter("storage.read.cold_rows")
+_COLD_DISPATCHES = ROOT.counter("storage.read.cold_dispatches")
 
 
-def _dispatch_decode(words, npoints, window: int, unit_nanos: int):
-    """The block plane decode through the compute-fault guard: primary
-    is the fused device program (tsz.decode_plane, itself guarded at the
-    codec.decode level for its Pallas-vs-XLA routing); fallback is the
-    host ref_codec oracle."""
-    from ..parallel import guard
-
-    def _device():
-        return tsz.decode_plane(words, npoints, window=window,
-                                unit_nanos=unit_nanos)
-
-    return guard.dispatch(
-        "block.decode", _device,
-        lambda _err: _decode_plane_host(words, npoints, window,
-                                        unit_nanos))
+def count_cold(rows: int, calls: int, acc=None):
+    """A node's rows that no cache held and the decode calls they took
+    (the row read above, storage/read_batch.py's sweep; a session's
+    rows are not a node's cold rows). `acc` (a detailed span) receives
+    `cold_rows_n` and `cold_dispatch_n`."""
+    _COLD_ROWS.inc(rows)
+    _COLD_DISPATCHES.inc(calls)
+    if acc is not None:
+        acc.add_cost("cold_rows_n", rows)
+        acc.add_cost("cold_dispatch_n", calls)
 
 
 def _next_pow2(n: int, floor: int = 8) -> int:
     n = max(n, floor)
     return 1 << (n - 1).bit_length()
-
-
-# The row counts a cold read's decode is padded to: a lone row is never
-# a program of its own (one-row u32-pair programs are suspect on the
-# chip: tsz.decode_plane), and five shapes a geometry serve every read
-# from one series' one block to a thousand rows; more rows go in calls
-# of the largest, which is also a whole 625-row block's decode. The two
-# smallest are the client's tile shapes for 1-8 and 9-16 rows
-# (client/decode.py::decode_tile), so a node and a session that decode
-# a few rows of one geometry in one process share the program.
-ROW_BUCKETS = (8, 16, 64, 256, 1024)
-
-
-def row_bucket(n: int) -> int:
-    """The row count a decode of `n` rows is padded to."""
-    return next((b for b in ROW_BUCKETS if b >= n), None) or _next_pow2(n)
-
-_COLD_ROWS = ROOT.counter("storage.read.cold_rows")
-_COLD_DISPATCHES = ROOT.counter("storage.read.cold_dispatches")
-_warmed: set = set()
-_warm_lock = threading.Lock()
-
-
-def _warm_buckets(words, npoints, window: int, unit_nanos: int):
-    """On an accelerator a shape's first decode is a compile of seconds
-    inside a served read, and which bucket a read needs depends on what
-    the cache holds at that instant: a geometry's first cold read brings
-    every row bucket through its compile at once, on rows of its own.
-    On the CPU (the gate `block_cache.wants_encoded` uses) a compile is
-    cheap and a shape compiles where it is first met."""
-    key = (int(window), int(unit_nanos), int(np.shape(words)[-1]))
-    if key in _warmed:
-        return
-    with _warm_lock:
-        if key in _warmed:
-            return
-        import jax
-
-        if jax.default_backend() != "cpu":
-            for rows in ROW_BUCKETS:
-                _dispatch_decode(np.repeat(words[:1], rows, 0),
-                                 np.repeat(npoints[:1], rows), window,
-                                 unit_nanos)
-        _warmed.add(key)
-
-
-def decode_rows(words, npoints, window: int, unit_nanos: int, acc=None
-                ) -> Tuple[np.ndarray, np.ndarray]:
-    """Decode rows of one geometry (window, time unit, words width),
-    whichever blocks they come from, to (ts_ns [N, W], vals [N, W]): one
-    dispatch, the rows padded to a bucket of ROW_BUCKETS with copies of
-    the first (always valid), and one more for every 1,024 rows past
-    that. `acc` (a detailed span) receives `cold_rows_n` and
-    `cold_dispatch_n`; the caller times it."""
-    words = np.asarray(words)
-    npoints = np.asarray(npoints, np.int32)
-    n = len(words)
-    if not n:
-        return (np.zeros((0, window), np.int64),
-                np.zeros((0, window), np.float64))
-    top = ROW_BUCKETS[-1]
-    _warm_buckets(words, npoints, window, unit_nanos)
-    out_t, out_v = [], []
-    for lo in range(0, n, top):
-        w, k = words[lo:lo + top], npoints[lo:lo + top]
-        have = len(w)
-        rows = row_bucket(have)
-        if rows != have:
-            w = np.concatenate([w, np.repeat(w[:1], rows - have, 0)])
-            k = np.concatenate([k, np.repeat(k[:1], rows - have)])
-        ts, vals = _dispatch_decode(w, k, window, unit_nanos)
-        out_t.append(ts[:have])
-        out_v.append(vals[:have])
-    dispatches = len(out_t)
-    _COLD_ROWS.inc(n)
-    _COLD_DISPATCHES.inc(dispatches)
-    if acc is not None:
-        acc.add_cost("cold_rows_n", n)
-        acc.add_cost("cold_dispatch_n", dispatches)
-    if dispatches == 1:
-        return out_t[0], out_v[0]
-    return np.concatenate(out_t), np.concatenate(out_v)
 
 
 def encode_block(block_start: int, series_indices, tdense, vdense, npoints,

@@ -20,6 +20,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from ..ops.decode_rows import decode_stacked
 from ..persist import commitlog as cl
 from ..persist.diskio import CorruptionError
 from ..persist.fs import FilesetReader, PersistManager, quarantine_fileset
@@ -577,13 +578,18 @@ def _install_encoded(shard, bs: int, built: SealedBlock):
 
 def _apply_mixed_unit_rows(shard, bs: int, rows: List[Tuple[int, dict]]):
     """Replicas sealed this block with different tick scales
-    (choose_time_unit diverged): decode each row at its own unit
-    (pow2-bucketed batched decode) and re-encode the tile uniformly."""
-    from ..client.decode import decode_segment_groups
+    (choose_time_unit diverged): decode each row at its own unit (one
+    call a geometry) and re-encode the tile uniformly."""
     from .block import encode_block
     from .buffer import to_dense
 
-    decoded = decode_segment_groups([b for _i, b in rows])
+    decoded: list = [(np.zeros(0, np.int64), np.zeros(0, np.float64))
+                     ] * len(rows)
+    for tile, ks, ts, vs in decode_stacked(
+            [dict(b, at=i, words=np.asarray(b["words"])[None],
+                  npoints=[b["npoints"]])
+             for i, (_idx, b) in enumerate(rows) if b["npoints"]]):
+        decoded[tile["at"]] = (ts[0, :ks[0]], vs[0, :ks[0]])
     sidx = np.concatenate([
         np.full(len(t), idx, np.int32)
         for (idx, _b), (t, _v) in zip(rows, decoded)])

@@ -9,9 +9,9 @@ in one step a (shard, block) (`SealedBlock.rows_of`). A block whose
 decoded planes the block cache holds answers with row slices. The rows
 of every other block are kept as PIECES (storage/tiles.py), gathered
 into tiles and decoded ONE DISPATCH A GEOMETRY for the whole fetch
-(`tiles.decode_stacked` over `block.decode_rows`: the mechanism the
-client's `Session._one_pass_points` decodes a fetch's frames with),
-never one a (series, block).
+(`ops/decode_rows.py`: `decode_stacked` over `decode_rows`, as the
+client's `Session._one_pass_points` decodes a fetch's frames), never
+one a (series, block).
 
 Admission (`DeviceBlockCache.offer`, after the fetch's cold decode). A
 block earns its place by touches (`admit_after`, a row read counting
@@ -32,12 +32,12 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..ops.decode_rows import ROW_BUCKETS, decode_rows, decode_stacked
 from ..persist.diskio import CorruptionError
-from ..utils import xtime
 from ..utils.tracing import clock_ns as _clock
 from . import block_cache
-from .block import ROW_BUCKETS, decode_rows
-from .tiles import decode_stacked, gather_tiles, piece_key
+from .block import count_cold
+from .tiles import gather_tiles, piece_key
 
 _NO_T = np.zeros(0, np.int64)
 _NO_V = np.zeros(0, np.float64)
@@ -74,8 +74,7 @@ def read_many(ns, shard_set, ids: Sequence[bytes], start_ns: int,
     `lock_wait_ns`, `buffer_ns`, `block_ns` / `block_n` (the sealed
     blocks' part, a (series, block) pair counting one: resolve, cache
     lookup, gather, decode), `merge_ns`, and of the cold rows
-    `cold_decode_ns` beside decode_rows' own `cold_rows_n` and
-    `cold_dispatch_n`."""
+    `cold_decode_ns`, `cold_rows_n` and `cold_dispatch_n`."""
     n = len(ids)
     out: List[Optional[tuple]] = [None] * n
     if not n:
@@ -189,13 +188,13 @@ def read_many(ns, shard_set, ids: Sequence[bytes], start_ns: int,
     t3 = _clock() if timed else 0
     cold_ns = 0
     if pieces:
-        def decode(words, npoints, window, unit):
+        def decode(words, npoints, window, unit_nanos):
             nonlocal cold_ns
             t = _clock() if timed else 0
-            got = decode_rows(words, npoints, window,
-                              xtime.Unit(unit).nanos, acc)
+            got = decode_rows(words, npoints, window, unit_nanos)
             if timed:
                 cold_ns += _clock() - t
+            count_cold(len(words), got[2], acc)
             return got
 
         for tile, ks, ts, vs in decode_stacked(

@@ -27,7 +27,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-from ..client.decode import decode_tile
+from ..ops.decode_rows import decode_stacked
 from ..utils.instrument import ROOT
 from ..utils.retry import Deadline, RetryOptions, Retrier
 from . import block_cache
@@ -197,13 +197,15 @@ class ShardRepairer:
         if local is not None:
             lts, lvs, lnp = local.read_all()
             flatten(np.asarray(local.series_indices), lts, lvs, lnp)
-        for tile in tlist:
-            pts, pvs = decode_tile(tile["words"], tile["npoints"],
-                                   int(tile["window"]),
-                                   int(tile["time_unit"]))
+        # one decode a geometry, the planes flattened in arrival order
+        planes: List[tuple] = [()] * len(tlist)
+        for tile, ks, pts, pvs in decode_stacked(
+                [dict(tile, at=i) for i, tile in enumerate(tlist)]):
+            planes[tile["at"]] = (pts, pvs, ks)
+        for tile, got in zip(tlist, planes):
             row_idx = np.fromiter((rank[sid] for sid in tile["ids"]),
                                   np.int32, count=len(tile["ids"]))
-            flatten(row_idx, pts, pvs, tile["npoints"])
+            flatten(row_idx, *got)
 
         sidx = np.concatenate(sidx_parts)
         ts = np.concatenate(t_parts)

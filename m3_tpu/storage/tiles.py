@@ -9,15 +9,12 @@ A (shard, block)'s wanted rows are resolved in one step
 caller's series list) under what a tile's rows must share — block start,
 window, time unit, words width; `gather_tiles` then makes one tile a
 key, cut at a row bound. The decode side stacks tiles of one geometry
-(window, time unit, words width) into one call (`decode_stacked`),
-whoever's decode it is: the client's `decode_stack` over ALL the frames
-a fetch holds (one decode a fetch, whichever replicas sent the tiles:
-client/session.py::_one_pass_points), the node's `decode_rows` over its
-own cold rows."""
+(window, time unit, words width) into one call, whoever asks
+(ops/decode_rows.py::decode_stacked)."""
 
 from __future__ import annotations
 
-from typing import Callable, Dict, Iterator, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
@@ -78,31 +75,3 @@ def gather_tiles(pieces: Dict[tuple, list], max_rows: int,
                 "time_unit": time_unit,
             })
     return tiles
-
-
-def decode_stacked(tiles: List[dict], decode: Callable
-                   ) -> Iterator[Tuple[dict, np.ndarray, np.ndarray,
-                                       np.ndarray]]:
-    """One decode a geometry, not one a tile: the decode is
-    row-independent, so the tiles of one window, time unit and words
-    width (a frame carries a tile per (shard, sealed block) of a series
-    or two) are stacked into one `decode(words, npoints, window,
-    time_unit) -> (ts [rows, window], vals)` call. Yields every tile in
-    block-start order with its point counts and its rows of the planes:
-    (tile, npoints, ts, vals)."""
-    groups: Dict[tuple, List[dict]] = {}
-    for tile in sorted(tiles, key=lambda d: d["bs"]):
-        groups.setdefault(
-            (int(tile["window"]), int(tile["time_unit"]),
-             int(np.asarray(tile["words"]).shape[-1])), []).append(tile)
-    for (window, unit, _width), members in groups.items():
-        words = [np.asarray(t["words"]) for t in members]
-        npts = [np.asarray(t["npoints"], np.int32) for t in members]
-        one = len(members) == 1
-        ts, vs = decode(words[0] if one else np.concatenate(words),
-                        npts[0] if one else np.concatenate(npts),
-                        window, unit)
-        at = 0
-        for tile, ks in zip(members, npts):
-            yield tile, ks, ts[at:at + len(ks)], vs[at:at + len(ks)]
-            at += len(ks)

@@ -116,33 +116,94 @@ def test_the_namespace_list_cell_ships_what_it_names():
     assert sizes == {"default": "20m", "metrics_1m_72h": "2h"}
 
 
-def _metrics():
+def _readings():
+    """(kind, entry, cell) for every metric of BENCHMARK.json, once for
+    each cell that reports it (an entry with no list: every cell): a
+    reading folded into its base's `workloads` keeps its case."""
     with open(os.path.join(ROOT_DIR, "BENCHMARK.json")) as f:
         bench = json.load(f)
+    cells = [w["name"] for w in bench["workloads"]]
     for kind, key in (("end_to_end", "end_to_end"),
                       ("layer_metrics", "per_layer")):
         for m in bench[key]:
-            yield pytest.param(kind, m, id=m["name"])
+            for c in m.get("workloads", cells):
+                yield pytest.param(kind, m, c, bench, id=m["name"] + "@" + c)
 
 
-@pytest.mark.parametrize("kind,decl", list(_metrics()))
-def test_every_metric_has_its_reader_and_its_declaration(kind, decl,
-                                                         monkeypatch):
-    """A metric of BENCHMARK.json is a reader file found by its name
+@pytest.mark.parametrize("kind,decl,workload,bench", list(_readings()))
+def test_every_metric_has_its_reader_and_its_declaration(
+        kind, decl, workload, bench, monkeypatch):
+    """One case a (metric, cell) pair (the tier-1 copy of
+    benchmark/tests/test_seams.py::
+    test_every_reading_of_every_cell_has_its_reader_and_its_declaration).
+    A metric of BENCHMARK.json is a reader file found by its name
     (`read(m)`), and a per-layer one a declaration beside it that mirrors
-    the entry; the cells it lists exist and report what it moves."""
+    the entry; the cell exists, `load_cell` hands the entry to its run,
+    and the cell is one the end-to-end metric the reading moves lists."""
     monkeypatch.syspath_prepend(BENCH_DIR)
     from harness import spec
 
     assert callable(spec.load_reader(kind, decl["name"]))
+    assert workload in {w["name"] for w in bench["workloads"]}
+    cell = spec.load_cell(workload, bench)
+    if kind == "end_to_end":
+        assert decl in cell.end_to_end
+        return
+    assert decl in cell.per_layer
+    with open(os.path.join(BENCH_DIR, kind, decl["name"] + ".json")) as f:
+        assert json.load(f) == decl
+    assert decl["moves"] in {m["name"] for m in cell.end_to_end}
+
+
+def _per_layer():
     with open(os.path.join(ROOT_DIR, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    cells = {w["name"] for w in bench["workloads"]}
-    assert set(decl.get("workloads", ())) <= cells
-    if kind == "layer_metrics":
-        with open(os.path.join(BENCH_DIR, kind, decl["name"] + ".json")) as f:
-            assert json.load(f) == decl
-        moved = next(m for m in bench["end_to_end"]
-                     if m["name"] == decl["moves"])
-        assert set(decl.get("workloads", ())) <= set(
-            moved.get("workloads", cells))
+        return {m["name"]: m for m in json.load(f)["per_layer"]}
+
+
+def test_a_folded_name_is_no_entry_and_its_base_lists_its_cells():
+    """One entry a reading: a twin that a `benchmark` PR folded into its
+    base (benchmark/tools/folded_names.json) has no entry, declaration
+    or reader any more, and the base it names is an entry."""
+    with open(os.path.join(BENCH_DIR, "tools", "folded_names.json")) as f:
+        folded = json.load(f)
+    names = set(_per_layer())
+    assert folded and not set(folded) & names
+    assert set(folded.values()) <= names
+    for old in folded:
+        for ext in (".json", ".py"):
+            assert not os.path.exists(os.path.join(
+                BENCH_DIR, "layer_metrics", old + ext)), old + ext
+
+
+def test_a_twin_still_to_fold_reads_with_its_bases_code(monkeypatch):
+    """The twins this tree still holds (an entry whose `moves`, unit,
+    source and layer are another entry's and whose name is that entry's
+    stem plus a cell's suffix) stay safe to fold: each is a forwarder to
+    its base's reader or a copy of its body, `device_idle_share.rf3`
+    alone apart (the busiest device, which on one chip is the chip)."""
+    import ast
+
+    monkeypatch.syspath_prepend(BENCH_DIR)
+    from harness import spec
+
+    def body(name):
+        with open(os.path.join(BENCH_DIR, "layer_metrics",
+                               name + ".py")) as f:
+            tree = ast.parse(f.read())
+        return [ast.dump(n) for n in tree.body
+                if not (isinstance(n, ast.Expr)
+                        and isinstance(n.value, ast.Constant))]
+
+    by_name = _per_layer()
+    for name, m in by_name.items():
+        stem, _, suffix = name.rpartition(".")
+        base = by_name.get(stem) or by_name.get(stem + ".query")
+        if not suffix or base is None or base is m or any(
+                base[k] != m[k] for k in ("unit", "better", "source",
+                                          "layer", "moves")):
+            continue
+        read = spec.load_reader("layer_metrics", name)
+        forwarded = os.path.basename(read.__code__.co_filename) \
+            == base["name"] + ".py"
+        assert forwarded or body(name) == body(base["name"]) \
+            or name == "device_idle_share.rf3", name

@@ -116,7 +116,7 @@ class TestCachedDecodeBitIdentity:
         for plane in (t, v):
             assert plane.flags.c_contiguous and not plane.flags.writeable
         assert t.dtype == np.int64 and v.dtype == np.float64
-        padded = block_mod.row_bucket(rows) != rows
+        padded = rows not in block_mod.ROW_BUCKETS
         assert (t.base is not None and t.base.nbytes > t.nbytes) or not padded
         with block_cache.disabled():
             want = blk.read_all()

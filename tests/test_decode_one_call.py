@@ -12,13 +12,15 @@ import numpy as np
 import pytest
 
 from m3_tpu.ops import ref_codec, tsz
+from m3_tpu.ops.decode_rows import ROW_BUCKETS
 from m3_tpu.utils import instrument
 
 
-# One decode call's protocol: rows x values x time unit x route. The
-# 256- and 1,024-row shapes run on the XLA route only (interpret mode
-# pays wall time by the row).
-_PROTO_ROWS = (1, 2, 8, 16, 64, 256, 1024)
+# One decode call's protocol: rows x values x time unit x route, the
+# rows a lone row, a pair and every rung a caller's rows are padded to.
+# The shapes past 64 rows run on the XLA route only (interpret mode pays
+# wall time by the row).
+_PROTO_ROWS = (1, 2) + ROW_BUCKETS
 _PROTO_CASES = [
     (rows, kind, unit, route)
     for rows in _PROTO_ROWS

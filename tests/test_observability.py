@@ -548,8 +548,8 @@ class TestTelemetry:
         assert sp.costs == {"h2d_bytes": 1024, "d2h_bytes": 2048}
 
     def test_decode_records_bucket(self):
-        from m3_tpu.client.decode import decode_segment_groups
         from m3_tpu.ops import tsz
+        from m3_tpu.ops.decode_rows import decode_stacked
         from m3_tpu.utils.instrument import ROOT
 
         ts = np.arange(T0, T0 + 5 * S, S, np.int64)
@@ -560,19 +560,18 @@ class TestTelemetry:
             inp["dt"], inp["t0"], inp["vhi"], inp["vlo"], inp["int_mode"],
             inp["k"], inp["npoints"], inp["ts_regular"], inp["delta0"],
             max_words=64)
-        seg = {"bs": T0, "words": np.asarray(words[0]),
-               "nbits": int(nbits[0]), "npoints": 5, "window": 8,
-               "time_unit": 4}
+        tile = {"bs": T0, "words": np.asarray(words[:1]),
+                "nbits": np.asarray(nbits[:1]), "npoints": [5], "window": 8,
+                "time_unit": 4}
         before = ROOT.snapshot().get("telemetry.shape_bucket.misses", 0)
-        out = decode_segment_groups([seg])
-        np.testing.assert_array_equal(out[0][1], vals)
+        (_tile, _ks, _ts, out), = decode_stacked([tile])
+        np.testing.assert_array_equal(out[0, :5], vals)
         after = ROOT.snapshot()["telemetry.shape_bucket.misses"]
         assert after >= before  # first geometry may or may not be new
         snap = ROOT.snapshot()
-        assert (snap.get("telemetry.shape_bucket.misses{path=client.decode}",
-                         0)
-                + snap.get("telemetry.shape_bucket.hits{path=client.decode}",
-                           0)) >= 1
+        path = "{path=block.decode_plane}"
+        assert (snap.get("telemetry.shape_bucket.misses" + path, 0)
+                + snap.get("telemetry.shape_bucket.hits" + path, 0)) >= 1
 
 
 # ------------------------------------------------- /debug surface satellites

@@ -9,6 +9,7 @@ import numpy as np
 import pytest
 
 from m3_tpu.index.namespace_index import NamespaceIndex
+from m3_tpu.ops import decode_rows as rows_mod
 from m3_tpu.parallel import scope as dscope
 from m3_tpu.parallel.sharding import ShardSet
 from m3_tpu.query.model import Matcher, MatchType
@@ -150,13 +151,13 @@ def test_a_lone_miss_is_one_dispatch_and_never_a_one_row_program(
         store, cache, monkeypatch):
     cache(1 << 30, admit_after=10**9)
     shapes = []
-    real = block_mod._dispatch_decode
+    real = rows_mod._call
 
-    def spy(words, npoints, window, unit_nanos):
+    def spy(words, *args):
         shapes.append(np.shape(words))
-        return real(words, npoints, window, unit_nanos)
+        return real(words, *args)
 
-    monkeypatch.setattr(block_mod, "_dispatch_decode", spy)
+    monkeypatch.setattr(rows_mod, "_call", spy)
     sid = store.ids[3]
     assert_same(store, [sid], T0 + BLOCK, T0 + 2 * BLOCK)
     # the batched read's one dispatch, then the per-row read's

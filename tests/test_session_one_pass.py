@@ -13,11 +13,12 @@ import numpy as np
 import pytest
 
 from m3_tpu.client import ConflictStrategy, Session, SessionOptions
-from m3_tpu.client import decode as client_decode
-from m3_tpu.client.decode import decode_tile, merge_replica_points
+from m3_tpu.client.decode import merge_replica_points
 from m3_tpu.client.session import _ReadCosts
 from m3_tpu.cluster.topology import ReadConsistencyLevel
 from m3_tpu.index import query as iq
+from m3_tpu.ops import decode_rows as rows_mod
+from m3_tpu.ops.decode_rows import decode_rows
 from m3_tpu.parallel import scope as dscope, telemetry
 from m3_tpu.rpc import wire
 from m3_tpu.storage.block import encode_block
@@ -53,6 +54,12 @@ def tile_of(bs, rows, points, window):
     return {"bs": bs, "rows": np.asarray(rows, np.int32), "words": blk.words,
             "nbits": blk.nbits, "npoints": blk.npoints,
             "window": blk.window, "time_unit": int(blk.time_unit)}
+
+
+def tile_planes(tile):
+    """A tile's rows decoded by themselves: (ts [rows, window], vals)."""
+    return decode_rows(tile["words"], tile["npoints"], tile["window"],
+                       xtime.Unit(tile["time_unit"]).nanos)[:2]
 
 
 def replica_frame(rng, ids, tags, windows, no_tiles=False):
@@ -118,8 +125,7 @@ def sealed_points(frame):
     """A frame's sealed points a position: [(t, v), ...] in tile order."""
     out = [[] for _ in frame["series"]]
     for tile in frame["tiles"]:
-        ts, vs = decode_tile(tile["words"], tile["npoints"], tile["window"],
-                             tile["time_unit"])
+        ts, vs = tile_planes(tile)
         for j, (pos, k) in enumerate(zip(tile["rows"].tolist(),
                                          tile["npoints"].tolist())):
             out[pos].append((ts[j, :k], vs[j, :k]))
@@ -130,8 +136,7 @@ def shifted(frame, by):
     """The frame with every point of it `by` later (tiles re-encoded)."""
     tiles = []
     for tile in frame["tiles"]:
-        ts, vs = decode_tile(tile["words"], tile["npoints"], tile["window"],
-                             tile["time_unit"])
+        ts, vs = tile_planes(tile)
         tiles.append(tile_of(
             tile["bs"] + by, tile["rows"],
             [(ts[j, :k] + by, vs[j, :k])
@@ -150,8 +155,7 @@ def fold(frames, strategy):
         parts_t = [[] for _ in range(n)]
         parts_v = [[] for _ in range(n)]
         for tile in sorted(r["tiles"], key=lambda d: d["bs"]):
-            ts, vs = decode_tile(tile["words"], tile["npoints"],
-                                 tile["window"], tile["time_unit"])
+            ts, vs = tile_planes(tile)
             for j, (pos, k) in enumerate(zip(tile["rows"].tolist(),
                                              tile["npoints"].tolist())):
                 parts_t[pos].append(ts[j, :k])
@@ -298,7 +302,7 @@ def test_a_geometrys_first_decode_compiles_every_bucket_a_stack_can_reach(
     the power-of-two buckets up to the row bound through their compile;
     after it no stack of one, two or three responders' rows, nor one
     past the bound, compiles a program."""
-    monkeypatch.setattr(client_decode, "_compiles_are_dear", lambda: True)
+    monkeypatch.setattr(rows_mod, "_compiles_are_dear", lambda: True)
     seen = []
     real = telemetry.record_bucket
     monkeypatch.setattr(
@@ -308,7 +312,7 @@ def test_a_geometrys_first_decode_compiles_every_bucket_a_stack_can_reach(
     tile = tile_of(T0, [0, 1, 2], [
         (T0 + np.arange(4) * STEP, rng.integers(0, 9, 4).astype(np.float64))
         for _ in range(3)], 4)
-    args = (tile["window"], tile["time_unit"])
+    args = (tile["window"], xtime.Unit(tile["time_unit"]).nanos)
     width = tile["words"].shape[-1]
     compiles = []
 
@@ -317,20 +321,20 @@ def test_a_geometrys_first_decode_compiles_every_bucket_a_stack_can_reach(
             compiles.append(event)
 
     with dscope.DeviceScope([6], "warm-test"):
-        ts, vs, calls = client_decode.decode_stack(
+        ts, vs, calls = decode_rows(
             tile["words"], tile["npoints"], *args)
         assert calls == 1 and len(ts) == 3
         buckets = [key[0] for path, key in seen
-                   if path == "client.decode_tile"
+                   if path == "block.decode_plane"
                    and key[1:] == (width, tile["window"])]
         assert buckets == [8, 16, 32, 64, 128, 256, 512, 1024, 8]
         jax.monitoring.register_event_duration_secs_listener(listener)
         try:
             for rows in (1, 8, 9, 24, 72, 240, 480, 720, 1024, 1025, 2100):
                 at = rng.integers(0, 3, rows)
-                got_t, got_v, calls = client_decode.decode_stack(
+                got_t, got_v, calls = decode_rows(
                     tile["words"][at], tile["npoints"][at], *args)
-                assert calls == -(-rows // client_decode.STACK_MAX_ROWS)
+                assert calls == -(-rows // rows_mod.ROW_BUCKETS[-1])
                 np.testing.assert_array_equal(got_t, ts[at])
                 assert got_v.tobytes() == vs[at].tobytes()
         finally:
@@ -338,11 +342,11 @@ def test_a_geometrys_first_decode_compiles_every_bucket_a_stack_can_reach(
         assert not compiles
         # warmed once a geometry a scope: the next decode warms nothing
         del seen[:]
-        client_decode.decode_stack(tile["words"], tile["npoints"], *args)
+        decode_rows(tile["words"], tile["npoints"], *args)
         assert [key[0] for _path, key in seen] == [8]
     # another scope's device has programs of its own to compile
-    monkeypatch.setattr(client_decode, "STACK_MAX_ROWS", 16)
+    monkeypatch.setattr(rows_mod, "ROW_BUCKETS", (8, 16))
     with dscope.DeviceScope([7], "another-scope"):
         del seen[:]
-        client_decode.decode_stack(tile["words"], tile["npoints"], *args)
+        decode_rows(tile["words"], tile["npoints"], *args)
         assert [key[0] for _path, key in seen] == [8, 16, 8]
