@@ -46,6 +46,11 @@ DENSE_FREE_LANES = 4096
 
 _WINDOWS = ROOT.counter("query.range_selector.windows")
 _SAMPLES = ROOT.counter("query.range_selector.samples")
+# Which layout a range selector took, once a selector evaluated.
+_LAYOUT_DENSE = ROOT.sub_scope("query.range_selector",
+                               layout="dense").counter("layouts")
+_LAYOUT_PACKED = ROOT.sub_scope("query.range_selector",
+                                layout="packed").counter("layouts")
 
 
 @dataclasses.dataclass
@@ -133,7 +138,11 @@ def _layout(series, params, range_ns, offset_ns, consolidate):
     lanes = W + (steps - 1) * stride
     if W < 1 or len(items) * lanes > (DENSE_LANES_PER_SAMPLE * n
                                       + DENSE_FREE_LANES):
-        return _packed(items, groups, x0, steps, step, range_ns)
+        _LAYOUT_PACKED.inc()
+        # the packed layout's share of the `window` phase
+        with tracing.phase("window_pack"):
+            return _packed(items, groups, x0, steps, step, range_ns)
+    _LAYOUT_DENSE.inc()
     first = phase + (k_open + 1) * cell
     meta = BlockMeta(first, cell, lanes)
     tags, values = consolidate(meta, cell)

@@ -132,6 +132,10 @@ class DeviceBlockCache:
         self._admitted = scope.counter("admitted")
         self._retained = scope.counter("retained")
         self._fill_errors = scope.counter("fill_errors")
+        # the fill thread waited its whole stand-back for a quiet moment
+        # and got none (utils/foreground.py)
+        self._fill_quiet_timeouts = scope.sub_scope("fill").counter(
+            "quiet_timeouts")
         self._bytes_gauge = scope.gauge("bytes")
         # Per-instance tallies (the instrument scope aggregates
         # process-wide by name — the postings-cache convention).
@@ -282,7 +286,8 @@ class DeviceBlockCache:
         queue."""
         with scope:
             while True:
-                foreground.wait_quiet(FILL_STANDS_BACK_S)
+                if not foreground.wait_quiet(FILL_STANDS_BACK_S):
+                    self._fill_quiet_timeouts.inc()
                 with self._lock:
                     if not self._fill:
                         self._filler = None

@@ -26,6 +26,7 @@ that the append stays three slice stores."""
 from __future__ import annotations
 
 import dataclasses
+import time
 from bisect import bisect_left
 from typing import Dict, Iterable, List, Optional, Tuple
 
@@ -40,6 +41,11 @@ _READ_INDEXED = ROOT.counter("storage.buffer.read.indexed")
 _READ_TAIL_SCANS = ROOT.counter("storage.buffer.read.tail_scans")
 _INDEX_BUILDS = ROOT.counter("storage.buffer.index.builds")
 _INDEX_ROWS = ROOT.counter("storage.buffer.index.rows")
+# What an open bucket that is being appended to costs its reads: the
+# rows a tail scan looked through, and the time spent grouping a bucket
+# again (under the shard lock) once its tail had outgrown the index.
+_READ_TAIL_ROWS = ROOT.counter("storage.buffer.read.tail_rows")
+_INDEX_REGROUP_NS = ROOT.counter("storage.buffer.index.regroup_ns")
 _NO_ROWS = np.zeros(0, np.intp)
 
 
@@ -117,6 +123,7 @@ class BlockBucket:
         tail = n - self.indexed_n
         if self.order is not None and tail <= self.indexed_n:
             return tail
+        t0 = time.perf_counter_ns()
         sidx = self.cols.sidx[:n]
         self.order = np.argsort(sidx, kind="stable")
         counts = np.bincount(sidx)
@@ -125,6 +132,7 @@ class BlockBucket:
         self.indexed_n = n
         _INDEX_BUILDS.inc()
         _INDEX_ROWS.inc(n)
+        _INDEX_REGROUP_NS.inc(time.perf_counter_ns() - t0)
         return 0
 
 
@@ -239,6 +247,7 @@ class ShardBuffer:
                 rows = b.order[b.bounds[series_idx]:b.bounds[series_idx + 1]]
             if tail:
                 _READ_TAIL_SCANS.inc()
+                _READ_TAIL_ROWS.inc(tail)
                 late = np.flatnonzero(
                     cols.sidx[b.indexed_n:cols.n] == series_idx)
                 if len(late):

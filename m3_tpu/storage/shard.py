@@ -34,6 +34,13 @@ _CORRUPTION = ROOT.sub_scope("storage.corruption")
 # Series a write handed to the reverse index again because they had
 # crossed into an index block that did not hold them yet.
 _REINDEXED = ROOT.counter("index.insert.reindexed")
+# Blocks a tick sealed, by the time unit their streams took
+# (block.choose_time_unit): whole-second scrapes seal at SECOND,
+# Prometheus' millisecond offsets at MILLISECOND.
+_SEALED_BY_UNIT = {
+    u: ROOT.sub_scope("storage.block", unit=u.name.lower()).counter("sealed")
+    for u in (xtime.Unit.MINUTE, xtime.Unit.SECOND, xtime.Unit.MILLISECOND,
+              xtime.Unit.MICROSECOND, xtime.Unit.NANOSECOND)}
 
 
 class ShardState(enum.Enum):
@@ -383,6 +390,7 @@ class Shard:
                     cache.invalidate_block(blk)
                     blk = merged
                 self.blocks[bs] = blk
+                _SEALED_BY_UNIT[blk.time_unit].inc()
                 # Hot tier: adopt the seal's still-device-resident encode
                 # output so warm reads decode without re-uploading it.
                 cache.retain_encoded(blk, self.namespace_name, self.shard_id)
