@@ -258,8 +258,9 @@ class NodeService:
         identity (id + tags — host label algebra); the data plane rides
         beside them as ONE buffer sidecar (concatenated mutable-buffer
         columns + an offsets vector) and one TILE PER BLOCK START — the
-        requested rows of every shard's block at that start, fancy-
-        indexed out of the blocks' word matrices and concatenated, with
+        requested rows of every shard's block at that start, gathered
+        out of the blocks' word matrices into one array a column
+        (storage/tiles.py), with
         `rows` their positions in `series`: the tile shape peer
         streaming moves (rpc_fetch_block_tiles) and the client's batched
         device decode consumes (ops/decode_rows.py). Blocks of
@@ -269,7 +270,8 @@ class NodeService:
         per-series segments instead (DIVERGENCES.md). A dashboard read
         puts a series or two in a shard, so nothing here is paid once a
         series but its buffer read, nor once a (shard, block) but its
-        row resolve and three gathers."""
+        row resolve; a tile's array operations do not grow with its
+        pieces."""
         q = wire.query_from_wire(query)
         nsobj = self.db.namespace(ns)
         # Under a detailed span (a traced request's rpc.fetch_tagged) the
@@ -373,23 +375,22 @@ class NodeService:
         t_tiles = _clock() if timed else 0
         # A (shard, block)'s rows are resolved in one step and kept as a
         # PIECE under what a tile's rows must share: block start, window,
-        # time unit, words width.
+        # time unit, words width. A block that holds every index of its
+        # shard (every series written in every block) leaves the piece
+        # its plain ints: no array is made a group or a piece.
         pieces: Dict[tuple, list] = {}
         for (shard, idxs, poss), blocks in zip(groups, snapshots):
-            idxs_a = None
+            top = max(idxs)
             for bs, blk in blocks.items():
                 if bs + shard.opts.block_size_ns <= start_ns or bs >= end_ns:
                     continue
                 if not len(blk.series_indices):
                     continue
-                if idxs_a is None:
-                    idxs_a, poss_a = np.asarray(idxs), np.asarray(poss)
-                    top = max(idxs)
-                at, present = blk.rows_of(idxs_a, top)
+                at, present = blk.rows_of(idxs, top)
                 if present is None:
-                    piece = (blk, at, poss_a)
+                    piece = (blk, at, poss)
                 elif len(at):
-                    piece = (blk, at, poss_a[present])
+                    piece = (blk, at, np.asarray(poss)[present])
                 else:
                     continue
                 pieces.setdefault(piece_key(blk), []).append(piece)
@@ -402,7 +403,7 @@ class NodeService:
             self._check_deadline("fetch_tagged")
             charge_read(n_bytes=n_bytes)
 
-        tiles = gather_tiles(pieces, TILE_MAX_ROWS, before_tile)
+        tiles = gather_tiles(pieces, TILE_MAX_ROWS, before_tile, acc)
         tile_ns = _clock() - t_tiles if timed else 0
         offs = np.zeros(n + 1, np.int64)
         if n:

@@ -143,13 +143,14 @@ class SealedBlock:
             return i
         return None
 
-    def rows_of(self, idxs: np.ndarray, top: int
+    def rows_of(self, idxs, top: int
                 ) -> Tuple[np.ndarray, Optional[np.ndarray]]:
-        """The rows that hold registry indices `idxs` (an array whose
-        largest is `top`), resolved in one step: (rows, present), where
-        `present` masks the `idxs` this block holds and is None where it
-        holds them all. A block whose sorted indices end at their own
-        length holds every index below it, each in its own row."""
+        """The rows that hold registry indices `idxs` (an array or a
+        list of ints whose largest is `top`), resolved in one step:
+        (rows, present), where `present` masks the `idxs` this block
+        holds and is None where it holds them all. A block whose sorted
+        indices end at their own length holds every index below it, each
+        in its own row: `idxs` itself is handed back, no array made."""
         si = self.series_indices
         held = len(si)
         if held and si[-1] == held - 1 and top < held:

@@ -74,7 +74,8 @@ def read_many(ns, shard_set, ids: Sequence[bytes], start_ns: int,
     `lock_wait_ns`, `buffer_ns`, `block_ns` / `block_n` (the sealed
     blocks' part, a (series, block) pair counting one: resolve, cache
     lookup, gather, decode), `merge_ns`, and of the cold rows
-    `cold_decode_ns`, `cold_rows_n` and `cold_dispatch_n`."""
+    `cold_decode_ns`, `cold_rows_n`, `cold_dispatch_n` and
+    `tile_gathers_n` (the array operations that gathered them)."""
     n = len(ids)
     out: List[Optional[tuple]] = [None] * n
     if not n:
@@ -198,7 +199,7 @@ def read_many(ns, shard_set, ids: Sequence[bytes], start_ns: int,
             return got
 
         for tile, ks, ts, vs in decode_stacked(
-                gather_tiles(pieces, ROW_BUCKETS[-1]), decode):
+                gather_tiles(pieces, ROW_BUCKETS[-1], acc=acc), decode):
             scatter(tile["bs"], ts, vs, range(len(ks)),
                     tile["rows"].tolist(), ks.tolist())
         if cache is not None:

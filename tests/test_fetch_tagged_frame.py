@@ -3,7 +3,11 @@ query's ids: what the client recovers from it equals a per-series
 `db.read`; it carries one tile a (block start, window, unit, width),
 cut at the row bound; a seal between two buffer chunks hides no point;
 the bytes-read limit and the deadline stop it inside the frame;
-`rpc_query` returns the identity sweep alone."""
+`rpc_query` returns the identity sweep alone; and the tiles
+`gather_tiles` makes of a frame's pieces equal a plain gather (a fancy
+take a piece a column, concatenated) in value, dtype, shape and
+contiguity, charged tile by tile before they exist, with a number of
+array operations a tile that does not grow with its pieces."""
 
 import numpy as np
 import pytest
@@ -14,11 +18,12 @@ from m3_tpu.index.namespace_index import NamespaceIndex
 from m3_tpu.parallel.sharding import ShardSet
 from m3_tpu.rpc import node_server, wire
 from m3_tpu.rpc.node_server import NodeService
+from m3_tpu.storage.block import SealedBlock
 from m3_tpu.storage.database import Database
 from m3_tpu.storage import tiles
 from m3_tpu.storage.namespace import NamespaceOptions
 from m3_tpu.utils import limits as xlimits
-from m3_tpu.utils import xtime
+from m3_tpu.utils import tracing, xtime
 from m3_tpu.utils.limits import LimitOptions, QueryLimits, ResourceExhausted
 from m3_tpu.utils.retry import Deadline, DeadlineExceeded
 
@@ -328,3 +333,175 @@ def test_ids_of_shards_this_node_does_not_hold_leave_no_row():
         if sid != GHOST:
             np.testing.assert_array_equal(
                 got[sid][0], node.db.read(NS, sid, T0, node.end)[0])
+
+
+# ------------------------------------------------- the gather, piece by piece
+
+
+def sealed(rng, bs, held, window=32, width=40):
+    """A block of `held` registry indices (sorted), random words."""
+    held = np.asarray(held, np.int32)
+    return SealedBlock(
+        block_start=bs, window=window, series_indices=held,
+        words=rng.integers(0, 2**32, (len(held), width), dtype=np.uint32),
+        nbits=rng.integers(1, 32 * width, len(held)).astype(np.int32),
+        npoints=rng.integers(1, window, len(held)).astype(np.int32),
+        time_unit=xtime.Unit.SECOND)
+
+
+def pieces_of(groups, as_lists):
+    """The node's piece loop over (blocks, wanted registry indices)
+    groups, positions numbered through the groups: a piece's rows and
+    positions as plain ints where the block holds every index and the
+    caller has lists (the node RPC), as arrays otherwise (the cold
+    read)."""
+    pieces, pos = {}, 0
+    for blocks, idxs in groups:
+        poss = list(range(pos, pos + len(idxs)))
+        pos += len(idxs)
+        if not as_lists:
+            idxs, poss = np.asarray(idxs), np.asarray(poss)
+        for blk in blocks:
+            at, present = blk.rows_of(idxs, int(max(idxs)))
+            if present is not None:
+                poss_b = np.asarray(poss)[present]
+            else:
+                poss_b = poss
+            if len(at):
+                pieces.setdefault(tiles.piece_key(blk), []).append(
+                    (blk, at, poss_b))
+    return pieces
+
+
+def plain_tiles(pieces, max_rows):
+    """The reference: a fancy take a piece a column, concatenated a key,
+    cut at the bound."""
+    out = []
+    for key in sorted(pieces):
+        bs, window, unit, width = key
+        cols = {
+            "rows": np.concatenate([np.asarray(poss) for _, _, poss in
+                                    pieces[key]]).astype(np.int32),
+            "words": np.concatenate([np.asarray(blk.words)[np.asarray(at)]
+                                     for blk, at, _ in pieces[key]]),
+            "nbits": np.concatenate([np.asarray(blk.nbits)[np.asarray(at)]
+                                     for blk, at, _ in pieces[key]]
+                                    ).astype(np.int32),
+            "npoints": np.concatenate([np.asarray(blk.npoints)[np.asarray(at)]
+                                       for blk, at, _ in pieces[key]]
+                                      ).astype(np.int32),
+        }
+        for lo in range(0, len(cols["rows"]), max_rows):
+            out.append({"bs": bs, "window": window, "time_unit": unit,
+                        **{k: np.ascontiguousarray(v[lo:lo + max_rows])
+                           for k, v in cols.items()}})
+    return out
+
+
+def grid_case(rows_a_piece, shards, starts=1, held=625):
+    def build(rng):
+        blocks = [[sealed(rng, T0 + b * BLOCK, range(held))
+                   for b in range(starts)] for _ in range(shards)]
+        return [(blks, sorted(rng.choice(held, rows_a_piece,
+                                         replace=False).tolist()))
+                for blks in blocks]
+    return build
+
+
+def lacking_case(rng):
+    """Blocks that lack a series: one without index 3 (its rows are not
+    its indices in a row), one that holds none of the wanted."""
+    whole = sealed(rng, T0, range(12))
+    gap = sealed(rng, T0, [i for i in range(12) if i != 3])
+    none = sealed(rng, T0, [0, 1])
+    return [([whole], [2, 3, 7]), ([gap], [2, 3, 7]), ([none], [5, 9]),
+            ([gap], [3])]
+
+
+def two_geometries_case(rng):
+    """One block start, two windows and two words widths."""
+    return [([sealed(rng, T0, range(9), window=8, width=12)], [0, 4, 8]),
+            ([sealed(rng, T0, range(9), window=32, width=40)], [1, 2]),
+            ([sealed(rng, T0, range(9), window=32, width=12)], [5])]
+
+
+GATHERS = {
+    **{"%d-row pieces x %d shards" % (k, sh): (grid_case(k, sh), 4096)
+       for k in (1, 2, 7, 625) for sh in (1, 3, 20)},
+    "3 tiles x 23 one-row pieces": (grid_case(1, 23, starts=3, held=60), 4096),
+    "a block lacks a series": (lacking_case, 4096),
+    "a cut straddles the bound": (grid_case(7, 3, held=40), 5),
+    "a piece of many rows straddles the bound": (
+        grid_case(600, 2), 512),
+    "two geometries at one start": (two_geometries_case, 4096),
+}
+
+
+@pytest.mark.parametrize("as_lists", [True, False], ids=["ints", "arrays"])
+@pytest.mark.parametrize("name", GATHERS)
+def test_gathered_tiles_equal_the_plain_gather(name, as_lists, monkeypatch):
+    from m3_tpu.utils.instrument import ROOT
+
+    build, bound = GATHERS[name]
+    pieces = pieces_of(build(np.random.default_rng(47)), as_lists)
+    want = plain_tiles(pieces, bound)
+    made = counting_columns(monkeypatch)
+    charged = []
+    before = {k: ROOT.counter("storage.tiles." + k).value()
+              for k in ("gathers", "rows")}
+    got = tiles.gather_tiles(
+        pieces, bound, lambda n_bytes: charged.append((n_bytes, made())))
+    moved = {k: ROOT.counter("storage.tiles." + k).value() - v
+             for k, v in before.items()}
+    assert len(got) == len(want) and len(want)
+    for g, w in zip(got, want):
+        assert set(g) == set(w)
+        for k in ("bs", "window", "time_unit"):
+            assert g[k] == w[k]
+        for k in ("rows", "words", "nbits", "npoints"):
+            assert g[k].dtype == w[k].dtype and g[k].shape == w[k].shape
+            assert g[k].flags["C_CONTIGUOUS"] and g[k].flags["OWNDATA"]
+            np.testing.assert_array_equal(g[k], w[k])
+    # charged tile by tile, each before its columns exist, the bytes of
+    # its words
+    assert charged == [(w["words"].nbytes, i) for i, w in enumerate(want)]
+    # the array operations: four a tile and three a many-row piece,
+    # whatever the number of pieces
+    many = sum(len(at) > tiles.PIECE_TAKE_ROWS for key in pieces
+               for cut in tiles.cut_rows(pieces[key], bound)
+               for _, at, _ in cut)
+    assert moved == {"gathers": 4 * len(want) + 3 * many,
+                     "rows": sum(len(w["rows"]) for w in want)}
+    if name == "3 tiles x 23 one-row pieces":
+        assert len(want) == 3 and moved["gathers"] == 12
+        assert sum(len(v) for v in pieces.values()) == 69
+
+
+@pytest.mark.parametrize("bound", [None, 5, 1])
+def test_a_frames_tiles_are_the_plain_gather_of_its_pieces(
+        node, monkeypatch, bound):
+    """The node's own piece loop: blocks that lack a series, two
+    geometries at one start, cuts at the bound."""
+    if bound is not None:
+        monkeypatch.setattr(node_server, "TILE_MAX_ROWS", bound)
+    seen = []
+    real = node_server.gather_tiles
+
+    def gather(pieces, *rest):
+        seen.append(pieces)
+        return real(pieces, *rest)
+
+    monkeypatch.setattr(node_server, "gather_tiles", gather)
+    frame, sp = node.svc.dispatch_traced(
+        "fetch_tagged", node.args(), trace_ctx=tracing.SpanContext(47, 1))
+    want = plain_tiles(seen[0], bound or node_server.TILE_MAX_ROWS)
+    assert len(frame["tiles"]) == len(want) == sp["costs"]["tiles_n"]
+    for g, w in zip(frame["tiles"], want):
+        for k in ("rows", "words", "nbits", "npoints"):
+            assert g[k].dtype == w[k].dtype and g[k].shape == w[k].shape
+            assert g[k].flags["C_CONTIGUOUS"]
+            np.testing.assert_array_equal(g[k], w[k])
+    # a piece whose block holds every index carries plain ints
+    kinds = {type(at) for v in seen[0].values() for _, at, _ in v}
+    assert kinds == {list, np.ndarray}
+    assert sp["costs"]["tile_gathers_n"] == 4 * len(want)

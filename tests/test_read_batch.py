@@ -193,6 +193,31 @@ def test_cold_rows_cost_a_dispatch_a_geometry_not_a_pair(store, cache,
 ANATOMY = ("h2d", "launch", "device_wait", "d2h", "layout")
 
 
+def test_a_cold_fetch_gathers_a_tile_in_four_array_operations(store, cache):
+    """Nothing admitted: every (series, block) row is gathered cold. The
+    points are the per-row read's bit for bit, and the gathers are four
+    a tile however many (shard, block) pieces a tile holds."""
+    cache(1 << 30, admit_after=10**9)
+    tracer = tracing.Tracer(sample_rate=1.0)
+    before = {k: ROOT.counter("storage.tiles." + k).value()
+              for k in ("gathers", "rows")}
+    with tracer.background_span("query.fetch") as sp:
+        got = assert_same(store, store.ids, T0, store.end, sp)
+    for sid, (_, t, v) in zip(store.ids, got):
+        wt, wv = store.per_row(sid, T0, store.end)
+        assert t.tobytes() == wt.tobytes() and v.tobytes() == wv.tobytes()
+    costs = sp.to_dict()["costs"]
+    keys = {(bs, b.window, int(b.time_unit), np.shape(b.words)[-1])
+            for sh in store.ns.shards.values() for bs, b in sh.blocks.items()}
+    pieces = sum(len(sh.blocks) for sh in store.ns.shards.values())
+    assert len(keys) < pieces and costs["cold_rows_n"] > pieces
+    assert costs["tile_gathers_n"] == 4 * len(keys)
+    assert ROOT.counter("storage.tiles.gathers").value() \
+        - before["gathers"] == costs["tile_gathers_n"]
+    assert ROOT.counter("storage.tiles.rows").value() - before["rows"] \
+        == costs["cold_rows_n"]
+
+
 def test_an_admission_is_one_call_with_all_five_stretches(store, cache,
                                                           monkeypatch):
     """A full cache admits one block a fetch on the fetch's own thread:

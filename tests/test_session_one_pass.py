@@ -295,6 +295,48 @@ def test_three_responders_of_one_geometry_are_one_dispatch_on_the_scope():
         "client.decode_tile.dispatches{device=%d}" % jax.devices()[5].id: 1}
 
 
+def test_a_traced_three_replica_fetch_carries_each_frames_gathers():
+    """What a session recovers from three replicas' frames is what was
+    written, bit for bit, and every replica's detailed span says how many
+    array operations its tiles took: four a tile."""
+    from m3_tpu.utils import tracing
+
+    cluster = ClusterHarness(n_nodes=3, replica_factor=3, num_shards=8)
+    session = Session(cluster.topology, SessionOptions(
+        read_consistency=ReadConsistencyLevel.ALL, timeout_s=10))
+    try:
+        now = cluster.clock.now_ns
+        ids = [b"gathers-%02d" % i for i in range(12)]
+        ts = [now - k * S for k in reversed(range(20))]
+        for k, t in enumerate(ts):
+            session.write_batch(
+                NS, ids, [t] * len(ids),
+                np.arange(len(ids), dtype=np.float64) * 0.1 + k,
+                [{b"app": b"gathers", b"i": sid} for sid in ids])
+        session.drain()
+        cluster.clock.advance(2 * xtime.HOUR + 11 * xtime.MINUTE)
+        cluster.tick_all()
+        with tracing.TRACER.span("test.query") as root:
+            got = session.fetch_tagged(NS, iq.new_term(b"app", b"gathers"),
+                                       now - xtime.HOUR, now + xtime.MINUTE)
+    finally:
+        session.close()
+        cluster.close()
+    assert sorted(got) == ids
+    for i, sid in enumerate(ids):
+        assert got[sid]["t"].tobytes() == np.array(ts, np.int64).tobytes()
+        assert got[sid]["v"].tobytes() == (
+            np.float64(i) * 0.1 + np.arange(20, dtype=np.float64)).tobytes()
+    client = root.to_dict()["children"][0]
+    grafts = [c for c in client.get("children", [])
+              if c.get("name") == "rpc.fetch_tagged"]
+    assert len(grafts) == 3
+    for g in grafts:
+        # 12 series over 8 shards: more pieces than tiles
+        assert g["costs"]["tiles_n"] >= 1
+        assert g["costs"]["tile_gathers_n"] == 4 * g["costs"]["tiles_n"]
+
+
 def test_a_geometrys_first_decode_compiles_every_bucket_a_stack_can_reach(
         monkeypatch):
     """On an accelerator (steered here: the CPU compiles a shape where it
