@@ -8,13 +8,18 @@ from typing import List, Tuple
 from . import phases, spans, trace_reduce
 
 # Several requests are in flight at once, beside the mediator's tick; an
-# idle instant goes to the first of these that is active. Deepest program
-# span first (the parts of a tick and of a write before `mediator.tick`
-# and `http`, which enclose them), the benchmark's own intervals after.
-PRIORITY = ["gc", "index.query", "storage.read", "query.fetch", "query.parse",
-            "query.execute_range", "persist.write", "encode.block",
-            "mediator.snapshot", "storage.write_batch", "remote_write.append",
-            "remote_write.decode", "mediator.tick", "render", "http"]
+# idle instant goes to the first of these that is active. A full
+# collection, then a stall the runtime probe recorded (a wake more than
+# 100 ms late: whoever held the CPU, every span under it stood still, so
+# the gap is the stall's and not theirs), then the deepest program span
+# first (the parts of a tick and of a write before `mediator.tick` and
+# `http`, which enclose them), the benchmark's own intervals after.
+STALL = "runtime.stall"
+PRIORITY = ["gc", STALL, "index.query", "storage.read", "query.fetch",
+            "query.parse", "query.execute_range", "persist.write",
+            "encode.block", "mediator.snapshot", "storage.write_batch",
+            "remote_write.append", "remote_write.decode", "mediator.tick",
+            "render", "http"]
 IDLE = "loadgen-wait"
 
 
@@ -28,6 +33,9 @@ def host_intervals(m) -> List[Tuple[float, float, str]]:
 
     for a, b, _gen in m.gc_events:
         add(a, b, "gc")
+    rt = phases.runtime_probe(m)
+    for s in list(rt.stalls) if rt is not None else ():
+        add(s["start_ns"], s["end_ns"], STALL)
     for _asked, a, b in m.ticks:
         add(a, b, "mediator.tick")
     trees = spans.by_trace_id(m.span_trees)

@@ -20,6 +20,18 @@ def request_roots(m, prefix: str = "http.") -> List[dict]:
             and any(c["name"] == "http.read" for c in t["children"])]
 
 
+def runtime_probe(m):
+    """The runtime probe's rings (PR 36: wakes and stalls, on the spans'
+    clock): a test's hand-built `m.runtime`, else the program's tracer's;
+    None on a program without one."""
+    rt = getattr(m, "runtime", None)
+    if rt is None:
+        from m3_tpu.utils import tracing
+
+        rt = getattr(tracing.TRACER, "runtime", None)
+    return rt
+
+
 def descendant(node: dict, name: str) -> Optional[dict]:
     return next((n for n in spans.walk(node) if n["name"] == name), None)
 

@@ -1,4 +1,8 @@
-"""storage.block_cache hits over lookups in the window."""
+"""storage.block_cache hits over lookups in the window.
+
+In `aggns-query-3d` the lookups fall on an aggregated namespace (2.77 GB
+of planes) larger than the cache's budget and read over 67 of its 72
+hours."""
 
 from harness import reduce
 

@@ -1,5 +1,8 @@
 """One SealedBlock.read (block-cache hit, decode or disk), mean: the
-`block_ns` cost over `block_n` on query.fetch and storage.read spans."""
+`block_ns` cost over `block_n` on query.fetch and storage.read spans.
+
+In `aggns-query-3d` a block is a two-hour block of 1-minute points
+(resolve, cache lookup, gather, cold decode)."""
 
 from harness import phases, spans
 

@@ -1,6 +1,8 @@
 """Host time inside the cold decode's calls per query (upload, dispatch,
 the device's work and the planes back): `cold_decode_ns` on
-`query.fetch` over the window's queries."""
+`query.fetch` over the window's queries.
+
+In `aggns-query-3d` the cold rows are the 1-minute namespace's."""
 
 from harness import phases, spans
 

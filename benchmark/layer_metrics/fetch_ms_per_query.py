@@ -1,5 +1,8 @@
 """Self time of the query.fetch and storage.read spans (the per-series read
-loop, block reads and decode dispatch), per query."""
+loop, block reads and decode dispatch), per query.
+
+In `aggns-query-3d`: the resolve, the routed sweep over 6-7 two-hour
+blocks a series, cache lookups, cold decode, merges."""
 
 from harness import spans
 

@@ -1,5 +1,8 @@
 """Time the server process spent in full (generation-2) collections inside
-the window, over the window: gc.callbacks, watched and never tuned."""
+the window, over the window: gc.callbacks, watched and never tuned.
+
+`rf3-query-thin` is one process that holds the coordinator and all three
+nodes: their collections are this one reading."""
 
 
 

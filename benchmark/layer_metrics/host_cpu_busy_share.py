@@ -23,7 +23,7 @@ there and a `runq_share` would have nothing to read (PERF.md section 7)."""
 
 import sys
 
-from harness import reduce
+from harness import phases, reduce
 
 PREFIX = "runtime."
 
@@ -45,19 +45,8 @@ def covered(m):
     return m.moved(PREFIX + "probe.wall_ns") or None
 
 
-def probe(m):
-    """The probe's rings: a test's hand-built `m.runtime`, else the
-    program's tracer's; None on a program without one."""
-    rt = getattr(m, "runtime", None)
-    if rt is None:
-        from m3_tpu.utils import tracing
-
-        rt = getattr(tracing.TRACER, "runtime", None)
-    return rt
-
-
 def wakes_in_window(m):
-    rt = probe(m)
+    rt = phases.runtime_probe(m)
     if rt is None:
         return None
     t0, t1 = m.window[0], max(m.t_end, m.window[1])
@@ -97,7 +86,7 @@ def stall_max_ms(m):
         return None
     t0, t1 = m.window[0], max(m.t_end, m.window[1])
     longest = 0.0
-    for s in list(probe(m).stalls):
+    for s in list(phases.runtime_probe(m).stalls):
         if s["end_ns"] < t0 or s["start_ns"] > t1:
             continue
         longest = max(longest, s["late_ns"] / 1e6)

@@ -1,5 +1,8 @@
 """Self time of query.execute_range on the queries the span says ran on the
-interpreter (below the plan floor, or a static fallback), per such query."""
+interpreter (below the plan floor, or a static fallback), per such query.
+
+In `rf3-query-thin` the session's fetch is a child span and is not in
+it."""
 
 from harness import spans
 

@@ -1,4 +1,6 @@
-"""memory_stats()['peak_bytes_in_use'], the fullest device."""
+"""memory_stats()['peak_bytes_in_use'], the fullest device.
+
+In `rf3-query-thin` the fullest of the four devices."""
 
 
 

@@ -1,6 +1,9 @@
 """Plan bind per compiled-route query: mean `bind_ns` cost of the
 query.execute_range spans tagged route=plan. The bind fetches and grids its
-selectors, so it holds those queries' query.fetch spans."""
+selectors, so it holds those queries' query.fetch spans.
+
+In `rf3-query-thin` that is those queries' whole clustered fetch; in
+`aggns-query-3d` a bind over 1-minute points."""
 
 from harness import phases
 

@@ -1,7 +1,9 @@
 """Enqueue plus wait per compiled-route query: mean of `dispatch_ns` (the
 guarded dispatch, which returns before the device is done) and
 `device_wait_ns` (where the result is read: at render, so under
-http.handler) over the requests whose span says route=plan."""
+http.handler) over the requests whose span says route=plan.
+
+In `rf3-query-thin` the device is the coordinator's own."""
 
 from harness import phases, spans
 

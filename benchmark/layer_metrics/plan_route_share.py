@@ -1,4 +1,6 @@
-"""Queries that ran on the compiled plan route, of the queries executed."""
+"""Queries that ran on the compiled plan route, of the queries executed.
+
+In `rf3-query-thin` the route runs on the coordinator's own device."""
 
 from harness import reduce
 

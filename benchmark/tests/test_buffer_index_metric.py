@@ -48,7 +48,8 @@ def test_declared_as_benchmark_json_has_it():
     entry = spec.layer_metric_declarations()[NAME]
     assert entry in bench["per_layer"]
     assert entry["workloads"] == ["cpu4k-query-thin", "rf3-query-thin",
-                                  "cpu4k-query-12h", "aggns-query-3d"]
+                                  "cpu4k-query-12h", "aggns-query-3d",
+                                  "promrw4k-mixed"]
     assert entry["moves"] == "query_p50_ms"
 
 

@@ -57,10 +57,10 @@ def session_decode_buckets_warm_as_on_a_chip():
     geometry's first decode brings every bucket through its compile, in
     the warm-up. The tests run the program's own warm-up (the embedded
     cells' tests warm a node's buckets by hand: `tiny.warm_decode_buckets`)."""
-    from m3_tpu.client import decode
+    from m3_tpu.ops import decode_rows
 
     with pytest.MonkeyPatch.context() as mp:
-        mp.setattr(decode, "_compiles_are_dear", lambda: True)
+        mp.setattr(decode_rows, "_compiles_are_dear", lambda: True)
         yield
 
 

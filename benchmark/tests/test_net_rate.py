@@ -22,7 +22,7 @@ from harness import cellrun, countergen, schedule, spec  # noqa: E402
 SEED = 3_000_000_061
 CELL = "net4k-query-rate"
 # no device plane on the CPU
-UNREADABLE_ON_CPU = {"temporal_roofline", "device_idle_share.net"}
+UNREADABLE_ON_CPU = {"temporal_roofline", "device_idle_share.query"}
 
 
 def tiny_cell(**traffic_overrides):
@@ -123,8 +123,8 @@ def test_a_run_is_correct_and_every_raw_sample_was_seen(run):
     want = {m_["name"] for m_ in run.cell.per_layer}
     assert want - set(got) <= UNREADABLE_ON_CPU | {
         # 192 series: no query reaches the plan's floor of 4,096 cells
-        "plan_bind_ms.net", "plan_device_wait_ms.net"}, want - set(got)
-    assert got["compiles_in_window.net"]["value"] == 0.0
+        "plan_bind_ms", "plan_device_wait_ms"}, want - set(got)
+    assert got["compiles_in_window.query"]["value"] == 0.0
 
 
 @pytest.mark.parametrize("control,rows", [

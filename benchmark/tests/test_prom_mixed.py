@@ -6,7 +6,9 @@ generators on one clock: the fleet's remote-writes at their offsets and
 the mix's six classes as plain range selectors ending at a moving now.
 The checks (`query_answers_frontier` against
 `reference/promql_offset_ref.py`, `mixed_readback`, `write_pace`,
-`served_path_verdict`), the cell's two readers on a traced run, and what
+`served_path_verdict`), the cell's per-layer readers on a traced run (its
+own five and the 30 it shares with `cpu4k-query-thin` and
+`net4k-query-rate`, under their one name each), and what
 has to come out not correct: the `stale` control, a reference fed
 aligned timestamps, a read-back that misses a sample, and a server that
 drops one sample it acknowledged. Not tier-1:
@@ -29,7 +31,13 @@ from harness import cellrun, datagen, promoffsets, schedule, spec  # noqa: E402
 SEED = 3_000_000_061
 CELL = "promrw4k-mixed"
 SECONDS = 12.0
-# no device plane on the CPU: neither of the cell's two readers needs one
+# no device plane on the CPU, and 240 series never clear the plan's floor
+# of 4,096 cells
+UNREADABLE_ON_CPU = {"device_idle_share.query", "temporal_roofline",
+                     "plan_bind_ms", "plan_device_wait_ms"}
+OWN = ["read_offcpu_share", "window_packed_share",
+       "window_pack_ms_per_packed_query", "read_lock_wait_us_per_query",
+       "buffer_tail_rows_per_read"]
 TINY = {"rate_per_s": 8.0, "samples_per_send": 30}
 
 
@@ -74,9 +82,16 @@ def test_the_cell_is_what_the_issue_names():
     assert cell.checks == ["query_answers_frontier", "mixed_readback",
                            "served_path_verdict", "write_pace"]
     assert {m["name"] for m in cell.end_to_end} == {"query_p50_ms", "setup_s"}
-    assert [m["name"] for m in cell.per_layer] == ["read_offcpu_share",
-                                                   "window_packed_share"]
-    assert len(spec.load_benchmark()["per_layer"]) == 128
+    # its own five, and every reading of its control (thin's 26) and of
+    # the range selector's layout (net's four) under the one name each has
+    listed = [m["name"] for m in cell.per_layer]
+    assert [n for n in listed if n in OWN] == OWN
+    thin = {m["name"] for m in spec.load_cell("cpu4k-query-thin").per_layer}
+    assert len(thin) == 26 and thin <= set(listed)
+    assert set(listed) - thin - set(OWN) == {
+        "range_window_ms_per_query", "window_samples_seen_share",
+        "temporal_device_ms_per_query", "temporal_roofline"}
+    assert len(spec.load_benchmark()["per_layer"]) == 91
     t = cell.traffic
     thin = spec.load_cell("cpu4k-query-thin").traffic
     assert (t["kind"], t["loop"], t["max_in_flight"]) == (
@@ -164,19 +179,24 @@ def test_a_run_is_correct_and_both_generators_ran(run):
     assert by["commitlog_samples_short"] == 0
     assert by["sealed_decode_series_blocks_at_least"] == -300
     assert by["compiles_in_window"] == 0
-    # the readings the full per-layer list has no place for, as rows
-    for name in ("write_ack_p50_ms", "write_ack_p99_ms",
-                 "buffer_tail_rows_per_read", "buffer_regroups_in_window",
-                 "fill_quiet_timeouts_in_window",
-                 "read_lock_wait_us_per_query", "frontier_pairs",
-                 "answers_took_in_flight", "reading.index_query_ms",
-                 "reading.range_window_ms_per_query"):
+    # the write side's readings stay rows; what the reads pay is the
+    # cell's per-layer readings, and a number stands in one place
+    for name in ("write_ack_p50_ms", "write_ack_p99_ms", "write_late_max_ms",
+                 "buffer_regroups_in_window", "fill_quiet_timeouts_in_window",
+                 "frontier_pairs", "answers_took_in_flight"):
         assert name in by, name
+    assert not [n for n in by if n.startswith("reading.") or n in OWN]
     result = run.result(m, checks, attempted, failed)
     assert result["correct"] is True
     json.loads(json.dumps(result))         # every limit is a JSON number
     got = result["metrics"]
-    assert set(got) == {"read_offcpu_share", "window_packed_share"}
+    want = {d["name"] for d in run.cell.per_layer}
+    assert set(got) <= want and want - set(got) <= UNREADABLE_ON_CPU, \
+        want - set(got)
+    assert got["window_pack_ms_per_packed_query"]["value"] > 0
+    assert got["read_lock_wait_us_per_query"]["value"] >= 0
+    assert got["buffer_tail_rows_per_read"]["value"] >= 0
+    assert got["window_samples_seen_share"]["value"] == 100.0
     assert 0 <= got["read_offcpu_share"]["value"] <= 100
     # 9 of 20 cards touch eight hosts: eight grids, the packed layout
     assert 25 <= got["window_packed_share"]["value"] <= 65
@@ -196,10 +216,56 @@ def test_a_program_without_the_counters_reads_nothing(run):
                    if "layouts" not in k and "tail_rows" not in k},
         counters1={k: v for k, v in run.m.counters1.items()
                    if "layouts" not in k and "tail_rows" not in k})
-    for name in ("read_offcpu_share", "window_packed_share"):
-        assert spec.load_reader("layer_metrics", name)(m) is None
-    rows, _ = spec.load_part("checks", "write_pace").check(run, m)
-    assert "buffer_tail_rows_per_read" not in {n for n, _v, _l in rows}
+    for name in OWN:
+        assert spec.load_reader("layer_metrics", name)(m) is None, name
+
+
+def test_a_row_of_pr_46_reads_what_its_entry_reads(run, tmp_path):
+    """benchmark/tools/fold_check.py --old-rows on this window: an older
+    tree whose `write_pace` carried the cell's readings as rows
+    (`reading.<name>` read by that tree's reader, and the cell's own
+    under their names) reads, on the one Measurement, what this tree's
+    line carries as entries; a row that reads something else is told
+    apart, and a row that is no reading of the cell is left alone."""
+    import shutil
+    import sys
+
+    sys.path.insert(0, os.path.join(spec.BENCH_DIR, "tools"))
+    import fold_check
+
+    old = tmp_path / "benchmark"
+    shutil.copytree(os.path.join(spec.BENCH_DIR, "layer_metrics"),
+                    old / "layer_metrics")
+    (old / "checks").mkdir()
+    (old / "checks" / "write_pace.py").write_text(
+        "from harness import spec\n\n\n"
+        "def check(run, m, control=None):\n"
+        "    rows = [('writes_failed', 0, 0)]\n"
+        "    for name in %r:\n"
+        "        v = spec.load_reader('layer_metrics', name)(m)\n"
+        "        if v is not None:\n"
+        "            rows.append(('reading.' + name, float(v), 1e18))\n"
+        "    for name in %r:\n"
+        "        rows.append((name, float(spec.load_reader(\n"
+        "            'layer_metrics', name)(m)), 1e18))\n"
+        "    return rows, 0\n" % (
+            ["index_query_ms", "range_window_ms_per_query",
+             "device_idle_share.query"], OWN[2:]))
+    m = run.m
+    result = run.result(m, *run.check(m))
+    listed = {d["name"] for d in run.cell.per_layer}
+    was = fold_check.old_rows(run, m, str(tmp_path), "write_pace")
+    rows = fold_check.compare_rows(was, listed, result["metrics"])
+    assert [r["new"] for r in rows] == [
+        "index_query_ms", "range_window_ms_per_query"] + OWN[2:]
+    assert all(r["same"] for r in rows), rows
+    assert spec.BENCH_DIR == os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))) and spec.ROOT_DIR == os.path.dirname(
+        spec.BENCH_DIR)
+    was["reading.index_query_ms"] += 1.0
+    rows = fold_check.compare_rows(was, listed, result["metrics"])
+    assert [r["old"] for r in rows if not r["same"]] == [
+        "checks:reading.index_query_ms"]
 
 
 def test_an_answer_that_took_an_in_flight_sample_is_sound(run):
@@ -219,6 +285,38 @@ def test_an_answer_that_took_an_in_flight_sample_is_sound(run):
     assert failed == 0 and not failing(rows), rows
     assert by["frontier_pairs"] > 0
     assert 0 <= by["answers_took_in_flight"] <= by["frontier_pairs"]
+
+
+def test_a_window_the_host_held_up_is_late_and_not_wrong(run):
+    """The driver's check of PR 50, seed 869126231: the machine stood
+    still for 2.3 s, the 11 of 358 writes that came due in its first
+    1.3 s went out more than a second late (0.031 against the limit 0.01
+    the row had), every one of them acknowledged in full and read back.
+    A write that is sent late is late: the row shows it and has no
+    limit, and `correct` is for what the writes and the reads say."""
+    m = run.m
+    rec = dict(m.rec)
+    late = np.arange(len(rec["w_sent"])) % 8 == 0
+    held = np.where(late, int(2.3e9), 0)
+    rec["w_sent"], rec["w_done"] = rec["w_sent"] + held, rec["w_done"] + held
+    m2 = dataclasses.replace(m, rec=rec)
+    rows, failed = spec.load_part("checks", "write_pace").check(run, m2)
+    by = {n: (v, lim) for n, v, lim in rows}
+    assert by["writes_late_share"][0] >= 0.1
+    assert by["write_late_max_ms"][0] >= 2300
+    assert failed == 0 and not failing(rows), rows
+    assert {n for n, (_v, lim) in by.items() if lim < 1e18} == {
+        "writes_failed", "writes_not_acknowledged_in_full",
+        "writes_sent_at_least"}
+    # a write that was not acknowledged in full is still what fails it
+    rec = dict(m.rec)
+    rec["w_samples"] = rec["w_samples"].copy()
+    rec["w_samples"][0] -= 1
+    rows, failed = spec.load_part("checks", "write_pace").check(
+        run, dataclasses.replace(m, rec=rec))
+    assert failing(rows) == {"writes_not_acknowledged_in_full"}
+    assert failed == 1
+    assert "writes_late_share" not in run.cell.traffic["limits"]
 
 
 @pytest.mark.parametrize("control,rows", [

@@ -124,9 +124,9 @@ def test_no_tpu_no_result():
 
 
 def test_traced_run_reads_every_layer_metric_it_can_on_a_cpu():
-    """No device plane exists on the CPU, so the device's numbers read
-    idle; every span, counter and clock reader still finds its input and
-    every idle instant gets a name."""
+    """No device plane exists on the CPU, so the busiest device's idle
+    share has nothing to read; every span, counter and clock reader still
+    finds its input and every idle instant gets a name."""
     from harness import breakdown
 
     cell = tiny.cell("cpu4k-query-thin")
@@ -134,7 +134,8 @@ def test_traced_run_reads_every_layer_metric_it_can_on_a_cpu():
                               need_chip=False)
     assert result["correct"] is True
     want = {m["name"] for m in cell.per_layer}
-    assert want - set(result["metrics"]) <= {"block_cache_hit_share"}
+    assert want - set(result["metrics"]) <= {"block_cache_hit_share",
+                                             "device_idle_share.query"}
     assert result["device"]["window_s"] >= 2.0
     names = {name for name, _s in result["breakdown"]["idle_gaps"]}
     assert names <= set(breakdown.PRIORITY) | {breakdown.IDLE}

@@ -121,6 +121,29 @@ def test_the_ring_readings(capsys):
         read("gil_wait_p95_ms", none) is None
 
 
+def test_a_stalls_gap_is_named_in_the_breakdown():
+    """`breakdown.idle_gaps` gives a stall's stretch to `runtime.stall`,
+    not to the spans that stood still under it; the part of it that is a
+    full collection stays `gc` (PR 50)."""
+    from harness import breakdown
+
+    stall = {"start_ns": T0 + 100 * MS, "end_ns": T0 + 500 * MS,
+             "late_ns": 400 * MS}
+    fetch = node("query.fetch", T0 + 50 * MS, T0 + 700 * MS)
+    m = measurement(
+        runtime=types.SimpleNamespace(wakes=[], stalls=[stall]),
+        span_trees=[fetch], gc_events=[(T0 + 100 * MS, T0 + 250 * MS, 2)],
+        rec={k: np.zeros(0, np.int64) for k in ("i", "sent", "done")},
+        trace=types.SimpleNamespace(to_trace_ns=lambda t: t,
+                                    busy=lambda lo, hi: {}))
+    gaps = breakdown.idle_by_host(m, T0, T0 + 1000 * MS)
+    assert gaps == pytest.approx({
+        "gc": 0.15, breakdown.STALL: 0.25, "query.fetch": 0.25,
+        breakdown.IDLE: 0.35})
+    assert breakdown.PRIORITY.index("gc") < breakdown.PRIORITY.index(
+        breakdown.STALL) < breakdown.PRIORITY.index("index.query")
+
+
 def test_the_cpu_twins_of_the_write_path():
     root = node("http.POST /api/v1/prom/remote/write", T0, T0 + 30 * MS,
                 tags={"samples": 500, "cpu_ns": 9 * MS}, trace_id=1, children=[

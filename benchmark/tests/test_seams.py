@@ -238,14 +238,24 @@ def test_the_per_layer_list_fits_the_drivers_limit():
 
 def test_a_folded_name_is_no_entry_and_its_base_lists_its_cells():
     """One entry a reading: a twin that a `benchmark` PR folded into its
-    base (tools/folded_names.json) has no entry, declaration or reader
-    any more, and the base it names is an entry."""
+    base (tools/folded_names.json: PR 41's 18 `.deep`, PR 50's 14 `.rf3`,
+    14 `.aggns` and 12 `.net`) has no entry, declaration or reader any
+    more, and the base it names is an entry that lists the twin's cell."""
     with open(os.path.join(spec.BENCH_DIR, "tools",
                            "folded_names.json")) as f:
         folded = json.load(f)
-    names = {m["name"] for m in spec.load_benchmark()["per_layer"]}
+    by_name = {m["name"]: m for m in spec.load_benchmark()["per_layer"]}
+    names = set(by_name)
     assert folded and not set(folded) & names
     assert set(folded.values()) <= names
+    cell_of = {"deep": "cpu4k-query-12h", "rf3": "rf3-query-thin",
+               "aggns": "aggns-query-3d", "net": "net4k-query-rate"}
+    by_suffix = {}
+    for old, new in folded.items():
+        suffix = old.rpartition(".")[2]
+        by_suffix[suffix] = by_suffix.get(suffix, 0) + 1
+        assert cell_of[suffix] in by_name[new]["workloads"], (old, new)
+    assert by_suffix == {"deep": 18, "rf3": 14, "aggns": 14, "net": 12}
     for old in folded:
         for ext in (".json", ".py"):
             assert not os.path.exists(os.path.join(
@@ -253,11 +263,12 @@ def test_a_folded_name_is_no_entry_and_its_base_lists_its_cells():
 
 
 def test_a_twin_still_to_fold_reads_with_its_bases_code():
-    """The twins this tree still holds (an entry whose `moves`, unit,
-    source and layer are another entry's and whose name is that entry's
-    stem plus a cell's suffix) stay safe to fold: each is a forwarder to
-    its base's reader or a copy of its body, `device_idle_share.rf3`
-    alone apart (the busiest device, which on one chip is the chip)."""
+    """A twin (an entry whose `moves`, unit, source and layer are another
+    entry's and whose name is that entry's stem plus a cell's suffix)
+    stays safe to fold: a forwarder to its base's reader or a copy of
+    its body. PR 50 folded the last 40, so none is held until a PR that
+    is no `benchmark` PR brings a cell that shares a reading (README,
+    "One entry a reading")."""
     import ast
 
     def body(name):
@@ -279,5 +290,4 @@ def test_a_twin_still_to_fold_reads_with_its_bases_code():
         read = spec.load_reader("layer_metrics", name)
         forwarded = os.path.basename(read.__code__.co_filename) \
             == base["name"] + ".py"
-        assert forwarded or body(name) == body(base["name"]) \
-            or name == "device_idle_share.rf3", name
+        assert forwarded or body(name) == body(base["name"]), name

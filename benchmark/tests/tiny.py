@@ -49,8 +49,9 @@ def warm_decode_buckets(handle, namespaces=None):
     row bucket through its compile); which bucket a read needs depends on
     what the cache holds, so the test meets them all before the window,
     in the handle's namespace or in each of `namespaces`."""
-    from m3_tpu.storage import block
+    from m3_tpu.storage import block, block_cache
 
+    cache = block_cache.active()
     for name in namespaces or (handle.namespace,):
         ns = handle.db.namespace(name)
         blk = next(iter(next(iter(ns.shards.values())).blocks.values()))
@@ -58,3 +59,19 @@ def warm_decode_buckets(handle, namespaces=None):
             at = [0] * rows
             block.decode_rows(blk.words[at], blk.npoints[at], blk.window,
                               blk.time_unit.nanos)
+        # where the cache retains a block's encode on the devices
+        # (M3_TPU_BLOCK_CACHE_RETAIN=1, which test_cluster_rf3.py sets for
+        # the whole session while it is collected, with 8 virtual devices),
+        # a rung-sized encode is decoded where it lies: a program of its
+        # own a sharding, which would else compile in a session's first
+        # window that reads such a block
+        seen = set()
+        for sh in ns.shards.values():
+            for b in sh.blocks.values():
+                enc = cache.encoded(b) if cache is not None else None
+                key = enc and (enc[0].shape, str(enc[0].sharding))
+                if enc and enc[0].shape[0] in block.ROW_BUCKETS \
+                        and key not in seen:
+                    seen.add(key)
+                    block.decode_rows(enc[0], enc[1], b.window,
+                                      b.time_unit.nanos)

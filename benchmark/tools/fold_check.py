@@ -9,6 +9,11 @@ carries under the reading's name of today (`folded_names.json`: old name
 
     python3 benchmark/tools/fold_check.py --workload W --seed N --seconds S --old DIR
 
+`--old-rows CHECK` (PR 50) also runs that one check of the older tree on
+the same `Measurement` and holds the rows it carried for readings that
+are entries today (`reading.<name>`, or a row named as an entry of this
+cell is: PR 46's `write_pace` in `promrw4k-mixed`) against the line.
+
 Prints one JSON line a reading of the older tree, a summary, and last
 the run's result line as `run.py --trace 1` prints it. Exits 1 where a
 reading differs or is missing from the line. The older tree's reader
@@ -56,6 +61,35 @@ def old_readings(m, workload: str, old_dir: str) -> dict:
     return out
 
 
+def old_rows(run, m, old_dir: str, check: str) -> dict:
+    """{row: value} of the older tree's check `check` on `m`, read by
+    that tree's file, its readers and its BENCHMARK.json."""
+    from harness import spec
+
+    here = spec.BENCH_DIR, spec.ROOT_DIR
+    try:
+        spec.BENCH_DIR, spec.ROOT_DIR = (os.path.join(old_dir, "benchmark"),
+                                         old_dir)
+        rows, _failed = spec._load_module("checks", check).check(run, m)
+    finally:
+        spec.BENCH_DIR, spec.ROOT_DIR = here
+    return {name: value for name, value, _limit in rows}
+
+
+def compare_rows(rows: dict, listed: set, metrics: dict) -> list:
+    """As `compare`, for the rows of `old_rows` that are readings of the
+    cell today (`listed`: the names of its per-layer entries)."""
+    out = []
+    for old, was in rows.items():
+        new = old[len("reading."):] if old.startswith("reading.") else old
+        if new in listed:
+            now = metrics.get(new, {}).get("value")
+            out.append({"old": "checks:" + old, "new": new,
+                        "old_value": was, "new_value": now,
+                        "same": float(was) == now})
+    return out
+
+
 def compare(m, workload: str, old_dir: str, metrics: dict) -> list:
     """Rows {old, new, old_value, new_value, same}; `metrics` is the
     `metrics` of this tree's result line for the same `m`."""
@@ -76,6 +110,7 @@ def main() -> int:
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--old", required=True)
+    ap.add_argument("--old-rows", default=None)
     args = ap.parse_args()
     from harness import cellrun, spec
 
@@ -89,8 +124,12 @@ def main() -> int:
         run.setup(args.seconds)
         m = run.window(args.seconds)
         result = run.result(m, *run.check(m))
-        rows = compare(m, args.workload, os.path.abspath(args.old),
-                       result["metrics"])
+        old_dir = os.path.abspath(args.old)
+        rows = compare(m, args.workload, old_dir, result["metrics"])
+        if args.old_rows:
+            rows += compare_rows(
+                old_rows(run, m, old_dir, args.old_rows),
+                {d["name"] for d in run.cell.per_layer}, result["metrics"])
     finally:
         run.close()
     for row in rows:
