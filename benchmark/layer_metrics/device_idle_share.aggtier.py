@@ -1,0 +1,5 @@
+"""`device_idle_share.query`'s reading in the cell of 12-hour panels read while the aggregation tier writes (`aggtier-query-live`)."""
+
+from harness import spec
+
+read = spec.load_reader("layer_metrics", "device_idle_share.query")

@@ -1,0 +1,5 @@
+"""`gc_pause_share`'s reading in the cell of 12-hour panels read while the aggregation tier writes (`aggtier-query-live`)."""
+
+from harness import spec
+
+read = spec.load_reader("layer_metrics", "gc_pause_share")

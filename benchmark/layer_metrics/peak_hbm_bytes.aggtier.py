@@ -1,0 +1,5 @@
+"""`peak_hbm_bytes.query`'s reading in the cell of 12-hour panels read while the aggregation tier writes (`aggtier-query-live`)."""
+
+from harness import spec
+
+read = spec.load_reader("layer_metrics", "peak_hbm_bytes.query")

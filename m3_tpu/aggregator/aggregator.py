@@ -11,6 +11,7 @@ aggregation ring (forwarded_writer.go)."""
 
 from __future__ import annotations
 
+import collections
 import threading
 import time as _time
 from typing import Callable, Dict, List, Optional, Sequence
@@ -18,7 +19,9 @@ from typing import Callable, Dict, List, Optional, Sequence
 from ..metrics.metadata import ForwardMetadata, StagedMetadata
 from ..metrics.metric import MetricType, MetricUnion
 from ..metrics.policy import StoragePolicy
+from ..utils import instrument, tracing
 from ..utils.hashing import murmur3_32_cached
+from ..utils.tracing import clock_ns as _span_clock
 from .election import ElectionManager
 from .entry import MetricMap
 from .flush import FlushManager, FlushTimesManager
@@ -158,6 +161,31 @@ class ForwardedWriter:
             self.dropped += undelivered
 
 
+class _TimedHandler:
+    """A traced round's flush handler, its time told apart from the
+    reduction's (emit_batch calls the one inside the other)."""
+
+    def __init__(self, handler):
+        self._handler = handler
+        self.emit_ns = 0
+        if hasattr(handler, "handle_columnar"):
+            self.handle_columnar = self._columnar
+
+    def _columnar(self, groups):
+        t0 = _span_clock()
+        try:
+            self._handler.handle_columnar(groups)
+        finally:
+            self.emit_ns += _span_clock() - t0
+
+    def __call__(self, *row):
+        t0 = _span_clock()
+        try:
+            self._handler(*row)
+        finally:
+            self.emit_ns += _span_clock() - t0
+
+
 class Aggregator:
     def __init__(self, num_shards: int = 64,
                  clock: Optional[Callable[[], int]] = None,
@@ -166,8 +194,16 @@ class Aggregator:
                  flush_times: Optional[FlushTimesManager] = None,
                  rate_limit_per_second: int = 0,
                  default_policies: Sequence[StoragePolicy] = (),
-                 buffer_past_ns: int = 0):
+                 buffer_past_ns: int = 0, instance_id: str = "",
+                 drop_late_timed: bool = False):
+        """`drop_late_timed`: a timed sample whose window closed more
+        than `buffer_past_ns` ago is dropped and counted
+        (`aggregator.add.late_dropped`), as the standalone tier must (its
+        window may be flushed already); off, it is staged whatever its
+        age, as the embedded uses and the tests of the lists expect."""
         self.num_shards = num_shards
+        self.instance_id = instance_id
+        self._drop_late = drop_late_timed
         self._clock = clock or (lambda: _time.time_ns())
         self._rate_limit = rate_limit_per_second
         self._default_policies = tuple(default_policies)
@@ -188,6 +224,18 @@ class Aggregator:
         self.forwarded_received = 0
         self._stats_lock = threading.Lock()
         self._shards_lock = threading.Lock()
+        # One flush round at a time, and a resignation between rounds:
+        # a leader that steps down has committed the flush times of
+        # everything it emitted before its successor can campaign.
+        self._flush_lock = threading.Lock()
+        # (type, policy, aggregation id) -> {metric id: (entry, elem)}:
+        # the timed batch path's memo of the per-metric path's lookup
+        self._timed_elems: Dict[tuple, Dict[bytes, tuple]] = {}
+        scope = instrument.ROOT.sub_scope("aggregator")
+        by_instance = scope.sub_scope("add", instance=instance_id)
+        self._timed_n = by_instance.counter("timed")
+        self._late_n = by_instance.counter("late_dropped")
+        self._flush_counters: Dict[str, tuple] = {}
 
     # -- placement ---------------------------------------------------------
 
@@ -203,6 +251,7 @@ class Aggregator:
             if sid in self._shards:
                 self._shards[sid].cutoff_nanos = now
         self._owned = new
+        self._timed_elems.clear()   # ownership is checked where it fills
 
     def set_forward_routing(self, placement_getter, transports,
                             local_instance_id):
@@ -278,9 +327,71 @@ class Aggregator:
     def add_timed(self, metric_type: MetricType, metric_id: bytes,
                   t_nanos: int, value: float, policy: StoragePolicy,
                   aggregation_id: int = 0) -> bool:
+        if self._drop_late and t_nanos < self._closed_before(
+                self._clock(), policy.resolution.window_ns):
+            self._late_n.inc()
+            return False
         shard = self._shard(metric_id)
-        return shard is not None and shard.map.add_timed(
+        ok = shard is not None and shard.map.add_timed(
             metric_type, metric_id, t_nanos, value, policy, aggregation_id)
+        if ok:
+            self._timed_n.inc()
+        return ok
+
+    def _closed_before(self, now: int, res_ns: int) -> int:
+        """Every instant before this lies in a window that may have been
+        flushed (its end plus `buffer_past` is not after `now`)."""
+        return (now - self._buffer_past_ns) // res_ns * res_ns
+
+    def add_timed_batch(self, metric_type: MetricType,
+                        ids: Sequence[bytes], times: Sequence[int],
+                        values, policy: StoragePolicy,
+                        aggregation_id: int = 0) -> int:
+        """One (type, policy, aggregation id) column group of timed
+        samples (a `tbatch` frame): what `add_timed` does a sample, with
+        the clock read, the policy's window and the id -> elem lookup
+        paid once a frame and once a series instead. `values` is a
+        float64 array; a sample stages a one-element view of it. A
+        sample whose window has closed (`buffer_past` past its end) is
+        dropped and counted, never staged: its window may have been
+        flushed, and a second point for it would not be the window's
+        aggregate. Returns the samples dropped late."""
+        if self._rate_limit:    # the limiter is the per-metric path's
+            add = self.add_timed
+            return sum(not add(metric_type, mid, t, float(v), policy,
+                               aggregation_id)
+                       for mid, t, v in zip(ids, times, values))
+        now = self._clock()
+        # one compare a sample; far in the past where late samples stay
+        closed_before = self._closed_before(
+            now, policy.resolution.window_ns) if self._drop_late \
+            else -1 << 62
+        key = (metric_type, policy, aggregation_id)
+        elems = self._timed_elems.get(key)
+        if elems is None:
+            elems = self._timed_elems.setdefault(key, {})
+        late = 0
+        for i, mid in enumerate(ids):
+            t = times[i]
+            if t < closed_before:
+                late += 1
+                continue
+            hit = elems.get(mid)
+            if hit is None or hit[1].tombstoned:
+                shard = self._shard(mid)
+                if shard is None:
+                    continue
+                hit = elems[mid] = shard.map.timed_elem(
+                    metric_type, mid, policy, aggregation_id)
+            # the entry's access time, as `Entry.add_timed` keeps it:
+            # `tick()` expires an entry by it, and a live series'
+            # minute must not be cut in two by a tombstone
+            hit[0].last_access_nanos = now
+            hit[1]._stage(t, values[i:i + 1])
+        self._timed_n.inc(len(ids) - late)
+        if late:
+            self._late_n.inc(late)
+        return late
 
     def add_forwarded(self, metric_type: MetricType, metric_id: bytes,
                       t_nanos: int, value: float, meta: ForwardMetadata) -> bool:
@@ -315,36 +426,117 @@ class Aggregator:
         (FlushTimesManager.store_many); without one, flush directly (the
         embedded coordinator downsampler runs leaderless,
         downsample/leader_local.go)."""
+        with self._flush_lock:
+            return self._flush_round(
+                self._clock() if now_nanos is None else now_nanos)
+
+    def resign(self):
+        """Step down from flush leadership between two rounds (POST
+        /resign): the round in progress ends, its flush times are in KV,
+        then the lease is given up, so the successor starts exactly one
+        window after this instance's last."""
+        if self._election is None:
+            raise RuntimeError("aggregator is not running an election")
+        with self._flush_lock:
+            self._election.resign()
+
+    def _role(self) -> str:
+        return "leader" if self._election.is_leader() else "follower"
+
+    def _flush_round(self, now: int) -> int:
         from .list import FlushBatch, emit_batch
 
-        now = self._clock() if now_nanos is None else now_nanos
+        t0 = _span_clock()
         batch = FlushBatch()
         commits = []
         with self._shards_lock:  # snapshot: handler threads insert shards
             shards = {sid: self._shards[sid] for sid in sorted(self._shards)}
+        if self._election is not None:
+            # one campaign and one read of the flush times a round
+            self._election.campaign()
+            persisted = self._flush_times.get_many(list(shards))
         for sid, shard in shards.items():
             if self._election is not None:
-                _, commit = self._flush_mgr(shard).plan_into(now, batch)
+                _, commit = self._flush_mgr(shard).plan_into(
+                    now, batch, persisted[sid])
                 commits.append(commit)
             else:
                 for lst in shard.lists.lists():
                     res = lst.resolution_ns
                     target = (now - self._buffer_past_ns) // res * res
                     lst.collect_into(target, batch)
-        total = emit_batch(batch, self._flush_handler, self._forward)
-        if commits:
-            pending: Dict[int, Dict[int, int]] = {}
-            for commit in commits:
-                commit(pending)
-            if pending:
-                self._flush_times.store_many(pending)
+        if self._election is None:
+            # the embedded, leaderless downsampler: its own flush span
+            # and counters are the coordinator's (`downsample.flush`)
+            return emit_batch(batch, self._flush_handler, self._forward)
+        windows = len(batch)
+        if not windows:
+            # a round that closes nothing: the followers' discards and
+            # the (unchanged) flush times still commit, no span opens
+            self._commit_flush_times(commits)
+            return 0
+        role = self._role()
+        # (backdated to the round's first instant: the collect pass ran
+        # before it was known that the round emits)
+        with tracing.background_span("aggregator.flush", start_ns=t0,
+                                     instance=self.instance_id, role=role,
+                                     windows=windows) as sp:
+            t1 = _span_clock()
+            handler = self._flush_handler
+            timed = _TimedHandler(handler) \
+                if sp.sampled and handler is not None else handler
+            total = emit_batch(batch, timed, self._forward)
+            t2 = _span_clock()
+            self._commit_flush_times(commits)
+            if sp.sampled:
+                t3 = _span_clock()
+                emit_ns = getattr(timed, "emit_ns", 0)
+                sp.add_cost("collect_ns", t1 - t0)
+                sp.add_cost("reduce_ns", t2 - t1 - emit_ns)
+                sp.add_cost("emit_ns", emit_ns)
+                sp.add_cost("flush_times_ns", t3 - t2)
+                sp.add_cost("rows_n", total)
+                # which windows left this instance in this round, by
+                # their END: [(end, windows), ...]
+                ends: Dict[int, int] = {}
+                for cls, rows in batch.classes.items():
+                    for start, n in collections.Counter(rows.starts).items():
+                        end = start + cls.res_ns
+                        ends[end] = ends.get(end, 0) + n
+                sp.set_tag("window_ends", sorted(ends.items()))
+        counters = self._flush_counters.get(role)
+        if counters is None:
+            by = instrument.ROOT.sub_scope(
+                "aggregator.flush", instance=self.instance_id, role=role)
+            counters = self._flush_counters[role] = (
+                by.counter("windows"), by.counter("rows"))
+        counters[0].inc(windows)
+        counters[1].inc(total)
         return total
+
+    def _commit_flush_times(self, commits):
+        if not commits:
+            return
+        pending: Dict[int, Dict[int, int]] = {}
+        for commit in commits:
+            commit(pending)
+        if pending:
+            self._flush_times.store_many(pending)
 
     def tick(self) -> int:
         """Expire idle entries across shards (aggregator.go tickInternal)."""
         with self._shards_lock:
             shards = list(self._shards.values())
-        return sum(s.map.tick() for s in shards)
+        expired = sum(s.map.tick() for s in shards)
+        if expired:
+            # the timed batch path's memo lets go of what expired (a hit
+            # on a tombstoned elem looks the series up again; one never
+            # hit again would stay for good)
+            for key, elems in list(self._timed_elems.items()):
+                self._timed_elems[key] = {
+                    mid: hit for mid, hit in list(elems.items())
+                    if not hit[1].tombstoned}
+        return expired
 
     def num_entries(self) -> int:
         with self._shards_lock:

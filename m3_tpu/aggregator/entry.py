@@ -212,6 +212,17 @@ class MetricMap:
         return self._entry_for(metric_id, metric_type).add_timed(
             t_nanos, value, policy, aggregation_id)
 
+    def timed_elem(self, metric_type: MetricType, metric_id: bytes,
+                   policy: StoragePolicy, aggregation_id: int = 0):
+        """(entry, elem): the elem `add_timed` stages this (id, policy,
+        aggregation id) into, and the entry whose `last_access_nanos`
+        `tick()` expires it by. The timed batch path looks both up once
+        a series, stages into the elem directly from then on and keeps
+        the entry's access time itself."""
+        entry = self._entry_for(metric_id, metric_type)
+        return entry, entry._get_elem(
+            ElemKey(metric_id, policy, aggregation_id))
+
     def add_forwarded(self, metric_type: MetricType, metric_id: bytes,
                       t_nanos: int, value: float, meta: ForwardMetadata) -> bool:
         return self._entry_for(metric_id, metric_type).add_forwarded(

@@ -38,6 +38,19 @@ class FlushTimesManager:
         raw = json.loads(val.data.decode())
         return {int(k): int(v) for k, v in raw.items()}
 
+    def get_many(self, shard_ids) -> Dict[int, Dict[int, int]]:
+        """Every listed shard's flush times in one read of the store
+        (one exchange with a networked KV): a flush round's."""
+        keys = {self._key(sid): sid for sid in shard_ids}
+        get_many = getattr(self._store, "get_many", None)
+        if get_many is None:
+            return {sid: self.get(sid) for sid in shard_ids}
+        out: Dict[int, Dict[int, int]] = {sid: {} for sid in shard_ids}
+        for key, val in get_many(list(keys)).items():
+            out[keys[key]] = {int(k): int(v) for k, v in
+                              json.loads(val.data.decode()).items()}
+        return out
+
     def store(self, shard_id: int, flush_times: Dict[int, int]):
         self._store.set(self._key(shard_id), json.dumps(
             {str(k): v for k, v in flush_times.items()}).encode())
@@ -82,6 +95,15 @@ class FlushManager:
         self._buffer_past_ns = buffer_past_ns
         self.windows_flushed = 0
         self.windows_discarded = 0
+        # resolution -> the instant before which every closed window has
+        # been popped (emitted as leader, discarded as follower). A round
+        # whose target has not passed it walks no elem: a flush loop that
+        # checks every second walked every series sixty times a minute
+        # to find nothing. (A window staged behind it afterwards — a late
+        # sample, where those are kept — is behind the persisted flush
+        # time too, and is dropped when the target next moves, as it was
+        # a second later before.)
+        self._drained_to: Dict[int, int] = {}
 
     def flush(self, now_nanos: int) -> int:
         """One standalone flush pass; returns number of windows consumed."""
@@ -93,21 +115,27 @@ class FlushManager:
         commit()
         return n if self._election.state == ElectionState.LEADER else 0
 
-    def plan_into(self, now_nanos: int, batch):
+    def plan_into(self, now_nanos: int, batch,
+                  flushed: Optional[Dict[int, int]] = None):
         """Collect this manager's closed windows into `batch` (a columnar
         list.FlushBatch, so a caller can batch many managers' shards into
         ONE device reduction — Aggregator.flush does this across shards)
         plus a commit callback. commit(pending=None): with a dict, the
         shard's updated flush times are RECORDED into it for one batched
         FlushTimesManager.store_many; without, they store immediately.
+        `flushed`: this shard's persisted flush times where the caller
+        read them already (one read a round for every shard), and the
+        election campaigned for by the caller then too.
         Returns (windows_collected, commit)."""
-        self._election.campaign()
+        if flushed is None:
+            self._election.campaign()
+            flushed = self._flush_times.get(self._shard_id)
         if self._election.state == ElectionState.LEADER:
-            return self._plan_as_leader(now_nanos, batch)
-        return self._plan_as_follower(now_nanos)
+            return self._plan_as_leader(now_nanos, batch, flushed)
+        return self._plan_as_follower(now_nanos, flushed)
 
-    def _plan_as_leader(self, now_nanos: int, batch):
-        flushed = self._flush_times.get(self._shard_id)
+    def _plan_as_leader(self, now_nanos: int, batch, flushed):
+        persisted = dict(flushed)
         n = 0
         stale = 0
         for lst in self._lists.lists():
@@ -118,10 +146,12 @@ class FlushManager:
             # may still hold closed windows it had not yet discarded, and
             # re-emitting them would double-count in forwarded rollup
             # pipelines.
-            c, d = lst.collect_into(target, batch,
-                                    already=flushed.get(res, 0))
-            n += c
-            stale += d
+            if target > self._drained_to.get(res, -1):
+                c, d = lst.collect_into(target, batch,
+                                        already=flushed.get(res, 0))
+                self._drained_to[res] = target
+                n += c
+                stale += d
             # Resume after the last persisted flush (leader_flush_mgr.go:
             # flush times seed the flush schedule on promotion).
             flushed[res] = max(flushed.get(res, 0), target)
@@ -129,6 +159,8 @@ class FlushManager:
         self.windows_flushed += n
 
         def commit(pending: Optional[Dict[int, Dict[int, int]]] = None):
+            if flushed == persisted:
+                return      # nothing moved: KV already says so
             if pending is None:
                 self._flush_times.store(self._shard_id, flushed)
             else:
@@ -136,11 +168,10 @@ class FlushManager:
 
         return n, commit
 
-    def _plan_as_follower(self, now_nanos: int):
+    def _plan_as_follower(self, now_nanos: int, flushed):
         """Discard windows the leader already flushed (follower_flush_mgr.go
         flushersFromKVUpdateFn): keeps follower memory bounded and marks the
         follower caught-up so PendingFollower can complete."""
-        flushed = self._flush_times.get(self._shard_id)
         caught_up = True
         discarded = 0
         for lst in self._lists.lists():
@@ -148,7 +179,9 @@ class FlushManager:
             if leader_target is None:
                 caught_up = False
                 continue
-            discarded += len(lst.collect(leader_target))
+            if leader_target > self._drained_to.get(lst.resolution_ns, -1):
+                discarded += len(lst.collect(leader_target))
+                self._drained_to[lst.resolution_ns] = leader_target
         self.windows_discarded += discarded
 
         def commit(pending=None):

@@ -9,7 +9,8 @@ tests use the capture/blackhole handlers."""
 from __future__ import annotations
 
 import logging
-from typing import Callable, List, NamedTuple, Sequence
+import threading
+from typing import Callable, List, NamedTuple, Optional, Sequence
 
 from ..metrics.policy import StoragePolicy
 from ..utils.limits import Backpressure
@@ -197,6 +198,88 @@ class ProducerHandler(Handler):
                 self.dropped_backpressure += len(rows)
 
 
+class TopicProducerHandler(Handler):
+    """`ProducerHandler` over a topic NAMED in configuration and kept in
+    KV (handler/protobuf.go's writer is built from the topic service):
+    the producer is made when the topic is first seen and made again
+    when its consumer services change; each consumer service's placement
+    is read from `_placement/<service id>`, watched. Until a topic with a
+    consumer service exists a flush has nowhere to go: its rows are
+    counted in `dropped_no_topic` (the flush itself must finish)."""
+
+    def __init__(self, store, topic: str, **producer_opts):
+        from ..msg.topic import TopicService
+
+        self._store = store
+        self._opts = producer_opts
+        self._lock = threading.Lock()
+        self._inner: Optional[ProducerHandler] = None
+        self._producer = None
+        self._services: tuple = ()
+        self._placements: dict = {}     # service id -> Placement | None
+        self._watched: set = set()
+        self.dropped_no_topic = 0
+        TopicService(store).on_change(topic, self._on_topic)
+
+    def _on_placement(self, service_id: str, value):
+        import json
+
+        from ..cluster.placement import Placement
+
+        self._placements[service_id] = Placement.from_json(
+            json.loads(value.data.decode()), value.version)
+
+    def _on_topic(self, topic):
+        from ..msg.producer import Producer
+
+        with self._lock:
+            services = tuple(cs.service_id for cs in topic.consumer_services)
+            if services == self._services or not services:
+                return
+            for sid in services:
+                if sid not in self._watched:
+                    self._watched.add(sid)
+                    self._store.on_change(
+                        "_placement/" + sid,
+                        lambda _k, v, sid=sid: self._on_placement(sid, v))
+            old = self._producer
+            self._producer = Producer(
+                topic, {sid: (lambda sid=sid: self._placements.get(sid))
+                        for sid in services}, **self._opts)
+            self._inner = ProducerHandler(self._producer, topic.num_shards)
+            self._services = services
+        if old is not None:
+            old.close()
+
+    @property
+    def producer(self):
+        return self._producer
+
+    def unacked(self) -> int:
+        producer = self._producer
+        return producer.unacked() if producer is not None else 0
+
+    def handle(self, metric: AggregatedMetric):
+        inner = self._inner
+        if inner is None:
+            self.dropped_no_topic += 1
+        else:
+            inner.handle(metric)
+
+    def handle_columnar(self, groups):
+        inner = self._inner
+        if inner is None:
+            self.dropped_no_topic += sum(len(g[0]) for g in groups)
+        else:
+            inner.handle_columnar(groups)
+
+    def close(self):
+        with self._lock:
+            producer, self._producer, self._inner = self._producer, None, None
+        if producer is not None:
+            producer.close()
+
+
 def decode_aggregated(payload: bytes) -> AggregatedMetric:
     """Inverse of ProducerHandler's encoding, for the coordinator ingester."""
     from ..metrics.policy import StoragePolicy
@@ -205,6 +288,29 @@ def decode_aggregated(payload: bytes) -> AggregatedMetric:
     obj = wire.decode(payload)
     return AggregatedMetric(
         obj["id"], obj["t"], obj["v"], StoragePolicy.parse(obj["sp"]))
+
+
+def decode_aggregated_columns(payload: bytes) -> list:
+    """Either ProducerHandler payload form as columnar groups a storage
+    policy: [(policy, ids, times, values)], times and values as lists —
+    what a batched sink takes, with no object a row."""
+    from ..metrics.policy import StoragePolicy
+    from ..rpc import wire
+
+    obj = wire.decode(payload)
+    if not obj.get("b"):
+        return [(StoragePolicy.parse(obj["sp"]), [obj["id"]], [obj["t"]],
+                 [obj["v"]])]
+    ids, ts, vs, sps = (obj["ids"], _tolist(obj["ts"]), _tolist(obj["vs"]),
+                        obj["sps"])
+    if not sps or sps.count(sps[0]) == len(sps):    # one policy: no cut
+        return [(StoragePolicy.parse(sps[0]), ids, ts, vs)] if sps else []
+    cut: dict = {}
+    for i, sp in enumerate(sps):
+        cut.setdefault(sp, []).append(i)
+    return [(StoragePolicy.parse(sp), [ids[i] for i in rows],
+             [ts[i] for i in rows], [vs[i] for i in rows])
+            for sp, rows in cut.items()]
 
 
 def decode_aggregated_batch(payload: bytes) -> List[AggregatedMetric]:

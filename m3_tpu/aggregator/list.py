@@ -433,13 +433,17 @@ class MetricList:
         Tuple-job compat path (follower discard, tests); the flush hot loop
         uses collect_into."""
         jobs = []
-        for elem in self._elems.values():
+        # (a snapshot, and dead keys deleted one by one, as collect_into
+        # does: a handler thread adds a new series' elem beside a
+        # follower's discard pass — a dict that grows under its iterator
+        # raises, and a rebuilt dict would drop the elem added meanwhile)
+        for elem in list(self._elems.values()):
             for start, vals in elem.closed_buckets(target_nanos):
                 jobs.append((elem, start, vals))
-        self._elems = {
-            k: e for k, e in self._elems.items()
-            if not (e.tombstoned and e.is_empty())
-        }
+            if elem.tombstoned and elem.is_empty():
+                e = self._elems.get(elem.key)
+                if e is elem and e.tombstoned and e.is_empty():
+                    del self._elems[elem.key]
         return jobs
 
     def collect_into(self, target_nanos: int, batch: FlushBatch,
@@ -475,8 +479,10 @@ class MetricList:
                     # population): peek, and only pop once the window is
                     # known closed — an open window is never removed, so
                     # a concurrent stage of it can't be clobbered by a
-                    # put-back
-                    start = next(iter(b))
+                    # put-back. (`min`, one C call: a stager's fresh key
+                    # between an `iter` and its `next` raised "dictionary
+                    # changed size during iteration" and failed the round)
+                    start = min(b)
                     if start + res > target_nanos:
                         continue
                     v = b.pop(start)

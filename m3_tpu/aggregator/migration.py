@@ -24,6 +24,7 @@ so the two byte spaces can never collide."""
 from __future__ import annotations
 
 import json
+import time
 import struct
 from typing import List
 
@@ -100,6 +101,9 @@ class MigrationReader:
     def __init__(self, sock):
         self._sock = sock
         self._buf = bytearray()
+        # what the last binary frame's decode took (its bytes in hand):
+        # the server's `aggregator.rawtcp.frame` span reads it
+        self.decode_ns = 0
 
     def _fill(self, n: int) -> None:
         while len(self._buf) < n:
@@ -143,7 +147,10 @@ class MigrationReader:
         if n > MIGRATION_MAX_FRAME:
             raise ValueError(
                 f"migration: frame too large ({n} > {MIGRATION_MAX_FRAME})")
-        frame = wire.decode(self._take(n))
+        body = self._take(n)
+        t0 = time.perf_counter_ns()
+        frame = wire.decode(body)
+        self.decode_ns = time.perf_counter_ns() - t0
         if isinstance(frame, dict) and frame.get("t") == "batch":
             return list(frame["entries"])
         return [frame]

@@ -44,6 +44,7 @@ from ..metrics.matcher import pipeline_from_json, pipeline_to_json
 from ..metrics.metric import MetricType, MetricUnion
 from ..metrics.policy import StoragePolicy
 from ..rpc import wire
+from ..utils import tracing
 from ..utils.health import AdmissionGate, Priority
 from ..utils.limits import Backpressure, tenant_of
 from .aggregator import Aggregator
@@ -245,6 +246,7 @@ class RawTCPServer:
                 # migration (encoding/migration/unaggregated_iterator.go).
                 from .migration import MigrationReader, RecoverableRecordError
 
+                threading.current_thread().name = "aggregator-rawtcp-conn"
                 reader = MigrationReader(self.request)
                 try:
                     while True:
@@ -263,7 +265,10 @@ class RawTCPServer:
                         # frames counts successfully ingested RECORDS (a
                         # columnar tbatch carries one per id); a failed
                         # dispatch contributes errors, not phantom frames.
-                        n_rec = sum(outer._handle(e) for e in entries)
+                        # the frame's decode is its first entry's
+                        spent = [reader.decode_ns] + [0] * (len(entries) - 1)
+                        n_rec = sum(outer._handle(e, d)
+                                    for e, d in zip(entries, spent))
                         with outer._stats_lock:
                             outer.frames += n_rec
                 except (ConnectionError, OSError):
@@ -275,7 +280,27 @@ class RawTCPServer:
 
         self._server = _Server((host, port), _Handler)
 
-    def _handle(self, e: dict) -> int:
+    def _timed_batch(self, e: dict, n: int, decode_ns: int):
+        """`aggregator.rawtcp.frame`: a `tbatch` frame under a root span
+        of its own (the client's context rides no frame: the tier is
+        fire-and-forget), so what a frame costs this instance is read
+        where it is paid; an unsampled root times nothing."""
+        agg = self.aggregator
+        election = getattr(agg, "_election", None)
+        role = "leader" if election is None or election.is_leader() \
+            else "follower"
+        with tracing.background_span(
+                "aggregator.rawtcp.frame", instance=agg.instance_id,
+                role=role) as sp:
+            t0 = tracing.clock_ns() if sp.sampled else 0
+            late = dispatch_timed_batch(agg, e)
+            if sp.sampled:
+                sp.add_cost("decode_ns", decode_ns)
+                sp.add_cost("add_ns", tracing.clock_ns() - t0)
+                sp.add_cost("samples_n", n)
+                sp.add_cost("late_dropped_n", late)
+
+    def _handle(self, e: dict, decode_ns: int = 0) -> int:
         """Dispatch one entry; returns the record count it ingested
         (len(ids) for a columnar tbatch, else 1), 0 on failure. Both
         counters are in RECORDS: a failed tbatch charges its id count to
@@ -293,7 +318,10 @@ class RawTCPServer:
                else Priority.NORMAL)
         try:
             with self.gate.held(n, priority=pri, tenant=_frame_tenant(e)):
-                dispatch_entry(self.aggregator, e)
+                if e.get("t") == "tbatch":
+                    self._timed_batch(e, n, decode_ns)
+                else:
+                    dispatch_entry(self.aggregator, e)
         except Backpressure:
             # fire-and-forget transport: shed = counted drop (the msg
             # path's consumer converts the same condition into a skipped
@@ -333,7 +361,7 @@ def dispatch_entry(agg: Aggregator, e: dict):
             MetricType(e["mtype"]), e["id"], e["time"], e["value"],
             StoragePolicy.parse(e["policy"]), e.get("agg_id", 0))
     elif e["t"] == "tbatch":
-        dispatch_timed_batch(agg, e)
+        return dispatch_timed_batch(agg, e)
     elif e["t"] == "fbatch":
         dispatch_forwarded_batch(agg, e)
     elif e["t"] == "forwarded":
@@ -343,11 +371,12 @@ def dispatch_entry(agg: Aggregator, e: dict):
         raise ValueError(f"unknown entry type {e.get('t')!r}")
 
 
-def dispatch_timed_batch(agg: Aggregator, e: dict):
+def dispatch_timed_batch(agg: Aggregator, e: dict) -> int:
     """Columnar timed batch: type/policy parsed once, numeric columns
-    converted in one C pass (tolist), then the tight add_timed loop. A
+    converted in one C pass, then `Aggregator.add_timed_batch`. A
     length mismatch between the columns is a malformed frame (ValueError
-    -> the caller's per-entry error accounting)."""
+    -> the caller's per-entry error accounting). Returns the samples
+    dropped as late."""
     ids = e["ids"]
     times = e["times"]
     values = e["values"]
@@ -378,11 +407,9 @@ def dispatch_timed_batch(agg: Aggregator, e: dict):
         raise ValueError("tbatch times/values must be numeric columns")
     if times.ndim != 1 or values.ndim != 1:
         raise ValueError("tbatch times/values must be one-dimensional")
-    times = times.tolist()
-    values = values.tolist()
-    add = agg.add_timed
-    for mid, t, v in zip(ids, times, values):
-        add(mt, mid, t, v, pol, agg_id)
+    return agg.add_timed_batch(
+        mt, ids, times.tolist(),
+        np.ascontiguousarray(values, dtype=np.float64), pol, agg_id)
 
 
 class HTTPAdminServer:
@@ -439,7 +466,7 @@ class HTTPAdminServer:
                         self._reply(400, {"error": "not running an election"})
                         return
                     try:
-                        election.resign()
+                        agg.resign()    # between two flush rounds
                         self._reply(200, {"state": "OK"})
                     except Exception as e:  # noqa: BLE001
                         self._reply(500, {"error": str(e)})
@@ -571,6 +598,7 @@ class TCPTransport(_BatchingTransport):
         super().__init__(batch_size)
         self._endpoint = endpoint
         self._sock = None
+        self._send_lock = threading.Lock()
 
     def _encode(self, mu: MetricUnion, metadatas: Sequence[StagedMetadata]) -> dict:
         return union_to_wire(mu, metadatas)
@@ -631,15 +659,22 @@ class TCPTransport(_BatchingTransport):
         return self._send_frame({"t": "batch", "entries": batch})
 
     def _send_frame(self, frame: dict) -> bool:
-        """Write one frame with one reconnect attempt — the shared send
-        loop behind batch and tbatch shipping."""
-        for _ in range(2):
-            try:
-                sock = self._ensure_conn()
-                wire.write_frame(sock, frame)
-                return True
-            except OSError:
-                self._drop_conn()
+        return self.send_body(wire.encode(frame))
+
+    def send_body(self, body: bytes) -> bool:
+        """Write one frame the caller encoded, with one reconnect
+        attempt — the shared send loop behind batch, tbatch and fbatch
+        shipping. One frame at a time on the connection: the client's
+        request threads share it."""
+        with self._send_lock:
+            for _ in range(2):
+                try:
+                    # DELIBERATE I/O under the lock: its whole job is that
+                    # two threads' frames never interleave on the stream
+                    wire.write_body(self._ensure_conn(), body)  # m3lint: disable=lock-held-blocking-call
+                    return True
+                except OSError:
+                    self._drop_conn()
         return False
 
     def _ensure_conn(self):

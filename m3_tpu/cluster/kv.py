@@ -82,6 +82,11 @@ class MemStore:
             self._fire_many(list(items))
             return out
 
+    def get_many(self, keys) -> Dict[str, Value]:
+        """The values of `keys` that exist, read in one pass."""
+        with self._lock:
+            return {k: self._data[k] for k in keys if k in self._data}
+
     def _fire_many(self, keys):
         for k in keys:
             self._fire(k)

@@ -103,6 +103,12 @@ class KVServer:
                         "version": v.version if v else 0}
             if op == "keys":
                 return {"ok": True, "keys": store.keys(req.get("prefix", ""))}
+            if op == "get_many":
+                found = store.get_many(req["keys"])
+                return {"ok": True, "values": {
+                    k: [v.data, v.version] for k, v in found.items()}}
+            if op == "set_many":
+                return {"ok": True, "versions": store.set_many(req["items"])}
             return {"ok": False, "err": f"unknown op {op!r}", "kind": "proto"}
         except KeyError as e:
             return {"ok": False, "err": str(e), "kind": "exists"}
@@ -183,7 +189,7 @@ class RemoteStore:
         return s
 
     def _request(self, req: dict, deadline: Optional[Deadline] = None) -> dict:
-        read_only = req.get("op") in ("get", "keys")
+        read_only = req.get("op") in ("get", "keys", "get_many")
         if read_only:
             # Reads ride the retrier: reconnect + backoff per attempt,
             # bounded by max_attempts and the optional deadline.
@@ -290,6 +296,18 @@ class RemoteStore:
     def keys(self, prefix: str = "",
              deadline: Optional[Deadline] = None) -> List[str]:
         return self._request({"op": "keys", "prefix": prefix}, deadline)["keys"]
+
+    def get_many(self, keys) -> Dict[str, cluster_kv.Value]:
+        """MemStore.get_many in one exchange (an aggregator's flush round
+        reads every shard's flush times)."""
+        r = self._request({"op": "get_many", "keys": list(keys)})
+        return {k: cluster_kv.Value(d, v) for k, (d, v) in r["values"].items()}
+
+    def set_many(self, items) -> Dict[str, int]:
+        """MemStore.set_many in one exchange: one transaction at the
+        server, not re-sent on failure (as every mutation)."""
+        return self._request({"op": "set_many",
+                              "items": dict(items)})["versions"]
 
     # -- watches -----------------------------------------------------------
 

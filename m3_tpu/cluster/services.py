@@ -112,6 +112,13 @@ class LeaderService:
         """Try to become leader; returns resulting CampaignState."""
         now = self.clock()
         obj, version = self._current()
+        if (obj is not None and obj["leader"] == self.instance_id
+                and 0 <= now - obj.get("resigned_at", -self.lease_ttl_ns)
+                < self.lease_ttl_ns):
+            # this instance gave the lease up: the others get one TTL to
+            # take it before it campaigns again (an etcd election queues
+            # a resigner behind every waiting candidate)
+            return CampaignState.FOLLOWER
         if obj is None or now - obj["at"] >= self.lease_ttl_ns or obj["leader"] == self.instance_id:
             try:
                 self.store.check_and_set(
@@ -145,5 +152,7 @@ class LeaderService:
         obj, version = self._current()
         if obj is not None and obj["leader"] == self.instance_id:
             self.store.check_and_set(
-                self.key, version, json.dumps({"leader": obj["leader"], "at": 0}).encode()
+                self.key, version, json.dumps(
+                    {"leader": obj["leader"], "at": 0,
+                     "resigned_at": self.clock()}).encode()
             )

@@ -24,7 +24,7 @@ from ..utils import instrument
 from .admin import AdminAPI
 from .downsample import Downsampler
 from .http_api import HTTPApi
-from .ingest import DownsamplerAndWriter
+from .ingest import DownsamplerAndWriter, RemoteDownsampler
 from .rules_engine import RulesEngine
 from .selfscrape import SelfScraper
 
@@ -44,6 +44,11 @@ class Coordinator:
     clock: Optional[object] = None
     _flush_stop: Optional[threading.Event] = None
     _flush_thread: Optional[threading.Thread] = None
+    # `downsample.remote_aggregator`: the client of the aggregator tier
+    # (its placement watch and connections); `ingest.m3msg`: the consumer
+    # the tier's flushes come back through
+    aggregator_client: Optional["_RemoteAggregator"] = None
+    m3msg_consumer: Optional[object] = None
 
     @property
     def endpoint(self) -> str:
@@ -62,8 +67,9 @@ class Coordinator:
         namespaces; stopped by `close`. A round whose sink fails is
         counted (`coordinator.downsample.flush_errors`) and logged, and
         its rows are the next round's (`Downsampler._held`)."""
-        if self.downsampler is None or self._flush_thread is not None:
-            return
+        if not isinstance(self.downsampler, Downsampler) \
+                or self._flush_thread is not None:
+            return      # a remote tier flushes itself
         stop = self._flush_stop = threading.Event()
         errors = instrument.ROOT.counter("coordinator.downsample.flush_errors")
 
@@ -94,6 +100,81 @@ class Coordinator:
         if self.self_scraper is not None:
             self.self_scraper.stop()
         self.api.close()
+        if self.m3msg_consumer is not None:
+            self.m3msg_consumer.close()
+        if self.aggregator_client is not None:
+            self.aggregator_client.close()
+
+
+class _RemoteAggregator:
+    """The coordinator's side of `downsample.remote_aggregator`: the
+    aggregator placement in KV, watched, a connection an instance it
+    names, and the `AggregatorClient` that routes over both."""
+
+    def __init__(self, kv_store, placement_key: str):
+        from ..aggregator.client import AggregatorClient
+
+        self.placement = None
+        self.transports: Dict[str, object] = {}
+        self._kv, self._key = kv_store, placement_key
+        self.client = AggregatorClient(1, lambda: self.placement,
+                                       self.transports)
+        kv_store.on_change(placement_key, self._on_placement)
+
+    def _on_placement(self, _key, value):
+        import json
+
+        from ..aggregator.server import TCPTransport
+        from ..cluster.placement import Placement
+
+        p = Placement.from_json(json.loads(value.data.decode()),
+                                value.version)
+        for iid, inst in p.instances.items():
+            tr = self.transports.get(iid)
+            if tr is not None and tr._endpoint != inst.endpoint:
+                tr.close()
+                tr = None
+            if tr is None:
+                self.transports[iid] = TCPTransport(inst.endpoint)
+        for iid in set(self.transports) - set(p.instances):
+            self.transports.pop(iid).close()
+        self.client.num_shards = p.num_shards
+        self.placement = p
+
+    def close(self):
+        self._kv.off_change(self._key, self._on_placement)
+        for tr in list(self.transports.values()):
+            tr.close()
+
+
+def _start_m3msg(cfg, kv_store, aggregated_storages, clock):
+    """`ingest.m3msg`: the consumer, its ingester, and this coordinator
+    made the topic's consumer service in KV (the topic, created or
+    joined, and the service's placement: this one instance, every
+    shard), so an aggregator's `producer` handler finds it by name."""
+    import json
+
+    from ..cluster.placement import (Instance, Placement, ShardAssignment,
+                                     ShardState)
+    from ..msg.consumer import Consumer
+    from ..msg.topic import ConsumerService, Topic, TopicService
+    from .ingest import M3MsgIngester
+
+    ingester = M3MsgIngester(aggregated_storages.get, clock=clock)
+    host, _, port = cfg.listen_address.rpartition(":")
+    consumer = Consumer(ingester, host=host or "127.0.0.1",
+                        port=int(port or 0)).start()
+    inst = Instance(cfg.consumer_service, consumer.endpoint, shards={
+        s: ShardAssignment(s, ShardState.AVAILABLE)
+        for s in range(cfg.num_shards)})
+    kv_store.set("_placement/" + cfg.consumer_service, json.dumps(
+        Placement({inst.id: inst}, cfg.num_shards, 1).to_json()).encode())
+    topics = TopicService(kv_store)
+    topic = topics.get(cfg.topic) or Topic(cfg.topic, cfg.num_shards)
+    if cfg.consumer_service not in {c.service_id
+                                    for c in topic.consumer_services}:
+        topics.upsert(topic.add_consumer(ConsumerService(cfg.consumer_service)))
+    return consumer
 
 
 def _policy_of(attrs: NamespaceAttrs) -> StoragePolicy:
@@ -126,15 +207,29 @@ def _build(storage, aggregated_storages: Dict[StoragePolicy, object],
            listen=("127.0.0.1", 0),
            self_scrape_interval_s: Optional[float] = None,
            device_scope=None,
-           downsample_all: Sequence[StoragePolicy] = ()) -> Coordinator:
+           downsample_all: Sequence[StoragePolicy] = (),
+           remote_aggregator=None, m3msg=None) -> Coordinator:
     """`device_scope` (parallel/scope.py): the devices this coordinator
     owns — its engine's query mesh is built over them and its HTTP
     handler threads work inside it; None owns every attached device.
     `downsample_all`: the policies of the `downsample.all` namespaces,
     installed as the default mapping rule (every metric, the metric
     type's default aggregation — `last` for a gauge) beside whatever
-    rule set the KV store holds."""
+    rule set the KV store holds. `remote_aggregator` (placement_key,
+    replicas): what the rules match goes to that m3aggregator placement
+    as timed metrics and no downsampler is embedded; `m3msg`
+    (listen_address, topic, consumer_service, num_shards): the consumer
+    that writes the tier's flushes into the aggregated namespaces. Both
+    live in `kv_store`."""
     downsampler = None
+    remote = consumer = None
+    if (remote_aggregator is not None or m3msg is not None) \
+            and kv_store is None:
+        raise ValueError("a remote aggregator and an m3msg ingester need "
+                         "the cluster's KV store")
+    if m3msg is not None:
+        consumer = _start_m3msg(m3msg, kv_store, aggregated_storages,
+                                clock)
     if kv_store is not None or downsample_all:
         auto = ()
         if downsample_all:
@@ -166,8 +261,16 @@ def _build(storage, aggregated_storages: Dict[StoragePolicy, object],
                     for mid, tags, t_ns, value, _pol in group:
                         target.write(mid, tags, t_ns, value)
 
-        downsampler = Downsampler(matcher, write_aggregated, clock=clock,
-                                  write_aggregated_batch=write_aggregated_batch)
+        if remote_aggregator is not None:
+            remote = _RemoteAggregator(kv_store,
+                                       remote_aggregator.placement_key)
+            downsampler = RemoteDownsampler(
+                matcher, remote.client, remote_aggregator.replicas,
+                lambda: remote.placement)
+        else:
+            downsampler = Downsampler(
+                matcher, write_aggregated, clock=clock,
+                write_aggregated_batch=write_aggregated_batch)
     writer = DownsamplerAndWriter(storage, downsampler)
     with dscope.entered(device_scope):
         engine = Engine(storage)
@@ -183,7 +286,8 @@ def _build(storage, aggregated_storages: Dict[StoragePolicy, object],
         scraper = SelfScraper(writer, clock=clock,
                               interval_s=self_scrape_interval_s).start()
     return Coordinator(engine, writer, api, downsampler, admin, scraper,
-                       clock=clock)
+                       clock=clock, aggregator_client=remote,
+                       m3msg_consumer=consumer)
 
 
 def run_embedded(db, namespace: bytes = b"default",
@@ -193,10 +297,11 @@ def run_embedded(db, namespace: bytes = b"default",
                  create_namespace=None,
                  self_scrape_interval_s: Optional[float] = None,
                  device_scope=None,
-                 cluster_namespaces: Optional[Sequence[NamespaceAttrs]] = None
-                 ) -> Coordinator:
+                 cluster_namespaces: Optional[Sequence[NamespaceAttrs]] = None,
+                 remote_aggregator=None, m3msg=None) -> Coordinator:
     """`cluster_namespaces`: the coordinator's namespace list
-    (`_storages`); given, it stands for `namespace`."""
+    (`_storages`); given, it stands for `namespace`. `remote_aggregator`
+    and `m3msg`: `_build`'s."""
     storage, agg, auto = _storages(
         lambda ns: LocalStorage(db, ns), namespace, cluster_namespaces, clock)
 
@@ -210,7 +315,8 @@ def run_embedded(db, namespace: bytes = b"default",
     return _build(storage, agg, kv_store, rules_namespace, clock,
                   create_namespace, listen,
                   self_scrape_interval_s=self_scrape_interval_s,
-                  device_scope=device_scope, downsample_all=auto)
+                  device_scope=device_scope, downsample_all=auto,
+                  remote_aggregator=remote_aggregator, m3msg=m3msg)
 
 
 def run_clustered(session, namespace: bytes = b"default",
@@ -219,11 +325,12 @@ def run_clustered(session, namespace: bytes = b"default",
                   clock=None, listen=("127.0.0.1", 0),
                   self_scrape_interval_s: Optional[float] = None,
                   device_scope=None,
-                  cluster_namespaces: Optional[Sequence[NamespaceAttrs]] = None
-                  ) -> Coordinator:
+                  cluster_namespaces: Optional[Sequence[NamespaceAttrs]] = None,
+                  remote_aggregator=None, m3msg=None) -> Coordinator:
     storage, agg, auto = _storages(
         lambda ns: SessionStorage(session, ns), namespace,
         cluster_namespaces, clock)
     return _build(storage, agg, kv_store, rules_namespace, clock, None,
                   listen, self_scrape_interval_s=self_scrape_interval_s,
-                  device_scope=device_scope, downsample_all=auto)
+                  device_scope=device_scope, downsample_all=auto,
+                  remote_aggregator=remote_aggregator, m3msg=m3msg)

@@ -18,7 +18,9 @@ from collections import deque
 from typing import Callable, List, Optional
 
 from ..rpc import wire
-from ..utils import tracing
+from ..utils import instrument, tracing
+
+_ACKS = instrument.ROOT.counter("msg.consumer.acks")
 
 
 class Consumer:
@@ -101,6 +103,7 @@ class Consumer:
             def handle(self):
                 import select
 
+                threading.current_thread().name = "m3msg-consume"
                 sock = self.request
                 pending_acks: List[int] = []
                 with outer._dedup_lock:
@@ -111,6 +114,7 @@ class Consumer:
                     nonlocal pending_acks
                     if pending_acks:
                         wire.write_frame(sock, {"t": "ack", "ids": pending_acks})
+                        _ACKS.inc(len(pending_acks))
                         pending_acks = []
 
                 try:
@@ -155,7 +159,9 @@ class Consumer:
                         tctx = wire.trace_from_frame(frame)
                         try:
                             with tracing.TRACER.span_from(
-                                    tctx, "msg.consume", shard=shard):
+                                    tctx, "msg.consume", shard=shard) as sp:
+                                if sp.sampled:
+                                    sp.add_cost("bytes", len(value))
                                 outer._handler(shard, value)
                         except Exception:  # noqa: BLE001 - app error, not desync
                             # Handler failure is the APPLICATION's error:

@@ -20,10 +20,13 @@ from typing import Callable, Dict, List, Optional
 
 from ..cluster.placement import Placement, ShardState
 from ..rpc import wire
-from ..utils import tracing
+from ..utils import instrument, tracing
 from ..utils.limits import Backpressure
 from ..utils.retry import Breaker, BreakerOptions, Retrier, RetryOptions
 from .topic import ConsumptionType, Topic
+
+
+_REDELIVERIES = instrument.ROOT.counter("msg.producer.redeliveries")
 
 
 class _Message:
@@ -49,12 +52,13 @@ class _Tracked:
     redelivery state must live here: writer A's successful send must not
     push writer B's first delivery down B's backoff schedule."""
 
-    __slots__ = ("msg", "due_at", "attempts")
+    __slots__ = ("msg", "due_at", "attempts", "first_sent")
 
     def __init__(self, msg: _Message):
         self.msg = msg
         self.due_at = 0    # monotonic ns when the next resend is due
         self.attempts = 0  # this writer's frame writes; drives its backoff
+        self.first_sent = 0  # spans' clock, at the first frame write
 
 
 def _writer_breaker_opts(retry_delay_s: float) -> BreakerOptions:
@@ -62,6 +66,18 @@ def _writer_breaker_opts(retry_delay_s: float) -> BreakerOptions:
     of connect/send failures, probes again after a few retry ticks."""
     return BreakerOptions(window=8, failure_ratio=0.5, min_samples=4,
                           cooldown_s=max(0.25, 2.0 * retry_delay_s))
+
+
+def _note_acked(t: _Tracked):
+    """`msg.produce`: one root a message, opened where its
+    acknowledgement arrives (a publish returns before it): costs
+    `ack_wait_ns` (first frame write to acknowledgement), `redelivered_n`
+    (frame writes after the first) and `bytes`."""
+    with tracing.background_span("msg.produce", shard=t.msg.shard) as sp:
+        if sp.sampled:
+            sp.add_cost("ack_wait_ns", tracing.clock_ns() - t.first_sent)
+            sp.add_cost("redelivered_n", max(0, t.attempts - 1))
+            sp.add_cost("bytes", t.msg.size)
 
 
 class MessageWriter:
@@ -144,7 +160,7 @@ class MessageWriter:
             return False
         self._breaker.record_success()
         self._reader = threading.Thread(
-            target=self._read_acks, name="producer-acks", daemon=True)
+            target=self._read_acks, name="m3msg-producer-acks", daemon=True)
         self._reader.start()
         return True
 
@@ -170,6 +186,8 @@ class MessageWriter:
                     # ids 0..N can never collide into a silent drop
                     frame["src"] = self._src
                 wire.write_frame(self._sock, frame)  # m3lint: disable=lock-held-blocking-call
+                if not t.attempts:
+                    t.first_sent = tracing.clock_ns()
                 t.attempts += 1
                 # The due time is rolled ONCE per send (jitter included):
                 # the scan below is then one integer compare per message,
@@ -207,6 +225,7 @@ class MessageWriter:
                              if i in self._queue]
                 for t in acked:
                     self.acked += 1
+                    _note_acked(t)
                     if self._on_ack is not None:
                         self._on_ack(t.msg)
         except (ConnectionError, OSError, ValueError):
@@ -237,6 +256,8 @@ class MessageWriter:
             stale = [t for t in self._queue.values() if now >= t.due_at]
         for t in stale:
             self.retried += 1
+            if t.attempts:
+                _REDELIVERIES.inc()
             if not self._send(t):
                 break
 
@@ -415,7 +436,8 @@ class Producer:
         # failing consumer handler live.
         self._closed = False
         self._retry_thread = threading.Thread(
-            target=self._retry_loop, name="producer-retry", daemon=True)
+            target=self._retry_loop, name="m3msg-producer-retry",
+            daemon=True)
         self._retry_thread.start()
 
     def publish(self, shard: int, value: bytes) -> int:

@@ -18,11 +18,12 @@ from .run import (
     run_collector,
     run_coordinator,
     run_dbnode,
+    run_kv,
 )
 
 __all__ = [
     "AggregatorConfig", "AggregatorHandle", "CollectorConfig", "ConfigError",
     "CoordinatorConfig", "DBNodeConfig", "DBNodeHandle", "NamespaceConfig",
     "load_dict", "load_file", "run_aggregator", "run_collector",
-    "run_coordinator", "run_dbnode",
+    "run_coordinator", "run_dbnode", "run_kv",
 ]

@@ -144,6 +144,46 @@ class ClusterNamespaceConfig:
 
 
 @dataclasses.dataclass
+class RemoteAggregatorConfig:
+    """`downsample.remote_aggregator` (the reference's
+    `downsample.remoteAggregator.client`): every sample a rule matches
+    goes to the m3aggregator placement this names, to every replica of
+    its shard, in place of the embedded downsampler."""
+
+    # The aggregator placement in the coordinator's KV store (what each
+    # `aggregator` service registers itself in).
+    placement_key: str = "_agg_placement"
+    # Writes are refused (503, nothing sent) until this many instances
+    # own every shard: a coordinator that boots before the tier never
+    # feeds one replica of a pair alone.
+    replicas: int = 1
+
+
+@dataclasses.dataclass
+class CoordinatorDownsampleConfig:
+    remote_aggregator: Optional[RemoteAggregatorConfig] = None
+
+
+@dataclasses.dataclass
+class M3MsgIngestConfig:
+    """`ingest.m3msg` (the reference's `ingest.m3msg.server`): the
+    consumer of the aggregators' flush topic. At start the coordinator
+    makes itself the topic's consumer service in KV (the topic, and the
+    service's one-instance placement at `_placement/<consumer_service>`),
+    so an aggregator's `producer` flush handler finds it by name."""
+
+    listen_address: str = "127.0.0.1:0"
+    topic: str = "aggregated_metrics"
+    consumer_service: str = "m3coordinator"
+    num_shards: int = 64
+
+
+@dataclasses.dataclass
+class CoordinatorIngestConfig:
+    m3msg: Optional[M3MsgIngestConfig] = None
+
+
+@dataclasses.dataclass
 class CoordinatorConfig:
     listen_address: str = "127.0.0.1:0"
     # One unaggregated namespace, read and written; or `namespaces`.
@@ -169,6 +209,19 @@ class CoordinatorConfig:
     # decode), as DBNodeConfig.devices. Empty: every attached device,
     # or the node's own when embedded in one.
     devices: List[int] = dataclasses.field(default_factory=list)
+    # `downsample.remote_aggregator`: a standalone m3aggregator tier in
+    # place of the embedded downsampler; `ingest.m3msg`: the consumer its
+    # flushes come back through (services/run.py assembles both).
+    downsample: Optional[CoordinatorDownsampleConfig] = None
+    ingest: Optional[CoordinatorIngestConfig] = None
+
+    @property
+    def remote_aggregator(self) -> Optional[RemoteAggregatorConfig]:
+        return self.downsample.remote_aggregator if self.downsample else None
+
+    @property
+    def m3msg(self) -> Optional[M3MsgIngestConfig]:
+        return self.ingest.m3msg if self.ingest else None
 
     @property
     def self_scrape_interval_s(self) -> Optional[float]:
@@ -210,6 +263,31 @@ class AggregatorConfig:
     # Leader lease TTL: a dead leader's lease expires after this long and a
     # follower's campaign wins (services/leader etcd-session TTL analog).
     election_ttl: str = "10s"
+    # A window closes this long after its end (maxAllowedWriteLatency /
+    # bufferDurationBeforeShardCutover's part in list.go flushBeforeFn):
+    # a timed sample that arrives later than that is dropped and counted.
+    buffer_past: str = "0s"
+    # Where flushed aggregates go: `producer` publishes them to `topic`
+    # over m3msg (handler/protobuf.go), `file` appends them to
+    # `flush_log`. Empty: `file` when `flush_log` is set, else whatever
+    # the caller of run_aggregator passes.
+    flush_handler: str = ""
+    # With a placement_key: join the placement at start as a member of
+    # `shard_set_id` (created if this is the first instance), owning the
+    # shard set's shards at this instance's own listen address. The
+    # members of one shard set mirror each other: RF is their number.
+    register_in_placement: bool = False
+
+    def validate(self):
+        if self.flush_handler not in ("", "producer", "file"):
+            raise ConfigError(
+                f"aggregator flush_handler {self.flush_handler!r}: one of "
+                "producer, file")
+        if self.flush_handler == "file" and not self.flush_log:
+            raise ConfigError("aggregator flush_handler file needs flush_log")
+        if self.register_in_placement and not self.placement_key:
+            raise ConfigError(
+                "aggregator register_in_placement needs a placement_key")
 
 
 @dataclasses.dataclass
@@ -271,6 +349,10 @@ _NESTED = {
     (DBNodeConfig, "coordinator"): CoordinatorConfig,
     (CoordinatorConfig, "namespaces"): ClusterNamespaceConfig,
     (ClusterNamespaceConfig, "downsample"): DownsampleConfig,
+    (CoordinatorConfig, "downsample"): CoordinatorDownsampleConfig,
+    (CoordinatorDownsampleConfig, "remote_aggregator"): RemoteAggregatorConfig,
+    (CoordinatorConfig, "ingest"): CoordinatorIngestConfig,
+    (CoordinatorIngestConfig, "m3msg"): M3MsgIngestConfig,
 }
 
 

@@ -9,6 +9,7 @@ An embedded coordinator inside the dbnode mirrors the reference's
 from __future__ import annotations
 
 import dataclasses
+import logging
 import os
 import threading
 import time
@@ -29,6 +30,7 @@ from ..query.promql import parse_duration_ns
 from ..rpc.node_server import NodeServer, NodeService
 from ..storage.database import Database
 from ..storage.namespace import NamespaceOptions
+from ..utils.instrument import ROOT
 from .config import (
     AggregatorConfig,
     CollectorConfig,
@@ -36,6 +38,9 @@ from .config import (
     CoordinatorConfig,
     DBNodeConfig,
 )
+
+
+_LOG = logging.getLogger(__name__)
 
 
 def _kv_store(path: str, endpoint: str = "") -> cluster_kv.MemStore:
@@ -230,7 +235,9 @@ def run_dbnode(cfg: DBNodeConfig, clock=None) -> DBNodeHandle:
             device_scope=dscope.from_config(
                 cfg.coordinator.devices, cfg.host_id + ".coordinator")
             or scope,
-            cluster_namespaces=_cluster_namespaces(cfg.coordinator))
+            cluster_namespaces=_cluster_namespaces(cfg.coordinator),
+            remote_aggregator=cfg.coordinator.remote_aggregator,
+            m3msg=cfg.coordinator.m3msg)
         _start_downsample_flush(coordinator, cfg.coordinator)
     mediator = None
     if cfg.tick_interval:
@@ -285,6 +292,8 @@ class AggregatorHandle:
         closer = getattr(self.flush_handler, "close", None)
         if closer is not None:
             closer()
+        if hasattr(self.kv, "close"):
+            self.kv.close()  # RemoteStore: stops watch threads + socket
 
 
 def run_aggregator(cfg: AggregatorConfig, flush_handler=None,
@@ -298,17 +307,20 @@ def run_aggregator(cfg: AggregatorConfig, flush_handler=None,
     kv = _kv_store(cfg.kv_path, cfg.kv_endpoint)
     clock = clock or time.time_ns
     owned_handler = None
-    if flush_handler is None and cfg.flush_log:
-        from ..aggregator.handler import FileHandler
-
-        flush_handler = owned_handler = FileHandler(cfg.flush_log)
+    if flush_handler is None:
+        flush_handler = owned_handler = _flush_handler(cfg, kv)
     leader = LeaderService(kv, cfg.election_id, cfg.instance_id, clock=clock,
                            lease_ttl_ns=parse_duration_ns(cfg.election_ttl))
-    election = ElectionManager(leader)
+    election = ElectionManager(
+        leader, on_change=lambda state: ROOT.sub_scope(
+            "aggregator.election", instance=cfg.instance_id,
+            to=state.name.lower()).counter("transitions").inc())
     flush_times = FlushTimesManager(kv, cfg.shard_set_id)
     agg = Aggregator(num_shards=cfg.num_shards, clock=clock,
                      flush_handler=flush_handler, election=election,
-                     flush_times=flush_times)
+                     flush_times=flush_times,
+                     buffer_past_ns=parse_duration_ns(cfg.buffer_past),
+                     instance_id=cfg.instance_id, drop_late_timed=True)
     host, port = _host_port(cfg.listen_address)
     server = RawTCPServer(agg, host=host, port=port).start()
 
@@ -349,6 +361,8 @@ def run_aggregator(cfg: AggregatorConfig, flush_handler=None,
                 on_placement(shards)
 
         kv.on_change(cfg.placement_key, _on_placement)
+        if cfg.register_in_placement:
+            _join_placement(kv, cfg, server.endpoint)
 
     admin = None
     if cfg.admin_address:
@@ -365,17 +379,75 @@ def run_aggregator(cfg: AggregatorConfig, flush_handler=None,
     handle = AggregatorHandle(agg, server, None, kv, admin, owned_handler)
     interval_s = parse_duration_ns(cfg.flush_interval) / 1e9
 
+    errors = ROOT.sub_scope("aggregator.flush",
+                            instance=cfg.instance_id).counter("errors")
+    seen = set()
+
     def flush_loop():
         while not handle._stop.wait(interval_s):
             try:
                 agg.flush()
-            except Exception:  # noqa: BLE001 - keep the loop alive
-                pass
+            except Exception as e:  # noqa: BLE001 - keep the loop alive
+                # counted, so a tier that stopped flushing shows at
+                # /debug/vars; the first of a kind is logged whole
+                errors.inc()
+                kind = (type(e).__name__, str(e)[:80])
+                if kind not in seen and len(seen) < 64:
+                    seen.add(kind)
+                    _LOG.exception("aggregator %s: flush failed",
+                                   cfg.instance_id)
 
     handle.flush_thread = threading.Thread(
         target=flush_loop, name="aggregator-flush", daemon=True)
     handle.flush_thread.start()
     return handle
+
+
+def _flush_handler(cfg: AggregatorConfig, kv):
+    """The flush handler the configuration names (`flush_handler`;
+    absent, `file` where `flush_log` is set)."""
+    kind = cfg.flush_handler or ("file" if cfg.flush_log else "")
+    if kind == "file":
+        from ..aggregator.handler import FileHandler
+
+        return FileHandler(cfg.flush_log)
+    if kind == "producer":
+        from ..aggregator.handler import TopicProducerHandler
+
+        return TopicProducerHandler(kv, cfg.topic)
+    return None
+
+
+def _join_placement(kv, cfg: AggregatorConfig, endpoint: str):
+    """`register_in_placement`: this instance made a member of its shard
+    set in the placement at `placement_key`, which is created by the
+    first to come. One shard set owns every shard and its members mirror
+    each other, so RF is their number (a placement of several shard sets
+    is an operator's to lay out: `cluster/placement.py`
+    `mirrored_initial_placement`, the coordinator's admin API)."""
+    import json as _json
+
+    from ..cluster import kv as kvmod
+    from ..cluster.placement import (Instance, Placement, ShardAssignment,
+                                     ShardState)
+
+    for _ in range(16):
+        obj, version = kvmod.get_json(kv, cfg.placement_key)
+        p = Placement.from_json(obj, version) if obj is not None \
+            else Placement({}, cfg.num_shards, 0, is_mirrored=True)
+        p.instances[cfg.instance_id] = Instance(
+            cfg.instance_id, endpoint, shard_set_id=cfg.shard_set_id,
+            shards={s: ShardAssignment(s, ShardState.AVAILABLE)
+                    for s in range(p.num_shards)})
+        p.replica_factor = len(p.instances)
+        try:
+            kv.check_and_set(cfg.placement_key, version,
+                             _json.dumps(p.to_json()).encode())
+            return
+        except ValueError:      # the pair's other member came between
+            continue
+    raise RuntimeError(f"aggregator {cfg.instance_id}: could not join the "
+                       f"placement at {cfg.placement_key!r}")
 
 
 def _cluster_namespaces(cfg: CoordinatorConfig):
@@ -419,7 +491,9 @@ def run_coordinator(cfg: CoordinatorConfig, session=None, db=None,
                              clock=clock, listen=listen,
                              self_scrape_interval_s=scrape_s,
                              device_scope=scope or db.scope,
-                             cluster_namespaces=members)
+                             cluster_namespaces=members,
+                             remote_aggregator=cfg.remote_aggregator,
+                             m3msg=cfg.m3msg)
     else:
         coord = run_clustered(session, namespace=cfg.namespace.encode(),
                               kv_store=kv_store,
@@ -427,7 +501,9 @@ def run_coordinator(cfg: CoordinatorConfig, session=None, db=None,
                               clock=clock, listen=listen,
                               self_scrape_interval_s=scrape_s,
                               device_scope=scope,
-                              cluster_namespaces=members)
+                              cluster_namespaces=members,
+                              remote_aggregator=cfg.remote_aggregator,
+                              m3msg=cfg.m3msg)
     _start_downsample_flush(coord, cfg)
     if cfg.remotes:
         stores = [coord.engine.storage] + [RemoteStorage(r) for r in cfg.remotes]

@@ -96,8 +96,9 @@ class BlockBucket:
 
     block_start: int
     cols: _Cols = dataclasses.field(default_factory=_Cols)
-    # Rows already drained to a snapshot (exclusive); snapshot persistence
-    # reuses the same columns without copying.
+    # Rows its newest persisted snapshot holds (the mediator's mark): the
+    # columns only append, so a bucket still at this count is that
+    # snapshot's content and is not written again.
     snapshotted_rows: int = 0
     # The index by series over rows [0:indexed_n), built by reads and
     # never by an append: `order` is the stable argsort of the series

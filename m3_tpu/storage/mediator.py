@@ -19,7 +19,7 @@ from typing import Dict, Optional
 
 from ..parallel import scope as dscope
 from ..persist.diskio import DiskWriteError
-from ..persist.fs import PersistManager
+from ..persist.fs import PersistManager, fileset_complete
 from ..storage.block import encode_block
 from ..utils import tracing, xtime
 
@@ -38,6 +38,8 @@ class Mediator:
         self.opts = opts
         self._snapshot_version = 0
         self._version_seeded = False
+        # snapshot volumes cleanup has seen complete (their paths)
+        self._complete_snapshots: set = set()
         self._thread: Optional[threading.Thread] = None
         self._stop = threading.Event()
         self.last_stats: Dict[str, int] = {}
@@ -72,7 +74,9 @@ class Mediator:
 
     def snapshot(self, now_ns: int) -> int:
         """Persist warm (still-mutable) buckets as snapshot filesets
-        (storage/flush.go snapshot state; persist/fs snapshot volumes).
+        (storage/flush.go snapshot state; persist/fs snapshot volumes):
+        those that took a row since their last snapshot; the others keep
+        the one they have. Returns the buckets written.
 
         The commit log position is recorded ONCE, before any buffer is
         read: every WAL entry durable at-or-before it is provably
@@ -129,6 +133,21 @@ class Mediator:
                         # (the pre-existing snapshot, if any, remains
                         # the newest for this block start).
                         continue
+                    # A bucket's columns only append: the row count
+                    # its newest snapshot was cut at is its content.
+                    # Nothing appended since leaves that snapshot the
+                    # newest of its block start (cleanup keeps it; its
+                    # older WAL position only means the replay looks
+                    # through more chunks, which hold none of its rows).
+                    # Read BEFORE the columns: an append between the two
+                    # is in this snapshot and asks for the next.
+                    bucket = shard.buffer.buckets.get(bs)
+                    if bucket is None:      # sealed meanwhile
+                        continue
+                    rows = bucket.cols.n
+                    if rows == bucket.snapshotted_rows:
+                        tracing.count_cost("buckets_unchanged_n")
+                        continue
                     with tracing.phase("buffer_snapshot"):
                         dense = shard.buffer.snapshot(bs)
                     if dense is None:
@@ -150,48 +169,73 @@ class Mediator:
                         if health is not None:
                             health.failure()
                         continue
+                    bucket.snapshotted_rows = rows
                     count += 1
         return count
 
     def cleanup(self, now_ns: int) -> int:
         """cleanup.go: remove filesets past retention, superseded snapshots,
-        and snapshots for blocks already flushed."""
+        and snapshots for blocks already flushed.
+
+        One directory listing a shard, and a digest chain is read only
+        where the answer decides something: a fileset that is up for
+        removal or shares its block start with a snapshot, a snapshot
+        this mediator has not yet seen complete (a complete volume is
+        never written again under its name, so what was seen stays)."""
         removed = 0
+        seen_complete = set()
         for ns in list(self.db.namespaces.values()):
             cutoff = now_ns - ns.opts.retention_ns
+            block_size = ns.opts.block_size_ns
             for shard_id in ns.shards:
                 shard_dir = os.path.join(self.persist.root, ns.name.decode(),
                                          f"shard-{shard_id:05d}")
-                if os.path.isdir(shard_dir):
-                    for name in os.listdir(shard_dir):
-                        if name.endswith(".tmp"):
-                            # Mid-write crash residue (SIGKILL between
-                            # the checkpoint write and os.replace):
-                            # never servable, never auto-replaced.
-                            shutil.rmtree(os.path.join(shard_dir, name),
-                                          ignore_errors=True)
-                            removed += 1
-                shard_removed = 0
-                for bs, path in self.persist.list_filesets(ns.name, shard_id):
-                    if bs + ns.opts.block_size_ns <= cutoff:
+                try:
+                    names = os.listdir(shard_dir)
+                except (FileNotFoundError, NotADirectoryError):
+                    continue
+                filesets: Dict[int, str] = {}
+                snaps = []
+                for name in names:
+                    path = os.path.join(shard_dir, name)
+                    if name.endswith(".tmp"):
+                        # Mid-write crash residue (SIGKILL between
+                        # the checkpoint write and os.replace):
+                        # never servable, never auto-replaced.
                         shutil.rmtree(path, ignore_errors=True)
+                        removed += 1
+                    elif name.startswith("fileset-"):
+                        filesets[int(name.split("-")[-1])] = path
+                    elif name.startswith("snapshot-"):
+                        _, version, bs = name.split("-")
+                        if path in self._complete_snapshots \
+                                or fileset_complete(path):
+                            seen_complete.add(path)
+                            snaps.append((int(bs), int(version), path))
+                shard_removed = 0
+                for bs in sorted(filesets):
+                    if bs + block_size <= cutoff \
+                            and fileset_complete(filesets[bs]):
+                        shutil.rmtree(filesets.pop(bs), ignore_errors=True)
                         shard_removed += 1
                 if shard_removed and getattr(self.db, "retriever", None) is not None:
                     # Cached listings/seekers/wired rows now point at deleted
                     # directories — drop them before the next cold read.
                     self.db.retriever.invalidate(ns.name, shard_id)
                 removed += shard_removed
-                snaps = self.persist.list_snapshots(ns.name, shard_id)
                 newest: Dict[int, int] = {}
                 for bs, version, _p in snaps:
                     newest[bs] = max(newest.get(bs, -1), version)
-                flushed = {bs for bs, _p in self.persist.list_filesets(ns.name, shard_id)}
-                for bs, version, path in snaps:
+                flushed = {bs for bs in newest
+                           if bs in filesets and fileset_complete(filesets[bs])}
+                for bs, version, path in sorted(snaps):
                     stale = (version < newest[bs] or bs in flushed
-                             or bs + ns.opts.block_size_ns <= cutoff)
+                             or bs + block_size <= cutoff)
                     if stale:
                         shutil.rmtree(path, ignore_errors=True)
+                        seen_complete.discard(path)
                         removed += 1
+        self._complete_snapshots = seen_complete
         removed += self._trim_commitlog()
         return removed
 

@@ -279,7 +279,8 @@ def _env_rate() -> float:
 #   stall. A thread's role is the kind of the last root span it opened
 #   (`http.` request, `rpc.` rpc of a node, `mediator.tick` tick,
 #   `bootstrap.` bootstrap), else what its name says (accept, fanout,
-#   prep, cache-fill, probe, main), else `python-other`; a thread of the process that is
+#   prep, cache-fill, aggregator, m3msg, probe, main), else
+#   `python-other`; a thread of the process that is
 #   no Python thread is `native` (XLA's, the TPU runtime's). A request's
 #   thread lives 10-70 ms, so a root counts its thread's CPU when it
 #   ends and the sample counts whatever a live thread has used past
@@ -304,6 +305,7 @@ _ROOT_ROLES = (("http.", "request"), ("rpc.", "rpc"),
                ("mediator.tick", "tick"), ("bootstrap.", "bootstrap"))
 _NAME_ROLES = (("accept", "accept"), ("fanout", "fanout"),
                ("tsz-prep", "prep"), ("block-cache-fill", "cache-fill"),
+               ("aggregator", "aggregator"), ("m3msg", "m3msg"),
                (PROBE_THREAD_NAME, "probe"),
                ("MainThread", "main"))
 _ADDITIVE = ("process_cpu_ns", "probe.wakes", "probe.late_ns",
@@ -652,21 +654,26 @@ class Tracer:
         self._recent = collections.deque(maxlen=max_traces)
         self.sample_rate = _env_rate() if sample_rate is None else sample_rate
         self.runtime = RuntimeProbe()
+        # Each finished root is handed to these as it joins the ring (the
+        # reference's jaeger reporter: an exporter, or a reader that must
+        # not lose a root to the ring's bound). None by default.
+        self.reporters: List[Callable[[Span], None]] = []
 
     def set_sample_rate(self, rate: float):
         self.sample_rate = min(1.0, max(0.0, float(rate)))
 
-    def span(self, name: str, **tags):
+    def span(self, name: str, start_ns: Optional[int] = None, **tags):
         """New span: child of the current span when one is active, else a
         sampling-gated new root. Entry points (query execute, session
-        calls, rpc dispatch) use this; internals use child_span."""
+        calls, rpc dispatch) use this; internals use child_span.
+        `start_ns` backdates it to a stamp taken before it could be
+        opened (a round whose first phase decides whether it is one)."""
         parent = getattr(self._local, "current", None)
         if parent is None:
             rate = self.sample_rate
             if rate <= 0.0 or (rate < 1.0 and _random.random() >= rate):
                 return NOOP_SPAN
-            return Span(name, self, None, tags)
-        return Span(name, self, parent, tags)
+        return Span(name, self, parent, tags, start_ns=start_ns)
 
     def child_span(self, name: str, start_ns: Optional[int] = None,
                    cpu_start_ns: Optional[int] = None, **tags):
@@ -693,12 +700,13 @@ class Tracer:
         return Span(name, self, None, tags, remote=ctx, start_ns=start_ns,
                     cpu_start_ns=cpu_start_ns)
 
-    def background_span(self, name: str, **tags):
+    def background_span(self, name: str, start_ns: Optional[int] = None,
+                        **tags):
         """Root of background work nobody's request waits on (the
         mediator's tick): sampling-gated like `span`, and detailed — a
         few such roots a minute can afford what a request root cannot.
         A child, and its parent's kind, when some span is active."""
-        sp = self.span(name, **tags)
+        sp = self.span(name, start_ns=start_ns, **tags)
         if sp.sampled and sp._parent is None:
             sp.detailed = True
         return sp
@@ -733,6 +741,8 @@ class Tracer:
                 self.runtime.root_ended(span)
             with self._lock:
                 self._recent.append(span)
+            for report in self.reporters:
+                report(span)
 
     def recent_traces(self, trace_id: Optional[int] = None) -> List[dict]:
         with self._lock:
@@ -754,8 +764,8 @@ def child_span(name: str, **tags):
     return TRACER.child_span(name, **tags)
 
 
-def background_span(name: str, **tags):
-    return TRACER.background_span(name, **tags)
+def background_span(name: str, start_ns: Optional[int] = None, **tags):
+    return TRACER.background_span(name, start_ns=start_ns, **tags)
 
 
 def detail() -> Optional[Span]:

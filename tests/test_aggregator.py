@@ -224,6 +224,52 @@ class TestLeaderFollower:
         old_times = {m.time_nanos for m in cap_a.by_id(mid)}
         assert all(m.time_nanos not in old_times for m in new)
 
+    def test_a_round_walks_the_elems_once_a_target(self, monkeypatch):
+        """A flush loop checks every second; the series are walked when
+        the flush target (leader) or the leader's persisted flush time
+        (follower) has moved, not at every check — and the windows come
+        out as they did."""
+        from m3_tpu.aggregator.list import MetricList
+
+        walks = {"collect_into": 0, "collect": 0}
+        for name in walks:
+            real = getattr(MetricList, name)
+
+            def spy(self, *a, _real=real, _name=name, **kw):
+                walks[_name] += 1
+                return _real(self, *a, **kw)
+            monkeypatch.setattr(MetricList, name, spy)
+        store = cluster_kv.MemStore()
+        clock = SettableClock(100 * S)
+        cap_a, cap_b = CaptureHandler(), CaptureHandler()
+        agg_a, el_a = self._mk(store, clock, "a", cap_a)
+        agg_b, el_b = self._mk(store, clock, "b", cap_b)
+        mid = b"ha_metric"
+        md = meta(PipelineMetadata(0, (TEN_S,)))
+        for i in range(3):
+            agg_a.add_untimed(MetricUnion.counter(mid, 1), md)
+            agg_b.add_untimed(MetricUnion.counter(mid, 1), md)
+            for _ in range(10):     # ten checks a window
+                clock.advance(1 * S)
+                agg_a.flush()
+                agg_b.flush()
+        assert el_a.state == ElectionState.LEADER
+        assert len(cap_a.by_id(mid)) == 3 and not cap_b.by_id(mid)
+        # one shard holds the series, one list: of thirty rounds an
+        # instance, one walk a target (the first round's, which closes
+        # nothing, and the three windows')
+        assert walks == {"collect_into": 4, "collect": 4}
+        # the follower takes over and emits what the leader had not
+        el_a.resign()
+        clock.advance(31 * S)
+        agg_b.add_untimed(MetricUnion.counter(mid, 1), md)
+        clock.advance(10 * S)
+        agg_b.flush()
+        agg_b.flush()
+        assert el_b.state == ElectionState.LEADER
+        assert len(cap_b.by_id(mid)) == 1
+        assert walks["collect_into"] == 5
+
 
 class TestFlushTimesIsolation:
     def test_multi_resolution_across_shards_no_double_flush(self):

@@ -116,6 +116,70 @@ def test_the_namespace_list_cell_ships_what_it_names():
     assert sizes == {"default": "20m", "metrics_1m_72h": "2h"}
 
 
+def test_the_aggregation_tier_cell_ships_what_it_names():
+    """`aggtier-query-live` (PR 51): its configuration's deployment kind,
+    its mix's set-up, its checks and its reference are files with their
+    functions, the traffic kind is the accepted one, and the
+    configuration states the topology and the guarantees the issue asks
+    of it."""
+    with open(os.path.join(BENCH_DIR, "configs",
+                           "m3-aggtier-prom-4k.json")) as f:
+        cfg = json.load(f)
+    with open(os.path.join(BENCH_DIR, "traffic",
+                           "prom-live-agg-12h.json")) as f:
+        traffic = json.load(f)
+    assert cfg["deployment_kind"] == "aggregator-tier"
+    assert traffic["kind"] == "query_under_write"
+    assert traffic["setup"]["via"] == "filesets-aggtier"
+    assert traffic["reference"] == "aggtier_ref"
+    assert traffic["checks"] == ["query_answers_agg_frontier",
+                                 "tier_readback", "write_pace",
+                                 "mixed_readback", "served_path_verdict"]
+    for kind, name in (("deployments", "aggregator-tier"),
+                       ("setups", "filesets-aggtier"),
+                       ("reference", "aggtier_ref"),
+                       ("checks", "query_answers_agg_frontier"),
+                       ("checks", "tier_readback")):
+        assert os.path.isfile(os.path.join(BENCH_DIR, kind, name + ".py"))
+    with open(os.path.join(BENCH_DIR, "reference", "aggtier_ref.py")) as f:
+        src = f.read()
+    assert "import m3_tpu" not in src and "from m3_tpu" not in src
+    for key in ("source", "reduced", "assumed", "guarantees", "held",
+                "resolver_rule", "aggregation", "run_values",
+                "source_values", "kv", "aggregators"):
+        assert cfg[key], key
+    assert len(cfg["source"]) <= 200 and cfg["architecture"] is None
+    assert {"aggregation", "delivery", "handoff", "lateness",
+            "resolution"} <= set(cfg["guarantees"])
+    assert cfg["reduced"] == [
+        "aggregated_history_held", "unaggregated_retention",
+        "unaggregated_history_held", "replication_factor",
+        "aggregator_shard_sets"]
+    # never cut: the fleet, the front, the placement, the rule
+    assert (cfg["scale"], cfg["cadence_s"], cfg["dbnode"]["num_shards"],
+            len(cfg["schema"]["fields"]), len(cfg["schema"]["tags"]["order"])
+            ) == (4000, 10, 64, 10, 10)
+    assert [(a["instance_id"], a["num_shards"], a["shard_set_id"])
+            for a in cfg["aggregators"]] == [("agg0", 64, "shardset-0"),
+                                             ("agg1", 64, "shardset-0")]
+    assert (traffic["senders"], traffic["samples_per_send"]) == (8, 500)
+    # the window's first scrape step begins 50 s past a minute boundary
+    assert (traffic["setup"]["load_steps"] + 1) * cfg["cadence_s"] % 60 == 50
+    with open(os.path.join(ROOT_DIR, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    cell = next(w for w in bench["workloads"]
+                if w["name"] == "aggtier-query-live")
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        "m3-aggtier-prom-4k", "prom-live-agg-12h", 1)
+    own = [m["name"] for m in bench["per_layer"]
+           if m["layer"] == "aggregation tier"]
+    assert own == ["agg_client_us_per_sample", "agg_add_us_per_sample",
+                   "agg_flush_s", "agg_sink_us_per_row",
+                   "agg_msg_ack_ms_p95", "agg_staleness_s",
+                   "agg_redeliveries_in_window", "agg_cpu_share"]
+    assert len(bench["per_layer"]) <= 128
+
+
 def _readings():
     """(kind, entry, cell) for every metric of BENCHMARK.json, once for
     each cell that reports it (an entry with no list: every cell): a

@@ -753,6 +753,79 @@ class TestBootstrapOracle:
             t, v = db2.read(NS, sid, 0, now["t"] + 1)
             assert v.tolist() == [want], f"acked async write lost: {sid!r}"
 
+    def test_unchanged_bucket_keeps_its_snapshot_and_recovers(
+            self, tmp_path, rng):
+        """A tick snapshots only the buckets that took a row since their
+        last snapshot: the others keep the one on disk (an older version
+        and WAL position, through cleanup), and a kill -9 after it loses
+        nothing of either kind."""
+        root = str(tmp_path)
+        now = {"t": T0 + xtime.MINUTE}
+        log = cl.CommitLog(os.path.join(root, "cl"))
+        db = Database(ShardSet(4), commitlog=log, clock=lambda: now["t"])
+        db.create_namespace(NS, NamespaceOptions(index_enabled=False))
+        pm = PersistManager(os.path.join(root, "data"))
+        ids = [b"keep-%03d" % i for i in range(40)]
+        vals = rng.standard_normal(40)
+        db.write_batch(NS, ids, np.full(40, T0, np.int64), vals)
+        log.flush()
+        mediator = Mediator(db, pm)
+        buckets = mediator.snapshot(now["t"])
+        assert buckets == 4
+        assert mediator.snapshot(now["t"]) == 0     # nothing appended
+        db.write_batch(NS, ids[:1], np.full(1, T0 + xtime.SECOND, np.int64),
+                       np.array([5.5]))
+        log.flush()
+        assert mediator.snapshot(now["t"]) == 1     # that row's bucket
+        mediator.cleanup(now["t"])
+        ns = db.namespace(NS)
+        versions = sorted(v for sid in ns.shards
+                          for _bs, v, _p in pm.list_snapshots(NS, sid))
+        assert versions == [1, 1, 1, 3]     # one a bucket, the newest
+        # abandoned without close(): on-disk state == SIGKILL
+        db2 = _recover(root, pm, now, 4, "chain")
+        for sid, want in zip(ids, vals):
+            t, v = db2.read(NS, sid, 0, now["t"] + xtime.HOUR)
+            assert v.tolist() == ([want, 5.5] if sid == ids[0] else [want])
+
+    def test_cleanup_reads_a_digest_chain_only_where_it_decides(
+            self, tmp_path, rng, monkeypatch):
+        """One listing a shard; a snapshot volume seen complete is not
+        read again at the next tick; a newer volume without its
+        checkpoint supersedes nothing and is left alone."""
+        from m3_tpu.storage import mediator as mediator_mod
+
+        root = str(tmp_path)
+        now = {"t": T0 + xtime.MINUTE}
+        log = cl.CommitLog(os.path.join(root, "cl"))
+        db = Database(ShardSet(4), commitlog=log, clock=lambda: now["t"])
+        db.create_namespace(NS, NamespaceOptions(index_enabled=False))
+        pm = PersistManager(os.path.join(root, "data"))
+        ids = [b"keep-%03d" % i for i in range(40)]
+        mediator = Mediator(db, pm)
+        for k in range(2):
+            db.write_batch(NS, ids, np.full(40, T0 + k * xtime.SECOND,
+                                            np.int64),
+                           rng.standard_normal(40))
+            log.flush()
+            assert mediator.snapshot(now["t"]) == 4
+        ns = db.namespace(NS)
+        torn = pm.list_snapshots(NS, 2)[-1][2]      # shard 2's version 2
+        os.remove(os.path.join(torn, pfs.CHECKPOINT_FILE))
+        checked = []
+        real = mediator_mod.fileset_complete
+        monkeypatch.setattr(mediator_mod, "fileset_complete",
+                            lambda d: checked.append(d) or real(d))
+        assert mediator.cleanup(now["t"]) == 3      # three version 1s
+        assert len(checked) == 8
+        versions = {sid: [v for _bs, v, _p in pm.list_snapshots(NS, sid)]
+                    for sid in ns.shards}
+        assert versions == {0: [2], 1: [2], 2: [1], 3: [2]}
+        assert os.path.isdir(torn)
+        del checked[:]
+        assert mediator.cleanup(now["t"]) == 0
+        assert checked == [torn]        # the others were seen complete
+
     def test_skipped_replay_is_surfaced(self, tmp_path, rng):
         """Satellite: no shard_lookup + a partial shard set must COUNT
         the skip, and surface it on the BootstrapResult notes."""
